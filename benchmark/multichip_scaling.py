@@ -501,6 +501,9 @@ def run(per_chip: int = PER_CHIP, steps: int = STEPS,
 
 
 def main():
+    from mxnet_tpu import program_store
+
+    program_store.enable_persistent_cache(min_compile_secs=1)
     argv = sys.argv[1:]
 
     def _val(flag, default):

@@ -179,6 +179,9 @@ def run() -> dict:
 
 
 def main():
+    from mxnet_tpu import program_store
+
+    program_store.enable_persistent_cache(min_compile_secs=1)
     res = {"pipeline": run()}
     if "--json" in sys.argv:
         print(json.dumps(res), flush=True)

@@ -268,7 +268,14 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
 
     for _, (arr, ct) in leaf_grads.items():
         req = getattr(arr, "_ag_grad_req", "null")
-        if req == "null" or arr._grad is None:
+        if req == "null":
+            continue
+        if arr._grad is None:
+            # the buffer was released (Parameter._release_grad, the
+            # compiled train step): the tape re-creates it
+            from .ndarray import ndarray as _nd
+
+            arr._grad = _nd._wrap(ct.astype(arr._data.dtype), arr.ctx)
             continue
         ct = ct.astype(arr._grad._data.dtype) if ct.dtype != arr._grad._data.dtype else ct
         if req == "add":

@@ -154,8 +154,9 @@ class TrainStep:
         trainer.step(batch_size)
 
     Parameter ``.grad()`` buffers are NOT materialized on the compiled
-    path (gradients live only inside the program); the eager fallback
-    writes them as usual.
+    path (gradients live only inside the program, and the step releases
+    the buffers — ``Parameter._release_grad``); the eager fallback
+    re-creates and writes them as usual.
     """
 
     def __init__(self, net, loss_fn: Callable, trainer, bucket: bool = False,
@@ -256,6 +257,17 @@ class TrainStep:
             return sh is not None and len(sh.device_set) > 1
         return False
 
+    def _platform(self) -> str:
+        """The platform the step runs on — the mesh's, else the
+        parameters' context's (where :meth:`_prep` places every operand)
+        — not the process default."""
+        if self._mesh is not None:
+            return self._mesh.devices.flat[0].platform
+        for p in self._trainer._params:
+            if p._data is not None:
+                return p.data().ctx.jax_device.platform
+        return current_context().jax_device.platform
+
     def _resolve_mesh(self):
         if not self._mesh_resolved:
             from .parallel import spmd as _spmd
@@ -335,6 +347,13 @@ class TrainStep:
             opt._index_update_count.clear()
             opt._index_update_count.update(count_snap[0])
             opt.num_update = count_snap[1]
+            if self._platform() != "cpu":
+                # on an accelerator a step that cannot compile or
+                # dispatch (Mosaic refusal, HBM OOM, operand/executable
+                # device mismatch) must never become a slow run that
+                # exits 0: only the DECLARED ineligible set-ups
+                # (_eligibility) take the eager tape there
+                raise
             self.fallback_reason = f"{type(e).__name__}: {e}"
             self.last_fallback_reason = self.fallback_reason
             _telemetry.event("fallback", "cached_step",
@@ -518,7 +537,7 @@ class TrainStep:
         if gt is not None:
             named = {}
             for n, p in self._net.collect_params().items():
-                if p.grad_req != "null" and p._grad is not None:
+                if p.grad_req != "null":
                     named[n] = p.grad()._data
             for n, g in gt(dict(named)).items():
                 if named.get(n) is not g:
@@ -575,62 +594,82 @@ class TrainStep:
                 slot_of_name[n] = i
         frozen_names = [n for n in names if n not in slot_of_name]
 
-        mesh = self._mesh
-        rep = None
-        if mesh is not None:
-            from .parallel import spmd as _spmd
+        from .parallel import spmd as _spmd
 
+        mesh = self._mesh
+        if mesh is not None:
             rep = _spmd.replicated(mesh)
             model_axes = _spmd.model_axes_active(mesh)
-            name_of = {id(p): n for n, p in params.items()}
+        else:
+            # one chip is the one-device case of the same code: every
+            # operand is placed on the step's device (the parameters'
+            # context) explicitly, so a host-committed array is
+            # re-placed (counted in spmd.reshard) instead of deciding
+            # where the program runs
+            owner = trainable[0] if trainable else next(
+                iter(params.values()), None)
+            ctx = owner.data().ctx if owner is not None \
+                else current_context()
+            rep = jax.sharding.SingleDeviceSharding(ctx.jax_device)
+            model_axes = False
+        name_of = {id(p): n for n, p in params.items()}
 
-            def _sharding_of(shape, pname=None):
-                # any model axis present (fsdp/pp/ep): per-leaf
-                # name+shape-aware placement — pp packed stage buffers
-                # and ep expert weights by NAME, then the ZeRO rule
-                # (largest divisible dim, small/indivisible leaves
-                # replicate — the latter loudly); otherwise the classic
-                # replicated KVStore-broadcast layout
-                if model_axes:
-                    return _spmd.param_sharding(tuple(shape), mesh,
-                                                name=pname)
-                return rep
+        def _sharding_of(shape, pname=None):
+            # any model axis present (fsdp/pp/ep): per-leaf
+            # name+shape-aware placement — pp packed stage buffers
+            # and ep expert weights by NAME, then the ZeRO rule
+            # (largest divisible dim, small/indivisible leaves
+            # replicate — the latter loudly); otherwise the classic
+            # replicated KVStore-broadcast layout
+            if model_axes:
+                return _spmd.param_sharding(tuple(shape), mesh,
+                                            name=pname)
+            return rep
 
-            def _place_nd(d, sh=None):
-                new = _spmd.ensure_placed(
-                    d._data, sh if sh is not None else rep)
-                if new is not d._data:
-                    d._set_data(new)
+        def _place_nd(d, sh=None):
+            new = _spmd.ensure_placed(
+                d._data, sh if sh is not None else rep)
+            if new is not d._data:
+                d._set_data(new)
 
-            def _place_state(s, wshape, wsh):
-                # optimizer-state leaves SHAPED like their weight
-                # (momentum, Adam moments, the fp32 master copy) shard
-                # with it — that is the ZeRO part of FSDP; scalars and
-                # odd-shaped leaves replicate
-                if s is None:
-                    return
-                if hasattr(s, "_set_data"):
-                    same = tuple(s.shape) == tuple(wshape)
-                    _place_nd(s, wsh if same else rep)
-                    return
-                for x in s:
-                    _place_state(x, wshape, wsh)
+        def _place_state(s, wshape, wsh):
+            # optimizer-state leaves SHAPED like their weight
+            # (momentum, Adam moments, the fp32 master copy) shard
+            # with it — that is the ZeRO part of FSDP; scalars and
+            # odd-shaped leaves replicate
+            if s is None:
+                return
+            if hasattr(s, "_set_data"):
+                same = tuple(s.shape) == tuple(wshape)
+                _place_nd(s, wsh if same else rep)
+                return
+            for x in s:
+                _place_state(x, wshape, wsh)
 
-            # one-time placement (the KVStore init/broadcast analog):
-            # steady state sees already-placed buffers — the step's
-            # outputs carry the same shardings back into the
-            # parameters, so reshard_count stays flat after warmup
-            for p in trainable:
-                _place_nd(p.data(), _sharding_of(p.data().shape,
-                                                 name_of.get(id(p))))
-            for n in frozen_names:
-                _place_nd(params[n].data(),
-                          _sharding_of(params[n].data().shape, n))
-            for p, s in zip(trainable, states):
-                _place_state(s, p.data().shape,
-                             _sharding_of(p.data().shape,
-                                          name_of.get(id(p))))
+        # one-time placement (the KVStore init/broadcast analog):
+        # steady state sees already-placed buffers — the step's
+        # outputs carry the same shardings back into the
+        # parameters, so reshard_count stays flat after warmup
+        for p in trainable:
+            _place_nd(p.data(), _sharding_of(p.data().shape,
+                                             name_of.get(id(p))))
+        for n in frozen_names:
+            _place_nd(params[n].data(),
+                      _sharding_of(params[n].data().shape, n))
+        for p, s in zip(trainable, states):
+            _place_state(s, p.data().shape,
+                         _sharding_of(p.data().shape,
+                                      name_of.get(id(p))))
 
+        # gradients live only inside the program: a Parameter's grad
+        # buffer (single-device zeros on the first device) would be a
+        # second model there that nothing reads; the eager tape
+        # re-creates it on its next backward
+        for p in trainable:
+            if p._grad is not None:
+                p._release_grad()
+
+        if mesh is not None:
             # per-device memory accounting (gauges
             # spmd.param_bytes_per_device / spmd.opt_bytes_per_device):
             # computed from the placed leaves' ACTUAL shardings, so the
@@ -641,13 +680,17 @@ class TrainStep:
                 [l for s in states
                  for l in jax.tree_util.tree_leaves(_fused._unwrap(s))])
 
+        # donation aliases the old weight/optimizer-state HBM into the
+        # outputs — the whole point of the fused step on chip; decided
+        # by the platform the step RUNS on, not the process default
+        platform = next(iter(rep.device_set)).platform
         return SimpleNamespace(
             opt=opt, scaler=scaler, updater=updater, params=params,
             names=names, trainable=trainable, indices=indices,
             states=states, group_layout=group_layout,
             slot_of_name=slot_of_name, frozen_names=frozen_names,
             mesh=mesh, rep=rep, has_ok=scaler is not None,
-            donate=jax.default_backend() not in ("cpu",))
+            donate=platform != "cpu")
 
     def _signature(self, prep, in_struct_key, in_specs, ctx, flavor):
         """The program-cache key: input structure + shapes/dtypes ×
@@ -707,19 +750,13 @@ class TrainStep:
             with self._mesh_ctx(prep.mesh):
                 if kind == "full":
                     jitted, out_struct, mutated_names = \
-                        self._build_program(
-                            prep.params, prep.names, in_struct, ctx,
-                            flavor, prep.slot_of_name, prep.frozen_names,
-                            prep.group_layout, prep.has_ok, prep.donate)
+                        self._build_program(prep, in_struct, ctx, flavor)
                 elif kind == "grad":
                     jitted, out_struct, mutated_names = \
-                        self._build_grad_program(
-                            prep.params, prep.names, in_struct, ctx,
-                            flavor, prep.slot_of_name, prep.frozen_names,
-                            prep.has_ok, prep.donate)
+                        self._build_grad_program(prep, in_struct, ctx,
+                                                 flavor)
                 else:
-                    jitted = self._build_update_program(
-                        prep.group_layout, prep.has_ok, prep.donate)
+                    jitted = self._build_update_program(prep)
                     out_struct, mutated_names = None, ()
                 rec = _pstore.build(
                     "train_step", jitted, lower_args,
@@ -924,15 +961,16 @@ class TrainStep:
         s_args = tuple(_fused._unwrap(s) for s in states)
         frozen_args = [prep.params[n].data()._data
                        for n in prep.frozen_names]
-        if mesh is not None:
-            from .parallel import spmd as _spmd
+        from .parallel import spmd as _spmd
 
+        if mesh is not None:
             # batch leaves shard over 'dp' (legalized: an indivisible
             # batch axis replicates, loudly).  Leaves the prefetcher
             # already staged with this sharding pass through untouched.
             in_args = [_spmd.put_batch(l._data, mesh) for l in in_leaves]
         else:
-            in_args = [l._data for l in in_leaves]
+            in_args = [_spmd.ensure_placed(l._data, rep)
+                       for l in in_leaves]
 
         # sentinel cadence: the traced want_digest flag selects the
         # in-program lax.cond digest branch — value changes never
@@ -1046,10 +1084,8 @@ class TrainStep:
         bufs = []
         for p in prep.trainable:
             w = p.data()._data
-            z = jnp.zeros(w.shape, w.dtype)
-            if prep.mesh is not None:
-                z = jax.device_put(z, w.sharding)
-            bufs.append(z)
+            bufs.append(jax.device_put(jnp.zeros(w.shape, w.dtype),
+                                       w.sharding))
         self._accum_bufs = bufs
         self._accum_key = key
         self._accum_i = 0
@@ -1110,12 +1146,13 @@ class TrainStep:
         w_args = [p.data()._data for p in prep.trainable]
         frozen_args = [prep.params[n].data()._data
                        for n in prep.frozen_names]
-        if mesh is not None:
-            from .parallel import spmd as _spmd
+        from .parallel import spmd as _spmd
 
+        if mesh is not None:
             in_args = [_spmd.put_batch(l._data, mesh) for l in in_leaves]
         else:
-            in_args = [l._data for l in in_leaves]
+            in_args = [_spmd.ensure_placed(l._data, rep)
+                       for l in in_leaves]
         g_call = (w_args, frozen_args, list(self._accum_bufs), in_args,
                   _random.next_key(),
                   jnp.asarray(s_clean, jnp.float32),
@@ -1210,6 +1247,34 @@ class TrainStep:
                 scaler.update_scale(overflow)
         return loss
 
+    @staticmethod
+    def _pinned(prep):
+        """``(weights, optimizer state)`` output shardings = the
+        placements :meth:`_prep` just gave the inputs.  Left to the
+        partitioner, a small replicated leaf can come back sharded over
+        ``fsdp`` (or the reverse): ``_prep`` would then re-place it
+        EVERY step (a steady-state reshard) and its donated buffer
+        could not alias."""
+        from .optimizer import fused as _fused
+
+        def sh(tree):
+            return jax.tree_util.tree_map(lambda a: a.sharding, tree)
+
+        return (sh([p.data()._data for p in prep.trainable]),
+                tuple(sh(_fused._unwrap(s)) for s in prep.states))
+
+    @staticmethod
+    def _pin_mutations(prep, mutated_names, muts):
+        """Same pin for forward-mutated parameters (BN running stats).
+        Their names are only known once the forward has traced, so the
+        pin is a constraint inside the program, not an ``out_shardings``
+        entry."""
+        if prep.mesh is None:
+            return muts
+        return [jax.lax.with_sharding_constraint(
+                    m, prep.params[n].data()._data.sharding)
+                for n, m in zip(mutated_names, muts)]
+
     def _grad_hook(self, slot_of_name):
         """The net-level compiled gradient hook: a net exposing
         ``compiled_grad_transform(named_grads) -> named_grads`` (e.g.
@@ -1253,11 +1318,13 @@ class TrainStep:
         at = (at * aux_w).astype(jnp.float32)
         return list(heads) + [at * scale_eff if has_ok else at]
 
-    def _build_grad_program(self, params, names, in_struct, ctx, flavor,
-                            slot_of_name, frozen_names, has_ok, donate):
+    def _build_grad_program(self, prep, in_struct, ctx, flavor):
         """The accumulation-window microbatch program: forward + vjp
         only, adding this microbatch's (scaled) grads into the DONATED
         accumulator buffers — no optimizer math, no state touched."""
+        params, names = prep.params, prep.names
+        slot_of_name, frozen_names = prep.slot_of_name, prep.frozen_names
+        has_ok, donate = prep.has_ok, prep.donate
         from .gluon import block as _gb
 
         from .parallel import moe as _moe
@@ -1295,16 +1362,21 @@ class TrainStep:
                      for g, w in zip(grads, w_list)]
             grads = self._apply_grad_transform(slot_names, gtrans, grads)
             new_acc = [a + g for a, g in zip(acc_list, grads)]
-            return outs, muts, new_acc
+            return (outs, self._pin_mutations(prep, mutated_names, muts),
+                    new_acc)
 
-        jitted = jax.jit(grad_fn, donate_argnums=(2,) if donate else ())
+        w_sh, _s_sh = self._pinned(prep)
+        jitted = jax.jit(grad_fn, donate_argnums=(2,) if donate else (),
+                         out_shardings=(None, None, w_sh))
         return (jitted, out_struct, mutated_names)
 
-    def _build_update_program(self, group_layout, has_ok, donate):
+    def _build_update_program(self, prep):
         """The window-closing program: ONE fused optimizer update from
         the accumulated grads (overflow detected on the SUM), the
         sentinel digest cond, and freshly ZEROED accumulators returned
         in the donated buffers so the next window starts clean."""
+        group_layout, has_ok, donate = (prep.group_layout, prep.has_ok,
+                                        prep.donate)
         from .optimizer import fused as _fused
 
         opt = self._trainer._optimizer
@@ -1347,8 +1419,10 @@ class TrainStep:
             new_acc = [jnp.zeros_like(a) for a in acc_list]
             return new_w, tuple(new_s), new_acc, ok, dig
 
+        w_sh, s_sh = self._pinned(prep)
         return jax.jit(update_fn,
-                       donate_argnums=(0, 1, 2) if donate else ())
+                       donate_argnums=(0, 1, 2) if donate else (),
+                       out_shardings=(w_sh, s_sh, w_sh, None, None))
 
     def _grad_lower_args(self, prep, in_specs):
         """Abstract lowering args for the microbatch grad program
@@ -1399,14 +1473,16 @@ class TrainStep:
         return (w_args, s_args, list(self._accum_bufs),
                 list(g32), list(g32), list(g32), f32, f32, prev_ok, want)
 
-    def _build_program(self, params, names, in_struct, ctx, flavor,
-                       slot_of_name, frozen_names, group_layout, has_ok,
-                       donate):
+    def _build_program(self, prep, in_struct, ctx, flavor):
         from .gluon import block as _gb
         from .optimizer import fused as _fused
 
         from .parallel import moe as _moe
 
+        params, names = prep.params, prep.names
+        slot_of_name, frozen_names = prep.slot_of_name, prep.frozen_names
+        group_layout, has_ok, donate = (prep.group_layout, prep.has_ok,
+                                        prep.donate)
         net, loss_fn = self._net, self._loss_fn
         opt = self._trainer._optimizer
         raw_fwd, out_struct, mutated_names = _gb._stage_fn(
@@ -1487,11 +1563,11 @@ class TrainStep:
                 lambda: _sentinel.program_digest(new_w, state_leaves,
                                                  grads),
                 _sentinel.zero_digest)
-            return outs, muts, new_w, tuple(new_s), ok, dig
+            return (outs, self._pin_mutations(prep, mutated_names, muts),
+                    new_w, tuple(new_s), ok, dig)
 
-        # donation aliases the old weight/optimizer-state HBM into the
-        # outputs — the whole point of the fused step on chip; CPU has no
-        # donation support and would only warn
+        w_sh, s_sh = self._pinned(prep)
         jitted = jax.jit(step_fn,
-                         donate_argnums=(0, 1) if donate else ())
+                         donate_argnums=(0, 1) if donate else (),
+                         out_shardings=(None, None, w_sh, s_sh, None, None))
         return (jitted, out_struct, mutated_names)

@@ -6,10 +6,18 @@ TPU: the first-class accelerator is ``mx.tpu(i)`` backed by a JAX/PJRT device.
 ``mx.gpu(i)`` is accepted as an alias for ``mx.tpu(i)`` so reference scripts
 run unchanged (the north-star requirement).
 
-A ``Context`` resolves lazily to a concrete ``jax.Device``; when the requested
-platform is unavailable (e.g. tests forced onto CPU via ``JAX_PLATFORMS=cpu``)
-it falls back to the default JAX backend with a one-time warning, the way the
-reference falls back from gpu to cpu in ``test_utils.default_context`` usage.
+A ``Context`` resolves lazily to a concrete ``jax.Device``.
+
+The DEFAULT context is the process's default JAX device: ``tpu(0)`` on a
+machine with an accelerator, ``cpu(0)`` otherwise.  (The reference defaults
+to ``cpu(0)`` and expects ``ctx=mx.gpu(0)`` everywhere; on a TPU host that
+default commits every ``mx.nd.array(numpy)`` to the HOST cpu device, and one
+host-committed operand pulls a whole jitted program off the chip — silently.)
+``mx.cpu()`` still names the host explicitly.
+
+``tpu(i)`` with no accelerator present resolves to the CPU (one-time warning)
+ONLY when ``JAX_PLATFORMS`` names ``cpu`` explicitly — the test suite's
+setting; under any other setting a missing accelerator raises.
 """
 from __future__ import annotations
 
@@ -75,14 +83,17 @@ class Context:
 
     # --- context-manager protocol: `with mx.tpu(0):` sets default ctx ---
     def __enter__(self):
-        if not hasattr(Context._default_ctx, "value"):
-            Context._default_ctx.value = Context("cpu", 0)
-        self._old_ctx = Context._default_ctx.value
+        # None = the lazy default (current_context resolves it from the
+        # JAX backend on first use; entering a scope must not open one)
+        self._old_ctx = getattr(Context._default_ctx, "value", None)
         Context._default_ctx.value = self
         return self
 
     def __exit__(self, ptype, value, trace):
-        Context._default_ctx.value = self._old_ctx
+        if self._old_ctx is None:
+            del Context._default_ctx.value
+        else:
+            Context._default_ctx.value = self._old_ctx
 
     # --- JAX resolution ---
     @property
@@ -123,11 +134,18 @@ def _resolve_device(devtype: str, device_id: int) -> "jax.Device":
     # tpu (or alias)
     platform = _accelerator_platform()
     if platform is None:
+        requested = (jax.config.jax_platforms or "").lower().split(",")
+        if requested[0].strip() != "cpu":
+            raise RuntimeError(
+                f"tpu({device_id}) requested but JAX found no accelerator "
+                f"(default backend 'cpu', JAX_PLATFORMS="
+                f"{jax.config.jax_platforms!r}).  Set JAX_PLATFORMS=cpu to "
+                "run tpu() contexts on the host deliberately.")
         if "tpu" not in _warned_fallback:
             _warned_fallback.add("tpu")
             warnings.warn(
-                "No accelerator platform available; tpu() falls back to CPU "
-                "(expected under JAX_PLATFORMS=cpu test runs)."
+                "JAX_PLATFORMS names cpu and no accelerator is present; "
+                "tpu() resolves to the host CPU."
             )
         devs = _platform_devices("cpu")
         return devs[min(device_id, len(devs) - 1)]
@@ -168,6 +186,9 @@ def num_gpus() -> int:
 
 
 def current_context() -> Context:
+    """The thread's default context: whatever an enclosing ``with ctx:``
+    set, else the default JAX device's context (module docstring)."""
     if not hasattr(Context._default_ctx, "value"):
-        Context._default_ctx.value = Context("cpu", 0)
+        Context._default_ctx.value = Context(
+            "cpu" if _accelerator_platform() is None else "tpu", 0)
     return Context._default_ctx.value

@@ -857,7 +857,9 @@ def _spawn_replica(scen_dir: str, label: str, boot_timeout: float = 120.0
     """Launch a ``replica`` child and wait for its port handshake.
     Returns ``(popen, port)``; the caller owns the process handle.
     Environment is inherited — the fleet shares ``MXNET_PROGRAM_CACHE_DIR``
-    (warm joins) and ``MXNET_TELEMETRY_DIR`` (rank-stamped shards)."""
+    (warm joins) and ``MXNET_TELEMETRY_DIR`` (rank-stamped shards) —
+    except the platform: a replica is a CPU process (a chip belongs to
+    one process, and the spawning drill may hold it)."""
     port_path = os.path.join(scen_dir, f"port-{label}.txt")
     if os.path.exists(port_path):
         os.remove(port_path)
@@ -865,7 +867,8 @@ def _spawn_replica(scen_dir: str, label: str, boot_timeout: float = 120.0
     popen = subprocess.Popen(
         [sys.executable, "-m", "mxnet_tpu.drills", "replica",
          "--dir", scen_dir, "--label", label],
-        stdout=log, stderr=subprocess.STDOUT, cwd=_REPO)
+        stdout=log, stderr=subprocess.STDOUT, cwd=_REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     deadline = time.monotonic() + boot_timeout
     while time.monotonic() < deadline:
         if os.path.exists(port_path):

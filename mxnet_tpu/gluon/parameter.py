@@ -214,6 +214,29 @@ class Parameter:
         for d, g in zip(self._data, self._grad):
             d._mark_variable(g, self._grad_req)
 
+    def _release_grad(self):
+        """Drop the gradient buffers, keeping ``grad_req``.  The compiled
+        train step keeps gradients inside its program, so the buffers would
+        be a second model in device memory that nothing reads.  The eager
+        tape re-creates a buffer on its next ``backward`` and
+        ``grad()``/``list_grad()`` adopt it (or hand out zeros)."""
+        self._grad = None
+        for d in self._data or ():
+            d._grad = None
+
+    def _live_grad(self):
+        if (self._grad is None and self._data is not None
+                and self._grad_req != "null"):
+            # released by the compiled step: adopt what the tape wrote since
+            self._grad = [
+                d._grad if d._grad is not None
+                else _wrap(jnp.zeros(d.shape, d._data.dtype), d.ctx)
+                for d in self._data
+            ]
+            for d, g in zip(self._data, self._grad):
+                d._mark_variable(g, self._grad_req)
+        return self._grad
+
     def _load_init(self, data, ctx=None, cast_dtype=False, dtype_source="current"):
         """Install loaded value (reference parameter.py:280)."""
         if isinstance(data, NDArray):
@@ -276,20 +299,21 @@ class Parameter:
         return list(self._data)
 
     def grad(self, ctx: Optional[Context] = None) -> NDArray:
-        if self._data is not None and self._grad is None:
+        if self._data is not None and self._grad_req == "null":
             raise RuntimeError(
                 f"Cannot get gradient array for Parameter '{self._name}' "
                 "because grad_req='null'"
             )
-        return self._check_and_get(self._grad, ctx)
+        return self._check_and_get(self._live_grad(), ctx)
 
     def list_grad(self) -> List[NDArray]:
-        if self._data is not None and self._grad is None:
+        if self._data is not None and self._grad_req == "null":
             raise RuntimeError(
                 f"Cannot get gradient array for Parameter '{self._name}' "
                 "because grad_req='null'"
             )
-        self._check_and_get(self._grad, None if not self._ctx_list or
+        self._check_and_get(self._live_grad(),
+                            None if not self._ctx_list or
                             len(self._ctx_list) == 1 else self._ctx_list[0])
         return list(self._grad)
 
@@ -319,6 +343,10 @@ class Parameter:
 
     def zero_grad(self):
         if self._grad is None:
+            # grad_req='null', or released (_release_grad): dropping what
+            # the tape re-created since IS the zeroing
+            for d in self._data or ():
+                d._grad = None
             return
         for g in self._grad:
             g._set_data(jnp.zeros(g.shape, g._data.dtype))

@@ -148,7 +148,13 @@ class Trainer:
                 idx = self._param2idx[id(param)]
                 value = param.data(param.list_ctx()[0])
                 if hasattr(self._kvstore, "init"):
-                    self._kvstore.init(idx, value)
+                    # the built-in store's copy is the SERVER-side weight.
+                    # With local updates nothing reads it (pushpull puts
+                    # the reduced gradient in its place), so it would be
+                    # a second model in device memory
+                    if self._update_on_kvstore or \
+                            not isinstance(self._kvstore, kvs.KVStore):
+                        self._kvstore.init(idx, value)
                 else:
                     # hvd-style adapters have no server-side store: param
                     # init is a rank-0 broadcast into every replica

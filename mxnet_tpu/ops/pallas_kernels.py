@@ -9,8 +9,9 @@ Reference analog: the fused transformer attention matmuls
 ``interleaved_matmul_selfatt_qk/valatt``) — which still materialized the
 full score matrix; this is the TPU-first replacement, not a translation.
 
-Off-TPU the kernels run under the Pallas interpreter (slow but exact) so
-the CPU test suite validates the same code path that runs on hardware.
+On the CPU the kernels run under the Pallas interpreter (slow but exact) so
+the CPU test suite validates the same code path that runs on hardware; any
+platform that is neither ``tpu`` nor ``cpu`` raises (:func:`_interpret`).
 
 TPU lowering constraints honored throughout (Mosaic requires the last two
 block dims divisible by (8, 128) or equal to the array dims): softmax
@@ -38,7 +39,17 @@ _NEG_INF = -1e30
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Mosaic on a TPU, the Pallas interpreter on the CPU (the test
+    suite), and nothing else: any other platform raises instead of
+    interpreting silently."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels need platform 'tpu' (Mosaic) or 'cpu' "
+        f"(interpret mode); the default JAX backend is {platform!r}")
 
 
 # ---------------------------------------------------------------------------

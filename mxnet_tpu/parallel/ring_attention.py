@@ -24,23 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map as _jax_shard_map
-except ImportError:      # this jax ships it under experimental
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
-
-def shard_map(*args, **kwargs):
-    """shard_map with the check_vma kwarg mapped onto older jax's
-    check_rep spelling (renamed upstream; semantics unchanged here)."""
-    try:
-        return _jax_shard_map(*args, **kwargs)
-    except TypeError:
-        if "check_vma" in kwargs:
-            kwargs = dict(kwargs)
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-            return _jax_shard_map(*args, **kwargs)
-        raise
+from jax import shard_map
 
 __all__ = ["ring_attention", "ring_attention_sharded", "local_attention_block"]
 

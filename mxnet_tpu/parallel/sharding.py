@@ -218,8 +218,8 @@ def _ambient_mesh():
     """The mesh jax itself already has in scope — works INSIDE a traced
     fn, where no explicit mesh was threaded through: first the classic
     ``with mesh:`` context (thread_resources physical mesh — what
-    ``mesh_scope`` enters), then the newer abstract-mesh ambient
-    (``jax.sharding.get_abstract_mesh``, private fallback on older jax).
+    ``mesh_scope`` enters), then the abstract-mesh ambient
+    (``jax.sharding.get_abstract_mesh``).
     Returns ``None`` when there is genuinely no mesh anywhere."""
     try:
         from jax._src import mesh as _jm
@@ -229,13 +229,7 @@ def _ambient_mesh():
             return pm
     except Exception:
         pass
-    get_ambient = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_ambient is None:
-        try:
-            from jax._src.mesh import get_abstract_mesh as get_ambient
-        except ImportError:
-            get_ambient = None
-    ambient = get_ambient() if get_ambient is not None else None
+    ambient = jax.sharding.get_abstract_mesh()
     if ambient is not None and getattr(ambient, "shape", None):
         return ambient
     return None

@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import os
 import time
+import traceback
 import weakref
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -62,8 +63,8 @@ from . import telemetry as _telemetry
 
 __all__ = ["Program", "Namespace", "ScopeCache", "namespace", "scope",
            "build", "count_trace", "stats", "reset_counters", "disk_stats",
-           "compile_seconds", "persistent_cache_dir", "version_fingerprint",
-           "NAMESPACES"]
+           "compile_seconds", "persistent_cache_dir", "cache_dir",
+           "enable_persistent_cache", "version_fingerprint", "NAMESPACES"]
 
 
 def version_fingerprint() -> Tuple[str, str, str]:
@@ -86,7 +87,6 @@ def version_fingerprint() -> Tuple[str, str, str]:
 # With the cache disabled neither moves.
 _DISK = {"hits": 0, "misses": 0, "requests": 0,
          "compile_time_saved_s": 0.0, "retrieval_s": 0.0}
-_ENABLED_BY_US = False
 
 
 def _on_event(event: str, **_kw) -> None:
@@ -109,25 +109,56 @@ jax.monitoring.register_event_listener(_on_event)
 jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
+def _env_cache_dir() -> Optional[str]:
+    # graftlint: disable=env-discipline -- JAX_COMPILATION_CACHE_DIR is
+    # jax's knob (jax reads it itself), not ours to declare
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+
+
+def cache_dir() -> str:
+    """THE compile-cache directory — the only code in the repository
+    that names one.  ``JAX_COMPILATION_CACHE_DIR`` set: that directory,
+    and nothing is set in code (jax reads the variable itself, so the
+    cache can be placed from outside).  Otherwise
+    ``MXNET_PROGRAM_CACHE_DIR``, else ``<checkout>/.jax_cache`` derived
+    from this file — a FIXED path (the directory is part of the cache
+    key: one built from a pid, a time or ``tempfile`` never hits)."""
+    env = _env_cache_dir()
+    if env:
+        return env
+    d = _config.get("MXNET_PROGRAM_CACHE_DIR")
+    if d:
+        return os.path.expanduser(d)
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+
+
+def enable_persistent_cache(min_compile_secs: float = 0) -> str:
+    """Back every compile of this process with the persistent cache at
+    :func:`cache_dir` (what the benchmark tools and ``chip_smoke.py``
+    call; a plain ``import mxnet_tpu`` enables it only when
+    ``MXNET_PROGRAM_CACHE_DIR`` asks).  Returns the live directory."""
+    d = cache_dir()
+    if not _env_cache_dir():
+        jax.config.update("jax_compilation_cache_dir", d)
+    # persist EVERYTHING by default: the parity contract (a warm second
+    # process performs 0 fresh compiles) needs even sub-second CPU
+    # programs and tiny eager-op executables on disk
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      min_compile_secs)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return d
+
+
 def _enable_persistent() -> None:
     """Apply MXNET_PROGRAM_CACHE_DIR (off by default, enabled
     per-process).  Runs at import — before any program this framework
     emits compiles — and never overrides a cache dir the user or a
-    driver (bench.py) already configured via JAX_COMPILATION_CACHE_DIR."""
-    global _ENABLED_BY_US
-    d = _config.get("MXNET_PROGRAM_CACHE_DIR")
-    if not d or jax.config.jax_compilation_cache_dir is not None:
-        return
-    jax.config.update("jax_compilation_cache_dir", os.path.expanduser(d))
-    # persist EVERYTHING: the parity contract (a warm second process
-    # performs 0 fresh compiles) needs even sub-second CPU programs and
-    # tiny eager-op executables on disk
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:                     # knob absent on older jax
-        pass
-    _ENABLED_BY_US = True
+    driver already configured via JAX_COMPILATION_CACHE_DIR."""
+    if _config.get("MXNET_PROGRAM_CACHE_DIR") \
+            and jax.config.jax_compilation_cache_dir is None:
+        enable_persistent_cache()
 
 
 _enable_persistent()
@@ -408,6 +439,18 @@ class _loud_cache_errors:
         jax.config.update("jax_raise_persistent_cache_errors", self._prev)
 
 
+def _persistent_entry_involved(e: BaseException) -> bool:
+    """True when ``e`` is an injected ``program_store.load`` fault or
+    was raised while jax read/wrote a persistent-cache entry (its
+    traceback passes through ``jax._src.compilation_cache``) — the only
+    failures a cache-bypassing recompile can cure."""
+    if isinstance(e, _faults.FaultInjected):
+        return True
+    return any(
+        fr.f_code.co_filename.endswith("compilation_cache.py")
+        for fr, _ in traceback.walk_tb(e.__traceback__))
+
+
 def build(name: str, jitted, lower_args: Tuple, meta: Any = None,
           label: str = "") -> Program:
     """Trace + compile ``jitted`` for ``lower_args`` (concrete arrays
@@ -418,7 +461,8 @@ def build(name: str, jitted, lower_args: Tuple, meta: Any = None,
     enabled the compile step READS disk entries, and a corrupted or
     unreadable entry (or an injected fault) degrades LOUDLY to a fresh
     compile with the disk cache bypassed for this program — recorded in
-    ``load_degrades`` + the faults event log, never a crash."""
+    ``load_degrades`` + the faults event log, never a crash.  Any other
+    trace/compile failure propagates to the caller untouched."""
     ns = namespace(name)
     t0 = time.perf_counter()
     executable = None
@@ -428,26 +472,24 @@ def build(name: str, jitted, lower_args: Tuple, meta: Any = None,
             with _loud_cache_errors():
                 executable = jitted.lower(*lower_args).compile()
         except Exception as e:
+            live_dir = persistent_cache_dir()
+            if live_dir is None or not _persistent_entry_involved(e):
+                # no persistent entry was in play: a real trace/compile
+                # failure (a forward that cannot stage, a Mosaic
+                # refusal, an HBM OOM) — it propagates, never retried
+                # into a second identical failure
+                raise
             ns.bump("load_degrades")
             _faults.record_event(
                 "program_store.load", "degrade_to_recompile", e,
-                namespace=name, label=label,
-                cache_dir=persistent_cache_dir())
-            cache_dir = persistent_cache_dir()
-            if cache_dir is not None:
-                # bypass the (possibly corrupt) disk entry and compile
-                # fresh; the cache comes back for every later program
-                try:
-                    jax.config.update("jax_compilation_cache_dir", None)
-                    executable = jitted.lower(*lower_args).compile()
-                finally:
-                    jax.config.update("jax_compilation_cache_dir",
-                                      cache_dir)
-            else:
-                # no persistent entry was in play: this is a real
-                # trace/compile failure — the caller's fallback story
-                # (eager tape, single-request serving) owns it
-                raise
+                namespace=name, label=label, cache_dir=live_dir)
+            # bypass the (possibly corrupt) disk entry and compile
+            # fresh; the cache comes back for every later program
+            try:
+                jax.config.update("jax_compilation_cache_dir", None)
+                executable = jitted.lower(*lower_args).compile()
+            finally:
+                jax.config.update("jax_compilation_cache_dir", live_dir)
     ns.bump("compile_count")
     ns.bump("compile_seconds", time.perf_counter() - t0)
     return Program(executable, jitted, meta, ns)

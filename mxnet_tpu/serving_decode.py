@@ -88,6 +88,7 @@ from . import faults as _faults
 from . import preemption as _preemption
 from . import program_store as _pstore
 from . import telemetry as _telemetry
+from .context import current_context
 from .faults import ShedError
 from .serving import BucketPolicy
 
@@ -535,8 +536,13 @@ class PagePool:
             if geom not in self._storage:
                 shape = (self.pages + 1, self.page, geom[0], geom[1],
                          geom[2])
-                self._storage[geom] = [jnp.zeros(shape, dtype=dtype),
-                                       jnp.zeros(shape, dtype=dtype)]
+                # committed to the default context's device: an
+                # uncommitted buffer would follow whatever operand
+                # happens to be committed elsewhere
+                dev = current_context().jax_device
+                self._storage[geom] = [
+                    jax.device_put(jnp.zeros(shape, dtype=dtype), dev),
+                    jax.device_put(jnp.zeros(shape, dtype=dtype), dev)]
                 self._geom_locks[geom] = threading.RLock()
         return geom
 
@@ -1119,8 +1125,14 @@ class GenerativeEngine:
                  draft_params=None,
                  spec_k: Optional[Any] = None):
         self._model = model
-        self._params = (params if params is not None
-                        else model.init_params())
+        # weights, pool and every program live on ONE device — the
+        # default context's — placed explicitly: host-committed params
+        # (a numpy-loaded checkpoint) are moved here instead of pulling
+        # the decode programs onto the host
+        self._device = current_context().jax_device
+        self._params = jax.device_put(
+            params if params is not None else model.init_params(),
+            self._device)
         self._pool = pool if pool is not None else shared_pool()
         self.name = name or type(model).__name__
         self._rows = int(max_rows if max_rows is not None
@@ -1157,8 +1169,9 @@ class GenerativeEngine:
                     f"draft vocab {draft.vocab} != target vocab "
                     f"{model.vocab}: rejection sampling needs one "
                     "token space")
-            self._draft_params = (draft_params if draft_params
-                                  is not None else draft.init_params())
+            self._draft_params = jax.device_put(
+                draft_params if draft_params is not None
+                else draft.init_params(), self._device)
             self._draft_geom = self._pool.register(
                 draft.n_layers, draft.n_heads, draft.head_dim)
             self._draft_max_pages = -(-int(draft.max_seq)
@@ -2605,47 +2618,50 @@ class GenerativeEngine:
         self._spec_programs.insert(("verify", k), rec)
         return rec
 
+    def _donated(self, *argnums: int) -> Tuple[int, ...]:
+        # pool buffers update in place on an accelerator; the CPU test
+        # backend keeps donation off (the cached_step idiom)
+        return argnums if self._device.platform != "cpu" else ()
+
     @property
     def _spec_prefill_donate(self) -> Tuple[int, ...]:
-        return (4, 5) if jax.default_backend() != "cpu" else ()
+        return self._donated(4, 5)
 
     @property
     def _spec_round_donate(self) -> Tuple[int, ...]:
-        return (10, 11) if jax.default_backend() != "cpu" else ()
+        return self._donated(10, 11)
+
+    @staticmethod
+    def _spec_of(a):
+        # the spec carries the buffer's (committed) placement, so the
+        # AOT executable is compiled for the engine's device, not for
+        # whatever the process default happens to be
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
 
     def _draft_pool_specs(self):
         k, v = self._pool.storage(self._draft_geom)
-        return (jax.ShapeDtypeStruct(k.shape, k.dtype),
-                jax.ShapeDtypeStruct(v.shape, v.dtype))
+        return self._spec_of(k), self._spec_of(v)
 
     def _draft_param_specs(self):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            self._draft_params)
+        return jax.tree_util.tree_map(self._spec_of, self._draft_params)
 
     # -- shapes / specs ------------------------------------------------------
     @property
     def _donate(self) -> Tuple[int, ...]:
-        # pool buffers update in place on real devices; CPU skips
-        # donation to avoid jax's unusable-donation warning (the
-        # cached_step idiom)
-        return (8, 9) if jax.default_backend() != "cpu" else ()
+        return self._donated(8, 9)
 
     @property
     def _chunk_donate(self) -> Tuple[int, ...]:
         # chunk prefill carries (offset, length): pool buffers sit one
         # argument later
-        return (9, 10) if jax.default_backend() != "cpu" else ()
+        return self._donated(9, 10)
 
     def _pool_specs(self):
         k, v = self._pool.storage(self._geom)
-        return (jax.ShapeDtypeStruct(k.shape, k.dtype),
-                jax.ShapeDtypeStruct(v.shape, v.dtype))
+        return self._spec_of(k), self._spec_of(v)
 
     def _param_specs(self):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            self._params)
+        return jax.tree_util.tree_map(self._spec_of, self._params)
 
     @staticmethod
     def _sampling_specs():

@@ -278,6 +278,38 @@ def test_fallback_matches_eager_numerics():
                                ne.collect_params()[k].data().asnumpy()), k
 
 
+def test_compiled_step_keeps_no_grad_buffers_and_no_store_copy():
+    """Nothing reads a Parameter's grad buffer or the built-in store's
+    init copy under the compiled step; each was a model's worth of memory
+    on the first device (chip 0 of 4 held 2.1x its peers, chip run PR 21).
+    The step releases the buffers; the eager tape re-creates them."""
+    net = _mlp(8)
+    params = net.collect_params()
+    trainer = gluon.Trainer(params, "sgd", {"learning_rate": 0.1})
+    step = trainer.compile_step(net, _loss_fn)
+    x, y = _batch()
+    step(x, y, batch_size=6)
+    assert step.last_step_compiled, step.last_fallback_reason
+    assert all(p._grad is None and p.data()._grad is None
+               for p in params.values())
+    assert trainer._kvstore._data == {}
+    # a hand-written tape step on the same net: backward re-creates the
+    # buffers, grad() and Trainer.step adopt them
+    w = params["d1.weight"]
+    w0 = w.data().asnumpy().copy()
+    with mx.autograd.record():
+        loss = _loss_fn(net, x, y)
+    loss.backward()
+    g = w.grad()
+    assert g is w.data()._grad and onp.abs(g.asnumpy()).sum() > 0
+    trainer.step(6)
+    assert onp.allclose(w.data().asnumpy(), w0 - 0.1 * g.asnumpy() / 6,
+                        rtol=1e-6, atol=1e-7)
+    step(x, y, batch_size=6)                    # released again
+    assert step.last_step_compiled and w._grad is None
+    assert not w.grad().asnumpy().any()         # nothing written: zeros
+
+
 def test_grad_req_add_falls_back():
     net = _mlp(6)
     net.collect_params()["d1.weight"].grad_req = "add"

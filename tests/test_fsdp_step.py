@@ -256,6 +256,66 @@ def test_fsdp_one_launch_no_retrace_no_reshard():
         assert spmd.reshard_count() == r_warm
 
 
+def test_leaves_under_the_fsdp_floor_stay_placed_and_donate(monkeypatch):
+    """Leaves below MXNET_FSDP_MIN_SIZE replicate while their neighbours
+    shard.  Left to the partitioner their UPDATED values came back sharded
+    over ``fsdp``: ``_prep`` re-placed them every step (a steady-state
+    reshard) and their donated buffers could not alias.  The step pins its
+    outputs to its inputs' shardings (``TrainStep._pinned`` for weights and
+    optimizer state, ``_pin_mutations`` for BN running stats)."""
+    class Net(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            self.d1 = nn.Dense(16, in_units=8)
+            self.bn = nn.BatchNorm(in_channels=16)
+            self.d2 = nn.Dense(4, in_units=16)
+
+        def forward(self, x):
+            return self.d2(self.bn(self.d1(x)))
+
+    orig = cached_step.TrainStep._prep
+
+    def donating(self):     # the cpu backend keeps donation off otherwise
+        prep = orig(self)
+        prep.donate = True
+        return prep
+
+    monkeypatch.setattr(cached_step.TrainStep, "_prep", donating)
+    spmd.reset_counters()
+    with _mesh_env("dp=2,fsdp=2", min_size="64"):
+        net = Net()
+        net.initialize(mx.init.Xavier())
+        net.hybridize()
+        trainer = gluon.Trainer(net.collect_params(), "sgd",
+                                {"learning_rate": 0.1, "momentum": 0.9},
+                                kvstore="tpu")
+        step = trainer.compile_step(net, _loss_sum)
+        x, y = _data()
+        params = net.collect_params()
+
+        def specs():
+            return {n: p.data()._data.sharding.spec
+                    for n, p in params.items()}
+
+        step(mx.nd.array(x), mx.nd.array(y), batch_size=16)
+        assert step.last_step_compiled, step.last_fallback_reason
+        engine.waitall()
+        placed = specs()
+        under = [n for n, p in params.items() if p.data().size < 64]
+        assert len(under) == 6          # two biases, BN's four (16,) leaves
+        assert all("fsdp" not in str(placed[n]) for n in under)
+        assert "fsdp" in str(placed["d1.weight"])
+        r_warm = spmd.reshard_count()   # first placement only
+        for _ in range(3):
+            old = [p.data()._data for p in params.values()
+                   if p.grad_req != "null"]     # the donated operands
+            step(mx.nd.array(x), mx.nd.array(y), batch_size=16)
+            engine.waitall()
+            assert specs() == placed
+            assert len(old) == 6 and all(o.is_deleted() for o in old)
+        assert spmd.reshard_count() == r_warm
+
+
 @pytest.mark.parametrize("optimizer,opt_params,scaler", [
     ("sgd", {"learning_rate": 0.05, "momentum": 0.9, "wd": 1e-4}, None),
     ("sgd", {"learning_rate": 0.05, "momentum": 0.9}, 8.0),

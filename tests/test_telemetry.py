@@ -18,6 +18,7 @@ reset clears events, never registry-backed ``profiler.Counter`` values;
 dump; (7) the legacy accessors as registry views; (8) the JSON-lines
 flight recorder flushed by ``engine.waitall()``; (9) the gate itself.
 """
+import gc
 import json
 import os
 import subprocess
@@ -433,7 +434,10 @@ def test_legacy_accessors_are_registry_views():
         == telemetry.snapshot()["spmd.replicated_batch"]
     assert sharding.legalize_refusal_count() \
         == telemetry.snapshot()["sharding.legalize_refusal"]
-    # engine drainables (computed gauge)
+    # engine drainables (computed gauge).  The registry is weak: settle
+    # the collector first, or a collection between the two reads (earlier
+    # tests leave TrainSteps in cycles) makes them differ
+    gc.collect()
     assert telemetry.snapshot()["engine.drainables"] \
         == engine.drainable_count()
     # program_store-backed module views

@@ -993,6 +993,9 @@ def _measure_store_cold_start() -> dict:
     # the knob under test must own the cache dir (never piggyback on an
     # externally configured jax cache)
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    # persistence semantics are platform-independent, and this process
+    # may already hold the chip: the workers are CPU processes
+    env["JAX_PLATFORMS"] = "cpu"
     runs = []
     for i in ("A", "B"):
         r = subprocess.run(

@@ -7,8 +7,11 @@ multi-controller JAX: N identical worker processes, process 0 doubling as
 the coordination point — no scheduler/server processes needed (collectives
 replace the parameter server).  Supported launchers:
 
-  local  N worker processes on this machine (how the reference tests
-         multi-node without a cluster, tests/nightly/dist_sync_kvstore.py)
+  local  N CPU worker processes on this machine (how the reference tests
+         multi-node without a cluster, tests/nightly/dist_sync_kvstore.py).
+         Workers get JAX_PLATFORMS=cpu: a chip belongs to ONE process, so
+         N ranks inheriting a chip host's environment would each open
+         every chip and hang.  Override with --env JAX_PLATFORMS=...
   ssh    one worker per host from --host-file
   mpi    one worker per MPI rank via ``mpirun``; ranks map their
          OMPI_COMM_WORLD_RANK / PMI_RANK onto the same env contract
@@ -130,6 +133,11 @@ def main():
         procs, threads = [], []
         for rank in range(n):
             env = dict(os.environ)
+            # N processes on ONE host: a chip belongs to one process, and
+            # every rank inheriting a chip host's environment would open
+            # every chip and hang.  Local workers are CPU workers unless
+            # --env JAX_PLATFORMS=... says otherwise.
+            env["JAX_PLATFORMS"] = "cpu"
             env.update(extra_env)
             env.update({
                 "MXNET_TPU_COORDINATOR": coordinator,

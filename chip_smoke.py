@@ -18,7 +18,10 @@ Phases (a failed check is a nonzero exit; nothing is retried or skipped):
 - ``kernel/flash``     ``__graft_entry__.entry()`` as the driver jits it (the
                        Mosaic custom call must be in the compiled text),
                        then flash forward/backward against the einsum
-                       reference at two shapes
+                       reference at two shapes, and at the two shapes the
+                       benchmark's BERT cells run with the dropout inside
+                       (same-mask reference; ms a call beside the unfused
+                       expression's)
 - ``serve/decode``     ``serving_decode.GenerativeEngine`` at the default
                        precision against the float32 eager oracle (token-exact
                        up to stated bf16 near ties), pool buffers donated
@@ -53,10 +56,13 @@ PHASES = ("train/resnet50", "train/bert_base", "kernel/flash",
 REAL = dict(rn_batch=128, rn_img=224, bert_batch=32, bert_seq=128,
             bert_layers=12, entry_layers=12,
             flash_shapes=((32 * 12, 128, 64), (8, 2048, 64)),
+            # (seq, batch) of the benchmark's BERT cells: tok_s512, tok_s128
+            cell_shapes=((512, 32), (128, 128)),
             kernel_batch=32)
 TOY = dict(rn_batch=8, rn_img=32, bert_batch=4, bert_seq=16,      # --rehearse
            bert_layers=1, entry_layers=1,
-           flash_shapes=((4, 128, 64), (2, 256, 64)), kernel_batch=2)
+           flash_shapes=((4, 128, 64), (2, 256, 64)),
+           cell_shapes=((32, 2), (16, 4)), kernel_batch=2)
 
 _PREFIX = ""
 
@@ -149,6 +155,8 @@ def train_phase(name, build, sz, mesh_spec=None, n_dev=1, steps=5):
     say(f"== {name}  (MXNET_SPMD_MESH="
         f"{os.environ.get('MXNET_SPMD_MESH', 'auto (unset)')})")
     mx.random.seed(0)
+    attn0 = {k: tel.snapshot()[f"attention.{k}"]
+             for k in ("fused", "unfused")}
     net, loss_fn, (x_np, y_np), opt, opt_params = build(sz)
     trainer = mx.gluon.Trainer(net.collect_params(), opt, opt_params,
                                kvstore="tpu")
@@ -196,7 +204,23 @@ def train_phase(name, build, sz, mesh_spec=None, n_dev=1, steps=5):
     if not on_tpu:
         say("  donation is off on the cpu backend: is_deleted not checked")
     say("  losses: " + " ".join(f"{l:.4f}" for l in losses))
-    check(not tel.events("fallback"), "no 'fallback' event in telemetry")
+    fallbacks = tel.events("fallback")
+    if mesh_spec is not None:
+        # pallas_call has no partitioning rule: under a mesh on a TPU every
+        # BERT attention site keeps the unfused expression and says so
+        meshed = [e for e in fallbacks if e["name"] == "attention.fused"
+                  and e["why"].startswith("mesh of")]
+        say(f"  attention sites that kept the unfused expression under the "
+            f"mesh: {len(meshed)}")
+        fallbacks = [e for e in fallbacks if e not in meshed]
+    check(not fallbacks, "no 'fallback' event in telemetry")
+    snap = tel.snapshot()
+    fused, unfused = (snap[f"attention.{k}"] - attn0[k]
+                      for k in ("fused", "unfused"))
+    say(f"  attention sites traced: {fused} onto the Pallas kernel, "
+        f"{unfused} as the unfused expression")
+    if on_tpu and mesh_spec is None:
+        check(unfused == 0, "one chip: no attention site left the kernel")
     check(all(onp.isfinite(l) for l in losses), "every loss finite")
     check(losses[-1] < losses[0],
           f"loss fell: step {steps} {losses[-1]:.4f} < step 0 "
@@ -368,8 +392,85 @@ def flash_phase(sz, rehearse):
                   f"flash fwd+bwd {shape} causal={causal} vs fp32 einsum: "
                   f"out {o_err:.1e} dq {g[0][1]:.1e} dk {g[1][1]:.1e} "
                   f"dv {g[2][1]:.1e} (err / scale)")
+    for shape in sz["cell_shapes"]:
+        _flash_cell_shape(shape, rehearse)
     check(tlm.flash_fallback_count() == fb0,
           "transformer_lm.flash_fallback_count() did not move")
+
+
+def _flash_cell_shape(shape, rehearse, dropout_p=0.1, calls=20, heads=12):
+    """A BERT cell's attention core at its own shape, the two paths of
+    ``interleaved_selfatt`` over the (seq, batch, 12 * 3 * 64) projection
+    with the probability dropout on.  The Pallas kernels against the dense
+    float32 reference under the SAME mask, then forward + backward ms a
+    call beside the unfused expression (bf16 products, float32 softmax).
+    The arrays lie batch-major in memory and reach the operator through a
+    transpose, as ``BERTSelfAttention`` hands them over."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import contrib
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    seq, bsz = shape
+    d, bh = 64, shape[1] * heads
+    key = jax.random.PRNGKey(seq)
+    x, w = (jax.random.normal(kk, (bsz, seq, n * heads * d), jnp.float32)
+            .astype(jnp.bfloat16)
+            for kk, n in zip(jax.random.split(jax.random.PRNGKey(bsz)),
+                             (3, 1)))
+
+    def dense(qkv):
+        q, k, v = (t.astype(jnp.float32)
+                   for t in contrib._split_heads(qkv, heads))
+        att = jax.nn.softmax(jnp.einsum(
+            "bqd,bkd->bqk", q, k, precision=jax.lax.Precision.HIGHEST)
+            / d ** 0.5, axis=-1)
+        att = jnp.where(pk.dropout_keep_mask(key, bh, seq, seq, dropout_p),
+                        att, 0.0) / (1.0 - dropout_p)
+        return contrib._merge_heads(jnp.einsum(
+            "bqk,bkd->bqd", att, v, precision=jax.lax.Precision.HIGHEST),
+            bsz)
+
+    def kernel(qkv):
+        return pk.flash_attention_qkv(qkv, heads, dropout_p=dropout_p,
+                                      dropout_key=key)
+
+    def unfused(qkv):
+        return contrib._unfused_selfatt(qkv, key, heads, dropout_p)
+
+    def batch_major(f):
+        return lambda x: f(x.transpose(1, 0, 2)).transpose(1, 0, 2)
+
+    def with_grad(f):
+        return jax.jit(jax.value_and_grad(
+            lambda x: (batch_major(f)(x).astype(jnp.float32)
+                       * w.astype(jnp.float32)).sum()))
+
+    g_kernel, g_unfused = with_grad(kernel), with_grad(unfused)
+    o_ok, o_err = _close(jax.jit(batch_major(kernel))(x),
+                         jax.jit(batch_major(dense))(x), 3e-2)
+    g_ok, g_err = _close(g_kernel(x)[1], with_grad(dense)(x)[1], 3e-2)
+    check(o_ok and g_ok,
+          f"flash_attention_qkv (seq, batch) {shape} dropout_p={dropout_p}, "
+          f"{pk.qkv_heads_per_step(seq, heads, d)} heads a grid step, vs the "
+          f"fp32 reference under the same mask: out {o_err:.1e} "
+          f"dqkv {g_err:.1e} (err / scale)")
+    if rehearse:
+        say("  not timed: a time comes only from the chip")
+        return
+
+    def ms_a_call(fn):
+        jax.block_until_ready(fn(x))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(x)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / calls * 1e3
+
+    say(f"  {shape} forward + backward, ms a call over {calls}: Pallas "
+        f"kernels {ms_a_call(g_kernel):.3f}, unfused expression "
+        f"{ms_a_call(g_unfused):.3f}")
 
 
 # ---------------------------------------------------------------------------

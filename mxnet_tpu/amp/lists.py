@@ -27,7 +27,11 @@ LOW_PRECISION_FUNCS = [
     "FullyConnected", "Convolution", "Deconvolution", "dot", "batch_dot",
     "matmul", "interleaved_matmul_selfatt_qk",
     "interleaved_matmul_selfatt_valatt", "interleaved_matmul_encdec_qk",
-    "interleaved_matmul_encdec_valatt", "linalg_gemm", "linalg_gemm2",
+    "interleaved_matmul_encdec_valatt",
+    # the attention core as one op (ops/contrib.py): qkv casts down like
+    # the two interleaved ops it replaces in BERT; its softmax statistics
+    # are float32 inside, on the Pallas path and the unfused one
+    "interleaved_selfatt", "linalg_gemm", "linalg_gemm2",
     "_rnn_fused", "DeformableConvolution", "ModulatedDeformableConvolution",
     # fused conv+BN (ops/nn.py): conv-dominated, classified LOW for the
     # registry-exhaustiveness contract, but amp/__init__.py::_policy has

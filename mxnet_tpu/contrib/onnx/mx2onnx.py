@@ -347,6 +347,17 @@ def _att_valatt(node, ins, outs, ctx):
     ctx.add_node("Reshape", [t, shp3], outs)
 
 
+@translator("interleaved_selfatt")
+def _att_core(node, ins, outs, ctx):
+    """The attention core as inference runs it: the two interleaved
+    decompositions around a Softmax (the probability dropout is a
+    training-only term, and its key input is not exported)."""
+    scores, probs = ctx.uid("att_scores"), ctx.uid("att_probs")
+    _att_qk(node, ins[:1], [scores], ctx)
+    ctx.add_node("Softmax", [scores], [probs], axis=-1)
+    _att_valatt(node, [ins[0], probs], outs, ctx)
+
+
 @translator("dot", "linalg_gemm2", "batch_dot")
 def _matmul(node, ins, outs, ctx):
     ctx.add_node("MatMul", ins, outs)

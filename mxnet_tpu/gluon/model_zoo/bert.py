@@ -16,7 +16,9 @@ from __future__ import annotations
 import math
 from typing import Optional
 
-from ...ndarray.ndarray import invoke
+from ... import autograd
+from ... import random as _random
+from ...ndarray.ndarray import _wrap, invoke
 from ..block import HybridBlock
 from ..nn import Dense, Dropout, Embedding, GELU, HybridSequential, LayerNorm
 
@@ -25,31 +27,35 @@ __all__ = ["BERTEncoderLayer", "BERTModel", "bert_base", "bert_small",
 
 
 class BERTSelfAttention(HybridBlock):
-    """Multi-head self-attention over the contrib interleaved ops
-    (reference transformer.cc: interleaved_matmul_selfatt_{qk,valatt})."""
+    """Multi-head self-attention: the interleaved projection (reference
+    transformer.cc layout), then scores, softmax, probability dropout and
+    value product as ONE operator, ``interleaved_selfatt`` (the Pallas
+    flash kernel on a TPU, ops/contrib.py)."""
 
     def __init__(self, units: int, num_heads: int, dropout: float = 0.0):
         super().__init__()
         assert units % num_heads == 0
         self._units = units
         self._num_heads = num_heads
+        self._dropout = dropout
         self.qkv = Dense(3 * units, flatten=False, in_units=units)
         self.out_proj = Dense(units, flatten=False, in_units=units)
-        self.dropout = Dropout(dropout) if dropout else None
 
     def forward(self, x):
-        # x: [batch, seq, units] -> interleaved layout [seq, batch, 3*units]
-        xt = x.transpose((1, 0, 2))
-        qkv = self.qkv(xt)
-        scores = invoke("interleaved_matmul_selfatt_qk", [qkv],
-                        {"heads": self._num_heads})
-        att = invoke("softmax", [scores], {"axis": -1})
-        if self.dropout is not None:
-            att = self.dropout(att)
-        out = invoke("interleaved_matmul_selfatt_valatt", [qkv, att],
-                     {"heads": self._num_heads})
-        out = self.out_proj(out)
-        return out.transpose((1, 0, 2))
+        # x: [batch, seq, units].  The projections work on the last axis,
+        # so they take x as it lies; only the operator speaks the
+        # interleaved [seq, batch, 3*units], and its kernels undo that
+        # transpose themselves: the compiled program moves nothing.
+        qkv = self.qkv(x).transpose((1, 0, 2))
+        inputs = [qkv]
+        training = bool(self._dropout) and autograd.is_training()
+        if training:
+            # the step's key, drawn as gluon.nn.Dropout draws it
+            inputs.append(_wrap(_random.next_key(), qkv.ctx))
+        out = invoke("interleaved_selfatt", inputs,
+                     {"heads": self._num_heads, "p": self._dropout,
+                      "training": training})
+        return self.out_proj(out.transpose((1, 0, 2)))
 
 
 class BERTEncoderLayer(HybridBlock):

@@ -11,39 +11,138 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry as _telemetry
 from ..base import enable_x64 as _enable_x64
 from .registry import register
+
+
+def _split_heads(qkv, heads):
+    """(seq, batch, 3*embed) interleaved per head -> q, k, v, each
+    (batch*heads, seq, head_dim)."""
+    seq, bsz, three_embed = qkv.shape
+    x = qkv.reshape(seq, bsz, heads, 3, three_embed // (3 * heads))
+    return (x[:, :, :, j, :].transpose(1, 2, 0, 3).reshape(bsz * heads, seq, -1)
+            for j in range(3))
+
+
+def _merge_heads(out, bsz):
+    """(batch*heads, seq, head_dim) -> (seq, batch, embed)."""
+    bh, seq, head_dim = out.shape
+    out = out.reshape(bsz, bh // bsz, seq, head_dim).transpose(2, 0, 1, 3)
+    return out.reshape(seq, bsz, -1)
 
 
 @register("interleaved_matmul_selfatt_qk", num_inputs=1)
 def interleaved_matmul_selfatt_qk(queries_keys_values, heads=1):
     """Input (seq, batch, 3*embed) interleaved per head; output
     (batch*heads, seq, seq) scaled QK^T."""
-    qkv = queries_keys_values
-    seq, bsz, three_embed = qkv.shape
-    embed = three_embed // 3
-    head_dim = embed // heads
-    x = qkv.reshape(seq, bsz, heads, 3, head_dim)
-    q = x[:, :, :, 0, :]  # (seq, bsz, heads, hd)
-    k = x[:, :, :, 1, :]
-    q = q.transpose(1, 2, 0, 3).reshape(bsz * heads, seq, head_dim)
-    k = k.transpose(1, 2, 0, 3).reshape(bsz * heads, seq, head_dim)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(head_dim, q.dtype))
+    q, k, _ = _split_heads(queries_keys_values, heads)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
     return jnp.matmul(q * scale, k.transpose(0, 2, 1))
 
 
 @register("interleaved_matmul_selfatt_valatt", num_inputs=2)
 def interleaved_matmul_selfatt_valatt(queries_keys_values, attention, heads=1):
     """attention (batch*heads, seq, seq) x V -> (seq, batch, embed)."""
+    _, _, v = _split_heads(queries_keys_values, heads)
+    return _merge_heads(jnp.matmul(attention, v),
+                        queries_keys_values.shape[1])
+
+
+# --- the attention core as ONE operator ------------------------------------
+#
+# scores -> softmax -> probability dropout -> value product.  On a TPU the
+# Pallas flash kernels compute it without the (batch*heads, seq, seq) tensor
+# ever reaching HBM (ops/pallas_kernels.py); everywhere else the same
+# operator computes the unfused expression with the SAME dropout mask
+# (pallas_kernels.dropout_keep_mask), so a key means one mask on every path.
+# Which path is decided from what the trace can observe, never by a switch:
+# the platform, the active mesh, and the shape.
+
+_ATTN_FUSED = _telemetry.counter(
+    "attention.fused",
+    "attention sites traced onto the Pallas flash kernels")
+_ATTN_UNFUSED = _telemetry.counter(
+    "attention.unfused",
+    "attention sites traced as the unfused scores/softmax/dropout/value "
+    "expression")
+
+
+def _attention_platform() -> str:
+    return jax.default_backend()
+
+
+def _fused_attention_refusal(seq, heads, head_dim):
+    """Why a TPU trace cannot take the Pallas kernels at this site, or None.
+
+    ``pallas_call`` has no partitioning rule, so a mesh of more than one
+    device keeps the unfused expression (ROADMAP S3: ``shard_map``).  Up to
+    512 keys the whole-row kernels read the interleaved array in place and
+    state their own rule (``pallas_kernels.qkv_heads_per_step``); longer
+    sequences go to the blocked kernels, which Mosaic tiles in eights."""
+    from ..parallel.mesh import current_mesh
+    from . import pallas_kernels as _pk
+
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"mesh of {mesh.size} devices"
+    if seq % 8 or head_dim % 8:
+        return "seq and head_dim must be multiples of 8"
+    if seq <= _pk._ROW_SEQ_MAX and _pk.qkv_heads_per_step(
+            seq, heads, head_dim) is None:
+        return (f"{heads} heads of {head_dim} do not split into whole "
+                "128-lane columns")
+    return None
+
+
+def _unfused_selfatt(qkv, key, heads, p):
+    """The two interleaved products around a float32 softmax, the kept
+    probabilities scaled by 1 / (1 - p) under the kernels' own mask."""
+    from . import pallas_kernels as _pk
+
+    scores = interleaved_matmul_selfatt_qk(qkv, heads)
+    att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    if p:
+        att = jnp.where(_pk.dropout_keep_mask(key, *att.shape, p), att,
+                        0.0) / (1.0 - p)
+    return interleaved_matmul_selfatt_valatt(qkv, att.astype(qkv.dtype),
+                                             heads)
+
+
+@register("interleaved_selfatt", num_inputs=2, rng_input=True)
+def interleaved_selfatt(queries_keys_values, key=None, heads=1, p=0.0,
+                        training=False):
+    """Self-attention core over the interleaved projection: input
+    (seq, batch, 3*embed) as ``interleaved_matmul_selfatt_qk`` takes it,
+    output (seq, batch, embed) as ``interleaved_matmul_selfatt_valatt``
+    gives it: ``dropout(softmax(q k^T / sqrt(head_dim))) v``.
+
+    ``p`` is the dropout rate on the normalised probabilities, applied only
+    when ``training``; ``key`` is the uint32 PRNG key of the mask (unused
+    otherwise).  Softmax statistics are float32 and the products take the
+    input's dtype on either path."""
+    from . import pallas_kernels as _pk
+
     qkv = queries_keys_values
     seq, bsz, three_embed = qkv.shape
-    embed = three_embed // 3
-    head_dim = embed // heads
-    x = qkv.reshape(seq, bsz, heads, 3, head_dim)
-    v = x[:, :, :, 2, :].transpose(1, 2, 0, 3).reshape(bsz * heads, seq, head_dim)
-    out = jnp.matmul(attention, v)  # (b*h, seq, hd)
-    out = out.reshape(bsz, heads, seq, head_dim).transpose(2, 0, 1, 3)
-    return out.reshape(seq, bsz, embed)
+    head_dim = three_embed // (3 * heads)
+    p = float(p) if training else 0.0
+    if _attention_platform() != "tpu":
+        _ATTN_UNFUSED.inc()
+        return _unfused_selfatt(qkv, key, heads, p)
+    refusal = _fused_attention_refusal(seq, heads, head_dim)
+    if refusal is not None:
+        _ATTN_UNFUSED.inc()
+        _telemetry.event("fallback", "attention.fused", seq=seq,
+                         head_dim=head_dim, why=refusal)
+        return _unfused_selfatt(qkv, key, heads, p)
+    _ATTN_FUSED.inc()
+    if seq <= _pk._ROW_SEQ_MAX:
+        return _pk.flash_attention_qkv(qkv, heads, dropout_p=p,
+                                       dropout_key=key)
+    return _merge_heads(_pk.flash_attention(
+        *_split_heads(qkv, heads), causal=False, dropout_p=p,
+        dropout_key=key), bsz)
 
 
 @register("interleaved_matmul_encdec_qk", num_inputs=2)

@@ -1,13 +1,19 @@
 """Pallas TPU kernels for the hot ops.
 
-Flash attention (forward + backward) as Pallas kernels: tiled onto the MXU
-with online softmax so the S×S score matrix never materializes in HBM —
-O(S) memory instead of O(S²), the enabler for long-context training.
+Flash attention (forward + backward) as Pallas kernels: the S×S score
+matrix never materializes in HBM.  Up to 512 keys a head's whole score tile
+lives in VMEM (plain softmax, one backward kernel for dq, dk and dv, several
+heads a grid step); longer sequences are tiled with online softmax — O(S)
+memory instead of O(S²), the enabler for long-context training.  Dropout on
+the probabilities happens inside the kernels, from a counter-based hash of
+the position that the backward regenerates (no mask is stored).
 
 Reference analog: the fused transformer attention matmuls
 (``src/operator/contrib/transformer.cc:650-740``,
 ``interleaved_matmul_selfatt_qk/valatt``) — which still materialized the
 full score matrix; this is the TPU-first replacement, not a translation.
+Callers: ``ops/contrib.py interleaved_selfatt`` (the Gluon BERT attention
+core) and ``models/transformer_lm.py``.
 
 On the CPU the kernels run under the Pallas interpreter (slow but exact) so
 the CPU test suite validates the same code path that runs on hardware; any
@@ -25,10 +31,12 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "matmul_bn_stats", "conv1x1_bn_stats",
+__all__ = ["flash_attention", "flash_attention_qkv", "qkv_heads_per_step",
+           "dropout_keep_mask", "matmul_bn_stats", "conv1x1_bn_stats",
            "conv1x1_bn_stats_train", "fused_blocks",
            "conv3x3_bn_stats", "conv3x3_bn_stats_train", "conv3x3_fits",
            "convkxk_bn_stats", "convkxk_bn_stats_train", "convkxk_fits",
@@ -53,254 +61,572 @@ def _interpret() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# forward kernel: one q-block per grid step, online softmax over k-blocks
+# dropout on the probabilities: a counter-based hash of the global position
+# ---------------------------------------------------------------------------
+#
+# The keep mask is a pure function of (key words, flattened batch*head index,
+# query position, key position): the forward and every backward kernel
+# regenerate it, nothing is stored, and it does not depend on the tiling.
+# It is plain int32 ``jax.numpy`` (wrapping multiply, logical shift, xor), so
+# Mosaic, the Pallas interpreter and a dense reference run the same function.
+# The hardware generator (``pltpu.prng_*``) has no CPU rule, so tier-1 could
+# not test a mask made with it.
+#
+# Cost where it matters, per (query, key) element: one add, one shift, one
+# xor, one multiply, one compare.  The row and column words are mixed once
+# per row and per column (``_mix``, the lowbias32 finaliser) and the last
+# round decorrelates their sum.
+
+_MIX_1 = 0x7FEB352D
+_MIX_2 = 0x846CA68B - (1 << 32)            # as a wrapped int32
+
+
+def _mix(x):
+    x = x ^ jax.lax.shift_right_logical(x, 16)
+    x = x * jnp.int32(_MIX_1)
+    x = x ^ jax.lax.shift_right_logical(x, 15)
+    x = x * jnp.int32(_MIX_2)
+    return x ^ jax.lax.shift_right_logical(x, 16)
+
+
+def _keep(seed0, seed1, head, q_pos, k_pos, dropout_p):
+    """Bernoulli(1 - dropout_p) keep mask at the broadcast of ``head``,
+    ``q_pos`` and ``k_pos`` (int32, any broadcastable shapes)."""
+    row_word = _mix(seed0 ^ head)
+    col_word = _mix(seed1 + row_word)
+    x = _mix(row_word + q_pos) + _mix(col_word + k_pos)
+    x = x ^ jax.lax.shift_right_logical(x, 15)
+    x = x * jnp.int32(_MIX_2)
+    # x is uniform over int32: P(x >= t) = (2^31 - t) / 2^32 = 1 - p
+    t = min(round((1 << 31) - (1.0 - dropout_p) * (1 << 32)), (1 << 31) - 1)
+    return x >= jnp.int32(t)
+
+
+def _seed_words(key):
+    """The two int32 words the kernels take, from a PRNG key (typed, or the
+    raw ``uint32[2]`` of ``mxnet_tpu.random.next_key``)."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    return jax.lax.bitcast_convert_type(key.astype(jnp.uint32), jnp.int32)
+
+
+def dropout_keep_mask(key, num_heads, seq_q, seq_k, dropout_p):
+    """The dense ``(num_heads, seq_q, seq_k)`` boolean keep mask that
+    :func:`flash_attention` applies inside its kernels for ``key``:
+    ``num_heads`` is the flattened batch*heads extent."""
+    seed = _seed_words(key)
+    iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32,
+                             (num_heads, seq_q, seq_k))
+    return _keep(seed[0], seed[1], iota(0), iota(1), iota(2), dropout_p)
+
+
+# ---------------------------------------------------------------------------
+# pieces every attention kernel shares
+# ---------------------------------------------------------------------------
+#
+# MXU operands stay in the caller's dtype (bf16 under AMP, float32 for
+# float32 callers); products accumulate in float32 and the softmax
+# statistics (m, l, lse, delta) are float32.
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):          # a @ b.T
+    return _dot(a, b, (1, 1))
+
+
+def _dot_nn(a, b):          # a @ b
+    return _dot(a, b, (1, 0))
+
+
+def _dot_tn(a, b):          # a.T @ b
+    return _dot(a, b, (0, 0))
+
+
+def _scores(q, k, sm_scale):
+    """(rows, cols) float32 ``q k^T * sm_scale``.  A power-of-two scale
+    folds into q without rounding (head_dim 64: 0.125); any other is applied
+    to the float32 scores."""
+    if math.frexp(sm_scale)[0] == 0.5:
+        return _dot_nt(q * jnp.asarray(sm_scale, q.dtype), k)
+    return _dot_nt(q, k) * sm_scale
+
+
+def _masks(seed_ref, head, q0, k0, rows, cols, causal, dropout_p):
+    """``(visible, keep)`` for the tile at (q0, k0); each is None when the
+    kernel was built without it."""
+    if not causal and not dropout_p:
+        return None, None
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    visible = (q_pos >= k_pos) if causal else None
+    keep = _keep(seed_ref[0], seed_ref[1], head, q_pos, k_pos,
+                 dropout_p) if dropout_p else None
+    return visible, keep
+
+
+def _where(mask, x, other=0.0):
+    return x if mask is None else jnp.where(mask, x, other)
+
+
+# ---------------------------------------------------------------------------
+# whole-row kernels (seq <= _ROW_SEQ_MAX): every key of a head at once
+# ---------------------------------------------------------------------------
+#
+# A (seq, seq) float32 score tile fits VMEM, so the softmax is the plain one
+# (no online rescaling), nothing but the output leaves the forward, and ONE
+# backward kernel recomputes the tile once for dq, dk and dv.  A grid step
+# takes several heads: short sequences would otherwise pay the per-step
+# cost a thousand times a call.  Two layouts share the per-head arithmetic:
+# (batch*heads, seq, head_dim) operands, and the interleaved projection
+# (seq, batch*heads*3*head_dim) read and written in place, whose blocks are
+# whole 128-lane rows and which needs no copy of q, k, v around the call.
+
+
+def _row_softmax(seed_ref, head, q, k, sm_scale, causal, dropout_p):
+    """``(e, keep, l)``: the unnormalised probabilities of one head, the
+    dropout keep mask (or None) and the float32 normaliser.  The normaliser
+    is the undropped one: dropout acts on the normalised probabilities."""
+    seq = q.shape[0]
+    visible, keep = _masks(seed_ref, head, 0, 0, seq, seq, causal, dropout_p)
+    s = _where(visible, _scores(q, k, sm_scale), _NEG_INF)
+    e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    return e, keep, jnp.sum(e, axis=-1, keepdims=True)
+
+
+def _row_head_fwd(seed_ref, head, q, k, v, *, sm_scale, causal, dropout_p):
+    e, keep, l = _row_softmax(seed_ref, head, q, k, sm_scale, causal,
+                              dropout_p)
+    return _dot_nn(_where(keep, e).astype(v.dtype), v) \
+        / (l * (1.0 - dropout_p))
+
+
+def _row_head_bwd(seed_ref, head, q, k, v, o, do, *, sm_scale, causal,
+                  dropout_p):
+    """float32 ``(dq, dk, dv)`` of one head."""
+    keep_p = 1.0 - dropout_p
+    e, keep, l = _row_softmax(seed_ref, head, q, k, sm_scale, causal,
+                              dropout_p)
+    # per-row factors stay out of the (seq, seq) tile: with
+    # w = 1 / (l * keep_p), P~ = keep * e * w and
+    # dS = e * w * (keep * dP~ - keep_p * delta)
+    w = 1.0 / (l * keep_p)
+    do32 = do.astype(jnp.float32)
+    delta = jnp.sum(do32 * o.astype(jnp.float32), axis=-1, keepdims=True)
+    dv = _dot_tn(_where(keep, e).astype(do.dtype), (do32 * w).astype(do.dtype))
+    ds = (e * (_where(keep, _dot_nt(do, v)) - keep_p * delta)).astype(q.dtype)
+    w = w * sm_scale
+    dq = _dot_nn(ds, k) * w
+    dk = _dot_tn(ds, (q.astype(jnp.float32) * w).astype(q.dtype))
+    return dq, dk, dv
+
+
+def _row_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, *, heads, **kw):
+    for g in range(heads):
+        o = _row_head_fwd(seed_ref, pl.program_id(0) * heads + g, q_ref[g],
+                          k_ref[g], v_ref[g], **kw)
+        o_ref[g] = o.astype(o_ref.dtype)
+
+
+def _row_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref, dq_ref,
+                    dk_ref, dv_ref, *, heads, **kw):
+    for g in range(heads):
+        grads = _row_head_bwd(seed_ref, pl.program_id(0) * heads + g,
+                              q_ref[g], k_ref[g], v_ref[g], o_ref[g],
+                              do_ref[g], **kw)
+        for ref, grad in zip((dq_ref, dk_ref, dv_ref), grads):
+            ref[g] = grad.astype(ref.dtype)
+
+
+def _qkv_of_head(x_ref, g, d):
+    """Head ``g`` of an interleaved block: columns (head, q|k|v, d)."""
+    return (x_ref[:, (3 * g + j) * d:(3 * g + j + 1) * d] for j in range(3))
+
+
+def _qkv_first_head(heads, num_heads):
+    """The flattened batch*num_heads index of a grid step's first head:
+    the grid is (batch, num_heads // heads)."""
+    return pl.program_id(0) * num_heads + pl.program_id(1) * heads
+
+
+def _qkv_fwd_kernel(seed_ref, x_ref, o_ref, *, heads, num_heads, d, **kw):
+    head0 = _qkv_first_head(heads, num_heads)
+    outs = [_row_head_fwd(seed_ref, head0 + g, *_qkv_of_head(x_ref, g, d),
+                          **kw)
+            for g in range(heads)]
+    o_ref[...] = jnp.concatenate(outs, axis=-1).astype(o_ref.dtype)
+
+
+def _qkv_bwd_kernel(seed_ref, x_ref, o_ref, do_ref, dx_ref, *, heads,
+                    num_heads, d, **kw):
+    head0 = _qkv_first_head(heads, num_heads)
+    grads = []
+    for g in range(heads):
+        cols = slice(g * d, (g + 1) * d)
+        grads.extend(_row_head_bwd(
+            seed_ref, head0 + g, *_qkv_of_head(x_ref, g, d), o_ref[:, cols],
+            do_ref[:, cols], **kw))
+    dx_ref[...] = jnp.concatenate(grads, axis=-1).astype(dx_ref.dtype)
+
+
+# ---------------------------------------------------------------------------
+# blocked kernels (longer sequences): online softmax over k-blocks, the
+# backward as dq over q-blocks and dk/dv over k-blocks
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, block_k,
-                causal, block_q, seq_len):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * sm_scale        # (block_q, d)
+def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
+                block_k, causal, block_q, seq_len, dropout_p):
+    head, qi = pl.program_id(0), pl.program_id(1)
+    q = q_ref[0]                                        # (block_q, d)
     d = q.shape[-1]
-
-    num_kb = seq_len // block_k
-    if causal:
-        # only k-blocks at or before this q-block participate
-        num_kb_eff = (qi + 1) * block_q // block_k
-    else:
-        num_kb_eff = num_kb
+    # causal: only k-blocks at or before this q-block participate
+    num_kb = pl.cdiv((qi + 1) * block_q, block_k) if causal \
+        else seq_len // block_k
 
     def body(ki, carry):
         acc, m_prev, l_prev = carry                     # stats: (block_q, 1)
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = q @ k.T                                     # (block_q, block_k)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
+        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
+        s = _scores(q, k, sm_scale)                     # (block_q, block_k)
+        visible, keep = _masks(seed_ref, head, qi * block_q, ki * block_k,
+                               block_q, block_k, causal, dropout_p)
+        s = _where(visible, s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + p.sum(-1, keepdims=True)
-        acc = acc * alpha + p @ v
+        acc = acc * alpha + _dot_nn(_where(keep, p).astype(v.dtype), v)
         return acc, m_new, l_new
 
     acc0 = jnp.zeros((block_q, d), jnp.float32)
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, num_kb_eff, body, (acc0, m0, l0))
+    acc, m, l = jax.lax.fori_loop(0, num_kb, body, (acc0, m0, l0))
     l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log(l)).astype(jnp.float32)   # (block_q, 1)
+    o_ref[0] = (acc / (l * (1.0 - dropout_p))).astype(o_ref.dtype)
+    lse_ref[0] = m + jnp.log(l)                         # (block_q, 1)
 
 
-# ---------------------------------------------------------------------------
-# backward kernels: dq over q-blocks; dk/dv over k-blocks
-# ---------------------------------------------------------------------------
+def _bwd_tile(seed_ref, head, q0, k0, q, k, v, do, lse, delta, *, sm_scale,
+              causal, dropout_p):
+    """``(P~, dS)`` of one (block_q, block_k) tile, in the operand dtype:
+    the dropped probabilities and the gradient of the scaled scores."""
+    s = _scores(q, k, sm_scale)
+    visible, keep = _masks(seed_ref, head, q0, k0, s.shape[0], s.shape[1],
+                           causal, dropout_p)
+    p = jnp.exp(_where(visible, s, _NEG_INF) - lse)
+    inv_keep = 1.0 / (1.0 - dropout_p)
+    dp = _where(keep, _dot_nt(do, v)) * inv_keep
+    ds = p * (dp - delta) * sm_scale
+    return (_where(keep, p) * inv_keep).astype(do.dtype), ds.astype(q.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, sm_scale, block_k, causal, block_q, seq_len):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                                    # (block_q, 1)
-    delta = delta_ref[0]                                # (block_q, 1)
-    d = q.shape[-1]
-    num_kb_eff = ((qi + 1) * block_q // block_k) if causal \
+def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, *, block_k, block_q, seq_len, causal, **kw):
+    head, qi = pl.program_id(0), pl.program_id(1)
+    q, do = q_ref[0], do_ref[0]
+    lse, delta = lse_ref[0], delta_ref[0]               # (block_q, 1)
+    num_kb = pl.cdiv((qi + 1) * block_q, block_k) if causal \
         else seq_len // block_k
 
     def body(ki, dq):
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = (q @ k.T) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = do @ v.T
-        ds = p * (dp - delta) * sm_scale
-        return dq + ds @ k
+        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
+        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
+        _, ds = _bwd_tile(seed_ref, head, qi * block_q, ki * block_k, q, k, v,
+                          do, lse, delta, causal=causal, **kw)
+        return dq + _dot_nn(ds, k)
 
-    dq = jax.lax.fori_loop(0, num_kb_eff, body,
-                           jnp.zeros((block_q, d), jnp.float32))
+    dq = jax.lax.fori_loop(0, num_kb, body,
+                           jnp.zeros(q.shape, jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                    dv_ref, *, sm_scale, block_q, causal, block_k, seq_len):
-    ki = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                    # (block_k, d)
-    v = v_ref[0].astype(jnp.float32)
-    d = k.shape[-1]
-    num_qb = seq_len // block_q
+def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dk_ref, dv_ref, *, block_q, block_k, seq_len,
+                    causal, **kw):
+    head, ki = pl.program_id(0), pl.program_id(1)
+    k, v = k_ref[0], v_ref[0]                           # (block_k, d)
     start_qb = (ki * block_k) // block_q if causal else 0
 
     def body(qi, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q), :]     # (block_q, 1)
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q), :]
-        s = (q @ k.T) * sm_scale                        # (block_q, block_k)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dv = dv + p.T @ do
-        dp = do @ v.T
-        ds = p * (dp - delta) * sm_scale
-        dk = dk + ds.T @ q
-        return dk, dv
+        rows = pl.ds(qi * block_q, block_q)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        pd, ds = _bwd_tile(seed_ref, head, qi * block_q, ki * block_k, q, k,
+                           v, do, lse_ref[0, rows, :], delta_ref[0, rows, :],
+                           causal=causal, **kw)
+        return dk + _dot_tn(ds, q), dv + _dot_tn(pd, do)
 
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start_qb, num_qb, body, (dk0, dv0))
+    zeros = jnp.zeros(k.shape, jnp.float32)
+    dk, dv = jax.lax.fori_loop(start_qb, seq_len // block_q, body,
+                               (zeros, zeros))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
-# host-side wrappers
+# host-side wrappers (the pallas_call entries are jitted, so a model's
+# identical layers lower ONE function each way)
 # ---------------------------------------------------------------------------
 
+# The block rule (fixed in code; tuned on the v5e, PERF.md PR 25).  A
+# sequence of up to _ROW_SEQ_MAX keys takes the whole-row kernels; a grid
+# step holds as many heads as keep its float32 score tiles within
+# _ROW_TILE_ELEMS, at most _ROW_HEADS_MAX (the kernels unroll over them).
+# Longer sequences take the blocked kernels at _BLOCK: at 512 keys blocks of
+# 512 ran 2.8x faster than the 128 this rule used to prefer.
+_ROW_SEQ_MAX = 512
+_ROW_TILE_ELEMS = 512 * 512
+_ROW_HEADS_MAX = 16
+_BLOCK = 512
 
-def _pick_block(seq_len, preferred=128):
-    b = min(preferred, seq_len)
-    while seq_len % b != 0:
-        b //= 2
-    return max(b, 1)
+
+def _divisor(n, preferred, multiple=1):
+    """The largest divisor of ``n`` that is at most ``preferred``, a
+    multiple of ``multiple`` if ``n`` has such a divisor."""
+    fits = [b for b in range(1, min(preferred, n) + 1) if n % b == 0]
+    return max([b for b in fits if b % multiple == 0] or fits)
 
 
-def _fwd(q, k, v, causal, sm_scale, block_q, block_k):
-    bh, s, d = q.shape
-    grid = (bh, s // block_q)
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, block_k=block_k, causal=causal,
-        block_q=block_q, seq_len=s)
-    out, lse = pl.pallas_call(
+def _plan(bh, s):
+    """``("rows", heads)`` or ``("blocks", block_q, block_k)``."""
+    if s > _ROW_SEQ_MAX:
+        block = _divisor(s, _BLOCK, multiple=8)         # Mosaic's sublanes
+        return ("blocks", block, block)
+    return ("rows", _divisor(bh, min(_ROW_HEADS_MAX,
+                                     max(1, _ROW_TILE_ELEMS // (s * s)))))
+
+
+def _call(kernel, grid, in_specs, out_specs, out_shape, seed, *args):
+    """``pallas_call`` with the two seed words as the scalar prefetch (the
+    index maps take them as a trailing argument and ignore it)."""
+    return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs),
+        out_shape=out_shape,
         interpret=_interpret(),
-    )(q, k, v)
-    return out, lse
+    )(seed, *args)
 
 
-def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _forward(q, k, v, seed, causal, sm_scale, dropout_p, plan):
+    """``(out, lse)``; ``lse`` is None on the whole-row path, whose
+    backward recomputes the statistics."""
     bh, s, d = q.shape
+    kw = dict(sm_scale=sm_scale, causal=causal, dropout_p=dropout_p)
+    if plan[0] == "rows":
+        heads = plan[1]
+        spec = pl.BlockSpec((heads, s, d), lambda b, seed: (b, 0, 0))
+        out = _call(functools.partial(_row_fwd_kernel, heads=heads, **kw),
+                    (bh // heads,), [spec] * 3, spec,
+                    jax.ShapeDtypeStruct(q.shape, q.dtype), seed, q, k, v)
+        return out, None
+    _, bq, bk = plan
+    q_blk = pl.BlockSpec((1, bq, d), lambda b, i, seed: (b, i, 0))
+    whole = pl.BlockSpec((1, s, d), lambda b, i, seed: (b, 0, 0))
+    return _call(
+        functools.partial(_fwd_kernel, block_q=bq, block_k=bk, seq_len=s,
+                          **kw),
+        (bh, s // bq), [q_blk, whole, whole],
+        [q_blk, pl.BlockSpec((1, bq, 1), lambda b, i, seed: (b, i, 0))],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)], seed, q, k, v)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _backward(q, k, v, o, lse, do, seed, causal, sm_scale, dropout_p,
+              plan):
+    bh, s, d = q.shape
+    kw = dict(sm_scale=sm_scale, causal=causal, dropout_p=dropout_p)
+    like = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    if plan[0] == "rows":
+        heads = plan[1]
+        spec = pl.BlockSpec((heads, s, d), lambda b, seed: (b, 0, 0))
+        return _call(functools.partial(_row_bwd_kernel, heads=heads, **kw),
+                     (bh // heads,), [spec] * 5, [spec] * 3, [like] * 3,
+                     seed, q, k, v, o, do)
+    _, bq, bk = plan
+    kw.update(block_q=bq, block_k=bk, seq_len=s)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                     keepdims=True)                       # (bh, s, 1)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale,
-                          block_k=block_k, causal=causal, block_q=block_q,
-                          seq_len=s),
-        grid=(bh, s // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
-                          block_q=block_q, causal=causal, block_k=block_k,
-                          seq_len=s),
-        grid=(bh, s // block_k),
-        in_specs=[
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, 1), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        ],
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+    def blk(rows, width):
+        return pl.BlockSpec((1, rows, width), lambda b, i, seed: (b, i, 0))
+
+    whole = pl.BlockSpec((1, s, d), lambda b, i, seed: (b, 0, 0))
+    whole_stat = pl.BlockSpec((1, s, 1), lambda b, i, seed: (b, 0, 0))
+    dq = _call(functools.partial(_bwd_dq_kernel, **kw), (bh, s // bq),
+               [blk(bq, d), whole, whole, blk(bq, d), blk(bq, 1), blk(bq, 1)],
+               blk(bq, d), like, seed, q, k, v, do, lse, delta)
+    dk, dv = _call(functools.partial(_bwd_dkv_kernel, **kw), (bh, s // bk),
+                   [whole, blk(bk, d), blk(bk, d), whole, whole_stat,
+                    whole_stat],
+                   [blk(bk, d)] * 2, [like] * 2, seed, q, k, v, do, lse,
+                   delta)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal, sm_scale):
-    bh, s, d = q.shape
-    bq = _pick_block(s)
-    bk = _pick_block(s)
-    out, _ = _fwd(q, k, v, causal, sm_scale, bq, bk)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, seed, causal, sm_scale, dropout_p, plan):
+    return _forward(q, k, v, seed, causal, sm_scale, dropout_p, plan)[0]
 
 
-def _flash_fwd(q, k, v, causal, sm_scale):
-    bh, s, d = q.shape
-    bq = _pick_block(s)
-    bk = _pick_block(s)
-    out, lse = _fwd(q, k, v, causal, sm_scale, bq, bk)
-    return out, (q, k, v, out, lse)
+def _flash_fwd(q, k, v, seed, causal, sm_scale, dropout_p, plan):
+    out, lse = _forward(q, k, v, seed, causal, sm_scale, dropout_p, plan)
+    return out, (q, k, v, out, lse, seed)
 
 
-def _flash_bwd(causal, sm_scale, res, do):
-    q, k, v, out, lse = res
-    bh, s, d = q.shape
-    bq = _pick_block(s)
-    bk = _pick_block(s)
-    dq, dk, dv = _bwd(q, k, v, out, lse, do, causal, sm_scale, bq, bk)
-    return dq, dk, dv
+def _flash_bwd(causal, sm_scale, dropout_p, plan, res, do):
+    q, k, v, out, lse, seed = res
+    dq, dk, dv = _backward(q, k, v, out, lse, do, seed, causal, sm_scale,
+                           dropout_p, plan)
+    return dq, dk, dv, onp.zeros(seed.shape, jax.dtypes.float0)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, causal=True, sm_scale=None):
-    """Tiled attention: softmax(q kᵀ · scale [+ causal mask]) v.
+def _dropout_args(dropout_p, dropout_key):
+    """The static rate and the seed words a kernel call takes."""
+    dropout_p = float(dropout_p)
+    if not 0.0 <= dropout_p < 1.0:
+        raise ValueError(f"dropout_p must be in [0, 1), got {dropout_p}")
+    if dropout_p and dropout_key is None:
+        raise ValueError("dropout_p > 0 needs dropout_key")
+    return dropout_p, (_seed_words(dropout_key) if dropout_p
+                       else jnp.zeros((2,), jnp.int32))
+
+
+def flash_attention(q, k, v, causal=True, sm_scale=None, dropout_p=0.0,
+                    dropout_key=None):
+    """Tiled attention: ``dropout(softmax(q k^T * scale [+ causal mask])) v``.
 
     q/k/v: (..., num_heads, seq, head_dim); leading dims are flattened into
     the kernel grid.  Differentiable (custom VJP with flash backward).
+    ``dropout_p`` is static; with ``dropout_p > 0`` the probabilities are
+    dropped inside the kernels with the mask :func:`dropout_keep_mask`
+    gives for ``dropout_key`` (a PRNG key, required then) and the kept ones
+    scaled by ``1 / (1 - dropout_p)``.
     """
     orig_shape = q.shape
     *lead, s, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    bh = 1
-    for x in lead:
-        bh *= x
+    dropout_p, seed = _dropout_args(dropout_p, dropout_key)
+    bh = math.prod(lead)
     q3, k3, v3 = (t.reshape(bh, s, d) for t in (q, k, v))
-    out = _flash(q3, k3, v3, causal, sm_scale)
+    out = _flash(q3, k3, v3, seed, bool(causal), float(sm_scale), dropout_p,
+                 _plan(bh, s))
     return out.reshape(orig_shape)
+
+
+# -- self-attention over the interleaved projection, in place ---------------
+#
+# The kernels take the projection batch-major, (batch, seq, heads*3*head_dim):
+# a block is one batch row's (seq, heads*3*head_dim) columns, whole (8, 128)
+# tiles of the array as it lies in HBM.  The (seq, batch, width) the operator
+# speaks is the same buffer under another dimension order, which XLA's layout
+# assignment gives the producing product directly: no relayout runs.  A
+# (seq, batch*width) view of the seq-major array, the first in-place form,
+# cost four untiled copies of the projection a layer (7.8% of the step at 128
+# keys; PERF.md PR 25).
+
+_QKV_HEADS = 4          # heads a grid step (v5e: 2, 4 and 6 within 3%)
+
+
+def qkv_heads_per_step(seq, num_heads, head_dim):
+    """Heads a grid step of :func:`flash_attention_qkv`, or None when its
+    kernels cannot take the shape: whole-row kernels hold at most
+    ``_ROW_SEQ_MAX`` keys, Mosaic tiles rows in eights, and a block must be
+    whole 128-lane columns of one batch row."""
+    if seq > _ROW_SEQ_MAX or seq % 8 or head_dim % 8:
+        return None
+    unit = 128 // math.gcd(128, head_dim)
+    for heads in range(max(unit, _QKV_HEADS) // unit * unit, 0, -unit):
+        if num_heads % heads == 0:
+            return heads
+    return None
+
+
+def _qkv_grid(x, heads, d):
+    """``(grid, kernel arguments, projection spec, output spec)`` for the
+    batch-major projection ``x``: a grid step is ``heads`` heads of one
+    batch row."""
+    bsz, s, width = x.shape
+    num_heads = width // (3 * d)
+    x_spec, o_spec = (pl.BlockSpec((None, s, heads * n * d),
+                                   lambda b, i, seed: (b, 0, i))
+                      for n in (3, 1))
+    return ((bsz, num_heads // heads),
+            dict(heads=heads, num_heads=num_heads, d=d, causal=False),
+            x_spec, o_spec)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def _qkv_forward(x, seed, d, heads, sm_scale, dropout_p):
+    grid, kw, x_spec, o_spec = _qkv_grid(x, heads, d)
+    bsz, s, width = x.shape
+    return _call(
+        functools.partial(_qkv_fwd_kernel, sm_scale=sm_scale,
+                          dropout_p=dropout_p, **kw),
+        grid, [x_spec], o_spec,
+        jax.ShapeDtypeStruct((bsz, s, width // 3), x.dtype), seed, x)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _qkv_backward(x, o, do, seed, d, heads, sm_scale, dropout_p):
+    grid, kw, x_spec, o_spec = _qkv_grid(x, heads, d)
+    return _call(
+        functools.partial(_qkv_bwd_kernel, sm_scale=sm_scale,
+                          dropout_p=dropout_p, **kw),
+        grid, [x_spec, o_spec, o_spec], x_spec,
+        jax.ShapeDtypeStruct(x.shape, x.dtype), seed, x, o, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _flash_qkv(x, seed, d, heads, sm_scale, dropout_p):
+    return _qkv_forward(x, seed, d, heads, sm_scale, dropout_p)
+
+
+def _flash_qkv_fwd(x, seed, d, heads, sm_scale, dropout_p):
+    out = _qkv_forward(x, seed, d, heads, sm_scale, dropout_p)
+    return out, (x, out, seed)
+
+
+def _flash_qkv_bwd(d, heads, sm_scale, dropout_p, res, do):
+    x, out, seed = res
+    dx = _qkv_backward(x, out, do, seed, d, heads, sm_scale, dropout_p)
+    return dx, onp.zeros(seed.shape, jax.dtypes.float0)
+
+
+_flash_qkv.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)
+
+
+def flash_attention_qkv(qkv, num_heads, dropout_p=0.0, dropout_key=None):
+    """Self-attention straight from the interleaved projection: ``qkv`` is
+    (seq, batch, num_heads * 3 * head_dim), per head its query, key and
+    value; the result is (seq, batch, num_heads * head_dim).  The whole-row
+    kernels read and write these arrays in place: no (batch*heads, seq,
+    head_dim) copy of q, k, v is made, forward or backward, and the
+    backward keeps nothing but ``qkv`` and the output.  Only for shapes
+    :func:`qkv_heads_per_step` accepts; dropout as :func:`flash_attention`
+    (flattened index ``batch * num_heads + head``)."""
+    seq, bsz, width = qkv.shape
+    d = width // (3 * num_heads)
+    heads = qkv_heads_per_step(seq, num_heads, d)
+    if heads is None:
+        raise ValueError(f"flash_attention_qkv cannot take seq {seq}, "
+                         f"{num_heads} heads of {d}")
+    dropout_p, seed = _dropout_args(dropout_p, dropout_key)
+    # batch-major for the kernels: a dimension order, not a copy (above)
+    out = _flash_qkv(qkv.transpose(1, 0, 2), seed, d, heads,
+                     1.0 / math.sqrt(d), dropout_p)
+    return out.transpose(1, 0, 2)
 
 
 # ---------------------------------------------------------------------------

@@ -93,6 +93,8 @@ SPECS = {
                       feature_stride=8, rpn_post_nms_top_n=5)),
     # --- attention ------------------------------------------------------
     "interleaved_matmul_selfatt_qk": ([_f(6, 2, 24)], dict(heads=2)),
+    "interleaved_selfatt": ([_f(8, 2, 96), onp.zeros(2, onp.uint32)],
+                            dict(heads=2, p=0.5, training=True)),
     "interleaved_matmul_selfatt_valatt": ([_f(6, 2, 24), _f(4, 6, 6)],
                                           dict(heads=2)),
     "interleaved_matmul_encdec_qk": ([_f(6, 2, 8), _f(5, 2, 16)],

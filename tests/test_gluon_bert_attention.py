@@ -1,0 +1,314 @@
+"""The attention core of the Gluon BERT path as one operator
+(``interleaved_selfatt``): its two paths agree, ``BERTSelfAttention`` runs it
+hybridized and through ``Trainer.compile_step`` with the Pallas kernel forced
+(interpret mode on the CPU), the counters and the ``fallback`` event say
+which path a trace took, AMP classifies it, and Mosaic compiles the kernels
+at the benchmark's widths for a described v5e."""
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import amp, autograd, gluon, nd, telemetry
+from mxnet_tpu.gluon.model_zoo import bert
+from mxnet_tpu.ops import contrib
+from mxnet_tpu.ops import pallas_kernels as pk
+from mxnet_tpu.ops.registry import get_op
+from mxnet_tpu.parallel import mesh as pmesh
+
+HEADS, HEAD_DIM = 2, 64           # two heads fill a 128-lane row
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The operator's platform test says TPU; the kernels still see the CPU
+    and run under the Pallas interpreter."""
+    monkeypatch.setattr(contrib, "_attention_platform", lambda: "tpu")
+
+
+def _counts():
+    snap = telemetry.snapshot()
+    return snap["attention.fused"], snap["attention.unfused"]
+
+
+def _fallbacks():
+    return telemetry.events("fallback", "attention.fused")
+
+
+def _qkv(seq, bsz, dtype, seed=0):
+    rng = onp.random.RandomState(seed)
+    return jnp.asarray(rng.randn(seq, bsz, 3 * HEADS * HEAD_DIM), dtype)
+
+
+def _core(qkv, key, training):
+    return contrib.interleaved_selfatt(qkv, key, heads=HEADS, p=0.1,
+                                       training=training)
+
+
+# -- the two paths ----------------------------------------------------------
+@pytest.mark.parametrize("training", [False, True], ids=["eval", "train"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_fused_and_unfused_paths_agree(monkeypatch, dtype, training):
+    qkv = _qkv(16, 2, dtype)
+    key = jnp.asarray([5, 9], jnp.uint32)
+    w = jnp.asarray(onp.random.RandomState(1).randn(16, 2, HEADS * HEAD_DIM),
+                    jnp.float32)
+
+    def run(platform):
+        monkeypatch.setattr(contrib, "_attention_platform", lambda: platform)
+        out = _core(qkv, key, training)
+        grad = jax.grad(lambda x: (_core(x, key, training)
+                                   .astype(jnp.float32) * w).sum())(qkv)
+        return out, grad
+
+    fused, unfused = run("tpu"), run("cpu")
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for a, b, name in zip(fused, unfused, ("out", "dqkv")):
+        assert a.dtype == dtype and a.shape == b.shape
+        b = onp.asarray(b, onp.float32)
+        err = onp.abs(onp.asarray(a, onp.float32) - b).max()
+        assert err <= tol * max(1.0, onp.abs(b).max()), (name, err)
+
+
+def test_matches_the_two_interleaved_ops_and_drops_only_in_training():
+    qkv = nd.array(onp.random.RandomState(2).randn(16, 2, 384)
+                   .astype("float32"))
+    scores = nd.contrib.interleaved_matmul_selfatt_qk(qkv, heads=HEADS)
+    want = nd.contrib.interleaved_matmul_selfatt_valatt(
+        qkv, nd.softmax(scores, axis=-1), heads=HEADS).asnumpy()
+    # the frontend draws the key itself, as it does for Dropout
+    got = nd.contrib.interleaved_selfatt(qkv, heads=HEADS, p=0.1).asnumpy()
+    onp.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    a = nd.contrib.interleaved_selfatt(qkv, heads=HEADS, p=0.1,
+                                       training=True).asnumpy()
+    b = nd.contrib.interleaved_selfatt(qkv, heads=HEADS, p=0.1,
+                                       training=True).asnumpy()
+    assert not onp.allclose(a, want) and not onp.allclose(a, b)
+
+
+def test_unfused_dropout_is_the_kernels_mask():
+    qkv = _qkv(16, 2, jnp.float32, seed=3)
+    key = jnp.asarray([1, 2], jnp.uint32)
+    x = qkv.reshape(16, 2, HEADS, 3, HEAD_DIM)
+    q, k, v = (x[:, :, :, j].transpose(1, 2, 0, 3)
+               .reshape(2 * HEADS, 16, HEAD_DIM) for j in range(3))
+    att = jax.nn.softmax(jnp.einsum("bqd,bkd->bqk", q, k) / HEAD_DIM ** 0.5)
+    att = jnp.where(pk.dropout_keep_mask(key, 2 * HEADS, 16, 16, 0.1), att,
+                    0) / 0.9
+    want = jnp.einsum("bqk,bkd->bqd", att, v).reshape(2, HEADS, 16, HEAD_DIM)
+    want = want.transpose(2, 0, 1, 3).reshape(16, 2, HEADS * HEAD_DIM)
+    onp.testing.assert_allclose(onp.asarray(_core(qkv, key, True)),
+                                onp.asarray(want), rtol=1e-5, atol=1e-6)
+
+
+# -- the block, hybridized and compiled -------------------------------------
+def _attention_block(dropout=0.1):
+    net = bert.BERTSelfAttention(HEADS * HEAD_DIM, HEADS, dropout=dropout)
+    net.initialize(mx.init.Xavier())
+    return net
+
+
+def test_block_hybridized_runs_the_kernel(as_tpu):
+    net = _attention_block()
+    x = nd.array(onp.random.RandomState(4).randn(2, 16, HEADS * HEAD_DIM)
+                 .astype("float32"))
+    eager = net(x).asnumpy()
+    fused0, unfused0 = _counts()
+    net.hybridize()
+    hybrid = net(x).asnumpy()
+    onp.testing.assert_allclose(hybrid, eager, rtol=1e-5, atol=1e-6)
+    with autograd.record():
+        first = net(x).asnumpy()
+    with autograd.record():
+        second = net(x).asnumpy()
+    # training drops, with a fresh key a call; prediction does not
+    assert not onp.allclose(first, eager) and not onp.allclose(first, second)
+    onp.testing.assert_allclose(net(x).asnumpy(), eager, rtol=1e-5, atol=1e-6)
+    fused, unfused = _counts()
+    assert fused > fused0 and unfused == unfused0
+    assert not _fallbacks()
+
+
+def _train_tiny_bert(steps=8):
+    net = bert.BERTModel(vocab_size=64, units=HEADS * HEAD_DIM, mlp_units=64,
+                         num_layers=2, num_heads=HEADS, max_len=16,
+                         dropout=0.1)
+    net.initialize(mx.init.Xavier())
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "adam",
+                            {"learning_rate": 1e-2}, kvstore="tpu")
+    step = trainer.compile_step(net, lambda n, x, y: ((n(x) - y) ** 2).mean())
+    rng = onp.random.RandomState(5)
+    toks = nd.array(rng.randint(0, 64, (8, 16)), dtype="int32")
+    y = nd.array(rng.randn(8, 16, HEADS * HEAD_DIM).astype("float32"))
+    losses = [float(step(toks, y, batch_size=8).asscalar())
+              for _ in range(steps)]
+    assert step.last_step_compiled, step.last_fallback_reason
+    assert all(map(onp.isfinite, losses)) and losses[-1] < losses[0], losses
+
+
+def test_block_through_compile_step_with_the_kernel(as_tpu, monkeypatch):
+    monkeypatch.setenv("MXNET_SPMD_MESH", "off")        # one chip, as a cell
+    telemetry.clear_events()
+    fused0, unfused0 = _counts()
+    _train_tiny_bert()
+    # one count a site a trace: two layers, and nothing unfused
+    assert _counts() == (fused0 + 2, unfused0) and not _fallbacks()
+
+
+def test_compile_step_under_a_mesh_keeps_the_unfused_expression(as_tpu):
+    """kvstore='tpu' over the suite's 8 virtual devices is a dp=8 mesh:
+    ``pallas_call`` cannot be partitioned, so every site says why."""
+    telemetry.clear_events()
+    fused0, unfused0 = _counts()
+    _train_tiny_bert(steps=3)
+    assert _counts() == (fused0, unfused0 + 2)
+    assert [e["why"] for e in _fallbacks()] == ["mesh of 8 devices"] * 2
+
+
+# -- which path, and what says so -------------------------------------------
+def test_counters_and_fallback_event(as_tpu):
+    key = jnp.zeros((2,), jnp.uint32)
+    telemetry.clear_events()
+    fused0, unfused0 = _counts()
+    _core(_qkv(16, 2, jnp.float32), key, True)
+    assert _counts() == (fused0 + 1, unfused0) and not _fallbacks()
+
+    _core(_qkv(12, 2, jnp.float32), key, True)         # 12 % 8 != 0
+    assert _counts() == (fused0 + 1, unfused0 + 1)
+    (event,) = _fallbacks()
+    # "seq" is the bus's own key: the field is stored as x_seq
+    assert (event["x_seq"], event["head_dim"]) == (12, HEAD_DIM)
+    assert "multiples of 8" in event["why"]
+
+    telemetry.clear_events()
+    with pmesh.mesh_scope(pmesh.make_mesh({"dp": 2})):
+        _core(_qkv(16, 2, jnp.float32), key, True)
+    assert _counts() == (fused0 + 1, unfused0 + 2)
+    (event,) = _fallbacks()
+    assert "mesh of 2 devices" in event["why"]
+    # a mesh of one device is no mesh
+    with pmesh.mesh_scope(pmesh.make_mesh({"dp": 1})):
+        _core(_qkv(16, 2, jnp.float32), key, True)
+    assert _counts() == (fused0 + 2, unfused0 + 2)
+
+
+def test_cpu_takes_the_unfused_path_without_an_event():
+    telemetry.clear_events()
+    fused0, unfused0 = _counts()
+    _core(_qkv(12, 2, jnp.float32), jnp.zeros((2,), jnp.uint32), True)
+    assert _counts() == (fused0, unfused0 + 1) and not _fallbacks()
+
+
+# -- AMP --------------------------------------------------------------------
+def test_amp_casts_qkv_down_and_keeps_the_key():
+    from op_smoke_specs import SPECS
+
+    assert "interleaved_selfatt" in amp.lists.LOW_PRECISION_FUNCS
+    assert "interleaved_selfatt" in SPECS
+    assert get_op("interleaved_selfatt").rng_input
+    qkv = nd.array(onp.random.RandomState(6).randn(16, 2, 384)
+                   .astype("float32"))
+    key = nd.array(onp.array([3, 4], "uint32"), dtype="uint32")
+    plain = nd.contrib.interleaved_selfatt(qkv, key, heads=HEADS, p=0.1,
+                                           training=True)
+    amp.init("bfloat16")
+    try:
+        low = nd.contrib.interleaved_selfatt(qkv, key, heads=HEADS, p=0.1,
+                                             training=True)
+    finally:
+        amp.uninit()
+    assert plain.dtype == onp.float32 and str(low.dtype) == "bfloat16"
+    # the same key, so the same mask: only bf16 rounding apart
+    onp.testing.assert_allclose(low.astype("float32").asnumpy(),
+                                plain.asnumpy(), rtol=5e-2, atol=5e-2)
+
+
+# -- Mosaic, for a described v5e (no chip) ----------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("seq,bsz", [(512, 32), (128, 128)],
+                         ids=["s512", "s128"])
+def test_mosaic_compiles_the_cells_attention(one_chip, as_tpu, monkeypatch,
+                                             seq, bsz):
+    """``BERTSelfAttention`` at the benchmark's widths (bf16, 12 heads of 64,
+    dropout 0.1), forward and backward, as a step stages it: the program
+    holds the two Mosaic calls, and XLA neither transposes nor re-tiles an
+    activation around them (the block's transposes sit next to the
+    operator, whose kernels undo them: batch-major blocks are the layout
+    the projections write)."""
+    from mxnet_tpu.gluon import block as gblock
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    net = bert.BERTSelfAttention(768, 12, dropout=0.1)
+    net.initialize()
+    net.cast("bfloat16")
+    params = net.collect_params()
+    x = nd.zeros((1, 8, 768), dtype="bfloat16")
+    raw_fn, _, _ = gblock._stage_fn(net, params, list(params),
+                                    gblock._flatten_args((x,))[1], True,
+                                    x.ctx)
+
+    def loss(weights, x, key):
+        (out,), _ = raw_fn(weights, [x], key)
+        return out.astype(jnp.float32).sum()
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    text = _compiled_text(
+        jax.grad(loss, argnums=(0, 1)),
+        [shape(p.shape) for p in params.values()], shape((bsz, seq, 768)),
+        shape((2,), jnp.uint32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " transpose(%" not in text                  # the HLO opcode
+    copied = [dims for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+              if math.prod(map(int, dims.split(","))) >= bsz * seq * 768]
+    assert not copied, copied
+
+
+@pytest.mark.parametrize("bh,seq,calls", [(384, 128, 2), (8, 2048, 3)],
+                         ids=["rows", "blocked"])
+def test_mosaic_compiles_the_split_head_kernels(one_chip, monkeypatch, bh,
+                                                seq, calls):
+    """``flash_attention`` as ``transformer_lm`` and long sequences call
+    it: whole-row kernels to 512 keys, the blocked three beyond."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct((bh, seq, 64), jnp.bfloat16, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+
+    def loss(q, k, v, key):
+        return pk.flash_attention(q, k, v, causal=False, dropout_p=0.1,
+                                  dropout_key=key).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                          x, x, x, key)
+    assert text.count("tpu_custom_call") == calls

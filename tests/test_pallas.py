@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops.pallas_kernels import flash_attention
 
 
@@ -82,6 +83,153 @@ def test_flash_bf16():
                            v.astype(jnp.float32), False, 1 / 32 ** 0.5)
     onp.testing.assert_allclose(onp.asarray(out, onp.float32),
                                 onp.asarray(ref), rtol=3e-2, atol=3e-2)
+
+
+# -- dropout inside the kernels, operands in the caller's dtype -------------
+def _dense_dropout_attention(q, k, v, p, key):
+    """float32 reference with the SAME mask function as the kernels."""
+    bh, s, d = q.shape
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    pr = jax.nn.softmax(jnp.einsum("bqd,bkd->bqk", q, k) / d ** 0.5, axis=-1)
+    if p:
+        pr = jnp.where(pk.dropout_keep_mask(key, bh, s, s, p), pr, 0.0) \
+            / (1.0 - p)
+    return jnp.einsum("bqk,bkd->bqd", pr, v)
+
+
+_DROPOUT_PLANS = {
+    "single_tile": ((2, 64, 16), ("rows", 1)),
+    "several_heads_a_step": ((8, 32, 16), ("rows", 4)),
+    "multi_block": ((2, 64, 16), ("blocks", 16, 32)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("dropout_p", [0.0, 0.1])
+@pytest.mark.parametrize("case", sorted(_DROPOUT_PLANS))
+def test_flash_dropout_matches_dense_with_shared_mask(case, dropout_p, dtype):
+    shape, plan = _DROPOUT_PLANS[case]
+    key = jax.random.PRNGKey(5)
+    q, k, v, w = (jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+                  for kk in jax.random.split(jax.random.PRNGKey(1), 4))
+    w32 = w.astype(jnp.float32)
+
+    def flash(q, k, v):
+        return pk._flash(q, k, v, pk._seed_words(key), False,
+                         1 / shape[-1] ** 0.5, dropout_p, plan)
+
+    out = flash(q, k, v)
+    assert out.dtype == dtype
+    got = (out,) + jax.grad(
+        lambda *a: (flash(*a).astype(jnp.float32) * w32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    want = (_dense_dropout_attention(q, k, v, dropout_p, key),) + jax.grad(
+        lambda *a: (_dense_dropout_attention(*a, dropout_p, key) * w32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2      # of each tensor's scale
+    for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        b = onp.asarray(b, onp.float32)
+        err = onp.abs(onp.asarray(a, onp.float32) - b).max()
+        assert err <= tol * max(1.0, onp.abs(b).max()), (name, err)
+
+
+def test_flash_dropout_mask_is_a_bernoulli_of_key_head_and_position():
+    p, shape = 0.1, (6, 128, 128)
+    mask = onp.asarray(pk.dropout_keep_mask(jax.random.PRNGKey(3), *shape, p))
+    rate, n = mask.mean(), mask.size
+    assert abs(rate - (1 - p)) <= 3 * (p * (1 - p) / n) ** 0.5, rate
+    again = onp.asarray(pk.dropout_keep_mask(jax.random.PRNGKey(3), *shape, p))
+    assert (mask == again).all()
+    other = onp.asarray(pk.dropout_keep_mask(jax.random.PRNGKey(4), *shape, p))
+    # two independent Bernoulli(0.9) masks differ at 2 p (1 - p) = 18%
+    for a, b, what in ((mask, other, "key"), (mask[0], mask[1], "head"),
+                       (mask[:, 0], mask[:, 1], "query position"),
+                       (mask[:, :, 0], mask[:, :, 1], "key position")):
+        assert 0.14 < (a != b).mean() < 0.22, what
+    # a typed key and its raw words name the same mask
+    typed = jax.random.key(3)
+    assert (onp.asarray(pk.dropout_keep_mask(typed, *shape, p)) == onp.asarray(
+        pk.dropout_keep_mask(jax.random.key_data(typed), *shape, p))).all()
+
+
+def test_flash_dropout_does_not_depend_on_the_tiling():
+    shape, key = (4, 64, 16), jax.random.PRNGKey(9)
+    q, k, v = (jax.random.normal(kk, shape, jnp.float32)
+               for kk in jax.random.split(jax.random.PRNGKey(2), 3))
+
+    def flash(plan):
+        return onp.asarray(pk._flash(q, k, v, pk._seed_words(key), False,
+                                     0.25, 0.1, plan))
+
+    one_head, four_heads = flash(("rows", 1)), flash(("rows", 4))
+    assert (one_head == four_heads).all()               # to the bit
+    whole = one_head
+    # the blocked kernels sum in another order: the same mask, to rounding
+    onp.testing.assert_allclose(flash(("blocks", 16, 32)), whole,
+                                rtol=1e-5, atol=1e-6)
+    onp.testing.assert_allclose(flash(("blocks", 32, 16)), whole,
+                                rtol=1e-5, atol=1e-6)
+    # two query-block sizes walk the keys in the same order: to the bit
+    assert (flash(("blocks", 16, 32)) == flash(("blocks", 32, 32))).all()
+
+
+def test_flash_public_entry_takes_the_key_and_checks_the_rate():
+    q = jnp.ones((2, 16, 8), jnp.float32)
+    key = jax.random.PRNGKey(0)
+    dropped = flash_attention(q, q, q, causal=False, dropout_p=0.5,
+                              dropout_key=key)
+    assert not onp.allclose(onp.asarray(dropped), 1.0)  # v is all ones
+    kept = flash_attention(q, q, q, causal=False, dropout_p=0.0)
+    onp.testing.assert_allclose(onp.asarray(kept), 1.0, rtol=1e-6)
+    with pytest.raises(ValueError, match="dropout_key"):
+        flash_attention(q, q, q, dropout_p=0.5)
+    with pytest.raises(ValueError, match="dropout_p"):
+        flash_attention(q, q, q, dropout_p=1.0, dropout_key=key)
+
+
+@pytest.mark.parametrize("bh,seq,plan", [
+    (384, 512, ("rows", 1)), (1536, 128, ("rows", 16)),
+    (7, 96, ("rows", 7)), (8, 2048, ("blocks", 512, 512)),
+    (3, 520, ("blocks", 104, 104)), (2, 1030, ("blocks", 206, 206))])
+def test_flash_block_rule(bh, seq, plan):
+    assert pk._plan(bh, seq) == plan
+
+
+@pytest.mark.parametrize("seq,num_heads,head_dim,heads", [
+    (512, 12, 64, 4), (128, 16, 64, 4), (16, 6, 64, 2), (16, 3, 64, None),
+    (128, 8, 128, 4), (128, 8, 32, 4), (128, 16, 16, 8), (12, 8, 64, None),
+    (1024, 8, 64, None), (128, 8, 20, None)])
+def test_interleaved_heads_per_step_rule(seq, num_heads, head_dim, heads):
+    assert pk.qkv_heads_per_step(seq, num_heads, head_dim) == heads
+
+
+@pytest.mark.parametrize("dropout_p", [0.0, 0.1])
+def test_flash_qkv_is_flash_attention_over_the_interleaved_layout(dropout_p):
+    seq, bsz, heads, d = 32, 2, 4, 32
+    key = jax.random.PRNGKey(8)
+    qkv, w = (jax.random.normal(k, shape, jnp.float32) for k, shape in zip(
+        jax.random.split(jax.random.PRNGKey(6)),
+        ((seq, bsz, heads * 3 * d), (seq, bsz, heads * d))))
+
+    def split(qkv):
+        x = qkv.reshape(seq, bsz, heads, 3, d)
+        out = flash_attention(*(x[:, :, :, j].transpose(1, 2, 0, 3)
+                                for j in range(3)), causal=False,
+                              dropout_p=dropout_p, dropout_key=key)
+        return out.transpose(2, 0, 1, 3).reshape(seq, bsz, heads * d)
+
+    def in_place(qkv):
+        return pk.flash_attention_qkv(qkv, heads, dropout_p=dropout_p,
+                                      dropout_key=key)
+
+    onp.testing.assert_allclose(in_place(qkv), split(qkv), rtol=1e-5,
+                                atol=1e-6)
+    onp.testing.assert_allclose(
+        jax.grad(lambda x: (in_place(x) * w).sum())(qkv),
+        jax.grad(lambda x: (split(x) * w).sum())(qkv), rtol=1e-4, atol=1e-5)
+    with pytest.raises(ValueError, match="cannot take"):
+        pk.flash_attention_qkv(qkv[:12], heads)
 
 
 def test_transformer_uses_flash_when_forced():

@@ -108,6 +108,8 @@ def test_canonical_counters_registered():
         "sharding.legalize_refusal",
         "quantization.pallas_skipped",
         "transformer_lm.flash_fallback",
+        "attention.fused",
+        "attention.unfused",
         "fused.trace",
         "fused.dispatch",
         "nn.pad_channels",

@@ -21,7 +21,8 @@ Phases (a failed check is a nonzero exit; nothing is retried or skipped):
                        reference at two shapes, and at the two shapes the
                        benchmark's BERT cells run with the dropout inside
                        (same-mask reference; ms a call beside the unfused
-                       expression's)
+                       expression's); ``ops.random.keep_mask`` on the chip
+                       against the CPU's, bit for bit
 - ``serve/decode``     ``serving_decode.GenerativeEngine`` at the default
                        precision against the float32 eager oracle (token-exact
                        up to stated bf16 near ties), pool buffers donated
@@ -394,8 +395,26 @@ def flash_phase(sz, rehearse):
                   f"dv {g[2][1]:.1e} (err / scale)")
     for shape in sz["cell_shapes"]:
         _flash_cell_shape(shape, rehearse)
+    _keep_mask_everywhere(sz["cell_shapes"][0])
     check(tlm.flash_fallback_count() == fb0,
           "transformer_lm.flash_fallback_count() did not move")
+
+
+def _keep_mask_everywhere(shape, width=768, keep_prob=0.9):
+    """The dropout mask of a cell's hidden activations for one key: the
+    default device's against the host CPU's, bit for bit."""
+    import jax
+    import numpy as onp
+
+    from mxnet_tpu.ops.random import keep_mask
+
+    key = onp.asarray(jax.random.key_data(jax.random.key(shape[0])))
+    masks = [onp.asarray(keep_mask(jax.device_put(key, dev),
+                                   (shape[1], shape[0], width), keep_prob))
+             for dev in (jax.devices()[0], jax.devices("cpu")[0])]
+    check(bool((masks[0] == masks[1]).all()),
+          f"keep_mask {masks[0].shape} on {jax.devices()[0].platform} equals "
+          f"the CPU's bit for bit (kept {masks[0].mean():.4f})")
 
 
 def _flash_cell_shape(shape, rehearse, dropout_p=0.1, calls=20, heads=12):

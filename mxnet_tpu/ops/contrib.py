@@ -55,7 +55,7 @@ def interleaved_matmul_selfatt_valatt(queries_keys_values, attention, heads=1):
 # Pallas flash kernels compute it without the (batch*heads, seq, seq) tensor
 # ever reaching HBM (ops/pallas_kernels.py); everywhere else the same
 # operator computes the unfused expression with the SAME dropout mask
-# (pallas_kernels.dropout_keep_mask), so a key means one mask on every path.
+# (ops.random.keep_mask), so a key means one mask on every path.
 # Which path is decided from what the trace can observe, never by a switch:
 # the platform, the active mesh, and the shape.
 

@@ -12,12 +12,14 @@ computing in NHWC internally while presenting NCHW at the API boundary.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as onp
 
+from .random import keep_mask
 from .registry import register
 
 
@@ -614,11 +616,32 @@ def lrn(data, alpha=1e-4, beta=0.75, knorm=2.0, nsize=5):
 
 # --- dropout ---------------------------------------------------------------
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _dropout(data, key, keep_prob, mask_shape):
+    keep = keep_mask(key, mask_shape, keep_prob)
+    return jnp.where(keep, data, 0) * (1.0 / keep_prob)
+
+
+# Dropout is linear in ``data``: its transpose is itself under the same mask,
+# which the backward regenerates from the key.  Nothing of the operand's
+# shape is kept between the passes.
+_dropout.defvjp(
+    lambda data, key, keep_prob, mask_shape:
+        (_dropout(data, key, keep_prob, mask_shape), key),
+    lambda keep_prob, mask_shape, key, g:
+        (_dropout(g, key, keep_prob, mask_shape), None))
+
+
 @register("Dropout", num_inputs=2, rng_input=True)
 def dropout(data, key, p=0.5, mode="training", axes=None, training=False,
             cudnn_off=None):
     """Reference src/operator/nn/dropout.cc.  ``key`` is a uint32 PRNG key
-    array threaded explicitly so the op stays pure/traceable."""
+    array threaded explicitly so the op stays pure/traceable.
+
+    The mask is ``ops.random.keep_mask``: a pure function of ``key`` and
+    each element's index (with ``axes``, of the index in the broadcast
+    shape), the same on every platform, eager or compiled, and under any
+    mesh.  It is NOT the stream of ``jax.random.bernoulli(key, ...)``."""
     if not training and mode != "always":
         return data
     if p <= 0.0:
@@ -626,9 +649,7 @@ def dropout(data, key, p=0.5, mode="training", axes=None, training=False,
     shape = data.shape
     if axes:
         shape = tuple(1 if i in axes else s for i, s in enumerate(shape))
-    keep = 1.0 - p
-    mask = jax.random.bernoulli(key, keep, shape).astype(data.dtype) / keep
-    return data * mask
+    return _dropout(data, key, 1.0 - p, shape)
 
 
 # --- losses-as-ops ---------------------------------------------------------

@@ -35,6 +35,8 @@ import numpy as onp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .random import _keep, _seed_words, keep_mask
+
 __all__ = ["flash_attention", "flash_attention_qkv", "qkv_heads_per_step",
            "dropout_keep_mask", "matmul_bn_stats", "conv1x1_bn_stats",
            "conv1x1_bn_stats_train", "fused_blocks",
@@ -61,63 +63,19 @@ def _interpret() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# dropout on the probabilities: a counter-based hash of the global position
+# dropout on the probabilities: the program's one keep mask (ops/random.py)
 # ---------------------------------------------------------------------------
 #
-# The keep mask is a pure function of (key words, flattened batch*head index,
-# query position, key position): the forward and every backward kernel
-# regenerate it, nothing is stored, and it does not depend on the tiling.
-# It is plain int32 ``jax.numpy`` (wrapping multiply, logical shift, xor), so
-# Mosaic, the Pallas interpreter and a dense reference run the same function.
-# The hardware generator (``pltpu.prng_*``) has no CPU rule, so tier-1 could
-# not test a mask made with it.
-#
-# Cost where it matters, per (query, key) element: one add, one shift, one
-# xor, one multiply, one compare.  The row and column words are mixed once
-# per row and per column (``_mix``, the lowbias32 finaliser) and the last
-# round decorrelates their sum.
-
-_MIX_1 = 0x7FEB352D
-_MIX_2 = 0x846CA68B - (1 << 32)            # as a wrapped int32
-
-
-def _mix(x):
-    x = x ^ jax.lax.shift_right_logical(x, 16)
-    x = x * jnp.int32(_MIX_1)
-    x = x ^ jax.lax.shift_right_logical(x, 15)
-    x = x * jnp.int32(_MIX_2)
-    return x ^ jax.lax.shift_right_logical(x, 16)
-
-
-def _keep(seed0, seed1, head, q_pos, k_pos, dropout_p):
-    """Bernoulli(1 - dropout_p) keep mask at the broadcast of ``head``,
-    ``q_pos`` and ``k_pos`` (int32, any broadcastable shapes)."""
-    row_word = _mix(seed0 ^ head)
-    col_word = _mix(seed1 + row_word)
-    x = _mix(row_word + q_pos) + _mix(col_word + k_pos)
-    x = x ^ jax.lax.shift_right_logical(x, 15)
-    x = x * jnp.int32(_MIX_2)
-    # x is uniform over int32: P(x >= t) = (2^31 - t) / 2^32 = 1 - p
-    t = min(round((1 << 31) - (1.0 - dropout_p) * (1 << 32)), (1 << 31) - 1)
-    return x >= jnp.int32(t)
-
-
-def _seed_words(key):
-    """The two int32 words the kernels take, from a PRNG key (typed, or the
-    raw ``uint32[2]`` of ``mxnet_tpu.random.next_key``)."""
-    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
-        key = jax.random.key_data(key)
-    return jax.lax.bitcast_convert_type(key.astype(jnp.uint32), jnp.int32)
+# A pure function of (key words, flattened batch*head index, query position,
+# key position): the forward and every backward kernel regenerate it tile by
+# tile, nothing is stored, and it does not depend on the tiling.
 
 
 def dropout_keep_mask(key, num_heads, seq_q, seq_k, dropout_p):
     """The dense ``(num_heads, seq_q, seq_k)`` boolean keep mask that
     :func:`flash_attention` applies inside its kernels for ``key``:
     ``num_heads`` is the flattened batch*heads extent."""
-    seed = _seed_words(key)
-    iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32,
-                             (num_heads, seq_q, seq_k))
-    return _keep(seed[0], seed[1], iota(0), iota(1), iota(2), dropout_p)
+    return keep_mask(key, (num_heads, seq_q, seq_k), 1.0 - dropout_p)
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +122,7 @@ def _masks(seed_ref, head, q0, k0, rows, cols, causal, dropout_p):
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
     visible = (q_pos >= k_pos) if causal else None
     keep = _keep(seed_ref[0], seed_ref[1], head, q_pos, k_pos,
-                 dropout_p) if dropout_p else None
+                 1.0 - dropout_p) if dropout_p else None
     return visible, keep
 
 

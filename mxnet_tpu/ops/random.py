@@ -6,6 +6,8 @@ need per-step randomness (Dropout) thread keys as explicit inputs instead.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as _onp
@@ -18,6 +20,80 @@ def _dt(dtype):
     if dtype in (None, "None"):
         return jnp.float32
     return jnp.dtype(dtype) if isinstance(dtype, str) else dtype
+
+
+# --- the keep mask of every dropout: a counter hash of (key, index) --------
+#
+# One function for ``Dropout``, the fused ``RNN`` op and the attention core
+# (the Pallas kernels regenerate it tile by tile in their forward and
+# backward, ``ops/pallas_kernels.py``).  It is a pure function of the key's
+# two words and each element's global index: nothing is stored for a
+# backward pass, and the mask is the same on every platform, eager or
+# compiled, under any mesh and any tiling.  Plain int32 ``jax.numpy``
+# (wrapping multiply, logical shift, xor), so Mosaic, the Pallas interpreter
+# and XLA run the same arithmetic; the hardware generator (``pltpu.prng_*``)
+# has no CPU rule, so tier-1 could not test a mask made with it.
+#
+# Cost per element: one add, one shift, one xor, one multiply, one compare.
+# The row and column words are mixed once per row and per column (``_mix``,
+# the lowbias32 finaliser) and the last round decorrelates their sum.
+
+_MIX_1 = 0x7FEB352D
+_MIX_2 = 0x846CA68B - (1 << 32)            # as a wrapped int32
+
+
+def _mix(x):
+    x = x ^ jax.lax.shift_right_logical(x, 16)
+    x = x * jnp.int32(_MIX_1)
+    x = x ^ jax.lax.shift_right_logical(x, 15)
+    x = x * jnp.int32(_MIX_2)
+    return x ^ jax.lax.shift_right_logical(x, 16)
+
+
+def _keep(seed0, seed1, head, row, col, keep_prob):
+    """Bernoulli(keep_prob) keep mask at the broadcast of ``head``, ``row``
+    and ``col`` (int32, any broadcastable shapes)."""
+    row_word = _mix(seed0 ^ head)
+    col_word = _mix(seed1 + row_word)
+    x = _mix(row_word + row) + _mix(col_word + col)
+    x = x ^ jax.lax.shift_right_logical(x, 15)
+    x = x * jnp.int32(_MIX_2)
+    # x is uniform over int32: P(x >= t) = (2^31 - t) / 2^32 = keep_prob
+    t = min(round((1 << 31) - keep_prob * (1 << 32)), (1 << 31) - 1)
+    return x >= jnp.int32(t)
+
+
+def _seed_words(key):
+    """The two int32 words of a PRNG key (typed, or the raw ``uint32[2]`` of
+    ``mxnet_tpu.random.next_key``)."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    return jax.lax.bitcast_convert_type(key.astype(jnp.uint32), jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def keep_mask(key, shape, keep_prob):
+    """Boolean Bernoulli(``keep_prob``) mask of ``shape`` for ``key``.
+
+    The last two axes are the row and the column of the hash and the
+    leading axes fold, row-major, into its third word, so no two elements
+    of one call share an index triple."""
+    out_shape = tuple(shape)
+    shape = (1,) * (2 - len(out_shape)) + out_shape
+    rank = len(shape)
+
+    def iota(axis):
+        return jax.lax.broadcasted_iota(
+            jnp.int32, (1,) * axis + (shape[axis],) + (1,) * (rank - 1 - axis),
+            axis)
+
+    head = jnp.zeros((1,) * rank, jnp.int32)
+    for axis in range(rank - 2):
+        head = head * shape[axis] + iota(axis)
+    seed = _seed_words(key)
+    keep = _keep(seed[0], seed[1], head, iota(rank - 2), iota(rank - 1),
+                 keep_prob)
+    return jnp.broadcast_to(keep, shape).reshape(out_shape)
 
 
 @register("uniform", num_inputs=0, differentiable=False,

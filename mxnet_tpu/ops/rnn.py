@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .random import keep_mask
 from .registry import register
 
 __all__ = ["rnn_fused"]
@@ -120,7 +121,7 @@ def rnn_fused(arrays, mode="lstm", hidden_size=0, num_layers=1,
         x = ys_dirs[0] if ndir == 1 else jnp.concatenate(ys_dirs, axis=-1)
         if dropout > 0.0 and layer < num_layers - 1:
             layer_key = jax.random.fold_in(key, layer)
-            keep = jax.random.bernoulli(layer_key, 1.0 - dropout, x.shape)
+            keep = keep_mask(layer_key, x.shape, 1.0 - dropout)
             x = jnp.where(keep, x / (1.0 - dropout), 0.0)
 
     hT = jnp.stack(h_outs)

@@ -156,8 +156,9 @@ def train_phase(name, build, sz, mesh_spec=None, n_dev=1, steps=5):
     say(f"== {name}  (MXNET_SPMD_MESH="
         f"{os.environ.get('MXNET_SPMD_MESH', 'auto (unset)')})")
     mx.random.seed(0)
-    attn0 = {k: tel.snapshot()[f"attention.{k}"]
-             for k in ("fused", "unfused")}
+    snap0 = tel.snapshot()
+    attn0 = {k: snap0[f"attention.{k}"] for k in ("fused", "unfused")}
+    ce0 = snap0["loss.sparse_ce.fused"]
     net, loss_fn, (x_np, y_np), opt, opt_params = build(sz)
     trainer = mx.gluon.Trainer(net.collect_params(), opt, opt_params,
                                kvstore="tpu")
@@ -222,6 +223,9 @@ def train_phase(name, build, sz, mesh_spec=None, n_dev=1, steps=5):
         f"{unfused} as the unfused expression")
     if on_tpu and mesh_spec is None:
         check(unfused == 0, "one chip: no attention site left the kernel")
+    check(snap["loss.sparse_ce.fused"] > ce0,
+          "the loss traced as sparse_softmax_cross_entropy "
+          f"({snap['loss.sparse_ce.fused'] - ce0} site-traces)")
     check(all(onp.isfinite(l) for l in losses), "every loss finite")
     check(losses[-1] < losses[0],
           f"loss fell: step {steps} {losses[-1]:.4f} < step 0 "

@@ -143,6 +143,10 @@ FP16_FP32_FUNCS = [
     "fill_element_0index", "unravel_index", "ravel_multi_index",
     "shape_array", "size_array", "cast", "Cast", "_copy", "_index",
     "BlockGrad", "arange_like",
+    # the sparse-label cross-entropy takes its logits as they arrive, like
+    # `pick`: its statistics are float32 inside, and in FP32_FUNCS the
+    # policy would hand it a float32 copy of the (tokens x vocabulary) array
+    "sparse_softmax_cross_entropy",
     # ordering / extrema (value-preserving)
     "argmax", "argmin", "argmax_channel", "argsort", "sort", "topk",
     "max", "min", "unique",

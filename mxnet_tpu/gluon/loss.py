@@ -125,14 +125,20 @@ class SoftmaxCrossEntropyLoss(Loss):
         self._from_logits = from_logits
 
     def forward(self, pred, label, sample_weight=None):
-        if not self._from_logits:
-            pred = invoke("log_softmax", [pred], {"axis": self._axis})
-        if self._sparse_label:
-            loss = -invoke("pick", [pred, label],
-                           {"axis": self._axis, "keepdims": False})
+        if self._sparse_label and not self._from_logits:
+            # picks the label's logit, then normalises that one number:
+            # no log-softmax of pred's shape is formed (ops/nn.py)
+            loss = invoke("sparse_softmax_cross_entropy", [pred, label],
+                          {"axis": self._axis})
         else:
-            label = _reshape_like(pred, label)
-            loss = -(pred * label).sum(axis=self._axis, keepdims=False)
+            if not self._from_logits:
+                pred = invoke("log_softmax", [pred], {"axis": self._axis})
+            if self._sparse_label:
+                loss = -invoke("pick", [pred, label],
+                               {"axis": self._axis, "keepdims": False})
+            else:
+                label = _reshape_like(pred, label)
+                loss = -(pred * label).sum(axis=self._axis, keepdims=False)
         loss = _apply_weighting(loss, self._weight, sample_weight)
         return self._batch_mean(loss)
 

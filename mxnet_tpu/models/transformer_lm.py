@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops.nn import sparse_softmax_cross_entropy
 from ..parallel import moe as _moe
 from ..parallel import ring_attention as _ring_mod  # noqa: F401 (module import)
 from ..parallel.ring_attention import ring_attention_sharded as _ring_attention_sharded
@@ -254,9 +255,7 @@ def _masked_nll(logits, labels):
     """Per-position masked NLL: labels int32, -1 = unmasked (ignored).
     Returns (nll [B,S] with zeros at masked positions, valid mask [B,S])."""
     valid = labels >= 0
-    safe = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+    nll = sparse_softmax_cross_entropy(logits, jnp.where(valid, labels, 0))
     return jnp.where(valid, nll, 0.0), valid
 
 

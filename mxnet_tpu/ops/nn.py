@@ -21,6 +21,7 @@ import numpy as onp
 
 from .random import keep_mask
 from .registry import register
+from .tensor import pick_index
 
 
 # --- activations -----------------------------------------------------------
@@ -659,6 +660,64 @@ def softmax_cross_entropy(data, label):
     logp = jax.nn.log_softmax(data, axis=-1)
     onehot = jax.nn.one_hot(label.astype(jnp.int32), data.shape[-1], dtype=data.dtype)
     return -jnp.sum(onehot * logp)
+
+
+_SPARSE_CE_FUSED = _telemetry.counter(
+    "loss.sparse_ce.fused",
+    "sparse-label cross-entropy sites traced as one operator that picks "
+    "the label's logit before it normalises")
+
+
+def _sparse_ce_fwd(data, label, axis):
+    x = data.astype(jnp.promote_types(data.dtype, jnp.float32))
+    m = jnp.max(x, axis=axis, keepdims=True)
+    s = jnp.sum(jnp.exp(x - m), axis=axis, keepdims=True)
+    # gathered from ``data`` as it lies: the gather's operand is the array
+    # the producer wrote, not a float32 copy of it
+    picked = jnp.take_along_axis(data, label, axis=axis).astype(x.dtype)
+    loss = -((picked - m) - jnp.log(s))
+    return jnp.squeeze(loss, axis=axis), (data, m, 1.0 / s, label)
+
+
+def _sparse_ce_bwd(axis, res, g):
+    data, m, inv_s, label = res
+    g = jnp.expand_dims(g, axis).astype(m.dtype)
+    hit = jax.lax.broadcasted_iota(jnp.int32, data.shape, axis) == label
+    # (softmax - onehot) * g with the row's factors multiplied first: one
+    # subtract, exp, multiply, compare, select and subtract an element
+    grad = jnp.exp(data.astype(m.dtype) - m) * (inv_s * g) \
+        - jnp.where(hit, g, 0)
+    return grad.astype(data.dtype), None
+
+
+# The VJP is written by hand: the transpose of the gather is a scatter-add
+# into zeros of the logits' shape, and ``iota == label`` is the same one-hot
+# as an elementwise producer that a consumer fuses.
+_sparse_ce = jax.custom_vjp(
+    lambda data, label, axis: _sparse_ce_fwd(data, label, axis)[0],
+    nondiff_argnums=(2,))
+_sparse_ce.defvjp(_sparse_ce_fwd, _sparse_ce_bwd)
+
+
+@register("sparse_softmax_cross_entropy", num_inputs=2)
+def sparse_softmax_cross_entropy(data, label, axis=-1, mode="clip"):
+    """``-pick(log_softmax(data, axis), label, axis)`` without the
+    log-softmax: the label's logit is picked first and that one number
+    normalised, so nothing of ``data``'s shape is written in the forward
+    pass, and the backward is ``(softmax - onehot) * cotangent`` as one
+    elementwise expression in ``data``'s dtype.
+
+    Statistics (maximum, sum of exponentials) and the loss are float32
+    whatever ``data``'s dtype (float64 stays float64): the value is that
+    of ``log_softmax`` on a float32 copy, bit for bit.  ``label`` holds
+    class indices of any dtype, with ``data``'s shape less ``axis``;
+    out-of-range indices clip (``mode="clip"``) or wrap (``"wrap"``) as
+    ``pick``'s do."""
+    _SPARSE_CE_FUSED.inc()
+    axis = axis % data.ndim
+    label = jnp.expand_dims(
+        pick_index(label, data.shape[axis], mode), axis)
+    return _sparse_ce(data, label, axis)
 
 
 @register("SoftmaxOutput", num_inputs=2, aliases=["Softmax"])

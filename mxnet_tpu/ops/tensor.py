@@ -299,13 +299,22 @@ def take(a, indices, axis=0, mode="clip"):
     return jnp.take(a, indices.astype(jnp.int32), axis=axis, mode=jmode)
 
 
-@register("pick", num_inputs=2)
-def pick(data, index, axis=-1, keepdims=False, mode="clip"):
-    if _concrete_big(data.shape[axis]):
+def pick_index(index, size, mode):
+    """``pick``'s index as int32 inside [0, size): out-of-range values
+    clip to the ends or wrap around (reference pick's ``mode``)."""
+    if _concrete_big(size):
         raise NotImplementedError(
             "pick along a >int32-range dim: the int32 index cast would "
             "silently wrap; reshape so the picked dim fits int32")
+    if mode not in ("clip", "wrap"):
+        raise ValueError(f"pick mode must be 'clip' or 'wrap', got {mode!r}")
     index = index.astype(jnp.int32)
+    return jnp.clip(index, 0, size - 1) if mode == "clip" else index % size
+
+
+@register("pick", num_inputs=2)
+def pick(data, index, axis=-1, keepdims=False, mode="clip"):
+    index = pick_index(index, data.shape[axis], mode)
     out = jnp.take_along_axis(data, jnp.expand_dims(index, axis=axis), axis=axis)
     if not keepdims:
         out = jnp.squeeze(out, axis=axis)

@@ -212,6 +212,8 @@ SPECS = {
     # --- misc -----------------------------------------------------------
     "softmax_cross_entropy": ([_f(4, 6), _i(6, 4).astype(onp.float32)],
                               {}),
+    "sparse_softmax_cross_entropy": (
+        [_f(4, 6), _i(6, 4).astype(onp.float32)], {}),
     "embedding": ([_i(10, 4), _f(10, 8)], {}),
     "take": ([_f(10, 8), _i(10, 4).astype(onp.float32)], {}),
     "Cast": ([_f(4, 6)], dict(dtype="float16")),

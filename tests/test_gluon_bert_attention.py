@@ -295,6 +295,75 @@ def test_mosaic_compiles_the_cells_attention(one_chip, as_tpu, monkeypatch,
     assert not copied, copied
 
 
+VOCAB = 30528
+
+
+@pytest.mark.parametrize("seq,bsz", [(512, 32), (128, 128)],
+                         ids=["s512", "s128"])
+def test_v5e_head_and_loss_write_no_float32_vocabulary_tensor(one_chip, seq,
+                                                              bsz):
+    """``BERTMaskedLMHead`` and ``SoftmaxCrossEntropyLoss`` under bf16 AMP
+    at the cells' shapes, loss and gradients as a step takes them: no
+    instruction of the optimized program writes a float32 array of
+    (tokens x vocabulary) size, and the logits stay as the decoder wrote
+    them.  The largest float32 arrays with a 30,528 axis that belong there
+    are the decoder's weight and its gradient, (30,528 x 768).
+
+    With the loss among the outputs the parent's
+    ``-pick(log_softmax(x), y)`` fails this here as it did in the chip's
+    step program (``f32[128,128,30528]``, ``fusion.332`` there; PERF.md
+    section 6, PR 27); with the gradients alone the forward's gather is
+    dead code and the parent passes, so the loss has to be an output."""
+    from mxnet_tpu.gluon import block as gblock
+
+    class HeadAndLoss(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            self.head = bert.BERTMaskedLMHead(VOCAB, units=768)
+            self.ce = gluon.loss.SoftmaxCrossEntropyLoss()
+
+        def forward(self, hidden, labels):
+            return self.ce(self.head(hidden), labels).mean()
+
+    net = HeadAndLoss()
+    net.initialize()
+    params = net.collect_params()
+    x = nd.zeros((1, 8, 768), dtype="bfloat16")
+    y = nd.zeros((1, 8), dtype="int32")
+    raw_fn, _, _ = gblock._stage_fn(net, params, list(params),
+                                    gblock._flatten_args((x, y))[1], True,
+                                    x.ctx)
+
+    def loss(weights, x, y, key):
+        (out,), _ = raw_fn(weights, [x, y], key)
+        return out
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    fused0 = telemetry.snapshot()["loss.sparse_ce.fused"]
+    amp.init("bfloat16")
+    try:
+        text = _compiled_text(
+            jax.value_and_grad(loss, argnums=(0, 1)),
+            [shape(p.shape, jnp.float32) for p in params.values()],
+            shape((bsz, seq, 768), jnp.bfloat16),
+            shape((bsz, seq), jnp.int32), shape((2,), jnp.uint32))
+    finally:
+        amp.uninit()
+    assert telemetry.snapshot()["loss.sparse_ce.fused"] == fused0 + 1
+    # what an instruction of the entry computation writes: its type, up to
+    # the opcode (a fused computation's own values never reach HBM)
+    written = re.findall(r"^\s+(?:ROOT )?%\S+ = (.*?) [a-z][a-z0-9-]*\(",
+                         text[text.index("\nENTRY "):], re.M)
+    wide = [dims for out in written
+            for dims in re.findall(r"f32\[([\d,]+)\]", out)
+            if str(VOCAB) in dims.split(",")
+            and math.prod(map(int, dims.split(","))) > VOCAB * 768]
+    assert not wide, wide
+    assert any(f"bf16[{bsz},{seq},{VOCAB}]" in out for out in written)
+
+
 @pytest.mark.parametrize("bh,seq,calls", [(384, 128, 2), (8, 2048, 3)],
                          ids=["rows", "blocked"])
 def test_mosaic_compiles_the_split_head_kernels(one_chip, monkeypatch, bh,

@@ -110,6 +110,7 @@ def test_canonical_counters_registered():
         "transformer_lm.flash_fallback",
         "attention.fused",
         "attention.unfused",
+        "loss.sparse_ce.fused",
         "fused.trace",
         "fused.dispatch",
         "nn.pad_channels",

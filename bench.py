@@ -356,10 +356,8 @@ def lane_train(on_cpu: bool, bf16: bool,
         "batch": batch,
         "layout": layout,
         "stem_s2d": s2d,
-        # the round-9 MFU levers, stamped so A/B rounds read off the
-        # artifact: fused conv/BN/ReLU epilogues (MXNET_FUSED_EPILOGUE)
-        # and the MXU channel-alignment pass (MXNET_PAD_CHANNELS)
-        "fused_epilogue": bool(config.get("MXNET_FUSED_EPILOGUE")),
+        # stamped so A/B rounds read it off the artifact: the MXU
+        # channel-alignment pass (MXNET_PAD_CHANNELS)
         "pad_channels": int(config.get("MXNET_PAD_CHANNELS")),
         "compile_s": round(compile_s, 1),
         "platform": jax.default_backend(),
@@ -552,15 +550,9 @@ def lane_int8(on_cpu: bool, model_name: str = "resnet50_v1") -> dict:
     except Exception as exc:                    # pragma: no cover
         _progress(f"int8: bf16 inference reference skipped: {exc!r}")
 
-    # The round-5 in-lane Pallas A/B is RETIRED (round 9): the route
-    # measured 0.345x of lax (BENCH_builder_r05 pallas_vs_lax) and the
-    # conv kernels were deleted — quantized convs are always lax.conv
-    # s8.  The kernel-level decision bench lives in
-    # benchmark/microbench_tpu.py section_int8_pallas (the rebuilt
-    # fused int8_matmul vs lax dot); production re-entry requires that
-    # bench to win on chip.
+    # quantized convs are always lax.conv s8 (the Pallas route measured
+    # 0.345x of it, BENCH_builder_r05 pallas_vs_lax, and was deleted)
     lane["int8_path"] = "lax"
-    lane["pallas_skipped"] = quant.pallas_skipped_count()
     return lane
 
 

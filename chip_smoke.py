@@ -398,7 +398,7 @@ def flash_phase(sz, rehearse):
                   f"out {o_err:.1e} dq {g[0][1]:.1e} dk {g[1][1]:.1e} "
                   f"dv {g[2][1]:.1e} (err / scale)")
     for shape in sz["cell_shapes"]:
-        _flash_cell_shape(shape, rehearse)
+        _flash_cell_shape(shape, timed=not rehearse)
     _keep_mask_everywhere(sz["cell_shapes"][0])
     check(tlm.flash_fallback_count() == fb0,
           "transformer_lm.flash_fallback_count() did not move")
@@ -421,12 +421,13 @@ def _keep_mask_everywhere(shape, width=768, keep_prob=0.9):
           f"the CPU's bit for bit (kept {masks[0].mean():.4f})")
 
 
-def _flash_cell_shape(shape, rehearse, dropout_p=0.1, calls=20, heads=12):
+def _flash_cell_shape(shape, timed, dropout_p=0.1, calls=20, heads=12):
     """A BERT cell's attention core at its own shape, the two paths of
     ``interleaved_selfatt`` over the (seq, batch, 12 * 3 * 64) projection
     with the probability dropout on.  The Pallas kernels against the dense
-    float32 reference under the SAME mask, then forward + backward ms a
-    call beside the unfused expression (bf16 products, float32 softmax).
+    float32 reference under the SAME mask, then (``timed``) forward +
+    backward ms a call beside the unfused expression (bf16 products,
+    float32 softmax).
     The arrays lie batch-major in memory and reach the operator through a
     transpose, as ``BERTSelfAttention`` hands them over."""
     import jax
@@ -479,8 +480,7 @@ def _flash_cell_shape(shape, rehearse, dropout_p=0.1, calls=20, heads=12):
           f"{pk.qkv_heads_per_step(seq, heads, d)} heads a grid step, vs the "
           f"fp32 reference under the same mask: out {o_err:.1e} "
           f"dqkv {g_err:.1e} (err / scale)")
-    if rehearse:
-        say("  not timed: a time comes only from the chip")
+    if not timed:
         return
 
     def ms_a_call(fn):
@@ -599,151 +599,22 @@ def kernels_phase(sz):
     from mxnet_tpu.ops import pallas_kernels as pk
 
     say("== kernels (every public kernel in pallas_kernels.__all__)")
-    hi = jax.lax.Precision.HIGHEST
     b = sz["kernel_batch"]
-    bf = jnp.bfloat16
-    key = iter(jax.random.split(jax.random.PRNGKey(7), 64))
-    covered = set()
-
-    def rnd(shape, dtype=bf, scale=1.0):
-        return (jax.random.normal(next(key), shape, jnp.float32)
-                * scale).astype(dtype)
-
-    def f32(*xs):
-        return tuple(x.astype(jnp.float32) for x in xs)
-
-    def conv1x1_ref(x, w):
-        x32, w32 = f32(x, w)
-        z = jnp.einsum("nhwc,oc->nhwo", x32, w32[:, 0, 0, :], precision=hi)
-        return z, z.mean((0, 1, 2)), z.var((0, 1, 2))
-
-    def report(name, pairs, tol=3e-2):
-        res = [_close(a, r, tol) for a, r in pairs]
-        covered.add(name)
-        check(all(r[0] for r in res),
-              f"{name}: " + " ".join(f"{r[1]:.1e}" for r in res)
-              + " (err / scale per output)")
-
-    # ResNet-50 stage-1 1x1 conv: 56x56, 64 -> 256 channels
-    x = rnd((b, 56, 56, 64))
-    w = rnd((256, 1, 1, 64), scale=0.1)
-    m, kdim, n = b * 56 * 56, 64, 256
-    blocks = pk.fused_blocks(m, kdim, n)
-    check(blocks is not None, f"fused_blocks({m}, {kdim}, {n}) = {blocks}")
-    covered.add("fused_blocks")
-    x2, w2 = x.reshape(m, kdim), w.reshape(n, kdim).T
-    z_ref, mean_ref, var_ref = conv1x1_ref(x, w)
-    z2_ref = z_ref.reshape(m, n)
-
-    y, s, ss = jax.jit(lambda a, c: pk.matmul_bn_stats(a, c, **blocks))(
-        x2, w2)
-    report("matmul_bn_stats", [(y, z2_ref), (s / m, z2_ref.mean(0)),
-                               (ss / m, (z2_ref ** 2).mean(0))])
-    s, ss = jax.jit(lambda a, c: pk.matmul_stats(a, c, **blocks))(x2, w2)
-    report("matmul_stats", [(s / m, z2_ref.mean(0)),
-                            (ss / m, (z2_ref ** 2).mean(0))])
-    sc, sh = rnd((n,), jnp.float32), rnd((n,), jnp.float32)
-    res = rnd((m, n))
-    out = jax.jit(lambda a, c, r: pk.matmul_epilogue(
-        a, c, sc, sh, residual=r, relu=True, **blocks))(x2, w2, res)
-    report("matmul_epilogue",
-           [(out, jnp.maximum(z2_ref * sc + sh + res.astype(jnp.float32),
-                              0))])
-    z, mean, var = jax.jit(lambda a, c: pk.conv1x1_bn_stats(
-        a, c, **blocks))(x, w)
-    report("conv1x1_bn_stats", [(z, z_ref), (mean, mean_ref),
-                                (var, var_ref)])
-
-    def stats_loss(f):
-        def loss(x, w):
-            z, mean, var = f(x, w)
-            return (z.astype(jnp.float32).mean() + (mean * mean).sum()
-                    + var.sum())
-        return jax.jit(jax.grad(loss, argnums=(0, 1)))
-
-    gx, gw = stats_loss(pk.conv1x1_bn_stats_train)(x, w)
-    rx, rw = stats_loss(conv1x1_ref)(x, w)
-    report("conv1x1_bn_stats_train (grad)", [(gx, rx), (gw, rw)])
-
-    gamma, beta = rnd((n,), jnp.float32) + 1.0, rnd((n,), jnp.float32)
-    resid = rnd((b, 56, 56, n))
-
-    def act_ref(x, w, gamma, beta, resid):
-        z, mean, var = conv1x1_ref(x, w)
-        y = (z - mean) * jax.lax.rsqrt(var + 1e-5) * gamma + beta
-        return jnp.maximum(y + resid.astype(jnp.float32), 0), mean, var
-
-    def act_loss(f):
-        def loss(x, w, gamma, beta, resid):
-            out, mean, var = f(x, w, gamma, beta, resid)
-            return (out.astype(jnp.float32) ** 2).mean() + mean.sum() \
-                + var.sum()
-        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))
-
-    lv, g = act_loss(lambda *a: pk.conv1x1_bn_act_train(
-        a[0], a[1], a[2], a[3], residual=a[4]))(x, w, gamma, beta, resid)
-    lr, r = act_loss(act_ref)(x, w, gamma, beta, resid)
-    report("conv1x1_bn_act_train (loss + grads)",
-           [(lv, lr)] + list(zip(g, r)), tol=5e-2)
-
-    # ResNet-50 3x3 sites (stride 1, pad 1): 56x56x64 is the largest
-    # full-image tile the routing predicate (convkxk_fits) admits,
-    # 14x14x256 also passes the tighter conv3x3_fits budget
-    def c3_ref(x, w):
-        z = pk._ref_conv3x3(*f32(x, w))
-        return z, z.mean((0, 1, 2)), z.var((0, 1, 2))
-
-    for hw, c in ((56, 64), (14, 256)):
-        x3 = rnd((b, hw, hw, c))
-        w3 = rnd((c, 3, 3, c), scale=0.05)
-        zr, mr, vr = c3_ref(x3, w3)
-        rx, rw = stats_loss(c3_ref)(x3, w3)
-        tag = f"{hw}x{hw}x{c}"
-        check(pk.convkxk_fits(x3.shape, c, (3, 3), (1, 1)) is not None,
-              f"convkxk_fits admits {x3.shape} -> {c}")
-        z, mean, var = jax.jit(lambda a, c_: pk.convkxk_bn_stats(
-            a, c_, (1, 1)))(x3, w3)
-        report(f"convkxk_bn_stats {tag}", [(z, zr), (mean, mr), (var, vr)])
-        gx, gw = stats_loss(lambda a, c_: pk.convkxk_bn_stats_train(
-            a, c_, (1, 1)))(x3, w3)
-        report(f"convkxk_bn_stats_train (grad) {tag}", [(gx, rx), (gw, rw)])
-        if pk.conv3x3_fits(x3.shape, c) is None:
-            say(f"  conv3x3_fits (10 MiB budget) declines {tag}")
-            continue
-        z, mean, var = jax.jit(pk.conv3x3_bn_stats)(x3, w3)
-        report(f"conv3x3_bn_stats {tag}", [(z, zr), (mean, mr), (var, vr)])
-        gx, gw = stats_loss(pk.conv3x3_bn_stats_train)(x3, w3)
-        report(f"conv3x3_bn_stats_train (grad) {tag}", [(gx, rx), (gw, rw)])
-    covered.update({"conv3x3_fits", "convkxk_fits"})
-
-    # BERT-base FFN in s8: (batch*seq, 768) @ (768, 3072)
-    mi, ki, ni = b * 128, 768, 3072
-    ib = pk.int8_blocks(mi, ki, ni)
-    check(ib is not None, f"int8_blocks({mi}, {ki}, {ni}) = {ib}")
-    covered.add("int8_blocks")
-    xi = jax.random.randint(next(key), (mi, ki), -127, 128, jnp.int8)
-    wi = jax.random.randint(next(key), (ki, ni), -127, 128, jnp.int8)
-    acc = jnp.matmul(xi.astype(jnp.int32), wi.astype(jnp.int32))
-    out = jax.jit(lambda a, c: pk.int8_matmul(a, c, 1e-3, relu=True,
-                                              **ib))(xi, wi)
-    report("int8_matmul (fp32 out)",
-           [(out, jnp.maximum(acc.astype(jnp.float32) * 1e-3, 0))], tol=1e-5)
-    q = jax.jit(lambda a, c: pk.int8_matmul(a, c, 1e-3, out_scale=0.05,
-                                            **ib))(xi, wi)
-    qr = jnp.clip(jnp.round(acc.astype(jnp.float32) * 1e-3 * 0.05),
-                  -127, 127)
-    check(q.dtype == jnp.int8 and int(jnp.max(jnp.abs(
-        q.astype(jnp.int32) - qr.astype(jnp.int32)))) <= 1,
-        "int8_matmul (s8 requantized out) within 1 step of the reference")
-    covered.add("int8_matmul")
-
-    # BERT-base attention shape, forward only (kernel/flash does the rest)
-    qa, ka, va = (rnd((b * 12, 128, 64)) for _ in range(3))
-    report("flash_attention",
-           [(jax.jit(lambda *a: pk.flash_attention(*a, causal=False))(
-               qa, ka, va), _einsum_attention(qa, ka, va, False))])
-    missing = sorted(set(pk.__all__)
-                     - {c.split(" ")[0] for c in covered})
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    # BERT-base attention shape, forward only
+    qa, ka, va = (jax.random.normal(k, (b * 12, 128, 64), jnp.float32)
+                  .astype(jnp.bfloat16) for k in keys)
+    ok, err = _close(
+        jax.jit(lambda *a: pk.flash_attention(*a, causal=False))(qa, ka, va),
+        _einsum_attention(qa, ka, va, False), 3e-2)
+    check(ok, f"flash_attention: {err:.1e} (err / scale)")
+    # the in-place kernels over the interleaved projection, dropout inside,
+    # forward and backward against the dense reference under
+    # dropout_keep_mask's mask, qkv_heads_per_step heads a grid step
+    _flash_cell_shape((128, b), timed=False)
+    covered = {"flash_attention", "flash_attention_qkv",
+               "qkv_heads_per_step", "dropout_keep_mask"}
+    missing = sorted(set(pk.__all__) - covered)
     check(not missing, f"every name in pallas_kernels.__all__ was exercised"
                        f" (missing: {missing})")
 

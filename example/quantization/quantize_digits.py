@@ -7,9 +7,7 @@ batches, converts to an int8 graph (conv+BN+relu folded, requantize
 fused), and reports int8-vs-fp32 top-1 agreement and accuracy on the
 held-out split.
 
-On a TPU chip set MXNET_INT8_PALLAS=1 to route eligible convs through
-the explicit s8 MXU kernels (ops/pallas_kernels.py); the default lax
-path runs everywhere.
+Quantized convolutions run ``lax.conv`` s8 -> s32 on every backend.
 
     python example/quantization/quantize_digits.py
 """

@@ -40,26 +40,11 @@ class _AmpState:
 _STATE = _AmpState()
 
 
-_FUSED_CONV_BN = frozenset(("_fused_conv1x1_bn", "_fused_convkxk_bn",
-                            "_fused_conv1x1_bn_act"))
-
-
 def _policy(op_name, arrays):
     """Cast op inputs per the op lists (invoked from ndarray dispatch)."""
     target = _STATE.target_dtype
     if target is None:
         return arrays
-    if op_name in _FUSED_CONV_BN:
-        # dedicated rule: the conv operands (x, w, optional bias) follow
-        # the Convolution LOW cast, but the trailing gamma/beta are
-        # BatchNorm parameters and must stay fp32 EXACTLY like the
-        # unfused path (BatchNorm sits in FP32_FUNCS) — downcasting them
-        # would round the affine and the running statistics inference
-        # consumes.  The kernel accumulates fp32 internally either way.
-        head = [a.astype(target)
-                if hasattr(a, "dtype") and a.dtype == jnp.float32 else a
-                for a in arrays[:-2]]
-        return head + list(arrays[-2:])
     if op_name in _LOW:
         return [a.astype(target)
                 if hasattr(a, "dtype") and a.dtype == jnp.float32 else a

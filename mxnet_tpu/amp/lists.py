@@ -33,14 +33,6 @@ LOW_PRECISION_FUNCS = [
     # are float32 inside, on the Pallas path and the unfused one
     "interleaved_selfatt", "linalg_gemm", "linalg_gemm2",
     "_rnn_fused", "DeformableConvolution", "ModulatedDeformableConvolution",
-    # fused conv+BN (ops/nn.py): conv-dominated, classified LOW for the
-    # registry-exhaustiveness contract, but amp/__init__.py::_policy has
-    # a DEDICATED rule: conv operands (x, w, bias) cast down like
-    # Convolution while the trailing gamma/beta stay fp32 like the
-    # unfused BatchNorm (FP32_FUNCS) — parameter values and running
-    # stats must not round
-    "_fused_conv1x1_bn", "_fused_convkxk_bn",
-    "_fused_conv1x1_bn_act",
     "Correlation", "khatri_rao",
 ]
 

@@ -209,17 +209,6 @@ declare("MXNET_METRIC_SYNC_STEPS", int, 50,
 declare("MXNET_ENFORCE_DETERMINISM", bool, False,
         "Disable nondeterministic optimizations (XLA autotuning picks "
         "deterministic kernels)", subsystem="engine")
-declare("MXNET_INT8_PALLAS", int, 0,
-        "RETIRED (PR 9).  The Pallas int8 conv route measured 0.345x of "
-        "plain lax.conv s8 on chip (BENCH_builder_r05 pallas_vs_lax) and "
-        "int8 itself LOST to bf16 at matched batch, so the conv kernels "
-        "were deleted; quantized convs always use lax.conv s8->s32 on "
-        "the MXU.  0 (the only valid value) counts each conv that a "
-        "Pallas route would have claimed (quantization."
-        "pallas_skipped_count, logged once).  Setting 1/2 now REFUSES "
-        "loudly (MXNetError pointing at the measurement and at "
-        "benchmark/microbench_tpu.py section_int8_pallas, which "
-        "re-measures the rebuilt fused int8_matmul kernel on chip).")
 declare("MXNET_EAGER_JIT", int, 1,
         "Per-op jit compilation cache for eager dispatch (the reference "
         "engine's operator-bulking analog): one cached XLA executable per "
@@ -291,34 +280,6 @@ declare("MXNET_EAGER_JIT_EXCLUDE", str, "mean,sum,prod,max,min",
         "chip — one primitive is already one dispatch, so the cache only "
         "adds lookup overhead).  Override with your own list; empty "
         "string re-admits every op.", cached=False)
-declare("MXNET_FUSED_CONV_BN", int, 0,
-        "Trace-time fusion of eligible conv + BatchNorm(training) pairs "
-        "into the Pallas conv+BN-stats kernels.  0 = off (default: the "
-        "2026-08-01 on-chip A/B measured every fused variant SLOWER than "
-        "XLA's own conv+BN fusion — 1140-1791 vs 2556 img/s bf16 ResNet-50; "
-        "the pallas_call boundary blocks XLA's surrounding epilogue fusion "
-        "— see docs/PERF.md), 1 = on for single-device TPU execution, 2 = "
-        "force everywhere incl. the CPU Pallas interpreter (tests).")
-declare("MXNET_FUSED_CONV_BN_KINDS", str, "1x1,kxk",
-        "Which conv+BN fusion kernel classes are eligible when "
-        "MXNET_FUSED_CONV_BN is on: comma-set of '1x1' (matmul-tiled "
-        "any-stride 1x1) and 'kxk' (full-image-tile KxK stride-1).  The "
-        "on-chip A/B in docs/PERF.md decides the shipped default.")
-declare("MXNET_FUSED_EPILOGUE", int, 0,
-        "Fused conv/BN/ReLU EPILOGUE kernels for the model-zoo ResNet "
-        "bottleneck 1x1 convs (ops/pallas_kernels.py matmul_stats + "
-        "matmul_epilogue via the _fused_conv1x1_bn_act op): the batch "
-        "statistics come from a stats-only matmul pass (no activation "
-        "write) and the BN scale-shift -> residual-add -> ReLU run "
-        "in-register in the second matmul's epilogue, so the conv "
-        "output takes ONE HBM pass (the final write) instead of three "
-        "(conv write + stats read + normalize read/write) at 2x matmul "
-        "FLOPs — the flash-attention trade applied to the conv path.  "
-        "0 = off (default until the chip A/B lands: "
-        "benchmark/microbench_tpu.py section_fused_epilogue is the "
-        "decision bench, bench.py ResNet lanes stamp fused_epilogue "
-        "on/off), 1 = on for single-device TPU training, 2 = force "
-        "everywhere incl. the CPU Pallas interpreter (tests/CI gate).")
 declare("MXNET_PAD_CHANNELS", int, 1,
         "MXU-alignment padding pass for staged convolutions (ops/nn.py "
         "Convolution, trace-time only): channel axes that miss the TPU "
@@ -330,7 +291,7 @@ declare("MXNET_PAD_CHANNELS", int, 1,
         "underfilling the MXU.  The pad/slice live INSIDE the program, "
         "keyed by the unpadded shapes: 0 added retraces or dispatches "
         "per step.  Bit-exactness is asserted by "
-        "tools/check_fusion_budget.py.  1 = on for TPU staging "
+        "tests/test_pad_channels.py.  1 = on for TPU staging "
         "(default), 0 = off, 2 = force on every backend (tests/CI).",
         validator=lambda v: v in (0, 1, 2))
 declare("MXNET_BN_TWO_PASS_VAR", bool, False,
@@ -790,12 +751,6 @@ declare("BENCH_S2D", bool, False,
         "MLPerf trick).  Default OFF since the 2026-08-01 chip A/B: "
         "XLA now handles the 7x7 stem well and s2d costs ~2.2% "
         "(2,554 vs 2,611 img/s NHWC bs128); 1 re-enables",
-        subsystem="bench")
-declare("BENCH_INT8_AB", bool, False,
-        "RETIRED (PR 9): the bench.py int8 in-lane Pallas A/B is gone "
-        "with the Pallas int8 conv route (measured 0.345x of lax, "
-        "BENCH_builder_r05); the lane always runs lax.conv s8 and "
-        "stamps int8_path='lax'.  Accepted for compatibility, ignored.",
         subsystem="bench")
 declare("BENCH_ACCUM", int, 1,
         "bench.py BERT gradient-accumulation factor",

@@ -25,59 +25,9 @@ import numpy as onp
 from ..ops.registry import register
 
 __all__ = ["quantize", "dequantize", "requantize", "collect_calib_ranges",
-           "quantize_symbol", "quantize_net", "QuantizedNet",
-           "pallas_skipped_count"]
+           "quantize_symbol", "quantize_net", "QuantizedNet"]
 
 INT8_MIN, INT8_MAX = -127.0, 127.0       # symmetric, matches reference
-
-# The final half of ROADMAP item 2's "fix or delete loudly" verdict on
-# the Pallas int8 conv path: chip bench (BENCH_builder_r05) measured it
-# at 0.345x of plain lax — and int8 itself LOSING to bf16 at matched
-# batch — so round 9 DELETED the conv kernels (int8_conv1x1/int8_conv3x3
-# are gone from ops/pallas_kernels.py; the rebuilt int8_matmul stays as
-# the microbench A/B vehicle).  Every conv a Pallas route would have
-# claimed is still counted here and logged once per process, and setting
-# MXNET_INT8_PALLAS nonzero now REFUSES loudly instead of routing.
-from .. import telemetry as _telemetry
-
-_PALLAS_SKIPPED = _telemetry.counter(
-    "quantization.pallas_skipped",
-    "quantized convs a Pallas int8 route would have claimed (the "
-    "kernel was retired on the 0.345x measurement)")
-_PALLAS_SKIP_LOGGED = False
-
-_INT8_PALLAS_VERDICT = (
-    "the Pallas int8 conv route was retired in round 9: it measured "
-    "0.345x of plain lax.conv s8 on chip and int8 lost to bf16 at "
-    "matched batch (BENCH_builder_r05.json lanes[].pallas_vs_lax; "
-    "docs/PERF.md 'MFU campaign round 2').  Quantized convs always use "
-    "lax.conv s8->s32 on the MXU.  The rebuilt fused int8 matmul "
-    "(ops/pallas_kernels.py int8_matmul: (m,n,k) grid, s32 VMEM "
-    "accumulator, in-register requantize) is re-measured by 'python "
-    "benchmark/microbench_tpu.py --which int8' (section_int8_pallas); "
-    "production re-entry requires that bench to beat lax on chip.")
-
-
-def pallas_skipped_count() -> int:
-    """Quantized convs that a Pallas int8 route would have claimed
-    (the kernel was retired on the 0.345x measurement; see
-    ``_INT8_PALLAS_VERDICT``).  View over the
-    ``quantization.pallas_skipped`` telemetry counter."""
-    return int(_PALLAS_SKIPPED.value)
-
-
-def _count_pallas_skip() -> None:
-    global _PALLAS_SKIP_LOGGED
-    _PALLAS_SKIPPED.inc()
-    if not _PALLAS_SKIP_LOGGED:
-        _PALLAS_SKIP_LOGGED = True
-        from .. import log as _log
-
-        _log.get_logger("mxnet_tpu.quantization").warning(
-            "quantized convs use plain lax.conv s8 — "
-            + _INT8_PALLAS_VERDICT
-            + "  [logged once; convs counted in "
-            "quantization.pallas_skipped_count()]")
 
 
 # ---------------------------------------------------------------------------
@@ -159,25 +109,6 @@ def quantized_fully_connected(arrays, num_hidden=0, no_bias=False,
     return _quantized_epilogue(out, fused_relu, out_min, out_max)
 
 
-def _refuse_pallas_int8(kernel, stride, dilate, pad, num_group, layout):
-    """The retired-route gate: geometries a Pallas int8 conv would have
-    claimed (NHWC 1x1 any-stride / 3x3 stride-1/pad-1) count a skip and
-    log once; a nonzero MXNET_INT8_PALLAS refuses LOUDLY with the
-    measurement instead of silently routing nowhere."""
-    from .. import config as _config
-    from ..base import MXNetError
-
-    mode = _config.get("MXNET_INT8_PALLAS")
-    if mode:
-        raise MXNetError(
-            f"MXNET_INT8_PALLAS={mode} refused: " + _INT8_PALLAS_VERDICT)
-    if (tuple(dilate) == (1, 1) and num_group == 1 and layout == "NHWC"
-            and (tuple(kernel) == (1, 1) and tuple(pad) == (0, 0)
-                 or tuple(kernel) == (3, 3) and tuple(stride) == (1, 1)
-                 and tuple(pad) == (1, 1))):
-        _count_pallas_skip()
-
-
 @register("quantized_conv", num_inputs=-1, differentiable=False)
 def quantized_conv(arrays, kernel=(1, 1), stride=(1, 1), dilate=(1, 1),
                    pad=(0, 0), num_filter=1, num_group=1, no_bias=False,
@@ -200,7 +131,6 @@ def quantized_conv(arrays, kernel=(1, 1), stride=(1, 1), dilate=(1, 1),
     dilate = _tup(dilate, nsp) if dilate else (1,) * nsp
     pad = _tup(pad, nsp) if pad else (0,) * nsp
 
-    _refuse_pallas_int8(kernel, stride, dilate, pad, num_group, layout)
     qd = qd.astype(jnp.int8)
     qw = qw.astype(jnp.int8)
     # MXU-alignment padding pass (ops/nn.py): int8 sublane quantum is 32,

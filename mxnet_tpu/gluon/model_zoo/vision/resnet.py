@@ -13,8 +13,6 @@ options preserve the reference model function exactly (tests
 """
 from __future__ import annotations
 
-import jax
-
 from ... import nn
 from ...block import HybridBlock
 from ...parameter import Parameter
@@ -34,93 +32,6 @@ __all__ = [
 def _conv3x3(channels, stride, in_channels, layout="NCHW"):
     return nn.Conv2D(channels, kernel_size=3, strides=stride, padding=1,
                      use_bias=False, in_channels=in_channels, layout=layout)
-
-
-# --- fused conv/BN/ReLU epilogues (round 9, MXNET_FUSED_EPILOGUE) ----------
-#
-# The bottleneck's 1x1 convs (conv1, conv3, downsample — 36 of ResNet-50's
-# 53 convs) each feed a BatchNorm whose consumers (scale-shift, relu, the
-# block's residual add) are memory-bound epilogues.  When the knob is on,
-# BottleneckV1.forward routes those sites through ops/nn.py
-# _fused_conv1x1_bn_act: batch stats from a stats-only matmul pass, then
-# BN scale-shift -> residual-add -> ReLU in-register in the second
-# matmul's epilogue — ONE HBM pass over each conv output instead of
-# three.  Geometry is checked per site and anything ineligible falls back
-# to the plain layers, so the block computes the identical function
-# either way (tests/test_fused_epilogue.py pins outputs, grads, and
-# running stats).  Param names/children are untouched — checkpoints
-# interoperate.
-
-
-def _fused_epilogue_mode() -> int:
-    from .... import config as _config
-
-    mode = _config.get("MXNET_FUSED_EPILOGUE")
-    if not mode:
-        return 0
-    if mode != 2 and not (jax.default_backend() == "tpu"
-                          and len(jax.devices()) == 1):
-        # single-device only: pallas_call has no SPMD partitioning rule;
-        # 2 forces the CPU interpreter (tests / the fusion-budget gate)
-        return 0
-    return mode
-
-
-def _try_fused_epilogue(conv, bn, x, relu=False, residual=None):
-    """Route ``relu(bn(conv(x)) [+ residual])`` through the fused
-    epilogue op when eligible; return the output NDArray or None (the
-    caller then runs the plain layers).  Training-mode only (the batch
-    statistics ARE the fusion), trace-time only (eager dispatch must
-    never pay the Pallas interpreter), and the running statistics fold
-    exactly as BatchNorm.forward does."""
-    from .... import autograd as _ag
-
-    if not _ag.is_training() or bn._use_global_stats:
-        return None
-    if not isinstance(x._data, jax.core.Tracer):
-        return None
-    kw = conv._kwargs
-    if (tuple(kw["kernel"]) != (1, 1)
-            or tuple(kw.get("pad", (0, 0))) != (0, 0)
-            or tuple(kw.get("dilate", (1, 1))) != (1, 1)
-            or kw.get("num_group", 1) != 1
-            or kw.get("layout") != "NHWC"
-            or bn._axis not in (3, -1)
-            or str(x.dtype) not in ("float32", "bfloat16")):
-        return None
-    from ....ops.pallas_kernels import fused_blocks
-
-    stride = tuple(kw["stride"])
-    n, h, wd, cin = x.shape
-    ho, wo = -(-h // stride[0]), -(-wd // stride[1])
-    cout = conv._channels
-    if fused_blocks(n * ho * wo, cin, cout) is None:
-        return None
-    if residual is not None and tuple(residual.shape) != (n, ho, wo, cout):
-        return None
-    ctx = x.ctx
-    ins = [x, conv.weight.data(ctx)]
-    if conv.bias is not None:
-        ins.append(conv.bias.data(ctx))
-    if residual is not None:
-        ins.append(residual)
-    ins += [bn.gamma.data(ctx), bn.beta.data(ctx)]
-    out, mean, var = invoke(
-        "_fused_conv1x1_bn_act", ins,
-        {"stride": stride, "eps": bn._epsilon,
-         "fix_gamma": not bn._scale,
-         "has_bias": conv.bias is not None,
-         "has_residual": residual is not None, "relu": relu})
-    m = bn._momentum
-    rm = bn.running_mean.data(ctx)
-    rv = bn.running_var.data(ctx)
-    with _ag.pause():
-        # fold in the buffer dtype like the unfused op does
-        rm._set_data(rm._data * m
-                     + mean._data.astype(rm._data.dtype) * (1 - m))
-        rv._set_data(rv._data * m
-                     + var._data.astype(rv._data.dtype) * (1 - m))
-    return out
 
 
 def _bn(layout="NCHW", **kwargs):
@@ -198,21 +109,10 @@ class _StemConvS2D(HybridBlock):
             wp = wp.reshape(o, 4, 2, 4, 2, c)         # O,Ai,di,Aj,dj,C
             wt = wp.transpose(0, 1, 3, 2, 4, 5)       # O,Ai,Aj,di,dj,C
             wt = wt.reshape(o, 4, 4, 4 * c)
-        out = invoke("Convolution", [xp, wt],
-                     {"kernel": (4, 4), "stride": (1, 1), "pad": (0, 0),
-                      "num_filter": o, "no_bias": True,
-                      "layout": self._layout})
-        if isinstance(out._data, jax.core.Tracer):
-            # producer tag (same contract as conv_layers.py): the stem
-            # output is the network's LARGEST activation — fusing its BN
-            # stats into this conv's Pallas epilogue saves the single
-            # biggest stats read.  wt is graph-derived from the canonical
-            # 7x7 weight; gradients flow back through the regroup.
-            out._conv_src = (xp, wt, None,
-                             {"kernel": (4, 4), "stride": (1, 1),
-                              "pad": (0, 0), "dilate": (1, 1),
-                              "num_group": 1, "layout": self._layout})
-        return out
+        return invoke("Convolution", [xp, wt],
+                      {"kernel": (4, 4), "stride": (1, 1), "pad": (0, 0),
+                       "num_filter": o, "no_bias": True,
+                       "layout": self._layout})
 
 
 class BasicBlockV1(HybridBlock):
@@ -269,28 +169,6 @@ class BottleneckV1(HybridBlock):
             self.downsample = None
 
     def forward(self, x):
-        b = self.body
-        if _fused_epilogue_mode():
-            # conv1 (1x1, + bn + relu) through the fused epilogue; the
-            # 3x3 stays on XLA's own fusion (the round-5 measured winner
-            # for that geometry); conv3 (1x1 + bn) absorbs the residual
-            # add AND the block relu into its epilogue — the full
-            # ``relu(bn(conv(h)) + shortcut)`` in one HBM pass
-            h = _try_fused_epilogue(b[0], b[1], x, relu=True)
-            if h is not None:
-                h = b[5](b[4](b[3](h)))
-                if self.downsample:
-                    residual = _try_fused_epilogue(
-                        self.downsample[0], self.downsample[1], x)
-                    if residual is None:
-                        residual = self.downsample(x)
-                else:
-                    residual = x
-                out = _try_fused_epilogue(b[6], b[7], h, relu=True,
-                                          residual=residual)
-                if out is not None:
-                    return out
-                return (b[7](b[6](h)) + residual).relu()
         residual = x
         x = self.body(x)
         if self.downsample:

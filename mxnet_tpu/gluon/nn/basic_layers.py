@@ -229,100 +229,9 @@ class BatchNorm(HybridBlock):
         for p in (self.gamma, self.beta, self.running_mean, self.running_var):
             p.shape = (c,)
 
-    def _fused_conv_src(self, x):
-        """When ``x`` was produced by an eligible NHWC Convolution this
-        trace (see conv_layers.py producer tag) — 1x1 any-stride, or any
-        KxK stride-1 conv fitting the full-image VMEM tile (3x3
-        bottlenecks, the s2d stem's 4x4/pad-0) — return (src_x, src_w,
-        src_bias_or_None, geom, kind) for the fused Pallas conv+BN-stats
-        path, else None.  ``geom`` is the stride tuple for kind "1x1"
-        and (kernel, pad) for kind "kxk".
-        Single-device only: under a sharded pjit step the pallas_call has
-        no partitioning rule; MXNET_FUSED_CONV_BN=2 forces (CPU tests)."""
-        src = getattr(x, "_conv_src", None)
-        if src is None or type(self) not in (BatchNorm, BatchNormReLU):
-            return None
-        from ... import config as _config
-
-        mode = _config.get("MXNET_FUSED_CONV_BN")
-        if not mode:
-            return None
-        import jax as _jax
-
-        if mode != 2 and not (_jax.default_backend() == "tpu"
-                              and len(_jax.devices()) == 1):
-            return None
-        kinds = {k.strip()
-                 for k in _config.get("MXNET_FUSED_CONV_BN_KINDS").split(",")}
-        unknown = kinds - {"1x1", "kxk", ""}
-        if unknown:
-            raise ValueError(
-                f"MXNET_FUSED_CONV_BN_KINDS: unknown kind(s) {sorted(unknown)}"
-                " (valid: '1x1', 'kxk')")
-        sx, sw, sb, attrs = src
-        stride = tuple(attrs.get("stride", (1, 1)))
-        kernel = tuple(attrs.get("kernel", ()))
-        if (tuple(attrs.get("dilate", (1, 1))) != (1, 1)
-                or attrs.get("num_group", 1) != 1
-                or attrs.get("layout") != "NHWC"
-                or self._axis not in (3, -1)
-                or str(sx.dtype) not in ("float32", "bfloat16")):
-            return None
-        if kernel == (1, 1) and tuple(attrs.get("pad", (0, 0))) == (0, 0):
-            if "1x1" not in kinds:
-                return None
-            from ...ops.pallas_kernels import fused_blocks
-
-            n, h, w, cin = sx.shape
-            ho = -(-h // stride[0])
-            wo = -(-w // stride[1])
-            if fused_blocks(n * ho * wo, cin, sw.shape[0]) is None:
-                return None
-            return sx, sw, sb, stride, "1x1"
-        if len(kernel) == 2 and stride == (1, 1):
-            if "kxk" not in kinds:
-                return None
-            # KxK stride-1 full-image-tile kernel (3x3 bottlenecks, the
-            # s2d stem's 4x4/pad-0 conv, ...)
-            from ...ops.pallas_kernels import convkxk_fits
-
-            pad = tuple(attrs.get("pad", (0, 0)))
-            itemsize = 2 if str(sx.dtype) == "bfloat16" else 4
-            if convkxk_fits(sx.shape, sw.shape[0], kernel, pad,
-                            itemsize=itemsize) is None:
-                return None
-            return sx, sw, sb, (kernel, pad), "kxk"
-        return None
-
     def forward(self, x):
         ctx = x.ctx
         training = autograd.is_training() and not self._use_global_stats
-        if training:
-            fused = self._fused_conv_src(x)
-            if fused is not None:
-                sx, sw, sb, geom, kind = fused
-                ins = [sx, sw] + ([sb] if sb is not None else []) \
-                    + [self.gamma.data(ctx), self.beta.data(ctx)]
-                attrs = {"eps": self._epsilon,
-                         "fix_gamma": not self._scale,
-                         "has_bias": sb is not None}
-                if kind == "1x1":
-                    attrs["stride"] = geom
-                else:
-                    attrs["pad"] = geom[1]   # kernel size comes from w
-                out, mean, var = invoke(
-                    f"_fused_conv{kind}_bn", ins, attrs)
-                m = self._momentum
-                rm = self.running_mean.data(ctx)
-                rv = self.running_var.data(ctx)
-                with autograd.pause():
-                    # fold in the buffer dtype like the unfused op does
-                    # (its outputs are pre-cast, ops/nn.py batch_norm)
-                    rm._set_data(rm._data * m
-                                 + mean._data.astype(rm._data.dtype) * (1 - m))
-                    rv._set_data(rv._data * m
-                                 + var._data.astype(rv._data.dtype) * (1 - m))
-                return out
         rm, rv = self.running_mean.data(ctx), self.running_var.data(ctx)
         outs = invoke(
             "BatchNorm",

@@ -4,8 +4,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
 from ...ndarray.ndarray import invoke
 from ..block import HybridBlock
 from ..parameter import Parameter
@@ -97,19 +95,6 @@ class _Conv(HybridBlock):
         if self._use_bias:
             args.append(self.bias.data(x.ctx))
         out = invoke(self._op_name, args, dict(self._kwargs))
-        if (self._op_name == "Convolution" and self.act is None
-                and isinstance(out._data, jax.core.Tracer)):
-            # trace-time producer tag: a following BatchNorm(training) may
-            # re-derive this conv THROUGH the fused Pallas stats kernel
-            # (ops/nn.py _fused_conv1x1_bn); the untouched conv node is then
-            # dead code XLA eliminates.  Tracer-gated so eager mode never
-            # retains activations or computes the conv twice.  A conv BIAS
-            # is carried along: train-mode BN output is bias-invariant
-            # (the bias shifts z and the batch mean equally), so the op
-            # only folds it into the running-stat mean.
-            out._conv_src = (x, args[1],
-                             args[2] if self._use_bias else None,
-                             dict(self._kwargs))
         if self.act is not None:
             out = self.act(out)
         return out

@@ -40,13 +40,11 @@ __all__ = ["TransformerLMConfig", "init_params", "forward", "loss_fn",
            "sharding_plan", "make_train_step", "init_opt_state",
            "pp_pad_batch", "flash_fallback_count"]
 
-# The silent killer the PR-8 int8 gate-off taught us to count: flash
-# attention needs (seq, head_dim) divisible by 8 (TPU tiling), and the
+# Flash attention needs (seq, head_dim) divisible by 8 (TPU tiling), and the
 # auto path used to fall back to the O(S^2) einsum WITHOUT saying so —
 # a mis-sized config quietly trains at a fraction of the flash MFU.
 # Every fallback is counted here (once per trace of each misaligned
-# attention site) and logged once per process, mirroring
-# quantization.pallas_skipped_count.
+# attention site) and logged once per process.
 from .. import telemetry as _telemetry
 
 _FLASH_FALLBACK = _telemetry.counter(

@@ -70,7 +70,6 @@ class NDArray:
         "_ag_out_index",
         "_deferred_init",
         "_dc_sym",
-        "_conv_src",   # producer tag for trace-time conv+BN fusion
         "__weakref__",
     )
 
@@ -164,12 +163,6 @@ class NDArray:
             )
         self._data = new_data
         self._version += 1
-        try:
-            # a mutated array is no longer the tagged conv's output —
-            # a later BatchNorm must not fuse against the pre-mutation conv
-            del self._conv_src
-        except AttributeError:
-            pass
 
     # ------------------------------------------------------------------
     # sync / host transfer

@@ -167,15 +167,15 @@ def _conv_dimension_numbers(layout: str):
 # 0.0 — IEEE x + 0.0 == x, so the kept lanes are bit-exact) and pads
 # Cout with slice-back (output channels are independent dots, so the
 # kept channels are computed identically).  It runs ONLY at trace time
-# (Tracer-gated, like the conv+BN producer tag), so the pad/slice are
-# part of the compiled program keyed by the UNPADDED input shapes —
+# (Tracer-gated), so the pad/slice are part of the compiled program
+# keyed by the UNPADDED input shapes —
 # 0 added retraces and 0 added dispatches per step by construction; XLA
 # folds the pads into the surrounding layout work.  This generalizes the
 # stem_s2d idea (re-shaping conv0 onto the MXU) to every misaligned
 # conv.  Quanta: the sublane quantum of the operand dtype — 8 for
 # fp32/bf16, 32 for int8 (the int8 path applies it in
 # contrib/quantization.py quantized_conv).  Bit-exactness is asserted by
-# tools/check_fusion_budget.py and tests/test_fused_epilogue.py.
+# tests/test_pad_channels.py.
 
 from .. import telemetry as _telemetry  # noqa: E402
 
@@ -443,120 +443,6 @@ def batch_norm(arrays, eps=1e-3, momentum=0.9, fix_gamma=True,
         return (out, mean.astype(moving_mean.dtype),
                 var.astype(moving_var.dtype))
     return (out,)
-
-
-def _fused_bn_epilogue(z, mean, var, gamma, beta, b, eps, fix_gamma):
-    """Shared normalize for the fused conv+BN ops.  Normalizes against
-    the bias-FREE z with the bias-free mean (the conv bias cancels in
-    (z + b) - (mean + b); this is also ~16x more fp32-accurate than
-    stats on the shifted z — see tests/test_fused_conv_bn.py::
-    test_biased_conv_fuses_exactly), then folds the bias into the
-    returned mean so running statistics — hence inference — see the
-    biased conv exactly."""
-    f32 = jnp.float32
-    g = jnp.ones_like(gamma) if fix_gamma else gamma
-    inv = jax.lax.rsqrt(var + f32(eps))            # mean/var already fp32
-    sc = inv * g.astype(f32)
-    bi = beta.astype(f32) - mean * sc
-    out = z * sc.astype(z.dtype) + bi.astype(z.dtype)
-    if b is not None:
-        mean = mean + b.astype(f32)
-    return out, mean, var
-
-
-@register("_fused_conv1x1_bn", num_inputs=-1, num_outputs=-1)
-def fused_conv1x1_bn(arrays, stride=(1, 1), eps=1e-5, fix_gamma=False,
-                     has_bias=False):
-    """Training-mode 1x1-conv + BatchNorm with the batch statistics computed
-    in the conv's Pallas epilogue (ops/pallas_kernels.py
-    conv1x1_bn_stats_train) — one HBM pass over the conv output instead of
-    conv-write-then-stats-read.  NHWC x, OHWI w.  Strided 1x1 convs
-    pre-slice the input (exact: a 1x1 kernel never straddles the stride).
-    A conv bias shifts z and the batch mean EQUALLY, so the normalized
-    output is bias-invariant; the bias is folded only into the returned
-    mean (keeping running statistics — hence inference — exact).
-    Returns (out, batch_mean, batch_var) like BatchNorm(training=True).
-    No reference analog (src/operator/nn/batch_norm.cc stats are a separate
-    pass) — TPU-first fusion; the gluon BatchNorm layer routes here, see
-    gluon/nn/basic_layers.py."""
-    from .pallas_kernels import conv1x1_bn_stats_train
-
-    if has_bias:
-        x, w, b, gamma, beta = arrays
-    else:
-        x, w, gamma, beta = arrays
-        b = None
-    sh, sw = stride
-    if (sh, sw) != (1, 1):
-        x = x[:, ::sh, ::sw, :]
-    z, mean, var = conv1x1_bn_stats_train(x, w)
-    return _fused_bn_epilogue(z, mean, var, gamma, beta, b, eps, fix_gamma)
-
-
-@register("_fused_convkxk_bn", num_inputs=-1, num_outputs=-1,
-          aliases=("_fused_conv3x3_bn",))
-def fused_convkxk_bn(arrays, eps=1e-5, fix_gamma=False, has_bias=False,
-                     pad=(1, 1)):
-    """Training-mode KxK/stride-1 conv + BatchNorm with batch statistics
-    in the conv's Pallas epilogue (ops/pallas_kernels.py
-    convkxk_bn_stats_train; full-image VMEM tiles, KxK shifted MXU
-    matmuls).  Covers the 3x3/pad-1 bottleneck sites AND the s2d stem's
-    4x4/pad-0 conv (the network's LARGEST activation and biggest single
-    BN-stats read).  Bias handling identical to _fused_conv1x1_bn: the
-    normalized output is bias-invariant; the bias folds only into the
-    returned running-stat mean.  TPU-first fusion, no reference analog."""
-    from .pallas_kernels import convkxk_bn_stats_train
-
-    if has_bias:
-        x, w, b, gamma, beta = arrays
-    else:
-        x, w, gamma, beta = arrays
-        b = None
-    z, mean, var = convkxk_bn_stats_train(x, w, tuple(pad))
-    return _fused_bn_epilogue(z, mean, var, gamma, beta, b, eps, fix_gamma)
-
-
-@register("_fused_conv1x1_bn_act", num_inputs=-1, num_outputs=-1)
-def fused_conv1x1_bn_act(arrays, stride=(1, 1), eps=1e-5, fix_gamma=False,
-                         has_bias=False, has_residual=False, relu=True):
-    """The fused-EPILOGUE training op (round 9, ROADMAP item 2): 1x1
-    NHWC conv + train-mode BatchNorm + optional residual-add + optional
-    ReLU in ONE HBM pass over the conv output
-    (ops/pallas_kernels.py matmul_stats + matmul_epilogue behind
-    conv1x1_bn_act_train's custom_vjp).  Inputs
-    ``[x, w, (bias), (residual), gamma, beta]`` — conv operands lead,
-    BN affine trails (the AMP rule keeps the trailing pair fp32).
-    Strided 1x1 pre-slices the input (exact).  A conv bias shifts z and
-    the batch mean EQUALLY, so the normalized output is bias-invariant;
-    the bias folds only into the returned mean (running statistics —
-    hence inference — stay exact, same contract as _fused_conv1x1_bn).
-    The residual adds BEFORE the relu — the ResNet bottleneck order
-    ``relu(bn(conv(h)) + shortcut)``.  Returns
-    ``(out, batch_mean, batch_var)``.  No reference analog — TPU-first
-    fusion; the model-zoo BottleneckV1 routes here, see
-    gluon/model_zoo/vision/resnet.py (MXNET_FUSED_EPILOGUE)."""
-    from .pallas_kernels import conv1x1_bn_act_train
-
-    x, w = arrays[0], arrays[1]
-    idx = 2
-    b = None
-    if has_bias:
-        b = arrays[idx]
-        idx += 1
-    r = None
-    if has_residual:
-        r = arrays[idx]
-        idx += 1
-    gamma, beta = arrays[idx], arrays[idx + 1]
-    sh, sw = stride
-    if (sh, sw) != (1, 1):
-        x = x[:, ::sh, ::sw, :]
-    out, mean, var = conv1x1_bn_act_train(
-        x, w, gamma, beta, residual=r, eps=eps, relu=relu,
-        fix_gamma=fix_gamma)
-    if b is not None:
-        mean = mean + b.astype(jnp.float32)
-    return out, mean, var
 
 
 @register("LayerNorm")

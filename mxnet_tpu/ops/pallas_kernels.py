@@ -1,4 +1,5 @@
-"""Pallas TPU kernels for the hot ops.
+"""Pallas TPU kernels: the attention core, the one place where a hand-written
+kernel beats XLA's own fusion on the chip.
 
 Flash attention (forward + backward) as Pallas kernels: the S×S score
 matrix never materializes in HBM.  Up to 512 keys a head's whole score tile
@@ -38,12 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .random import _keep, _seed_words, keep_mask
 
 __all__ = ["flash_attention", "flash_attention_qkv", "qkv_heads_per_step",
-           "dropout_keep_mask", "matmul_bn_stats", "conv1x1_bn_stats",
-           "conv1x1_bn_stats_train", "fused_blocks",
-           "conv3x3_bn_stats", "conv3x3_bn_stats_train", "conv3x3_fits",
-           "convkxk_bn_stats", "convkxk_bn_stats_train", "convkxk_fits",
-           "matmul_stats", "matmul_epilogue", "conv1x1_bn_act_train",
-           "int8_matmul", "int8_blocks"]
+           "dropout_keep_mask"]
 
 _NEG_INF = -1e30
 
@@ -585,785 +581,3 @@ def flash_attention_qkv(qkv, num_heads, dropout_p=0.0, dropout_key=None):
     out = _flash_qkv(qkv.transpose(1, 0, 2), seed, d, heads,
                      1.0 / math.sqrt(d), dropout_p)
     return out.transpose(1, 0, 2)
-
-
-# ---------------------------------------------------------------------------
-# fused matmul + BN-stats epilogue (docs/PERF.md kernel roadmap item 3)
-# ---------------------------------------------------------------------------
-#
-# y = act(x @ w [+ bias]); per-column sum(y) and sum(y*y) accumulated in
-# the SAME kernel — the producing matmul's epilogue computes the batch-norm
-# statistics, removing the separate stats pass (one fewer HBM read of the
-# activation).  This is exactly the fusion XLA cannot express: a reduction
-# folded into a dot's output tiles.  Covers FullyConnected and 1x1-conv
-# (NHWC collapsed to (N*H*W, C)) producers, which carry roughly half of
-# ResNet-50's FLOPs.
-#
-# Reference analog: conv+BN folding exists in the reference only for
-# INFERENCE (MKLDNN subgraph fuser); training-time stats fusion has no
-# reference counterpart — TPU-first design.
-#
-# TPU grid semantics: grid iterations execute sequentially per core
-# ("arbitrary" dimension semantics), so accumulating the (1, N)-tiled
-# stats outputs across m-tiles is race-free by construction.
-
-
-def _mm_stats_kernel(x_ref, w_ref, o_ref, s_ref, ss_ref, *, relu, k_tiles,
-                     block_k):
-    # m is the INNER grid dim: the same (1, block_n) stats block is then
-    # revisited on consecutive grid steps, which is the only pattern whose
-    # VMEM contents Pallas guarantees to persist for read-modify-write
-    mi = pl.program_id(1)
-
-    def body(ki, acc):
-        xk = x_ref[:, pl.ds(ki * block_k, block_k)].astype(jnp.float32)
-        wk = w_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        return acc + xk @ wk
-
-    acc = jax.lax.fori_loop(
-        0, k_tiles, body,
-        jnp.zeros((x_ref.shape[0], w_ref.shape[1]), jnp.float32))
-    if relu:
-        acc = jnp.maximum(acc, 0.0)
-    o_ref[...] = acc.astype(o_ref.dtype)
-    part = jnp.sum(acc, axis=0, keepdims=True)          # (1, N_block)
-    part_sq = jnp.sum(acc * acc, axis=0, keepdims=True)
-
-    @pl.when(mi == 0)
-    def _init():
-        s_ref[...] = part
-        ss_ref[...] = part_sq
-
-    @pl.when(mi != 0)
-    def _accum():
-        s_ref[...] += part
-        ss_ref[...] += part_sq
-
-
-def matmul_bn_stats(x, w, relu=False, block_m=256, block_n=256,
-                    block_k=512):
-    """``y = act(x @ w)`` plus per-column ``sum(y)``/``sum(y*y)`` in one
-    kernel pass.  x: (M, K), w: (K, N) -> (y: (M, N), s: (N,), ss: (N,)),
-    stats in fp32.  M/K/N must be divisible by the (clamped) block sizes.
-    Wrap 1x1 convs by collapsing NHWC to (N*H*W, C)."""
-    m, k = x.shape
-    k2, n = w.shape
-    assert k == k2, (x.shape, w.shape)
-    block_m = min(block_m, m)
-    block_n = min(block_n, n)
-    block_k = min(block_k, k)
-    assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0, (
-        (m, k, n), (block_m, block_k, block_n))
-    grid = (n // block_n, m // block_m)       # m innermost (see kernel)
-    kernel = functools.partial(_mm_stats_kernel, relu=relu,
-                               k_tiles=k // block_k, block_k=block_k)
-    y, s, ss = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, k), lambda ni, mi: (mi, 0)),
-            pl.BlockSpec((k, block_n), lambda ni, mi: (0, ni)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m, block_n), lambda ni, mi: (mi, ni)),
-            pl.BlockSpec((1, block_n), lambda ni, mi: (0, ni)),
-            pl.BlockSpec((1, block_n), lambda ni, mi: (0, ni)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, n), x.dtype),
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(x, w)
-    return y, s[0], ss[0]
-
-
-def conv1x1_bn_stats(x, w, relu=False, **blocks):
-    """1x1-conv producer + BN-stats epilogue: x (N,H,W,Cin) NHWC,
-    w (Cout,1,1,Cin) OHWI -> (y (N,H,W,Cout), mean (Cout,), var (Cout,)).
-    The mean/var are the batch statistics BatchNorm(training=True) needs —
-    computed without re-reading y from HBM."""
-    n, h, wd, cin = x.shape
-    cout = w.shape[0]
-    x2 = x.reshape(n * h * wd, cin)
-    w2 = w.reshape(cout, cin).T                  # (Cin, Cout)
-    y, s, ss = matmul_bn_stats(x2, w2, relu=relu, **blocks)
-    cnt = jnp.float32(n * h * wd)
-    mean = s / cnt
-    var = jnp.maximum(ss / cnt - mean * mean, 0.0)
-    return y.reshape(n, h, wd, cout), mean, var
-
-
-# ---------------------------------------------------------------------------
-# Differentiable fused conv1x1 + BN-stats: the model-path entry point.
-#
-# Round-4 left matmul_bn_stats standalone; this wires it into training.
-# Forward runs the Pallas producer+stats kernel (one HBM pass over the
-# conv output instead of conv-write + stats-read); backward is explicit
-# XLA (dense MXU matmuls) because pallas_call has no transpose rule.
-# Reference analog: train-mode BN fusion does not exist in the reference
-# (src/operator/nn/batch_norm.cc computes stats in a separate pass) —
-# TPU-first design, used by gluon BatchNorm when its input was produced
-# by an eligible 1x1 Convolution (see gluon/nn/basic_layers.py).
-# ---------------------------------------------------------------------------
-
-
-def fused_blocks(m, k, n):
-    """Pick Mosaic-legal block sizes for matmul_bn_stats, or None when the
-    shape can't tile: block_m multiple of 8 (sublane), block_n multiple of
-    128 or the whole dim (lane), block_k any divisor of k."""
-    def pick(dim, target, quantum):
-        if dim <= target:
-            return dim
-        b = (min(target, dim) // quantum) * quantum
-        while b >= quantum and dim % b:
-            b -= quantum
-        return b if b >= quantum and dim % b == 0 else None
-
-    bm = pick(m, 256, 8)
-    bn = pick(n, 256, 128)
-    bk = pick(k, 512, 128)
-    if bm is None or bn is None or bk is None:
-        return None
-    if m % bm or n % bn or k % bk:
-        return None
-    return {"block_m": bm, "block_n": bn, "block_k": bk}
-
-
-@jax.custom_vjp
-def conv1x1_bn_stats_train(x, w):
-    """Differentiable ``(z, mean, var)`` of a 1x1 NHWC conv with fused
-    batch statistics.  x (N,H,W,Cin), w (Cout,1,1,Cin) OHWI.  Caller must
-    pre-check :func:`fused_blocks` eligibility."""
-    z, mean, var = _c1x1_fwd(x, w)
-    return z, mean, var
-
-
-def _c1x1_fwd(x, w):
-    n, h, wd, cin = x.shape
-    blocks = fused_blocks(n * h * wd, cin, w.shape[0])
-    return conv1x1_bn_stats(x, w, relu=False, **blocks)
-
-
-def _c1x1_fwd_vjp(x, w):
-    z, mean, var = _c1x1_fwd(x, w)
-    return (z, mean, var), (x, w, z, mean)
-
-
-def _c1x1_bwd(res, cts):
-    x, w, z, mean = res
-    gz, gmean, gvar = cts
-    n, h, wd, cin = x.shape
-    cout = w.shape[0]
-    m = n * h * wd
-    # total cotangent into the conv output: the stats outputs fold back as
-    #   d mean_j / d z_ij = 1/M,   d var_j / d z_ij = 2 (z_ij - mean_j) / M
-    z32 = z.reshape(m, cout).astype(jnp.float32)
-    g = (gz.reshape(m, cout).astype(jnp.float32)
-         + gmean[None, :].astype(jnp.float32) / m
-         + gvar[None, :].astype(jnp.float32) * 2.0 * (z32 - mean[None, :]) / m)
-    g = g.astype(x.dtype)                         # MXU-friendly operand dtype
-    x2 = x.reshape(m, cin)
-    w2 = w.reshape(cout, cin)
-    dx = jax.lax.dot(g, w2.astype(g.dtype),
-                     preferred_element_type=jnp.float32)
-    dw = jax.lax.dot(g.T, x2, preferred_element_type=jnp.float32)
-    return (dx.reshape(x.shape).astype(x.dtype),
-            dw.reshape(w.shape).astype(w.dtype))
-
-
-conv1x1_bn_stats_train.defvjp(_c1x1_fwd_vjp, _c1x1_bwd)
-
-
-# ---------------------------------------------------------------------------
-# Fused conv/BN/ReLU EPILOGUE family (round 9, ROADMAP item 2).
-#
-# The round-5 lesson (docs/PERF.md): a pallas_call is an opaque custom
-# call XLA cannot fuse INTO, so a kernel that leaves ANY of the epilogue
-# outside (scale/shift/relu/residual-add) breaks the surrounding fusion
-# and loses.  These kernels take the other branch of that fork: put the
-# ENTIRE consumer chain of the dominant ResNet 1x1 convs in-register —
-#
-#   matmul_stats     x @ w reduced DIRECTLY to per-column (sum, sumsq):
-#                    the conv output is never written to HBM at all
-#                    (the batch-norm statistics pass at 0 activation
-#                    bytes);
-#   matmul_epilogue  x @ w recomputed with bias -> BN scale-shift ->
-#                    residual-add -> ReLU applied in-register, writing
-#                    only the FINAL activation.
-#
-# Training conv+BN+ReLU(+residual) = stats pass + epilogue pass: ONE
-# HBM pass over the conv output (the final write) instead of three
-# (conv write, stats read, normalize read+write), at 2x matmul FLOPs —
-# the flash-attention recompute trade applied to the conv path.  The
-# backward (conv1x1_bn_act_train's custom_vjp) recomputes z with one
-# dense MXU matmul, exactly like flash recomputes attention scores.
-# No reference analog; wired via ops/nn.py _fused_conv1x1_bn_act into
-# the model-zoo BottleneckV1 behind MXNET_FUSED_EPILOGUE.
-# ---------------------------------------------------------------------------
-
-
-def _mm_statsonly_kernel(x_ref, w_ref, s_ref, ss_ref, *, k_tiles, block_k):
-    # m innermost (same revisit pattern as _mm_stats_kernel): the (1, bn)
-    # stats tiles accumulate race-free across sequential m steps
-    mi = pl.program_id(1)
-
-    def body(ki, acc):
-        xk = x_ref[:, pl.ds(ki * block_k, block_k)].astype(jnp.float32)
-        wk = w_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        return acc + xk @ wk
-
-    acc = jax.lax.fori_loop(
-        0, k_tiles, body,
-        jnp.zeros((x_ref.shape[0], w_ref.shape[1]), jnp.float32))
-    part = jnp.sum(acc, axis=0, keepdims=True)
-    part_sq = jnp.sum(acc * acc, axis=0, keepdims=True)
-
-    @pl.when(mi == 0)
-    def _init():
-        s_ref[...] = part
-        ss_ref[...] = part_sq
-
-    @pl.when(mi != 0)
-    def _accum():
-        s_ref[...] += part
-        ss_ref[...] += part_sq
-
-
-def matmul_stats(x, w, block_m=256, block_n=256, block_k=512):
-    """Per-column ``(sum(x@w), sum((x@w)**2))`` in fp32 WITHOUT writing
-    the product: x (M, K), w (K, N) -> (s (N,), ss (N,)).  The
-    activation-free half of the fused-epilogue pair."""
-    m, k = x.shape
-    k2, n = w.shape
-    assert k == k2, (x.shape, w.shape)
-    block_m = min(block_m, m)
-    block_n = min(block_n, n)
-    block_k = min(block_k, k)
-    assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0, (
-        (m, k, n), (block_m, block_k, block_n))
-    grid = (n // block_n, m // block_m)        # m innermost (see kernel)
-    kernel = functools.partial(_mm_statsonly_kernel,
-                               k_tiles=k // block_k, block_k=block_k)
-    s, ss = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, k), lambda ni, mi: (mi, 0)),
-            pl.BlockSpec((k, block_n), lambda ni, mi: (0, ni)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_n), lambda ni, mi: (0, ni)),
-            pl.BlockSpec((1, block_n), lambda ni, mi: (0, ni)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(x, w)
-    return s[0], ss[0]
-
-
-def _mm_epilogue_kernel(x_ref, w_ref, sc_ref, bi_ref, r_ref, o_ref, *,
-                        k_tiles, block_k, relu, has_res):
-    def body(ki, acc):
-        xk = x_ref[:, pl.ds(ki * block_k, block_k)].astype(jnp.float32)
-        wk = w_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        return acc + xk @ wk
-
-    acc = jax.lax.fori_loop(
-        0, k_tiles, body,
-        jnp.zeros((x_ref.shape[0], w_ref.shape[1]), jnp.float32))
-    out = acc * sc_ref[...] + bi_ref[...]       # BN scale-shift, (1, bn)
-    if has_res:
-        out = out + r_ref[...].astype(jnp.float32)
-    if relu:
-        out = jnp.maximum(out, 0.0)
-    o_ref[...] = out.astype(o_ref.dtype)
-
-
-def matmul_epilogue(x, w, scale, shift, residual=None, relu=False,
-                    block_m=256, block_n=256, block_k=512):
-    """``act((x @ w) * scale + shift [+ residual])`` in ONE kernel pass:
-    x (M, K), w (K, N), scale/shift per-column fp32 (N,), residual
-    (M, N) in the output dtype.  The residual adds BEFORE the relu —
-    the ResNet block order ``relu(bn(conv(h)) + shortcut)``.  A conv
-    bias folds into ``shift`` host-side (it is per-column affine)."""
-    m, k = x.shape
-    k2, n = w.shape
-    assert k == k2, (x.shape, w.shape)
-    block_m = min(block_m, m)
-    block_n = min(block_n, n)
-    block_k = min(block_k, k)
-    assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0, (
-        (m, k, n), (block_m, block_k, block_n))
-    has_res = residual is not None
-    r = residual if has_res else jnp.zeros((1, 1), x.dtype)
-    r_spec = (pl.BlockSpec((block_m, block_n), lambda ni, mi: (mi, ni))
-              if has_res else pl.BlockSpec((1, 1), lambda ni, mi: (0, 0)))
-    kernel = functools.partial(_mm_epilogue_kernel, k_tiles=k // block_k,
-                               block_k=block_k, relu=relu, has_res=has_res)
-    return pl.pallas_call(
-        kernel,
-        grid=(n // block_n, m // block_m),
-        in_specs=[
-            pl.BlockSpec((block_m, k), lambda ni, mi: (mi, 0)),
-            pl.BlockSpec((k, block_n), lambda ni, mi: (0, ni)),
-            pl.BlockSpec((1, block_n), lambda ni, mi: (0, ni)),
-            pl.BlockSpec((1, block_n), lambda ni, mi: (0, ni)),
-            r_spec,
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda ni, mi: (mi, ni)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        interpret=_interpret(),
-    )(x, w, scale.astype(jnp.float32).reshape(1, n),
-      shift.astype(jnp.float32).reshape(1, n), r)
-
-
-@functools.lru_cache(maxsize=None)
-def _c1x1_act_train_for(relu, has_res, eps, fix_gamma):
-    """One custom_vjp core per static (relu, has_residual, eps,
-    fix_gamma) — jax.custom_vjp cannot take non-array args positionally."""
-
-    def _fwd_impl(x, w, gamma, beta, *rs):
-        n, h, wd, cin = x.shape
-        cout = w.shape[0]
-        m = n * h * wd
-        x2 = x.reshape(m, cin)
-        w2 = w.reshape(cout, cin).T
-        blocks = fused_blocks(m, cin, cout)
-        s, ss = matmul_stats(x2, w2, **blocks)
-        cnt = jnp.float32(m)
-        mean = s / cnt
-        var = jnp.maximum(ss / cnt - mean * mean, 0.0)
-        inv = jax.lax.rsqrt(var + jnp.float32(eps))
-        g = jnp.ones_like(inv) if fix_gamma else gamma.astype(jnp.float32)
-        sc = inv * g
-        bi = beta.astype(jnp.float32) - mean * sc
-        r2 = rs[0].reshape(m, cout) if has_res else None
-        out = matmul_epilogue(x2, w2, sc, bi, residual=r2, relu=relu,
-                              **blocks)
-        return out.reshape(n, h, wd, cout), mean, var
-
-    @jax.custom_vjp
-    def f(x, w, gamma, beta, *rs):
-        return _fwd_impl(x, w, gamma, beta, *rs)
-
-    def fwd(x, w, gamma, beta, *rs):
-        out, mean, var = _fwd_impl(x, w, gamma, beta, *rs)
-        return (out, mean, var), (x, w, gamma, beta,
-                                  rs[0] if has_res else None, mean, var)
-
-    def bwd(res, cts):
-        x, w, gamma, beta, r, mean, var = res
-        gout, gmean, gvar = cts
-        n, h, wd, cin = x.shape
-        cout = w.shape[0]
-        m = n * h * wd
-        x2 = x.reshape(m, cin)
-        w2 = w.reshape(cout, cin)
-        # recompute z on the MXU (the flash-style trade: z never hit HBM
-        # in forward; one dense matmul rebuilds it here)
-        z = jax.lax.dot(x2, w2.T, preferred_element_type=jnp.float32)
-        z = z.astype(jnp.float32)
-        f32 = jnp.float32
-        inv = jax.lax.rsqrt(var + f32(eps))
-        g = jnp.ones_like(inv) if fix_gamma else gamma.astype(f32)
-        sc = inv * g
-        xhat = (z - mean[None, :]) * inv[None, :]
-        y = sc[None, :] * z + (beta.astype(f32) - mean * sc)[None, :]
-        ga = gout.reshape(m, cout).astype(f32)
-        if has_res:
-            a = y + r.reshape(m, cout).astype(f32)
-        else:
-            a = y
-        if relu:
-            ga = jnp.where(a > 0, ga, 0.0)
-        # d residual: the add sits under the relu, so it shares ga
-        dr = (ga.astype(r.dtype).reshape(r.shape) if has_res else None)
-        dbeta_f = jnp.sum(ga, axis=0)
-        dgamma_f = jnp.sum(ga * xhat, axis=0)
-        # BN backward into z (mean/var chains folded), per column:
-        #   dz = sc * (ga - mean_M(ga) - xhat * mean_M(ga * xhat))
-        dz = sc[None, :] * (ga - dbeta_f[None, :] / m
-                            - xhat * dgamma_f[None, :] / m)
-        # plus the DIRECT cotangents on the returned stats outputs
-        #   d mean_j / d z_ij = 1/M,  d var_j / d z_ij = 2 (z_ij - mu_j)/M
-        dz = (dz + gmean[None, :].astype(f32) / m
-              + gvar[None, :].astype(f32) * 2.0 * (z - mean[None, :]) / m)
-        dz = dz.astype(x.dtype)                  # MXU-friendly operands
-        dx = jax.lax.dot(dz, w2.astype(dz.dtype),
-                         preferred_element_type=jnp.float32)
-        dw = jax.lax.dot(dz.T, x2, preferred_element_type=jnp.float32)
-        dgamma = (jnp.zeros_like(gamma) if fix_gamma
-                  else dgamma_f.astype(gamma.dtype))
-        dbeta = dbeta_f.astype(beta.dtype)
-        outs = (dx.reshape(x.shape).astype(x.dtype),
-                dw.reshape(w.shape).astype(w.dtype), dgamma, dbeta)
-        return outs + ((dr,) if has_res else ())
-
-    f.defvjp(fwd, bwd)
-    return f
-
-
-def conv1x1_bn_act_train(x, w, gamma, beta, residual=None, eps=1e-5,
-                         relu=True, fix_gamma=False):
-    """Differentiable fused 1x1-conv + train-mode BN + residual-add +
-    ReLU: x (N,H,W,Cin) NHWC, w (Cout,1,1,Cin) OHWI, ``residual``
-    (N,H,W,Cout) added before the relu -> ``(out, mean, var)``, stats
-    fp32.  The conv output never materializes in HBM (stats pass +
-    in-register epilogue pass); the backward recomputes it with one
-    dense matmul.  Caller pre-checks :func:`fused_blocks`."""
-    core = _c1x1_act_train_for(bool(relu), residual is not None,
-                               float(eps), bool(fix_gamma))
-    if residual is not None:
-        return core(x, w, gamma, beta, residual)
-    return core(x, w, gamma, beta)
-
-
-# ---------------------------------------------------------------------------
-# int8 matmul with s32 accumulation — the MEASUREMENT kernel (round 9).
-#
-# History: round 5 shipped whole-K-row int8 kernels (x block (bm, K)
-# resident, fori over K slices) plus conv1x1/conv3x3 wrappers wired into
-# contrib/quantization.py behind MXNET_INT8_PALLAS.  The chip bench
-# measured that route at 0.345x of plain lax.conv s8 (BENCH_builder_r05
-# pallas_vs_lax) with int8 itself losing to bf16 at matched batch — so
-# round 9 DELETED the conv wrappers and the production routing (the knob
-# now refuses, contrib/quantization.py), and rebuilt the matmul itself in
-# the canonical Pallas shape so the microbench keeps an honest A/B
-# vehicle: full (m, n, k) grid with k innermost, an s32 VMEM scratch
-# accumulator revisited across k steps (VMEM footprint bm*bk + bk*bn +
-# bm*bn instead of bm*K whole rows — the round-5 kernel's K-resident rows
-# are what starved double-buffering), and the fp32 dequant / relu / s8
-# requantize epilogue applied IN REGISTER on the last k step only.
-# benchmark/microbench_tpu.py section_int8_pallas re-measures it against
-# lax; production re-entry requires that bench to win on chip.
-# ---------------------------------------------------------------------------
-
-
-def _int8_mm_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_tiles, scale, relu,
-                    out_scale):
-    ki = pl.program_id(2)                     # k innermost: the same
-                                              # (m, n) tile is revisited
-    @pl.when(ki == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-
-    @pl.when(ki == k_tiles - 1)
-    def _epilogue():
-        out = acc_ref[...].astype(jnp.float32) * scale
-        if relu:
-            out = jnp.maximum(out, 0.0)
-        if out_scale is not None:
-            q = jnp.clip(jnp.round(out * out_scale), -127, 127)
-            o_ref[...] = q.astype(jnp.int8)
-        else:
-            o_ref[...] = out.astype(o_ref.dtype)
-
-
-def int8_blocks(m, k, n):
-    """Mosaic-legal tiles for s8 operands: sublane quantum 32, lane 128
-    (or whole-dimension blocks)."""
-    def pick(dim, target, quantum):
-        if dim <= target:
-            return dim
-        b = (min(target, dim) // quantum) * quantum
-        while b >= quantum and dim % b:
-            b -= quantum
-        return b if b >= quantum and dim % b == 0 else None
-
-    bm = pick(m, 256, 32)
-    bn = pick(n, 256, 128)
-    bk = pick(k, 512, 128)
-    if bm is None or bn is None or bk is None:
-        return None
-    if m % bm or n % bn or k % bk:
-        return None
-    return {"block_m": bm, "block_n": bn, "block_k": bk}
-
-
-def int8_matmul(x, w, scale, relu=False, out_scale=None,
-                block_m=256, block_n=256, block_k=512):
-    """``dequant(x_s8 @ w_s8)``: x (M, K) s8, w (K, N) s8 -> fp32 (M, N)
-    scaled by ``scale`` (= data_scale * w_scale), with the optional relu
-    and s8 requantize (``out_scale``: fp32 -> s8 multiplier) fused
-    in-register on the final k step.  s32 accumulation in a VMEM scratch
-    tile on the MXU int8 path; (m, n, k) grid, k innermost."""
-    m, k = x.shape
-    k2, n = w.shape
-    assert k == k2, (x.shape, w.shape)
-    block_m = min(block_m, m)
-    block_n = min(block_n, n)
-    block_k = min(block_k, k)
-    assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0, (
-        (m, k, n), (block_m, block_k, block_n))
-    k_tiles = k // block_k
-    kernel = functools.partial(
-        _int8_mm_kernel, k_tiles=k_tiles, scale=float(scale), relu=relu,
-        out_scale=None if out_scale is None else float(out_scale))
-    out_dtype = jnp.int8 if out_scale is not None else jnp.float32
-    return pl.pallas_call(
-        kernel,
-        grid=(m // block_m, n // block_n, k_tiles),
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
-            pl.BlockSpec((block_k, block_n), lambda mi, ni, ki: (ki, ni)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n),
-                               lambda mi, ni, ki: (mi, ni)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        interpret=_interpret(),
-    )(x, w)
-
-
-# ---------------------------------------------------------------------------
-# 3x3 conv + BN-stats epilogue (round-5 VERDICT #2 second half).
-#
-# ResNet-50's 16 bottleneck 3x3 convs (stride 1, pad 1) are the BN sites
-# the 1x1 fusion can't reach.  Every ResNet geometry keeps a full padded
-# image tile resident in VMEM (56x56x64 -> 430 KB ... 7x7x2048 -> 230 KB),
-# so the kernel grids over (cout-tiles, batch), pads in VMEM, and
-# accumulates the conv as 9 statically-shifted matmuls on the MXU, with
-# the same race-free batch-accumulated sum/sumsq epilogue as
-# matmul_bn_stats (batch is the inner, sequential grid dim).
-# No reference analog (src/operator/nn/batch_norm.cc stats are a
-# separate pass) — TPU-first fusion.
-# ---------------------------------------------------------------------------
-
-
-def _tap_accumulate(xp_ref, w_ref, kh, kw, ho, wo, acc_dtype, w_cast=None):
-    """Sum of shifted-window matmuls over the kh*kw taps: xp_ref a
-    (Hp,Wp,Cin) already-padded VMEM ref, w_ref a (kh*kw,Cin,bn)
-    taps-leading ref -> (ho*wo, bn).
-
-    A fori_loop over the kh row shifts, NOT a fully unrolled Python
-    loop: Mosaic's scoped-VMEM stack allocator keeps each unrolled
-    iteration's shifted window + accumulator live simultaneously
-    (kh*kw copies — the round-5 on-chip compile OOM); the loop body
-    reuses one row block.  The row shift is a dynamic REF load
-    (``pl.ds`` on the untiled leading dim — this Pallas TPU lowering
-    has no ``dynamic_slice`` on values, and Mosaic requires sublane-dim
-    dynamic starts to be 8-aligned, so the kw column shifts stay as
-    static slices unrolled inside the body)."""
-    cin = xp_ref.shape[-1]
-    bn = w_ref.shape[-1]
-
-    def row(dy, acc):
-        xr = xp_ref[pl.ds(dy, ho), :, :]            # (ho, Wp, cin)
-        for dx in range(kw):
-            xs = xr[:, dx:dx + wo, :].reshape(ho * wo, cin)
-            wt = w_ref[pl.ds(dy * kw + dx, 1), :, :].reshape(cin, bn)
-            if w_cast is not None:
-                wt = wt.astype(w_cast)
-            acc = acc + jax.lax.dot_general(
-                xs, wt, (((1,), (0,)), ((), ())),
-                preferred_element_type=acc_dtype)
-        return acc
-
-    return jax.lax.fori_loop(0, kh, row,
-                             jnp.zeros((ho * wo, bn), acc_dtype))
-
-
-def _ckxk_kernel(x_ref, w_ref, o_ref, s_ref, ss_ref, xp_ref, *, ho, wo,
-                 kh, kw, ph, pw):
-    bi = pl.program_id(1)
-    x = x_ref[0].astype(jnp.float32)                  # (H, W, Cin)
-    xp_ref[...] = (jnp.pad(x, ((ph, ph), (pw, pw), (0, 0)))
-                   if (ph or pw) else x)
-    bn = w_ref.shape[-1]
-    acc = _tap_accumulate(xp_ref, w_ref, kh, kw, ho, wo, jnp.float32,
-                          w_cast=jnp.float32)
-    o_ref[0] = acc.reshape(ho, wo, bn).astype(o_ref.dtype)
-    part = jnp.sum(acc, axis=0, keepdims=True)        # (1, bn)
-    part_sq = jnp.sum(acc * acc, axis=0, keepdims=True)
-
-    @pl.when(bi == 0)
-    def _init():
-        s_ref[...] = part
-        ss_ref[...] = part_sq
-
-    @pl.when(bi != 0)
-    def _accum():
-        s_ref[...] += part
-        ss_ref[...] += part_sq
-
-
-def convkxk_fits(xshape, cout, kernel=(3, 3), pad=(1, 1), block_n=128,
-                 vmem_budget=12 * 2 ** 20 + 2 ** 19, itemsize=2):
-    """Eligibility for the full-image-tile KxK stride-1 kernel: NHWC
-    geometry whose tiles stay inside the VMEM budget, with a
-    Mosaic-friendly cout tiling.  ``itemsize`` is the storage dtype's
-    byte width (2 for bf16, 4 for fp32, 1 for the s8 kernel — which
-    also switches the in-kernel buffer dtypes to what
-    ``_c3x3_int8_kernel`` really allocates: s8 image/window/weights,
-    s32 accumulator, fp32 output).
-
-    The byte model counts buffers as Mosaic actually allocates them:
-    the last dim padded to 128 lanes, the second-to-last to the dtype's
-    sublane quantum (8 f32 / 16 bf16 / 32 s8).  Un-padded estimates
-    under-count tiny-channel geometries ~10x — the s2d stem's cin=12
-    pads to 128 lanes, which is how the round-5 on-chip compile blew the
-    16 MB scoped-VMEM limit; with honest accounting the stem is simply
-    ineligible and falls back to the unfused conv+BN pair."""
-    n, h, w, cin = xshape
-    kh, kw = kernel
-    ph, pw = pad
-    ho, wo = h + 2 * ph - kh + 1, w + 2 * pw - kw + 1
-    if ho <= 0 or wo <= 0:
-        return None
-    bn = min(block_n, cout)
-    if cout % bn or (bn % 128 and bn != cout):
-        return None
-
-    def up(v, q):
-        return -(-v // q) * q
-
-    def sub(isz):
-        return {1: 32, 2: 16, 4: 8}.get(isz, 8)
-
-    # per-buffer dtypes: the bf16/fp32 kernel pads+computes in fp32 and
-    # stores the conv output in the input dtype; the s8 kernel keeps the
-    # image/window/weights in s8, accumulates s32, and emits fp32.
-    int8 = itemsize == 1
-    img_isz = 1 if int8 else 4          # padded image + tap window
-    w_isz = 1 if int8 else 4            # weight taps as computed with
-    out_isz = 4 if int8 else itemsize   # output tile
-    m = up(ho * wo, sub(img_isz))
-    cl = up(cin, 128)
-    bl = up(bn, 128)
-    wp = w + 2 * pw
-    vmem = (h * up(w, sub(itemsize)) * cl * itemsize  # input tile as loaded
-            + (h + 2 * ph) * up(wp, sub(img_isz)) * cl * img_isz  # scratch
-            + ho * up(wp, sub(img_isz)) * cl * img_isz  # row-shift block
-            + 2 * m * cl * img_isz                  # live column windows
-            + 2 * m * bl * 4                        # accumulator in/out
-            + kh * kw * up(cin, sub(w_isz)) * bl * w_isz  # weight taps
-            + ho * up(wo, sub(out_isz)) * bl * out_isz)   # output tile
-    if vmem > vmem_budget:
-        return None
-    return {"block_n": bn, "out_hw": (ho, wo)}
-
-
-def convkxk_bn_stats(x, w, pad=(1, 1), block_n=128):
-    """x (N,H,W,Cin) NHWC, w (Cout,kh,kw,Cin) OHWI, stride 1, symmetric
-    per-dim ``pad`` -> (z (N,Ho,Wo,Cout), mean, var), stats fp32."""
-    n, h, wd, cin = x.shape
-    cout, kh, kw, _ = w.shape
-    fit = convkxk_fits(x.shape, cout, (kh, kw), pad, block_n,
-                       itemsize=jnp.dtype(x.dtype).itemsize)
-    assert fit is not None, (x.shape, w.shape, pad)
-    bn = fit["block_n"]
-    ho, wo = fit["out_hw"]
-    grid = (cout // bn, n)                        # batch innermost
-    kernel = functools.partial(_ckxk_kernel, ho=ho, wo=wo, kh=kh, kw=kw,
-                               ph=pad[0], pw=pad[1])
-    # taps-leading weight layout so the in-loop per-tap slice is on the
-    # (cheap, untiled) leading dim
-    wr = jnp.transpose(w, (1, 2, 3, 0)).reshape(kh * kw, cin, cout)
-    z, s, ss = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, h, wd, cin), lambda ci, b: (b, 0, 0, 0)),
-            pl.BlockSpec((kh * kw, cin, bn), lambda ci, b: (0, 0, ci)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, ho, wo, bn), lambda ci, b: (b, 0, 0, ci)),
-            pl.BlockSpec((1, bn), lambda ci, b: (0, ci)),
-            pl.BlockSpec((1, bn), lambda ci, b: (0, ci)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, ho, wo, cout), x.dtype),
-            jax.ShapeDtypeStruct((1, cout), jnp.float32),
-            jax.ShapeDtypeStruct((1, cout), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((h + 2 * pad[0], wd + 2 * pad[1], cin),
-                       jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(x, wr)
-    cnt = jnp.float32(n * ho * wo)
-    mean = s[0] / cnt
-    var = jnp.maximum(ss[0] / cnt - mean * mean, 0.0)
-    return z, mean, var
-
-
-def _ref_convkxk(x, w, pad):
-    dn = jax.lax.conv_dimension_numbers(
-        x.shape, w.shape, ("NHWC", "OHWI", "NHWC"))
-    return jax.lax.conv_general_dilated(
-        x, w, (1, 1), [(pad[0], pad[0]), (pad[1], pad[1])],
-        dimension_numbers=dn)
-
-
-@functools.lru_cache(maxsize=None)
-def _ckxk_train_for(pad):
-    """One custom_vjp core per static pad (jax.custom_vjp cannot take
-    non-array args positionally)."""
-
-    @jax.custom_vjp
-    def f(x, w):
-        return convkxk_bn_stats(x, w, pad)
-
-    def fwd(x, w):
-        z, mean, var = convkxk_bn_stats(x, w, pad)
-        return (z, mean, var), (x, w, z, mean)
-
-    def bwd(res, cts):
-        x, w, z, mean = res
-        gz, gmean, gvar = cts
-        n, ho, wo, _ = z.shape
-        m = n * ho * wo
-        z32 = z.astype(jnp.float32)
-        g = (gz.astype(jnp.float32)
-             + gmean.astype(jnp.float32) / m
-             + gvar.astype(jnp.float32) * 2.0 * (z32 - mean) / m)
-        # conv input/weight grads through XLA's own transposed convs (MXU)
-        _, vjp = jax.vjp(lambda x_, w_: _ref_convkxk(x_, w_, pad), x, w)
-        dx, dw = vjp(g.astype(z.dtype))
-        return dx.astype(x.dtype), dw.astype(w.dtype)
-
-    f.defvjp(fwd, bwd)
-    return f
-
-
-def convkxk_bn_stats_train(x, w, pad=(1, 1)):
-    """Differentiable (z, mean, var) of a stride-1 KxK NHWC conv with
-    fused batch statistics.  Caller pre-checks :func:`convkxk_fits`."""
-    return _ckxk_train_for((int(pad[0]), int(pad[1])))(x, w)
-
-
-# 3x3 compatibility surface (the original round-5 entry points)
-def conv3x3_fits(xshape, cout, block_n=128, vmem_budget=10 * 2 ** 20,
-                 itemsize=2):
-    return convkxk_fits(xshape, cout, (3, 3), (1, 1), block_n,
-                        vmem_budget, itemsize)
-
-
-def conv3x3_bn_stats(x, w, block_n=128):
-    return convkxk_bn_stats(x, w, (1, 1), block_n)
-
-
-def conv3x3_bn_stats_train(x, w):
-    return convkxk_bn_stats_train(x, w, (1, 1))
-
-
-def _ref_conv3x3(x, w):
-    return _ref_convkxk(x, w, (1, 1))
-
-
-# The round-5 int8 conv wrappers (int8_conv1x1 / int8_conv3x3 and the
-# _c3x3_int8_kernel full-image-tile body) were DELETED in round 9: the
-# chip bench measured the route at 0.345x of plain lax.conv s8
-# (BENCH_builder_r05 pallas_vs_lax) and contrib/quantization.py now
-# refuses MXNET_INT8_PALLAS with a pointer to that measurement.  The
-# rebuilt int8_matmul above stays as the microbench's A/B vehicle.

@@ -565,19 +565,25 @@ class PagePool:
         """Overwrite every FREE page (all geometries) with ``value`` —
         the aliasing canary: if any live sequence ever reads a page it
         does not own, its next tokens diverge loudly.  Returns the
-        number of pages poisoned."""
+        number of pages poisoned.  The free list is read UNDER the
+        geometry's dispatch lock: a page taken from it before the lock
+        could be allocated and written by a decode step in between, and
+        the poison would then land on live keys and values."""
         with self._lock:
-            free = list(self._free)
             geoms = list(self._storage)
-        if not free:
-            return 0
-        idx = jnp.asarray(free, jnp.int32)
+        poisoned = 0
         for g in geoms:
             with self.exclusive(g):
+                with self._lock:
+                    free = list(self._free)
+                if not free:
+                    continue
+                idx = jnp.asarray(free, jnp.int32)
                 k, v = self._storage[g]
                 self._storage[g] = [k.at[idx].set(value),
                                     v.at[idx].set(value)]
-        return len(free)
+                poisoned = max(poisoned, len(free))
+        return poisoned
 
 
 _SHARED: Optional[PagePool] = None

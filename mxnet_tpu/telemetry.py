@@ -5,7 +5,7 @@ The reference frame ships observability as a first-class subsystem
 server counters).  Our reproduction instead accreted ~57 ad-hoc counter
 references across 10+ modules — ``cached_step.trace_count``,
 ``spmd.reshard_count``, ``metric.host_sync_count``,
-``flash_fallback_count``, ``quantization.pallas_skipped_count()`` — plus
+``flash_fallback_count`` — plus
 three disjoint stats surfaces (``program_store.stats()``,
 ``GenerativeEngine.stats()``, ``faults.events()``) and a chrome-trace
 profiler the production paths never fed.  Every measured win so far

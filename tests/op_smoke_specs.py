@@ -35,17 +35,6 @@ SPECS = {
     "Deconvolution": ([_f(2, 8, 6, 6), _f(8, 4, 3, 3), _f(4)],
                       dict(kernel=(3, 3), num_filter=4)),
     "BatchNorm": ([_f(2, 4, 6, 6), _f(4), _f(4), _f(4), _f(4)], {}),
-    # fused conv+BN training kernels (NHWC x, OHWI w, gamma, beta);
-    # Pallas interpret path on CPU
-    "_fused_conv1x1_bn": ([_f(2, 6, 6, 4), _f(8, 1, 1, 4), _f(8), _f(8)],
-                          {}),
-    "_fused_convkxk_bn": ([_f(2, 6, 6, 4), _f(8, 3, 3, 4), _f(8), _f(8)],
-                          {}),
-    # fused EPILOGUE op (round 9): conv operands lead, BN affine trails;
-    # residual rides between (has_residual) — smoke the default
-    # no-bias/no-residual/relu form
-    "_fused_conv1x1_bn_act": ([_f(2, 6, 6, 4), _f(8, 1, 1, 4),
-                               _f(8), _f(8)], {}),
     "GroupNorm": ([_f(2, 4, 6, 6), _f(4), _f(4)], dict(num_groups=2)),
     "InstanceNorm": ([_f(2, 4, 6, 6), _f(4), _f(4)], {}),
     "Dropout": ([_f(4, 6), onp.zeros(2, onp.uint32)], dict(p=0.5)),

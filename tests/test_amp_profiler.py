@@ -34,7 +34,6 @@ def test_amp_init_casts_matmul_inputs():
     assert out2._data.dtype == jnp.float32
 
 
-@pytest.mark.slow   # ISSUE-20 wall: 150-step convergence
 def test_amp_training_converges():
     import jax.numpy as jnp
 

@@ -30,7 +30,7 @@ def test_smoke_refuses_to_start_without_a_tpu():
     assert r.stdout.strip() == ""            # no result of any kind
 
 
-@pytest.mark.slow
+@pytest.mark.slow     # 54 s alone, 85 s beside three other workers (PR 28)
 def test_smoke_rehearsal_passes_on_the_cpu_and_never_prints_the_pass_line():
     """Every phase, the mesh phase included (4 virtual devices)."""
     r = _run("--rehearse", "--chips", "4", timeout=900, devices=4)

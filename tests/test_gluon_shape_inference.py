@@ -89,8 +89,8 @@ def test_infer_shape_explicit():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("dtype", [
-    pytest.param("float16", marks=pytest.mark.slow),   # ISSUE-18 wall
-    "bfloat16",                     # the TPU-native dtype stays tier-1
+    "float16",
+    "bfloat16",                     # the TPU-native dtype
 ])
 def test_cast_then_forward_backward(dtype):
     net = gluon.model_zoo.vision.get_model("resnet18_v1", classes=10)

@@ -268,7 +268,6 @@ def test_real_tree_static_zero_findings():
     assert ctx.suppressed > 0              # pragmas are in play
 
 
-@pytest.mark.slow  # ISSUE-18 wall: subprocess gate; test_real_tree_static_zero_findings stays tier-1
 def test_full_gate_subprocess_and_artifact():
     """`python -m tools.lint --all`: static rules + the fresh-process
     lock-order scenario (compiled train step + decode batch + preemption

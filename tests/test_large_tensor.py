@@ -9,8 +9,6 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import nd
 
-pytestmark = pytest.mark.slow
-
 N = 1 << 22            # 4M elements (~16 MB fp32) per array
 
 

@@ -13,9 +13,7 @@ from mxnet_tpu.gluon.model_zoo import vision
 
 
 @pytest.mark.parametrize("name", [
-    "resnet18_v1", "resnet18_v2",
-    pytest.param("mobilenet0.25", marks=pytest.mark.slow),  # ISSUE-18 wall
-    pytest.param("squeezenet1.1", marks=pytest.mark.slow),  # ISSUE-18 wall
+    "resnet18_v1", "resnet18_v2", "mobilenet0.25", "squeezenet1.1",
 ])
 def test_model_forward(name):
     net = vision.get_model(name, classes=7)
@@ -30,7 +28,6 @@ def test_get_model_unknown():
         vision.get_model("not_a_model")
 
 
-@pytest.mark.slow
 def test_resnet18_train_step():
     net = vision.get_model("resnet18_v1", classes=4)
     net.initialize(mx.init.Xavier())
@@ -48,7 +45,6 @@ def test_resnet18_train_step():
     assert onp.isfinite(loss.asnumpy()).all()
 
 
-@pytest.mark.slow
 def test_resnet_channels_progression():
     net = vision.get_model("resnet50_v1", classes=10)
     net.initialize()
@@ -91,13 +87,11 @@ def test_pretrained_publish_and_load_smoke(tmp_path):
         model_store.get_model_file("resnet18_v1", root=root)
 
 
-@pytest.mark.slow
 def test_pretrained_publish_and_load_end_to_end(tmp_path):
     """Round-2 VERDICT item 9: the full pretrained path — train in-repo,
     publish sha1-keyed through model_store, and get_model(pretrained=True)
-    resolves it offline with identical predictions.  Slow-marked (~30s
-    training subprocess); tier-1 keeps the in-process publish smoke
-    above (ISSUE-17 wall slice 2)."""
+    resolves it offline with identical predictions (a 14 s training
+    subprocess; PR 28)."""
     import os
     import subprocess
     import sys
@@ -182,7 +176,6 @@ def test_shipped_pretrained_checkpoint_out_of_the_box(tmp_path):
                                        root=str(tmp_path / "empty"))
 
 
-@pytest.mark.slow   # ISSUE-20 wall: full-split exact reproduction
 def test_pretrained_real_data_accuracy_reproduces(tmp_path):
     """The shipped checkpoint carries MEASURED real-data accuracy (round-5
     VERDICT Missing #2 closure for an air-gapped environment: trained on

@@ -15,7 +15,9 @@ donated train step (ISSUE 20 tentpole + MoE parity satellite).
    dense-dispatch oracle across mesh shapes (1, ep=2, ep=4).  With
    k=2 routing each token has at most two nonzero combine
    contributions, so the partitioned reduction is a two-term float
-   add — associativity cannot bite and the match is bit-for-bit.
+   add — associativity cannot bite and the match is bit-for-bit
+   across mesh shapes (the loss's own sum is split by dp: a few ulp
+   against the no-mesh oracle).
 4. Capacity-drop determinism: over-capacity token drops are a pinned,
    reproducible function of the gating state.
 5. Composition: ``restore(like=)`` re-places expert weights across an
@@ -210,19 +212,26 @@ def test_moe_compiled_one_launch_ep_mesh():
 def test_moe_parity_bit_exact_across_mesh_shapes():
     """The ep-sharded OUTPUT is bit-exact vs unsharded: the first-step
     loss (a pure forward on identical params) matches to the last bit
-    on every mesh shape, including the no-mesh single-chip oracle —
-    partitioning the expert einsums over ep does not perturb a single
-    activation bit.  The 4-step training TRAJECTORY is pinned at
-    last-ulp tolerance instead: the gate-gradient psum tree
-    reassociates across ep shards (measured: <= 1 ulp on this stack),
-    the same bar the fsdp parity test holds sharded optimizers to."""
+    on every MESH shape (ep=1, 2, 4 under dp=2) — partitioning the
+    expert einsums over ep does not perturb a single activation bit.
+    The no-mesh single-chip oracle agrees to a few ulp, not to the
+    bit: its program sums the loss in ONE ``reduce`` over f32[4,6,8];
+    under dp=2 each device reduces its f32[2,6,8] half and an
+    ``all-reduce`` adds the two partial sums (both programs' optimized
+    HLO, PR 28): the same 192 terms, associated another way.  Measured:
+    4 ulp of the loss (3.1e-7 relative); the bound is 1e-6.
+    The 4-step training TRAJECTORY is pinned at last-ulp tolerance: the
+    gate-gradient psum tree reassociates across ep shards (measured:
+    <= 1 ulp on this stack), the same bar the fsdp parity test holds
+    sharded optimizers to."""
     n1, _t, _s, l1 = _run_moe("1", steps=4, seed=0)
     nu, _t, _s, lu = _run_moe("ep=1,dp=2", steps=4, seed=0)
     n2, _t, _s, l2 = _run_moe("ep=2,dp=2", steps=4, seed=0)
     n4, _t, _s, l4 = _run_moe("ep=4,dp=2", steps=4, seed=0)
     # forward parity: identical params -> the step-0 loss is the
     # ep-sharded output, and it is bit-exact on every mesh shape
-    assert l1[0] == lu[0] == l2[0] == l4[0], (l1[0], lu[0], l2[0], l4[0])
+    assert lu[0] == l2[0] == l4[0], (lu[0], l2[0], l4[0])
+    assert abs(l1[0] - lu[0]) <= 1e-6 * abs(lu[0]), (l1[0], lu[0])
     p1, pu = _params_of(n1), _params_of(nu)
     p2, p4 = _params_of(n2), _params_of(n4)
     for k in p1:

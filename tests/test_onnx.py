@@ -94,7 +94,6 @@ def test_export_import_resnet18(tmp_path):
         onp.abs(onp.asarray(got[0]) - ref).max())
 
 
-@pytest.mark.slow
 def test_export_import_bert_small(tmp_path):
     """BERT export: embedding/LayerNorm/interleaved-attention decompose to
     standard ONNX ops and round-trip numerically."""

@@ -169,7 +169,6 @@ def test_hetero_pipeline_matches_sequential():
     assert onp.allclose(onp.asarray(sp1["w"]), onp.asarray(w1))
 
 
-@pytest.mark.slow   # ISSUE-20 wall: remat + 4-microbatch compile
 def test_hetero_pipeline_grads_match_sequential():
     """Microbatch gradient accumulation through the pp scan equals the
     unpipelined gradient."""
@@ -276,12 +275,10 @@ def test_pp_transformer_loss_smoke():
     assert abs(pp_loss - ref_loss) < 1e-4, (pp_loss, ref_loss)
 
 
-@pytest.mark.slow
 def test_pp_transformer_loss_matches_unpipelined():
     """Flagship TransformerLM through HeteroPipeline pp=2: loss and grads
-    match the unpipelined model (VERDICT round-1 item 3).  ~35s of
-    grad/train-step compiles, so slow-marked; tier-1 keeps the
-    loss-equality smoke above (ISSUE-17 wall slice 2)."""
+    match the unpipelined model (VERDICT round-1 item 3).  11 s alone
+    with a warm compile cache, 23 s cold (PR 28)."""
     from mxnet_tpu import models
 
     cfg = models.TransformerLMConfig(
@@ -494,7 +491,6 @@ def _adam_ref_loop(cfg, params, batches, lr=1e-3, beta1=0.9, beta2=0.999,
     return params, losses
 
 
-@pytest.mark.slow
 def test_pp_multistep_convergence_matches_unpipelined():
     """VERDICT r3 item 9: ≥10 steps of pp training track the unpipelined
     loss curve — schedule bugs (stale activations, microbatch skew,
@@ -564,12 +560,10 @@ def test_pp_ragged_batch_pad_smoke():
     assert abs(pp_loss - ref_loss) < 1e-4, (pp_loss, ref_loss)
 
 
-@pytest.mark.slow
 def test_pp_ragged_batch_pad_and_mask():
     """dp x pp with a ragged batch: pp_pad_batch pads rows with label=-1;
     global-valid-count normalization makes loss/grads EXACTLY the
-    unpadded batch's.  Slow-marked for the grad compile; tier-1 keeps
-    the loss-equality smoke above (ISSUE-17 wall slice 2)."""
+    unpadded batch's."""
     from mxnet_tpu import models
 
     cfg = models.TransformerLMConfig(

@@ -483,14 +483,14 @@ def _load_gate():
     return mod
 
 
-@pytest.mark.slow
+@pytest.mark.slow     # 59 s alone, 63 s beside three other workers (PR 28)
 def test_check_recovery_budget_gate():
     """The suite-run gate (tools/check_recovery_budget.py, loaded like
     check_fault_sites): every drill scenario green, warm recovery at 0
     fresh compiles, 0 leaked pages / temp files, recovery inside the
-    wall-clock budget.  The FULL matrix is ~30s of subprocess drills,
-    so it runs slow-marked; tier-1 keeps the single-scenario smoke
-    below (ISSUE-16 wall relief)."""
+    wall-clock budget.  The FULL matrix is a minute of subprocess
+    drills, so it runs slow-marked; tier-1 keeps the single-scenario
+    smoke below."""
     gate = _load_gate()
     assert gate.main([]) == 0
 

@@ -450,7 +450,6 @@ def _run_worker(cache_dir):
     return json.loads(r.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.slow  # ISSUE-18 wall: subprocess spawn; in-process store tests above keep the contract
 def test_cold_start_parity_across_processes(tmp_path):
     """Process A warms N signatures with MXNET_PROGRAM_CACHE_DIR set;
     process B replays the same workload and must perform 0 fresh XLA
@@ -481,7 +480,6 @@ def test_cold_start_parity_across_processes(tmp_path):
     assert c["digest"] == a["digest"]
 
 
-@pytest.mark.slow
 def test_corrupted_cache_entry_degrades_loudly(tmp_path):
     """Garbage in a persistent entry must degrade to a fresh recompile
     under program_store.load — recorded, bit-exact, never a crash."""

@@ -5,12 +5,15 @@ quantize/dequantize/requantize op semantics, calibration, and the end-to-
 end quantize_model accuracy check (quantized net within 1% of fp32 on a
 synthetic classification check).
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as onp
 import pytest
 
 import mxnet_tpu as mx
+from mxnet_tpu import config
 from mxnet_tpu.contrib import quantization as q
 from mxnet_tpu.gluon import nn
 
@@ -171,7 +174,6 @@ def test_conv_bn_relu_folds_and_requantize_fuses():
     assert (ref.argmax(1) == got.argmax(1)).mean() >= 0.9
 
 
-@pytest.mark.slow
 def test_quantize_net_nhwc_s2d_fast_path():
     """The bench's channel-minor fast path quantizes natively: NHWC convs
     (incl. the space-to-depth stem) become quantized_conv with layout NHWC
@@ -226,3 +228,70 @@ def test_quantize_symbol_excluded_layers_stay_fp32():
     ref = net(x).asnumpy()
     got = onp.asarray(q.QuantizedNet(qsym, qparams)(x))
     assert onp.abs(got - ref).max() / (abs(ref).max() + 1e-9) < 0.05
+
+
+def test_quantized_conv_strided_shape():
+    rng = onp.random.RandomState(3)
+    qd = onp.asarray(rng.randint(-10, 10, (1, 4, 4, 8)), onp.int8)
+    qw3 = onp.asarray(rng.randint(-10, 10, (8, 3, 3, 8)), onp.int8)
+    out = q.quantized_conv([jnp.asarray(qd), jnp.asarray(qw3)],
+                           kernel=(3, 3), stride=(2, 2), pad=(1, 1),
+                           num_filter=8, layout="NHWC", no_bias=True,
+                           data_scale=0.1, w_scale=0.1)
+    assert onp.asarray(out).shape == (1, 2, 2, 8)
+
+
+def test_quantized_conv_pad_channels_bit_exact(monkeypatch):
+    """The MXU alignment pass on the s8 path (quantum 32): a traced
+    misaligned-channel quantized conv pads with zero taps and slices
+    back — integer math, so EXACT — and the eager call never pads."""
+    from mxnet_tpu.ops import nn as ops_nn
+
+    rng = onp.random.RandomState(5)
+    qd = jnp.asarray(rng.randint(-127, 128, (2, 6, 6, 24)), jnp.int8)
+    qw = jnp.asarray(rng.randint(-127, 128, (48, 1, 1, 24)), jnp.int8)
+
+    def make_run():
+        # fresh function object per mode: jax's trace cache keys on the
+        # function identity, and the knob must really retrace
+        def run(qd, qw):
+            return q.quantized_conv([qd, qw], kernel=(1, 1),
+                                    num_filter=48, layout="NHWC",
+                                    no_bias=True, data_scale=0.02,
+                                    w_scale=0.01)
+        return run
+
+    monkeypatch.setenv("MXNET_PAD_CHANNELS", "0")
+    config.refresh("MXNET_PAD_CHANNELS")
+    ref = onp.asarray(jax.jit(make_run())(qd, qw))
+    monkeypatch.setenv("MXNET_PAD_CHANNELS", "2")
+    config.refresh("MXNET_PAD_CHANNELS")
+    c0 = ops_nn.pad_channels_count()
+    padded = onp.asarray(jax.jit(make_run())(qd, qw))
+    assert ops_nn.pad_channels_count() - c0 == 1
+    onp.testing.assert_array_equal(ref, padded)
+    c1 = ops_nn.pad_channels_count()
+    make_run()(qd, qw)                            # eager: tracer gate
+    assert ops_nn.pad_channels_count() == c1
+    os.environ.pop("MXNET_PAD_CHANNELS", None)
+    config.refresh("MXNET_PAD_CHANNELS")
+
+
+def test_quantize_net_end_to_end_lax():
+    """Whole quantize->convert->run flow on the (only) lax route:
+    int8 predictions track the fp32 reference."""
+    rng = onp.random.RandomState(4)
+    net = nn.HybridSequential()
+    net.add(nn.Conv2D(32, 1, use_bias=False, in_channels=16, layout="NHWC",
+                      activation="relu"),
+            nn.Conv2D(64, 1, use_bias=False, in_channels=32, layout="NHWC"),
+            nn.GlobalAvgPool2D(layout="NHWC"),
+            nn.Dense(10, in_units=64))
+    net.initialize(mx.init.Xavier())
+    calib = [mx.nd.array(rng.rand(4, 8, 8, 16).astype(onp.float32))
+             for _ in range(3)]
+    x = mx.nd.array(rng.rand(8, 8, 8, 16).astype(onp.float32))
+    qnet = q.quantize_net(net, calib)
+    out = onp.asarray(qnet(x))
+    ref = net(x).asnumpy()
+    assert (ref.argmax(1) == out.argmax(1)).mean() >= 0.99

@@ -16,7 +16,6 @@ def _python_blocks():
     return re.findall(r"```python\n(.*?)```", text, re.DOTALL)
 
 
-@pytest.mark.slow
 def test_readme_python_snippets_execute():
     blocks = _python_blocks()
     assert len(blocks) >= 2, "README lost its quick-start snippets"

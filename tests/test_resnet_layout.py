@@ -33,10 +33,7 @@ def _build_ref(version, num_layers, x):
     return net, net(x).asnumpy()
 
 
-@pytest.mark.parametrize("version", [
-    pytest.param(1, marks=pytest.mark.slow),  # ISSUE-18 wall: v2 keeps layout parity tier-1
-    2,
-])
+@pytest.mark.parametrize("version", [1, 2])
 def test_nhwc_matches_nchw(version):
     x = mx.nd.array(onp.random.RandomState(0)
                     .randn(2, 3, 64, 64).astype(onp.float32))

@@ -414,7 +414,11 @@ def _masked_loss(n_, x, y, m):
 def test_train_step_bucket_parity_and_bounded_traces():
     """Variable-length batches with a pad-safe (masked) loss: params
     stay bit-exact vs unpadded eager training while the program cache
-    holds one program per bucket instead of one per length."""
+    holds one program per bucket instead of one per length.  The
+    one-time verify compares the padded LOSS to the unpadded one up to
+    summation order (``TrainStep._verify_pad``): the sum over (3, 4)
+    and over (4, 4) with a zero row differ by 1 ulp here, which the
+    verify used to take for a loss that is not pad-safe (PR 28)."""
     def build():
         net = _mlp(13, hybridize=True)
         tr = gluon.Trainer(net.collect_params(), "sgd",
@@ -486,13 +490,11 @@ def test_dispatch_budget_serving_lane_smoke():
         assert row[key] <= budget, (key, row[key], budget)
 
 
-@pytest.mark.slow
 def test_dispatch_budget_gate_covers_serving():
     """tools/check_dispatch_budget.py (run like check_fault_sites): the
     serving path must hold 1 launch/batch, 0 retraces, and programs <=
-    buckets over a randomized variable-length stream.  Slow-marked
-    (full lane matrix); tier-1 keeps the infer-lane smoke above
-    (ISSUE-17 wall slice 2)."""
+    buckets over a randomized variable-length stream (the full lane
+    matrix, 17 s; PR 28)."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(

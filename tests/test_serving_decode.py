@@ -372,7 +372,6 @@ def test_waitall_drains_engine():
     eng.close()
 
 
-@pytest.mark.slow
 def test_multi_model_shared_pool_accounting():
     """Two engines (distinct geometries) draw pages from ONE pool; both
     decode concurrently, results stay token-exact, and the shared

@@ -551,7 +551,6 @@ def test_dispatch_budget_router_lane_in_process():
     assert row["leaked_pages"] == 0
 
 
-@pytest.mark.slow
 def test_availability_gate_subprocess_scenarios():
     """The chaos-drill gate, end-to-end: a replica killed mid-decode
     (plus the preemption-notice drain) and the deadline storm, as real

@@ -374,7 +374,6 @@ def test_spec_requires_decode_chunk_and_matching_vocab():
 # ---------------------------------------------------------------------------
 # Tool-gate lanes (the full gates run as slow subprocess tests)
 # ---------------------------------------------------------------------------
-@pytest.mark.slow
 def test_dispatch_budget_spec_lane_in_process():
     """The CI gate's spec lane: bounded program set over BOTH
     namespaces, 0 retraces across mixed sampled/greedy traffic,
@@ -400,7 +399,6 @@ def test_dispatch_budget_spec_lane_in_process():
     assert d["greedy_off_outputs_equal"]
 
 
-@pytest.mark.slow
 def test_availability_gate_spec_draft_poison_scenario():
     """The chaos cell end-to-end as a real subprocess drill: a draft
     poisoned mid-round auto-disables speculation on BOTH replicas with

@@ -39,7 +39,6 @@ def _eval(sym, params, x):
     return onp.asarray((out[0] if isinstance(out, list) else out).asnumpy())
 
 
-@pytest.mark.slow  # ISSUE-18 wall: full resnet18; smaller partition tests below keep coverage
 def test_resnet18_conv_bn_relu_partition():
     rng = onp.random.RandomState(0)
     net = vision.get_model("resnet18_v1", classes=10)

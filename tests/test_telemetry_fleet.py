@@ -160,7 +160,6 @@ def test_rotation_deletes_oldest_shards(tmp_path, monkeypatch):
 # CLI + merge tool
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow   # ISSUE-20 wall: three CLI subprocesses
 def test_cli_report_trace_merge(tmp_path):
     d = tmp_path / "shards"
     d.mkdir()

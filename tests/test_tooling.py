@@ -19,7 +19,6 @@ from mxnet_tpu.gluon.model_zoo import vision
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.mark.slow
 def test_model_store_publish_and_pretrained(tmp_path):
     """Offline pretrained flow: train -> save -> publish -> get_model
     (pretrained=True) resolves from the local cache."""
@@ -244,9 +243,7 @@ def test_flakiness_checker_stable_test(tmp_path):
     assert "stable across 2" in r.stdout
 
 
-# ISSUE-20 wall: 4 checker subprocesses; the stable 2-run variant
-# above stays tier-1 through the same tool path
-@pytest.mark.slow
+# 4 checker subprocesses, 8 s (PR 28)
 def test_flakiness_checker_detects_seed_failure(tmp_path):
     target = tmp_path / "test_seeded.py"
     target.write_text(

@@ -71,7 +71,6 @@ def _score(net, val_data, ctx_list):
     return metric.get()[1]
 
 
-@pytest.mark.slow
 def test_train_autograd_end_to_end(mnist_files, tmp_path):
     train_data = mx.io.MNISTIter(image=mnist_files["train-img"],
                                  label=mnist_files["train-lbl"],

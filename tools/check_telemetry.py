@@ -92,11 +92,18 @@ def _base_matches_segment(base: str, seg: str) -> bool:
 
 def check_registered(accessors: Dict[str, Set[str]],
                      registry: Dict[str, dict]) -> List[str]:
-    """Accessor bases with NO matching registered counter."""
+    """Accessor bases with NO matching registered counter.  A base
+    ``<scope>_<what>`` (``spec_trace_count``) also matches a counter
+    ``...<scope>.<what>s`` (``program_store.serving_spec.traces``)."""
     segs = {n.rsplit(".", 1)[-1] for n in registry}
     missing = []
     for base, files in sorted(accessors.items()):
-        if not any(_base_matches_segment(base, s) for s in segs):
+        scope, _, what = base.rpartition("_")
+        scoped = scope and any(
+            _base_matches_segment(what, n.rsplit(".", 1)[-1])
+            and scope in n.rsplit(".", 1)[0] for n in registry)
+        if not scoped and not any(_base_matches_segment(base, s)
+                                  for s in segs):
             missing.append(f"{base}_count (declared in "
                            f"{', '.join(sorted(files))})")
     return missing

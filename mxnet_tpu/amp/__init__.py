@@ -16,6 +16,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as onp
 
+from .. import telemetry as _telemetry
 from ..base import MXNetError
 from . import lists
 from .loss_scaler import LossScaler
@@ -26,6 +27,12 @@ __all__ = ["init", "uninit", "init_trainer", "scale_loss", "unscale",
 _LOW = frozenset(lists.LOW_PRECISION_FUNCS)
 _F32 = frozenset(lists.FP32_FUNCS)
 _WIDEST = frozenset(lists.WIDEST_TYPE_CASTS)
+_BATCH_NORMS = frozenset(("BatchNorm", "SyncBatchNorm", "BatchNormWithReLU"))
+
+_BN_LOW = _telemetry.counter(
+    "amp.batch_norm.low_precision",
+    "batch-norm calls traced under AMP on a bf16/fp16 operand, which they "
+    "return in that type (float32 statistics and arithmetic inside)")
 
 
 class _AmpState:
@@ -53,6 +60,10 @@ def _policy(op_name, arrays):
         return [a.astype(jnp.float32)
                 if hasattr(a, "dtype") and a.dtype == target else a
                 for a in arrays]
+    if op_name in _BATCH_NORMS:
+        if arrays[0].dtype in (jnp.bfloat16, jnp.float16):
+            _BN_LOW.inc()
+        return arrays
     if op_name in _WIDEST:
         dtypes = {a.dtype for a in arrays if hasattr(a, "dtype")}
         if jnp.float32 in dtypes and target in dtypes:
@@ -161,8 +172,10 @@ def convert_hybrid_block(net, target_dtype="bfloat16", ctx=None):
     """Cast a Block for low-precision inference/training (reference
     amp.convert_hybrid_block).  Parameters cast to ``target_dtype`` except
     those owned by normalization layers, which stay fp32 (the op policy
-    casts their inputs up at dispatch).  ``ctx`` additionally re-homes the
-    parameters, matching the reference signature."""
+    casts the inputs of layer/group/instance norm up at dispatch; batch
+    norm takes its input as it arrives and is float32 inside).  ``ctx``
+    additionally re-homes the parameters, matching the reference
+    signature."""
 
     def walk(block):
         if type(block).__name__ in _F32_LAYERS:

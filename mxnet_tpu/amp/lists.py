@@ -3,20 +3,26 @@
 this module does the same for this registry, enforced exhaustive by
 tests/test_amp_profiler.py).
 
-Four classes, same split logic as the reference:
+Four classes, the reference's own:
 
 - LOW_PRECISION_FUNCS (reference FP16_FUNCS): matmul/conv-class ops that
   are safe and fast in bf16/fp16 — these are the MXU ops, where low
   precision doubles throughput.
-- FP32_FUNCS: numerically sensitive ops pinned to fp32 — norms, softmax /
-  log / exp family, losses, statistics-feeding reductions, linear
-  algebra factorizations, probability densities, and optimizer update
-  kernels (master-weight math stays fp32).
+- FP32_FUNCS: numerically sensitive ops pinned to fp32 — the norms that
+  reduce over one sample's features (LayerNorm, GroupNorm, InstanceNorm,
+  LRN), softmax / log / exp family, losses, statistics-feeding reductions,
+  linear algebra factorizations, probability densities, and optimizer
+  update kernels (master-weight math stays fp32).
 - WIDEST_TYPE_CASTS: multi-input elementwise ops that follow their widest
   input dtype (reference WIDEST_TYPE_CASTS).
 - FP16_FP32_FUNCS: dtype-neutral ops that run correctly in whichever
   precision arrives (moves/reshapes/indexing/comparisons/integer and
-  random ops).  The policy leaves their inputs untouched.
+  random ops).  The policy leaves their inputs untouched.  BatchNorm is
+  here, as in the reference (and as cuDNN's and flax's batch norm under
+  mixed precision): it takes the convolution's bf16 and returns bf16,
+  with float32 statistics and a float32 multiply-add INSIDE the operator
+  (ops/nn.py:batch_norm), so a cast in front of it buys no precision and
+  only widens every activation behind it to the next convolution.
 
 On TPU the low-precision dtype is bfloat16 by default — same exponent
 range as fp32, so the reference's loss-scaling machinery is optional
@@ -38,12 +44,12 @@ LOW_PRECISION_FUNCS = [
 
 FP32_FUNCS = [
     # normalization / losses
-    "BatchNorm", "LayerNorm", "GroupNorm", "InstanceNorm", "LRN",
+    "LayerNorm", "GroupNorm", "InstanceNorm", "LRN",
     "L2Normalization", "softmax", "log_softmax", "softmin",
     "softmax_cross_entropy", "SoftmaxOutput", "CTCLoss", "MakeLoss",
     "LinearRegressionOutput", "LogisticRegressionOutput",
     "MAERegressionOutput", "smooth_l1",
-    "SyncBatchNorm", "BatchNormWithReLU", "hawkesll",
+    "hawkesll",
     # exp/log family and friends
     "exp", "log", "log2", "log10", "log1p", "expm1", "square", "sqrt",
     "rsqrt", "cbrt", "rcbrt", "power", "power_scalar", "reciprocal",
@@ -105,6 +111,10 @@ WIDEST_TYPE_CASTS = [
 # (tests fail when a new op lands unclassified, mirroring the reference's
 # all-ops list files).
 FP16_FP32_FUNCS = [
+    # batch norm takes and returns the type that arrives (the reference's
+    # placement): its statistics and its multiply-add are float32 inside
+    # the operator, so a cast in front only widens what reaches HBM
+    "BatchNorm", "SyncBatchNorm", "BatchNormWithReLU",
     # activations / simple elementwise
     "Activation", "LeakyReLU", "relu", "sigmoid", "tanh", "softsign",
     "hard_sigmoid", "abs", "sign", "negative", "ceil", "floor", "rint",

@@ -189,6 +189,48 @@ def _device_scope(block):
     return jax.named_scope(cls if key is None else f"{cls}.{key}")
 
 
+def remat_call(blocks, x):
+    """``blocks`` called one after another on ``x``.  While a program is
+    staged (``_stage_fn`` is tracing) the run is ONE rematerialised segment:
+    ``jax.checkpoint`` keeps nothing of it for the backward but its input
+    and the parameters it reads, and the backward recomputes the rest.  For
+    a run of cheap elementwise blocks behind a tensor the backward keeps
+    anyway (a batch norm keeps its input): XLA left to itself keeps such a
+    run's outputs when they are narrow and recomputes them when they are
+    wide, so the peak would depend on the activations' type.  An eager call
+    is a plain call.
+
+    A parameter the run writes (a batch norm's running statistics) leaves
+    the segment as an output and is written back outside it, so no tracer
+    of the segment's own trace stays in a live buffer.  A block that draws
+    random numbers cannot be in the run (the key chain would keep one)."""
+    if not _random.in_trace():
+        for block in blocks:
+            x = block(x)
+        return x
+    replicas = [d for block in blocks
+                for p in block.collect_params().values()
+                for d in (p._data or ())]
+    written = []
+
+    def segment(arr):
+        held = [d._data for d in replicas]
+        y = _wrap(arr, x.ctx, type(x))
+        for block in blocks:
+            y = block(y)
+        written[:] = [d for d, old in zip(replicas, held)
+                      if d._data is not old]
+        new = [d._data for d in written]
+        for d, old in zip(replicas, held):
+            d._data = old
+        return y._data, new
+
+    out, new = jax.checkpoint(segment)(x._data)
+    for d, val in zip(written, new):
+        d._data = val           # its version was bumped inside the segment
+    return _wrap(out, x.ctx, type(x))
+
+
 class _BlockScope:
     """Tracks hook handles."""
 

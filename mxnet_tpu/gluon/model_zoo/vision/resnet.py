@@ -244,6 +244,27 @@ class _ResNetBase(HybridBlock):
         self._layout = layout
         self._input_layout = input_layout or "NCHW"
 
+    def _add_stem(self, channels, stem_s2d):
+        """The 7x7 convolution, batch norm, ReLU and max-pool, as ONE run a
+        staged program recomputes in its backward pass from the input batch
+        (``HybridSequential.recompute``).  These are the net's largest
+        activations.  Under AMP they are bf16, and XLA keeps a cheap bf16
+        tensor for the whole step where it recomputes the float32 one on its
+        own: left alone, ResNet-50's step at batch 256 held 0.59 GiB more
+        than with float32 activations.  Recomputing the three blocks behind
+        the convolution still held 0.20 GiB more; with the convolution
+        inside, 0.25 GiB LESS, for 1% of the step (PERF.md, PR 29)."""
+        first = len(self.features)
+        if stem_s2d:
+            self.features.add(_StemConvS2D(channels, self._layout))
+        else:
+            self.features.add(nn.Conv2D(channels, 7, 2, 3, use_bias=False,
+                                        layout=self._layout))
+        self.features.add(_bn(self._layout))
+        self.features.add(nn.Activation("relu"))
+        self.features.add(nn.MaxPool2D(3, 2, 1, layout=self._layout))
+        self.features.recompute(first, first + 4)
+
     def _to_compute_layout(self, x):
         if self._input_layout == self._layout:
             return x
@@ -261,14 +282,7 @@ class ResNetV1(_ResNetBase):
         if thumbnail:
             self.features.add(_conv3x3(channels[0], 1, 0, layout))
         else:
-            if stem_s2d:
-                self.features.add(_StemConvS2D(channels[0], layout))
-            else:
-                self.features.add(nn.Conv2D(channels[0], 7, 2, 3,
-                                            use_bias=False, layout=layout))
-            self.features.add(_bn(layout))
-            self.features.add(nn.Activation("relu"))
-            self.features.add(nn.MaxPool2D(3, 2, 1, layout=layout))
+            self._add_stem(channels[0], stem_s2d)
         for i, num_layer in enumerate(layers):
             stride = 1 if i == 0 else 2
             self.features.add(self._make_layer(
@@ -301,14 +315,7 @@ class ResNetV2(_ResNetBase):
         if thumbnail:
             self.features.add(_conv3x3(channels[0], 1, 0, layout))
         else:
-            if stem_s2d:
-                self.features.add(_StemConvS2D(channels[0], layout))
-            else:
-                self.features.add(nn.Conv2D(channels[0], 7, 2, 3,
-                                            use_bias=False, layout=layout))
-            self.features.add(_bn(layout))
-            self.features.add(nn.Activation("relu"))
-            self.features.add(nn.MaxPool2D(3, 2, 1, layout=layout))
+            self._add_stem(channels[0], stem_s2d)
         in_channels = channels[0]
         for i, num_layer in enumerate(layers):
             stride = 1 if i == 0 else 2

@@ -11,7 +11,7 @@ from ... import autograd
 from ... import random as _random
 from ...ndarray import NDArray
 from ...ndarray.ndarray import invoke, _wrap
-from ..block import Block, HybridBlock
+from ..block import Block, HybridBlock, remat_call
 from ..parameter import Parameter
 
 __all__ = [
@@ -70,13 +70,27 @@ class HybridSequential(HybridBlock):
 
     def __init__(self):
         super().__init__()
+        self._recomputed = None
 
     def add(self, *blocks):
         for block in blocks:
             self.register_child(block)
 
+    def recompute(self, start, stop):
+        """Children ``start`` up to ``stop`` run as one segment that a
+        staged program recomputes in its backward pass and keeps nothing
+        of but its input (``block.remat_call``)."""
+        self._recomputed = (start, stop)
+
     def forward(self, x):
-        for block in self._children.values():
+        blocks = list(self._children.values())
+        if self._recomputed is not None:
+            start, stop = self._recomputed
+            for block in blocks[:start]:
+                x = block(x)
+            x = remat_call(blocks[start:stop], x)
+            blocks = blocks[stop:]
+        for block in blocks:
             x = block(x)
         return x
 

@@ -393,6 +393,14 @@ def pooling(data, kernel=None, pool_type="max", global_pool=False,
 
 # --- normalization ---------------------------------------------------------
 
+def at_least_float32(x):
+    """``x`` as batch norm computes on it: a bf16/fp16 operand (AMP hands the
+    convolution's output over as it is, amp/lists.py) widened to float32,
+    anything else as it is.  The output is rounded once, back to ``x``'s
+    type."""
+    return x.astype(jnp.promote_types(x.dtype, jnp.float32))
+
+
 @register("BatchNorm", num_inputs=-1, num_outputs=-1)
 def batch_norm(arrays, eps=1e-3, momentum=0.9, fix_gamma=True,
                use_global_stats=False, output_mean_var=False, axis=1,
@@ -432,13 +440,16 @@ def batch_norm(arrays, eps=1e-3, momentum=0.9, fix_gamma=True,
     else:
         mean, var = moving_mean, moving_var
     # Fold the affine into per-channel scale/bias vectors (C-sized, fp32):
-    # the big tensor then sees ONE fused multiply-add in its own dtype.
+    # the big tensor then sees ONE multiply-add, in float32 for a bf16/fp16
+    # operand, rounded once to the operand's type: it is read and written
+    # at its own width, only the arithmetic in between is wide.
     f32 = jnp.float32
     inv = jax.lax.rsqrt(var.astype(f32) + f32(eps))
     sc = inv * g.astype(f32)
     bi = beta.astype(f32) - mean.astype(f32) * sc
-    out = data * sc.reshape(shape).astype(data.dtype) \
-        + bi.reshape(shape).astype(data.dtype)
+    x = at_least_float32(data)
+    out = (x * sc.reshape(shape).astype(x.dtype)
+           + bi.reshape(shape).astype(x.dtype)).astype(data.dtype)
     if training and not use_global_stats:
         return (out, mean.astype(moving_mean.dtype),
                 var.astype(moving_var.dtype))

@@ -21,12 +21,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .nn import at_least_float32
 from .registry import register
 
 
 def _bn_stats(x, axis_name=None):
     """Per-channel mean/var over (N, spatial), optionally pmean'd over a
     mesh axis (the SyncBatchNorm cross-device reduce)."""
+    x = at_least_float32(x)
     red = (0,) + tuple(range(2, x.ndim))
     mean = jnp.mean(x, axis=red)
     mean_sq = jnp.mean(jnp.square(x), axis=red)
@@ -42,8 +44,9 @@ def _bn_apply(x, gamma, beta, mean, var, eps, fix_gamma):
     if fix_gamma:
         gamma = jnp.ones_like(gamma)
     inv = lax.rsqrt(var + eps).reshape(shape)
-    return (x - mean.reshape(shape)) * inv * gamma.reshape(shape) \
-        + beta.reshape(shape)
+    out = (at_least_float32(x) - mean.reshape(shape)) * inv \
+        * gamma.reshape(shape) + beta.reshape(shape)
+    return out.astype(x.dtype)
 
 
 @register("SyncBatchNorm", num_inputs=5, num_outputs=1,

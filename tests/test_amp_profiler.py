@@ -230,6 +230,172 @@ def test_amp_lists_exhaustive_over_registry():
         for n in lst:
             (dups if n in seen else seen).add(n)
     assert not dups, f"ops in multiple AMP lists: {dups}"
+    # batch norm runs in the type that arrives (the reference's placement:
+    # float32 statistics inside the operator); the norms that reduce over
+    # the features of one sample, the softmax family and the losses stay
+    # pinned to float32
+    neutral, pinned = set(lists.FP16_FP32_FUNCS), set(lists.FP32_FUNCS)
+    assert {"BatchNorm", "SyncBatchNorm", "BatchNormWithReLU"} <= neutral
+    assert {"LayerNorm", "GroupNorm", "InstanceNorm", "LRN", "softmax",
+            "log_softmax", "softmax_cross_entropy", "SoftmaxOutput",
+            "CTCLoss"} <= pinned
+
+
+LOW_TYPES = pytest.mark.parametrize("low", ["bfloat16", "float16"])
+# one rounding to the type: half a unit in its last place
+ROUNDING = {"bfloat16": 2.0 ** -8, "float16": 2.0 ** -11}
+
+
+def _bn_low_count():
+    return mx.telemetry.snapshot()["amp.batch_norm.low_precision"]
+
+
+@LOW_TYPES
+def test_amp_batch_norm_returns_the_type_that_arrives(low):
+    """``Conv2D -> BatchNorm -> Activation`` under AMP: the activation stays
+    in the convolution's type; the batch statistics, the running statistics
+    and the gradients of gamma and beta are float32."""
+    from mxnet_tpu import autograd
+    from mxnet_tpu.ndarray.ndarray import invoke
+
+    net = nn.HybridSequential()
+    net.add(nn.Conv2D(8, 3, padding=1, in_channels=3), nn.BatchNorm(),
+            nn.Activation("relu"))
+    net.initialize()
+    x = nd.array(onp.random.RandomState(0).randn(4, 3, 8, 8)
+                 .astype("float32"))
+    amp.init(low)
+    before = _bn_low_count()
+    with autograd.record():
+        out = net(x)
+        loss = out.astype("float32").sum()
+    loss.backward()
+    assert _bn_low_count() == before + 1
+    assert str(out.dtype) == low
+    bn = net[1]
+    for p in (bn.gamma, bn.beta):
+        assert p.data().dtype == onp.float32
+        assert p.grad().dtype == onp.float32
+        assert onp.isfinite(p.grad().asnumpy()).all()
+    for p in (bn.running_mean, bn.running_var):
+        assert p.data().dtype == onp.float32
+    assert onp.abs(bn.running_mean.data().asnumpy()).max() > 0
+    # the operator's own outputs: the low type, float32 batch statistics
+    conv_out = net[0](x)
+    y, mean, var = invoke(
+        "BatchNorm", [conv_out, bn.gamma.data(), bn.beta.data(),
+                      bn.running_mean.data(), bn.running_var.data()],
+        {"eps": 1e-5, "fix_gamma": False, "training": True})
+    assert str(conv_out.dtype) == low and str(y.dtype) == low
+    assert mean.dtype == onp.float32 and var.dtype == onp.float32
+    # without AMP a float32 operand is no low-precision call
+    amp.uninit()
+    before = _bn_low_count()
+    assert net(x).dtype == onp.float32
+    assert _bn_low_count() == before
+
+
+def _bn_operands(low, seed=0):
+    import jax.numpy as jnp
+
+    rng = onp.random.RandomState(seed)
+    x = jnp.asarray(rng.randn(8, 6, 6, 16).astype("float32") * 3 + 1)
+    vec = [jnp.asarray(v.astype("float32")) for v in (
+        rng.rand(16) + 0.5, rng.randn(16), rng.randn(16), rng.rand(16) + 0.5)]
+    return x.astype(low), vec
+
+
+@LOW_TYPES
+@pytest.mark.parametrize("attrs", [{"training": True},
+                                   {"use_global_stats": True}],
+                         ids=["training", "use_global_stats"])
+def test_batch_norm_low_operand_is_one_rounding_from_float32(low, attrs):
+    """The operator on a bf16/fp16 operand against the float32 operator on
+    the SAME operand: float32 statistics and one float32 multiply-add, so
+    the only difference is the output's one rounding."""
+    from mxnet_tpu.ops.nn import batch_norm
+
+    x, vec = _bn_operands(low)
+    got = batch_norm([x] + vec, eps=1e-5, fix_gamma=False, axis=3, **attrs)
+    want = batch_norm([x.astype("float32")] + vec, eps=1e-5, fix_gamma=False,
+                      axis=3, **attrs)
+    assert str(got[0].dtype) == low and want[0].dtype == onp.float32
+    w = onp.asarray(want[0])
+    err = onp.abs(onp.asarray(got[0].astype("float32")) - w)
+    assert (err <= ROUNDING[low] * onp.abs(w) + 1e-30).all(), err.max()
+    for g, w in zip(got[1:], want[1:]):         # batch mean and variance
+        assert g.dtype == onp.float32
+        onp.testing.assert_array_equal(onp.asarray(g), onp.asarray(w))
+
+
+@pytest.mark.parametrize("attrs", [{"training": True},
+                                   {"use_global_stats": True}],
+                         ids=["training", "use_global_stats"])
+def test_batch_norm_float32_operand_is_the_parents_bit_for_bit(attrs):
+    """For a float32 operand the multiply-add in float32, rounded to the
+    operand's type, IS the line it replaced (``data * scale + shift`` in
+    the operand's type): replayed here."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.nn import batch_norm
+
+    x, (gamma, beta, mean, var) = _bn_operands("float32", seed=1)
+    out = batch_norm([x, gamma, beta, mean, var], eps=1e-5, fix_gamma=False,
+                     axis=3, **attrs)
+    if attrs.get("training"):
+        mean = jnp.mean(x, axis=(0, 1, 2))
+        var = jnp.maximum(jnp.mean(x * x, axis=(0, 1, 2)) - mean * mean, 0.0)
+    sc = jax.lax.rsqrt(var + jnp.float32(1e-5)) * gamma
+    bi = beta - mean * sc
+    onp.testing.assert_array_equal(
+        onp.asarray(out[0]),
+        onp.asarray(x * sc.reshape(1, 1, 1, 16) + bi.reshape(1, 1, 1, 16)))
+
+
+@LOW_TYPES
+@pytest.mark.parametrize("op", ["SyncBatchNorm", "BatchNormWithReLU"])
+def test_amp_graph_parity_batch_norms_are_float32_inside(op, low):
+    """The two graph-parity batch norms moved with ``BatchNorm``: a low
+    operand comes back in its type, one rounding from the float32 run."""
+    x, (gamma, beta, mean, var) = _bn_operands(low, seed=2)
+    x = x.transpose(0, 3, 1, 2)                      # these two are NCHW
+    args = [nd.array(onp.asarray(a.astype("float32"))) for a in
+            (x, gamma, beta, mean, var)]
+    want = getattr(nd, op)(*args, eps=1e-5, fix_gamma=False).asnumpy()
+    amp.init(low)
+    before = _bn_low_count()
+    got = getattr(nd, op)(args[0].astype(low), *args[1:], eps=1e-5,
+                          fix_gamma=False)
+    assert _bn_low_count() == before + 1
+    assert str(got.dtype) == low
+    err = onp.abs(got.astype("float32").asnumpy() - want)
+    assert (err <= ROUNDING[low] * onp.abs(want) + 1e-30).all(), err.max()
+
+
+@pytest.mark.parametrize("low,count", [("bfloat16", 53), (None, 0)],
+                         ids=["amp", "no_amp"])
+def test_resnet50_traces_53_low_precision_batch_norms(low, count):
+    """``amp.batch_norm.low_precision`` counts a site once a trace:
+    ``resnet50_v1`` under bf16 AMP hands every one of its 53 batch norms the
+    convolution's bf16; without AMP none."""
+    import jax
+    from mxnet_tpu.gluon import block as gblock
+    from mxnet_tpu.gluon.model_zoo import vision
+
+    net = vision.resnet50_v1(classes=10, layout="NHWC", input_layout="NHWC")
+    net.initialize()
+    x = nd.zeros((1, 32, 32, 3))
+    net(x)                                  # resolves the deferred shapes
+    params = net.collect_params()
+    raw_fn, _, _ = gblock._stage_fn(net, params, list(params),
+                                    gblock._flatten_args((x,))[1], True,
+                                    x.ctx)
+    if low:
+        amp.init(low)
+    before = _bn_low_count()
+    jax.eval_shape(raw_fn, [p.data()._data for p in params.values()],
+                   [x._data], jax.random.PRNGKey(0))
+    assert _bn_low_count() == before + count
 
 
 def test_memory_summary_attributes_params():

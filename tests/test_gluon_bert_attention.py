@@ -381,3 +381,70 @@ def test_mosaic_compiles_the_split_head_kernels(one_chip, monkeypatch, bh,
     text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
                           x, x, x, key)
     assert text.count("tpu_custom_call") == calls
+
+
+def test_v5e_resnet_stem_is_recomputed_and_no_activation_is_float32(one_chip):
+    """Stem and first bottleneck of ``resnet50_v1(layout="NHWC")`` under bf16
+    AMP at batch 32, forward and backward as a step stages them (here and
+    not in a file of its own: a test worker loads the TPU's compiler once,
+    so every compile for the described chip shares ``one_chip``'s file).
+    ``BatchNorm`` hands on the convolution's
+    bf16, so the entry computation writes no float32 array larger than the
+    input batch; and the stem is one rematerialised segment
+    (``HybridSequential.recompute``), so no array of the stem's
+    (32, 112, 112, 64) that the forward pass writes is read by the backward
+    pass: it writes its own.  At the parent the 16 float32 block outputs
+    fail the first assert and the stem convolution's output the second."""
+    from mxnet_tpu.gluon import block as gblock
+    from mxnet_tpu.gluon.model_zoo import vision
+
+    bsz, stem = 32, "bf16[32,112,112,64]"
+    net = vision.ResNetV1(vision.BottleneckV1, [1], [64, 256], classes=10,
+                          layout="NHWC", input_layout="NHWC")
+    net.initialize()
+    x = nd.zeros((1, 224, 224, 3))
+    net(x)                                  # resolves the deferred shapes
+    params = net.collect_params()
+    raw_fn, _, _ = gblock._stage_fn(net, params, list(params),
+                                    gblock._flatten_args((x,))[1], True,
+                                    x.ctx)
+
+    def loss(weights, x, key):
+        (out,), _ = raw_fn(weights, [x], key)
+        return out.astype(jnp.float32).sum()
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    low0 = telemetry.snapshot()["amp.batch_norm.low_precision"]
+    amp.init("bfloat16")
+    try:
+        text = _compiled_text(
+            jax.value_and_grad(loss),
+            [shape(p.shape) for p in params.values()],
+            shape((bsz, 224, 224, 3)), shape((2,), jnp.uint32))
+    finally:
+        amp.uninit()
+    # stem, three in the body, one on the shortcut
+    assert telemetry.snapshot()["amp.batch_norm.low_precision"] == low0 + 5
+    entry = re.findall(
+        r"^\s+(?:ROOT )?(%\S+) = (.*?) [a-z][a-z0-9-]*\((.*)$",
+        text[text.index("\nENTRY "):], re.M)
+    wide = [dims for _, out, _ in entry
+            for dims in re.findall(r"f32\[([\d,]+)\]", out)
+            if math.prod(map(int, dims.split(","))) > bsz * 224 * 224 * 3]
+    assert not wide, wide
+    # the entry computation is in schedule order: follow every stem-shaped
+    # array a forward instruction writes, through XLA's own copies, slices
+    # and concatenations (they carry no op_name), and see that no backward
+    # instruction reads one
+    forward_born, readers = set(), []
+    for name, out, rest in entry:
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        operands = set(re.findall(r"%[\w.\-]+", rest.split(", metadata")[0]))
+        if op_name and "transpose(" in op_name.group(1):
+            readers += [(name, o) for o in operands & forward_born]
+        elif stem in out if op_name else operands & forward_born:
+            forward_born.add(name)
+    assert forward_born                      # the forward pass did write it
+    assert not readers, readers

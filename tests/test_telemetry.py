@@ -110,6 +110,7 @@ def test_canonical_counters_registered():
         "attention.fused",
         "attention.unfused",
         "loss.sparse_ce.fused",
+        "amp.batch_norm.low_precision",
         "fused.trace",
         "fused.dispatch",
         "nn.pad_channels",
@@ -118,6 +119,7 @@ def test_canonical_counters_registered():
         "telemetry.spans",
     ]
     # the ops/nn + models + optimizer modules declare at import
+    from mxnet_tpu import amp  # noqa: F401
     from mxnet_tpu.contrib import quantization  # noqa: F401
     from mxnet_tpu.models import transformer_lm  # noqa: F401
     from mxnet_tpu.ops import nn as _nn  # noqa: F401

@@ -22,7 +22,7 @@ from . import lists
 from .loss_scaler import LossScaler
 
 __all__ = ["init", "uninit", "init_trainer", "scale_loss", "unscale",
-           "convert_hybrid_block", "LossScaler", "lists"]
+           "convert_hybrid_block", "LossScaler", "lists", "target_dtype"]
 
 _LOW = frozenset(lists.LOW_PRECISION_FUNCS)
 _F32 = frozenset(lists.FP32_FUNCS)
@@ -89,6 +89,13 @@ def init(target_dtype="bfloat16"):
 
     _ndmod._amp_policy = _policy
     _ndmod._amp_generation += 1
+
+
+def target_dtype():
+    """The low-precision type of the active policy, or None without one:
+    what a block casts to where it, not an operator, decides the type (a
+    residual stream that follows the activations' type)."""
+    return _STATE.target_dtype
 
 
 def uninit():

@@ -115,6 +115,14 @@ FP16_FP32_FUNCS = [
     # placement): its statistics and its multiply-add are float32 inside
     # the operator, so a cast in front only widens what reaches HBM
     "BatchNorm", "SyncBatchNorm", "BatchNormWithReLU",
+    # the RMS norms likewise: the type that arrives, float32 inside
+    "RMSNorm", "GatedRMSNorm",
+    # state-space and sparse-expert operators state their own precision:
+    # float32 decay sums and carried state in the scan, a float32 router at
+    # the highest precision in the expert layer, float32 softmax statistics
+    # in the attention core; their products take the activations' type
+    # (weights are cast to it inside), so the policy leaves the inputs alone
+    "ssd_scan", "causal_conv1d", "causal_gqa_selfatt", "held_experts",
     # activations / simple elementwise
     "Activation", "LeakyReLU", "relu", "sigmoid", "tanh", "softsign",
     "hard_sigmoid", "abs", "sign", "negative", "ceil", "floor", "rint",

@@ -646,6 +646,15 @@ class TrainStep:
         # eager fused path groups and checks finiteness in
         trainable = [p for p in tr._params if p.grad_req != "null"]
         indices = [tr._param2idx[id(p)] for p in trainable]
+        # gradients live only inside the program: a Parameter's grad
+        # buffer (single-device zeros on the first device) would be a
+        # second model there that nothing reads; the eager tape
+        # re-creates it on its next backward.  Released BEFORE the
+        # optimizer state is created, or weights, gradients and state are
+        # all alive at once and that moment is the process's peak
+        for p in trainable:
+            if p._grad is not None:
+                p._release_grad()
         for p, idx in zip(trainable, indices):
             if idx not in updater.states:
                 updater.states[idx] = opt.create_state_multi_precision(
@@ -734,14 +743,6 @@ class TrainStep:
             _place_state(s, p.data().shape,
                          _sharding_of(p.data().shape,
                                       name_of.get(id(p))))
-
-        # gradients live only inside the program: a Parameter's grad
-        # buffer (single-device zeros on the first device) would be a
-        # second model there that nothing reads; the eager tape
-        # re-creates it on its next backward
-        for p in trainable:
-            if p._grad is not None:
-                p._release_grad()
 
         if mesh is not None:
             # per-device memory accounting (gauges

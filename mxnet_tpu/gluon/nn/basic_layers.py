@@ -24,6 +24,7 @@ __all__ = [
     "SyncBatchNorm",
     "InstanceNorm",
     "LayerNorm",
+    "RMSNorm",
     "GroupNorm",
     "Embedding",
     "Flatten",
@@ -383,6 +384,29 @@ class LayerNorm(HybridBlock):
             [x, self.gamma.data(x.ctx), self.beta.data(x.ctx)],
             {"axis": self._axis, "eps": self._epsilon},
         )
+
+
+class RMSNorm(HybridBlock):
+    """``x / sqrt(mean(x^2) + epsilon) * gamma`` over the last axis (Zhang
+    and Sennrich 2019): no mean is subtracted and there is no shift.  Takes
+    and returns the type that arrives, float32 inside (op ``RMSNorm``)."""
+
+    def __init__(self, epsilon=1e-5, gamma_initializer="ones",
+                 in_channels=0):
+        super().__init__()
+        from ... import initializer as init
+
+        self._epsilon = epsilon
+        self.gamma = Parameter("gamma", shape=(in_channels,),
+                               init=init.create(gamma_initializer),
+                               allow_deferred_init=True)
+
+    def infer_shape(self, x):
+        self.gamma.shape = (int(x.shape[-1]),)
+
+    def forward(self, x):
+        return invoke("RMSNorm", [x, self.gamma.data(x.ctx)],
+                      {"eps": self._epsilon})
 
 
 class GroupNorm(HybridBlock):

@@ -145,6 +145,72 @@ def interleaved_selfatt(queries_keys_values, key=None, heads=1, p=0.0,
         dropout_key=key), bsz)
 
 
+def _unfused_causal_gqa(q, k, v, heads, kv_heads):
+    """Grouped causal attention as two products around a float32 softmax
+    under an explicit lower-triangular mask."""
+    bsz, seq, width = q.shape
+    d, group = width // heads, heads // kv_heads
+    q5 = q.reshape(bsz, seq, kv_heads, group, d)
+    k4, v4 = (t.reshape(bsz, seq, kv_heads, d) for t in (k, v))
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", q5, k4,
+                        preferred_element_type=jnp.float32) / (d ** 0.5)
+    visible = jnp.arange(seq)[:, None] >= jnp.arange(seq)[None, :]
+    att = jax.nn.softmax(jnp.where(visible, scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", att.astype(v.dtype), v4,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(bsz, seq, width).astype(q.dtype)
+
+
+@register("causal_gqa_selfatt", num_inputs=3)
+def causal_gqa_selfatt(queries, keys, values, heads=1, kv_heads=1):
+    """Causal self-attention core with grouped key-value heads:
+    ``queries`` (batch, seq, heads * head_dim), ``keys`` and ``values``
+    (batch, seq, kv_heads * head_dim), as the three projections give them;
+    query head ``h`` reads key-value head ``h // (heads // kv_heads)``.
+    ``softmax(q k^T / sqrt(head_dim) + causal mask) v``, shaped as
+    ``queries``.  On a TPU with no mesh the Pallas kernels read the
+    projections in place and write no copy of a key or a value
+    (``pallas_kernels.flash_attention_gqa``); anywhere else the unfused
+    expression.  Counted and refused as ``interleaved_selfatt`` is."""
+    from ..parallel.mesh import current_mesh
+    from . import pallas_kernels as _pk
+
+    seq, head_dim = queries.shape[1], queries.shape[2] // heads
+    if _attention_platform() != "tpu":
+        _ATTN_UNFUSED.inc()
+        return _unfused_causal_gqa(queries, keys, values, heads, kv_heads)
+    mesh = current_mesh()
+    refusal = None
+    if mesh is not None and mesh.size > 1:
+        refusal = f"mesh of {mesh.size} devices"
+    elif head_dim % 128 or _pk.gqa_block(seq) is None:
+        refusal = ("head_dim must be whole 128-lane columns and seq a "
+                   "multiple of 16")
+    if refusal is not None:
+        _ATTN_UNFUSED.inc()
+        _telemetry.event("fallback", "attention.fused", seq=seq,
+                         head_dim=head_dim, why=refusal)
+        return _unfused_causal_gqa(queries, keys, values, heads, kv_heads)
+    _ATTN_FUSED.inc()
+    return _pk.flash_attention_gqa(queries, keys, values, heads, kv_heads)
+
+
+@register("held_experts", num_inputs=5, num_outputs=2)
+def held_experts(data, router_weight, select_bias, up_weight, down_weight,
+                 held=(), k=1, scaling=1.0):
+    """One expert-parallel rank's part of a sparse-expert layer
+    (``parallel.moe.held_experts_layer``): sigmoid scores over ALL the
+    experts, the ``k`` largest of score + bias, the rows of the ``held``
+    experts sorted into a static buffer, two grouped products, a weighted
+    scatter back.  Outputs: the routed result, shaped and typed as
+    ``data``, and the call's float32 counts ``moe.HELD_STATS``."""
+    from ..parallel.moe import held_experts_layer
+
+    return held_experts_layer(
+        data, router_weight, select_bias, up_weight, down_weight,
+        held=tuple(held), k=int(k), scaling=float(scaling))
+
+
 @register("interleaved_matmul_encdec_qk", num_inputs=2)
 def interleaved_matmul_encdec_qk(queries, keys_values, heads=1):
     seq_q, bsz, embed = queries.shape

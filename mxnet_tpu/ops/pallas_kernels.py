@@ -38,8 +38,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .random import _keep, _seed_words, keep_mask
 
-__all__ = ["flash_attention", "flash_attention_qkv", "qkv_heads_per_step",
-           "dropout_keep_mask"]
+__all__ = ["flash_attention", "flash_attention_qkv", "flash_attention_gqa",
+           "qkv_heads_per_step", "gqa_block", "dropout_keep_mask",
+           "grouped_matmul", "GROUP_TILE"]
 
 _NEG_INF = -1e30
 
@@ -479,6 +480,232 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, dropout_p=0.0,
     return out.reshape(orig_shape)
 
 
+# -- causal attention with grouped key-value heads, in place -----------------
+#
+# The projections as they lie: q and the output (batch, seq, heads*head_dim),
+# k and v (batch, seq, kv_heads*head_dim); query head h reads key-value head
+# h // (heads // kv_heads) through the index map, so no copy of a key or a
+# value is written.  A block is (block, head_dim) columns of one batch row:
+# head_dim must be whole 128-lane columns.  The key blocks are a grid
+# dimension (accumulators in VMEM scratch): nothing of a whole sequence is
+# resident, so the length is bounded by HBM alone.  Blocks above the diagonal
+# are skipped, and their index maps repeat the block before, so nothing is
+# fetched for them.  The backward is two kernels: dq over (query head, query
+# block) with the key blocks inside, dk and dv over (key-value head, key
+# block) with the group's query heads and their query blocks inside.
+
+
+def _gqa_visible(qi, ki):
+    """Does key block ``ki`` hold a key that query block ``qi`` sees?
+    (Query and key blocks have one size.)"""
+    return ki <= qi
+
+
+def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
+                    sm_scale, block):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _():
+        acc[...] = jnp.zeros(acc.shape, acc.dtype)
+        m[...] = jnp.full(m.shape, _NEG_INF, m.dtype)
+        l[...] = jnp.zeros(l.shape, l.dtype)
+
+    @pl.when(_gqa_visible(qi, ki))
+    def _():
+        s = _scores(q_ref[...], k_ref[...], sm_scale)
+        visible, _ = _masks(None, 0, qi * block, ki * block, block, block, True,
+                            0.0)
+        s = jnp.where(visible, s, _NEG_INF)
+        m_prev = m[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l[...] = l[...] * alpha + p.sum(-1, keepdims=True)
+        acc[...] = acc[...] * alpha + _dot_nn(p.astype(v_ref.dtype),
+                                              v_ref[...])
+        m[...] = m_new
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _():
+        o_ref[...] = (acc[...] / l[...]).astype(o_ref.dtype)
+        lse_ref[...] = m[...] + jnp.log(l[...])
+
+
+def _gqa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, acc, *, sm_scale, block):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _():
+        acc[...] = jnp.zeros(acc.shape, acc.dtype)
+
+    @pl.when(_gqa_visible(qi, ki))
+    def _():
+        _, ds = _bwd_tile(None, 0, qi * block, ki * block, q_ref[...], k_ref[...],
+                          v_ref[...], do_ref[...], lse_ref[...],
+                          delta_ref[...], sm_scale=sm_scale, causal=True,
+                          dropout_p=0.0)
+        acc[...] += _dot_nn(ds, k_ref[...])
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _():
+        dq_ref[...] = acc[...].astype(dq_ref.dtype)
+
+
+def _gqa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                        dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, block,
+                        nq):
+    ki, t = pl.program_id(2), pl.program_id(3)
+    qi = t % nq
+
+    @pl.when(t == 0)
+    def _():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, dk_acc.dtype)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, dv_acc.dtype)
+
+    @pl.when(_gqa_visible(qi, ki))
+    def _():
+        pd, ds = _bwd_tile(None, 0, qi * block, ki * block, q_ref[...], k_ref[...],
+                           v_ref[...], do_ref[...], lse_ref[...],
+                           delta_ref[...], sm_scale=sm_scale, causal=True,
+                           dropout_p=0.0)
+        dk_acc[...] += _dot_tn(ds, q_ref[...])
+        dv_acc[...] += _dot_tn(pd, do_ref[...])
+
+    @pl.when(t == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _gqa_call(kernel, grid, in_specs, out_specs, out_shape, scratch, *args):
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        interpret=_interpret())(*args)
+
+
+def gqa_block(seq):
+    """Rows a block of the grouped causal kernels, or None when they cannot
+    take the length (Mosaic tiles bf16 rows in sixteens)."""
+    block = _divisor(seq, _BLOCK, multiple=16)
+    return block if block % 16 == 0 else None
+
+
+def _gqa_specs(heads, kv_heads, d, block):
+    """Block specs over the grid (batch, head, query block, key block):
+    ``(q-like, k-like, lse-like)``; a key block above the diagonal maps to
+    the diagonal's."""
+    group = heads // kv_heads
+    q_spec = pl.BlockSpec((None, block, d), lambda b, h, i, j: (b, i, h))
+    k_spec = pl.BlockSpec(
+        (None, block, d),
+        lambda b, h, i, j: (b, jnp.minimum(j, i), h // group))
+    stat_spec = pl.BlockSpec((None, None, block, 1),
+                             lambda b, h, i, j: (b, h, i, 0))
+    return q_spec, k_spec, stat_spec
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _gqa_forward(q, k, v, heads, kv_heads, sm_scale, block):
+    bsz, s, width = q.shape
+    d = width // heads
+    q_spec, k_spec, stat_spec = _gqa_specs(heads, kv_heads, d, block)
+    return _gqa_call(
+        functools.partial(_gqa_fwd_kernel, sm_scale=sm_scale, block=block),
+        (bsz, heads, s // block, s // block), [q_spec, k_spec, k_spec],
+        [q_spec, stat_spec],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((bsz, heads, s, 1), jnp.float32)],
+        [pltpu.VMEM((block, d), jnp.float32),
+         pltpu.VMEM((block, 1), jnp.float32),
+         pltpu.VMEM((block, 1), jnp.float32)], q, k, v)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _gqa_backward(q, k, v, o, lse, do, heads, kv_heads, sm_scale, block):
+    bsz, s, width = q.shape
+    d, group, nq = width // heads, heads // kv_heads, s // block
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(bsz, s, heads, d), axis=-1) \
+        .transpose(0, 2, 1)[..., None]                   # (bsz, heads, s, 1)
+    q_spec, k_spec, stat_spec = _gqa_specs(heads, kv_heads, d, block)
+    kw = dict(sm_scale=sm_scale, block=block)
+    dq = _gqa_call(
+        functools.partial(_gqa_bwd_dq_kernel, **kw),
+        (bsz, heads, nq, nq),
+        [q_spec, k_spec, k_spec, q_spec, stat_spec, stat_spec], q_spec,
+        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pltpu.VMEM((block, d), jnp.float32)], q, k, v, do, lse, delta)
+
+    # grid (batch, kv head, key block, group's heads x query blocks): a
+    # query block before the key block maps to the first one that sees it
+    def head(h, t):
+        return h * group + t // nq
+
+    def first_query(j, t):
+        return jnp.maximum(t % nq, j)
+
+    qd_spec = pl.BlockSpec(
+        (None, block, d),
+        lambda b, h, j, t: (b, first_query(j, t), head(h, t)))
+    kd_spec = pl.BlockSpec((None, block, d), lambda b, h, j, t: (b, j, h))
+    sd_spec = pl.BlockSpec(
+        (None, None, block, 1),
+        lambda b, h, j, t: (b, head(h, t), first_query(j, t), 0))
+    dk, dv = _gqa_call(
+        functools.partial(_gqa_bwd_dkv_kernel, nq=nq, **kw),
+        (bsz, kv_heads, nq, group * nq),
+        [qd_spec, kd_spec, kd_spec, qd_spec, sd_spec, sd_spec],
+        [kd_spec, kd_spec],
+        [jax.ShapeDtypeStruct(k.shape, k.dtype)] * 2,
+        [pltpu.VMEM((block, d), jnp.float32)] * 2, q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_gqa(q, k, v, heads, kv_heads, sm_scale, block):
+    return _gqa_forward(q, k, v, heads, kv_heads, sm_scale, block)[0]
+
+
+def _flash_gqa_fwd(q, k, v, heads, kv_heads, sm_scale, block):
+    out, lse = _gqa_forward(q, k, v, heads, kv_heads, sm_scale, block)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_gqa_bwd(heads, kv_heads, sm_scale, block, res, do):
+    return _gqa_backward(*res, do, heads, kv_heads, sm_scale, block)
+
+
+_flash_gqa.defvjp(_flash_gqa_fwd, _flash_gqa_bwd)
+
+
+def flash_attention_gqa(q, k, v, num_heads, num_kv_heads, sm_scale=None):
+    """Causal self-attention straight from grouped projections: ``q``
+    (batch, seq, num_heads * head_dim), ``k`` and ``v`` (batch, seq,
+    num_kv_heads * head_dim); query head ``h`` reads key-value head
+    ``h // (num_heads // num_kv_heads)``.  The result has ``q``'s shape.
+    Nothing is copied around the kernels, forward or backward, and the
+    backward keeps ``q``, ``k``, ``v``, the output and one float32
+    statistic a query.  Only for lengths :func:`gqa_block` accepts."""
+    bsz, s, width = q.shape
+    if num_heads % num_kv_heads or width % num_heads:
+        raise ValueError(f"flash_attention_gqa: {num_heads} query heads "
+                         f"over {num_kv_heads} key-value heads, width "
+                         f"{width}")
+    block = gqa_block(s)
+    if block is None:
+        raise ValueError(f"flash_attention_gqa cannot take seq {s}")
+    d = width // num_heads
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    return _flash_gqa(q, k, v, num_heads, num_kv_heads, float(sm_scale),
+                      block)
+
+
 # -- self-attention over the interleaved projection, in place ---------------
 #
 # The kernels take the projection batch-major, (batch, seq, heads*3*head_dim):
@@ -581,3 +808,134 @@ def flash_attention_qkv(qkv, num_heads, dropout_p=0.0, dropout_key=None):
     out = _flash_qkv(qkv.transpose(1, 0, 2), seed, d, heads,
                      1.0 / math.sqrt(d), dropout_p)
     return out.transpose(1, 0, 2)
+
+
+# -- grouped matrix product: rows sorted by group, a weight matrix a group ----
+#
+# ``x`` (rows, K) holds whole TILES of ``GROUP_TILE`` rows, each tile's rows
+# of ONE group (``tile_group[t]``, ascending; rows a group does not fill are
+# zeros), and ``w`` (groups, K, N) one matrix a group: ``out[tile t] =
+# x[tile t] @ w[tile_group[t]]``.  The tile's group reaches the index maps as
+# a scalar prefetch, so a tile fetches its group's matrix and nothing is
+# gathered or copied.  Tiles at or behind ``tiles_used`` are skipped (zeros
+# out).  The backward is the same kernel on the transposed matrices for the
+# rows' gradient and one kernel for the matrices': a group's tiles are
+# consecutive, so its (K, N) gradient is accumulated in VMEM over them and
+# written once.  Every group owns at least one tile (the caller's layout), so
+# every gradient block is written.  For a sparse-expert layer's held experts
+# (``parallel/moe.py``): XLA's own ragged product ran the same work 3 to 5
+# times slower on the v5e and carries no scope (PERF.md, PR 31).
+
+GROUP_TILE = 256
+_GROUP_K = 384          # the contracted extent a grid step (21 x 128 = 2688)
+
+
+def _group_k(k):
+    return _divisor(k, _GROUP_K, multiple=128)
+
+
+def _gmm_kernel(group_ref, used_ref, x_ref, w_ref, o_ref, acc):
+    i, kk = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(kk == 0)
+    def _():
+        acc[...] = jnp.zeros(acc.shape, acc.dtype)
+
+    @pl.when(i < used_ref[0])
+    def _():
+        acc[...] += _dot_nn(x_ref[...], w_ref[...])
+
+    @pl.when(kk == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = acc[...].astype(o_ref.dtype)
+
+
+def _tgmm_kernel(group_ref, used_ref, x_ref, dy_ref, dw_ref, acc):
+    i = pl.program_id(1)
+    last = pl.num_programs(1) - 1
+    group = group_ref[i]
+
+    @pl.when((i == 0) | (group != group_ref[jnp.maximum(i - 1, 0)]))
+    def _():
+        acc[...] = jnp.zeros(acc.shape, acc.dtype)
+
+    @pl.when(i < used_ref[0])
+    def _():
+        acc[...] += _dot_tn(x_ref[...], dy_ref[...])
+
+    @pl.when((i == last) | (group != group_ref[jnp.minimum(i + 1, last)]))
+    def _():
+        dw_ref[...] = acc[...].astype(dw_ref.dtype)
+
+
+def _grouped_call(kernel, grid, in_specs, out_spec, out_shape, scratch,
+                  semantics, *args):
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_spec, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        interpret=_interpret())(*args)
+
+
+@jax.jit
+def _gmm(x, w, tile_group, tiles_used):
+    rows, k = x.shape
+    n, tk = w.shape[2], _group_k(k)
+    return _grouped_call(
+        _gmm_kernel, (rows // GROUP_TILE, k // tk),
+        [pl.BlockSpec((GROUP_TILE, tk), lambda i, kk, g, u: (i, kk)),
+         pl.BlockSpec((None, tk, n), lambda i, kk, g, u: (g[i], kk, 0))],
+        pl.BlockSpec((GROUP_TILE, n), lambda i, kk, g, u: (i, 0)),
+        jax.ShapeDtypeStruct((rows, n), x.dtype),
+        [pltpu.VMEM((GROUP_TILE, n), jnp.float32)],
+        ("parallel", "arbitrary"), tile_group, tiles_used, x, w)
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _tgmm(x, dy, tile_group, tiles_used, groups):
+    rows, k = x.shape
+    n, tk = dy.shape[1], _group_k(k)
+    return _grouped_call(
+        _tgmm_kernel, (k // tk, rows // GROUP_TILE),
+        [pl.BlockSpec((GROUP_TILE, tk), lambda kk, i, g, u: (i, kk)),
+         pl.BlockSpec((GROUP_TILE, n), lambda kk, i, g, u: (i, 0))],
+        pl.BlockSpec((None, tk, n), lambda kk, i, g, u: (g[i], kk, 0)),
+        jax.ShapeDtypeStruct((groups, k, n), x.dtype),
+        [pltpu.VMEM((tk, n), jnp.float32)],
+        ("parallel", "arbitrary"), tile_group, tiles_used, x, dy)
+
+
+@jax.custom_vjp
+def _grouped(x, w, tile_group, tiles_used):
+    return _gmm(x, w, tile_group, tiles_used)
+
+
+def _grouped_fwd(x, w, tile_group, tiles_used):
+    return _gmm(x, w, tile_group, tiles_used), (x, w, tile_group, tiles_used)
+
+
+def _grouped_bwd(res, dy):
+    x, w, tile_group, tiles_used = res
+    dx = _gmm(dy, w.transpose(0, 2, 1), tile_group, tiles_used)
+    dw = _tgmm(x, dy, tile_group, tiles_used, w.shape[0])
+    zero = onp.zeros(tile_group.shape, jax.dtypes.float0)
+    return dx, dw, zero, onp.zeros(tiles_used.shape, jax.dtypes.float0)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def grouped_matmul(x, w, tile_group, tiles_used):
+    """``out[tile t] = x[tile t] @ w[tile_group[t]]`` over tiles of
+    ``GROUP_TILE`` rows (above).  ``x`` (tiles * GROUP_TILE, K), ``w``
+    (groups, K, N) with K and N multiples of 128, ``tile_group`` (tiles,)
+    int32 ascending and naming every group at least once, ``tiles_used``
+    (1,) int32.  Differentiable in ``x`` and ``w``."""
+    rows, k = x.shape
+    if rows % GROUP_TILE or k % 128 or w.shape[2] % 128 or w.shape[1] != k:
+        raise ValueError(f"grouped_matmul cannot take x {x.shape}, "
+                         f"w {w.shape}")
+    return _grouped(x, w, tile_group, tiles_used)

@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["top_k_gating", "moe_layer", "aux_scope", "record_aux",
-           "MoEBlock"]
+           "MoEBlock", "held_experts_layer", "held_buffer_rows",
+           "HELD_STATS"]
 
 
 # ---------------------------------------------------------------------------
@@ -137,6 +138,154 @@ def moe_layer(x, gate_w, w_in, w_out, *, k: int = 2,
         jnp.einsum("egch,ehm->egcm", h, w_out), ep_spec)
     out = jnp.einsum("gsec,egcm->gsm", combine, expert_out)
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# the second expert path: sorted, without drops, told which experts it holds
+# ---------------------------------------------------------------------------
+# One expert-parallel rank's part of a sparse-expert layer.  The router
+# scores ALL the experts (sigmoid scores, a selection bias, the k largest of
+# score + bias, weights score / sum of the chosen scores times a scaling
+# factor: the DeepSeek-V3 router that Nemotron-H takes), the assignments are
+# sorted by expert (a counting sort: rank inside the expert plus the
+# expert's offset), the rows of the HELD experts alone are gathered into a
+# buffer of static size, two grouped products run over it (on a TPU the
+# Pallas kernel ``pallas_kernels.grouped_matmul``, elsewhere XLA's
+# ``lax.ragged_dot``; group sizes stay on the device), and the result is
+# scattered back weighted.  What the absent experts would add is
+# left out: the exchange that brings it belongs to a mesh with an ``ep``
+# axis, and this layer adds nothing that stands in for it.  No row routed to
+# a held expert is dropped while the buffer holds; rows beyond it are
+# COUNTED, never silently lost (``HELD_STATS``).
+
+HELD_STATS = ("rows_routed", "rows_held", "rows_overflow", "load_max",
+              "steps")
+
+
+def held_buffer_rows(tokens: int, k: int, num_experts: int, num_held: int,
+                     capacity_factor: float) -> int:
+    """Rows of the held experts' buffer: ``capacity_factor`` times the mean
+    share ``tokens * k * num_held / num_experts``, up to the next 256, and
+    never more than every assignment (up to the next 256)."""
+    mean = tokens * k * num_held / num_experts
+    return -(-max(1, min(int(capacity_factor * mean), tokens * k)) // 256) \
+        * 256
+
+
+def _grouped_platform() -> str:
+    return jax.default_backend()
+
+
+def _kernel_products(width: int) -> bool:
+    """Do the grouped products take the Pallas kernel
+    (``pallas_kernels.grouped_matmul``: a TPU, no mesh of more than one
+    device, rows of whole 128-lane columns) or XLA's ``ragged_dot``?
+    Decided from what the trace can observe, as the attention core is."""
+    from .mesh import current_mesh
+
+    mesh = current_mesh()
+    return (_grouped_platform() == "tpu" and width % 128 == 0
+            and (mesh is None or mesh.size <= 1))
+
+
+def held_experts_layer(x, router_w, select_bias, w_up, w_down, *, held,
+                       k: int, scaling: float = 1.0,
+                       capacity_factor: float = 2.0):
+    """The held experts' part of the routed result.
+
+    ``x`` (..., M); ``router_w`` (E, M) float32; ``select_bias`` (E,);
+    ``w_up`` (H, M, F) and ``w_down`` (H, F, M), the weights of the H
+    experts ``held`` (global ids, ascending) of the E the router scores.
+    The router's product is float32 at the highest precision whatever ``x``
+    is (a rounding there flips a choice); the experts' products take ``x``'s
+    type; an expert is ``w_down relu(w_up x)^2``.  The buffer holds
+    ``capacity_factor`` times the mean share (``held_buffer_rows``).
+    Returns ``(out, stats)``: ``out`` of ``x``'s shape and type, ``stats``
+    float32 ``HELD_STATS`` of this call (``load_max`` the busiest held
+    expert's rows, ``steps`` 1)."""
+    from ..ops import pallas_kernels as _pk
+
+    shape, dtype = x.shape, x.dtype
+    x = x.reshape(-1, shape[-1])
+    tokens, num_experts = x.shape[0], router_w.shape[0]
+    held = tuple(int(e) for e in held)
+    num_held = len(held)
+    if sorted(set(held)) != list(held) or not held \
+            or held[-1] >= num_experts or w_up.shape[0] != num_held:
+        raise ValueError(f"held={held}: ascending ids below {num_experts}, "
+                         f"one for each of w_up's {w_up.shape[0]} experts")
+    f32 = jnp.float32
+    kernel = _kernel_products(x.shape[1])
+    tile = _pk.GROUP_TILE
+    rows = held_buffer_rows(tokens, k, num_experts, num_held,
+                            capacity_factor)
+    if kernel:
+        # every expert's rows start on a tile and every expert owns one:
+        # at most a tile an expert is lost to the rounding
+        rows = -(-rows // tile) * tile + num_held * tile
+
+    with jax.named_scope("MoERouter"):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "tm,em->te", x.astype(f32), router_w.astype(f32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, chosen = jax.lax.top_k(scores + select_bias.astype(f32), k)
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scaling
+
+    with jax.named_scope("MoEDispatch"):
+        # a counting sort by expert: a row's place is its expert's start
+        # plus its rank among the expert's rows
+        slot_of = jnp.full((num_experts,), -1, jnp.int32).at[
+            jnp.asarray(held)].set(jnp.arange(num_held, dtype=jnp.int32))
+        slot = slot_of[chosen].reshape(-1)                 # (tokens * k,)
+        mine = slot[:, None] == jnp.arange(num_held)[None, :]
+        count = jnp.sum(mine, axis=0, dtype=jnp.int32)     # rows an expert
+        room = jnp.maximum(-(-count // tile), 1) * tile if kernel else count
+        start = jnp.cumsum(room) - room
+        rank = jnp.cumsum(mine, axis=0, dtype=jnp.int32) - 1
+        at = jnp.sum(jnp.where(mine, rank + start[None, :], 0), axis=1)
+        is_held = slot >= 0
+        at = jnp.where(is_held, at, rows)                  # dropped below
+        token = jnp.arange(tokens * k, dtype=jnp.int32) // k
+        source = jnp.zeros((rows,), jnp.int32).at[at].set(token, mode="drop")
+        row_w = jnp.zeros((rows,), f32).at[at].set(
+            weights.reshape(-1), mode="drop")
+        # a row nothing landed on: zeros in, zeros out, whatever a grouped
+        # product leaves there, forward and backward
+        filled = jnp.zeros((rows,), bool).at[at].set(True, mode="drop")[
+            :, None]
+        n_held = jnp.sum(count)
+        stats = jnp.stack([
+            jnp.asarray(tokens * k, f32), n_held.astype(f32),
+            jnp.sum(is_held & (at >= rows)).astype(f32),
+            jnp.max(count).astype(f32), jnp.asarray(1.0, f32)])
+        gathered = jnp.where(filled, x[source], 0)         # (rows, M)
+
+    with jax.named_scope("MoEExperts"):
+        if kernel:
+            tiles = rows // tile
+            tile_group = jnp.clip(jnp.searchsorted(
+                start, jnp.arange(tiles, dtype=jnp.int32) * tile,
+                side="right") - 1, 0, num_held - 1).astype(jnp.int32)
+            used = jnp.minimum((start[-1] + room[-1]) // tile,
+                               tiles).reshape(1).astype(jnp.int32)
+            pad = -w_up.shape[2] % 128        # the hidden width in lanes
+            up = jnp.pad(w_up.astype(dtype), ((0, 0), (0, 0), (0, pad)))
+            down = jnp.pad(w_down.astype(dtype), ((0, 0), (0, pad), (0, 0)))
+            h = jnp.square(jax.nn.relu(
+                _pk.grouped_matmul(gathered, up, tile_group, used)))
+            y = _pk.grouped_matmul(h.astype(dtype), down, tile_group, used)
+        else:
+            sizes = jnp.clip(rows - start, 0, count)       # what fits
+            h = jax.lax.ragged_dot(gathered, w_up.astype(dtype), sizes)
+            h = jnp.square(jax.nn.relu(jnp.where(filled, h, 0)))
+            y = jax.lax.ragged_dot(h.astype(dtype), w_down.astype(dtype),
+                                   sizes)
+
+    with jax.named_scope("MoECombine"):
+        y = jnp.where(filled, y.astype(f32) * row_w[:, None], 0.0)
+        out = jnp.zeros(x.shape, dtype).at[source].add(y.astype(dtype))
+    return out.reshape(shape), jax.lax.stop_gradient(stats)
 
 
 # ---------------------------------------------------------------------------

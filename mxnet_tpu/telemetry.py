@@ -536,8 +536,25 @@ def event(kind: str, name: str, /, step: Any = "auto", **fields) -> None:
         _EVENTS.append(ev)
 
 
+_EVENT_SOURCES: List[Callable[[], None]] = []
+
+
+def event_source(poll: Callable[[], None]) -> None:
+    """Register ``poll``, called at the start of every :func:`events`: for
+    happenings only the device knows (a count a step program accumulates),
+    which become events when somebody asks, not by a host read in the step.
+    ``poll`` emits what is new through :func:`event` and must not raise."""
+    with _EVT_LOCK:
+        if poll not in _EVENT_SOURCES:
+            _EVENT_SOURCES.append(poll)
+
+
 def events(kind: Optional[str] = None,
            name: Optional[str] = None) -> List[Dict[str, Any]]:
+    with _EVT_LOCK:
+        sources = list(_EVENT_SOURCES)
+    for poll in sources:
+        poll()
     with _EVT_LOCK:
         evs = list(_EVENTS)
     if kind is not None:

@@ -84,6 +84,18 @@ SPECS = {
     "interleaved_matmul_selfatt_qk": ([_f(6, 2, 24)], dict(heads=2)),
     "interleaved_selfatt": ([_f(8, 2, 96), onp.zeros(2, onp.uint32)],
                             dict(heads=2, p=0.5, training=True)),
+    "causal_gqa_selfatt": ([_f(2, 8, 32), _f(2, 8, 16), _f(2, 8, 16)],
+                           dict(heads=4, kv_heads=2)),
+    # --- state-space and sparse-expert layers (ops/ssm.py, parallel/moe.py)
+    "ssd_scan": ([_f(2, 12, 4, 8), _f(2, 12, 4), -_f(4), _f(2, 12, 2, 16),
+                  _f(2, 12, 2, 16), _f(4)], dict(chunk_size=8)),
+    "causal_conv1d": ([_f(2, 9, 6), _f(6, 4), _f(6)],
+                      dict(activation="silu")),
+    "RMSNorm": ([_f(4, 6), _f(6)], {}),
+    "GatedRMSNorm": ([_f(4, 8), _f(4, 8), _f(8)], dict(num_groups=2)),
+    "held_experts": ([_f(10, 16), _f(8, 16), _f(8) * 0.05, _f(4, 16, 12),
+                      _f(4, 12, 16)],
+                     dict(held=(0, 1, 2, 3), k=2, scaling=2.5)),
     "interleaved_matmul_selfatt_valatt": ([_f(6, 2, 24), _f(4, 6, 6)],
                                           dict(heads=2)),
     "interleaved_matmul_encdec_qk": ([_f(6, 2, 8), _f(5, 2, 16)],

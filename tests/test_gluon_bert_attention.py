@@ -448,3 +448,45 @@ def test_v5e_resnet_stem_is_recomputed_and_no_activation_is_float32(one_chip):
             forward_born.add(name)
     assert forward_born                      # the forward pass did write it
     assert not readers, readers
+
+
+def test_mosaic_compiles_the_grouped_causal_kernels(one_chip, monkeypatch):
+    """``flash_attention_gqa`` at the state-space cell's widths (32 query
+    heads over 2 key-value heads of 128, bf16) at a quarter of its 8,192
+    keys, forward and backward: three Mosaic calls, and the two key-value
+    heads go in as they are."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((1, 2048, 4096), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 2048, 256), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return pk.flash_attention_gqa(q, k, v, 32, 2) \
+            .astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_mosaic_compiles_the_grouped_products(one_chip, monkeypatch):
+    """``grouped_matmul`` at the held experts' widths (8 experts, 2688 to
+    the hidden width 1856 padded to 1920 lanes, a buffer of 32 tiles),
+    forward and backward: the product, its transpose for the rows and the
+    accumulating kernel for the matrices."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    rows = 32 * pk.GROUP_TILE
+    x = jax.ShapeDtypeStruct((rows, 2688), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, 2688, 1920), jnp.bfloat16,
+                             sharding=one_chip)
+    group = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    used = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+
+    def loss(x, w, group, used):
+        return pk.grouped_matmul(x, w, group, used).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1)), x, w, group, used)
+    assert text.count("tpu_custom_call") == 2          # dx and dw
+    both = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), x, w,
+                          group, used)
+    assert both.count("tpu_custom_call") == 3

@@ -77,7 +77,13 @@ def test_engine_cross_var_dependency():
     eng.push(lambda: result.append("read_a_write_b"), const_vars=[a],
              mutable_vars=[b])
     eng.push(lambda: result.append("read_b"), const_vars=[b])
+    # wait_for_var promises the WRITES on b before it; the read of b that
+    # was pushed after them may still be running (it failed so once in a
+    # loaded six-worker run, PR 31), so the whole order is read after
+    # wait_for_all
     eng.wait_for_var(b)
+    assert result[:2] == ["write_a", "read_a_write_b"]
+    eng.wait_for_all()
     assert result == ["write_a", "read_a_write_b", "read_b"]
     eng.close()
 

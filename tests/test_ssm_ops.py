@@ -1,0 +1,261 @@
+"""State-space operators (``ops/ssm.py``) and the causal grouped-head
+attention entry, on the CPU at small sizes: the chunked scan against the
+recurrence one step a token, the depthwise convolution against shifted
+multiplies, the attention kernels (Pallas interpreter) against an explicit
+mask."""
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu.ops import contrib, pallas_kernels as pk, ssm
+
+
+def _scan_inputs(length, dtype, seed=0, b=2, h=4, p=8, g=2, n=16):
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(k[0], (b, length, h, p)).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (b, length, h)) - 1.0)
+    A = -jnp.exp(0.5 * jax.random.normal(k[2], (h,)))
+    B = jax.random.normal(k[3], (b, length, g, n)).astype(dtype)
+    C = jax.random.normal(k[4], (b, length, g, n)).astype(dtype)
+    D = jax.random.normal(k[5], (h,))
+    return x, dt, A, B, C, D
+
+
+@pytest.mark.parametrize("length", [32, 37, 5, 8])
+def test_ssd_scan_equals_the_sequential_recurrence(length):
+    """Lengths that are and are not multiples of the chunk (8), one shorter
+    than a chunk; float32 agrees to rounding."""
+    args = _scan_inputs(length, jnp.float32)
+    got = ssm.ssd_scan(*args, chunk_size=8)
+    want = ssm.ssd_scan_sequential(*args)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(
+        jnp.max(jnp.abs(want))))
+
+
+@pytest.mark.parametrize("length", [32, 21])
+def test_ssd_scan_gradients_equal_the_recurrences(length):
+    args = _scan_inputs(length, jnp.float32, seed=1)
+
+    def through(fn):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
+                        argnums=tuple(range(6)))(*args)
+
+    got = through(lambda *a: ssm.ssd_scan(*a, chunk_size=8))
+    want = through(ssm.ssd_scan_sequential)
+    for g, w, name in zip(got, want, "x dt A B C D".split()):
+        np.testing.assert_allclose(
+            g, w, atol=3e-5 * float(jnp.max(jnp.abs(w))), err_msg=name)
+
+
+@pytest.mark.parametrize("length", [32, 13])
+def test_ssd_scan_in_bf16_keeps_float32_state(length):
+    """bf16 operands: the result is bf16 and within bf16 rounding of the
+    float32 recurrence ON THE ROUNDED operands; a bf16 carried state or
+    bf16 decay sums would be several times further off."""
+    args = _scan_inputs(length, jnp.bfloat16, seed=2)
+    got = ssm.ssd_scan(*args, chunk_size=8)
+    assert got.dtype == jnp.bfloat16
+    want = ssm.ssd_scan_sequential(*args)
+    scale = float(jnp.max(jnp.abs(want)))
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) / scale
+    assert err < 2 ** -6, err
+
+
+def test_ssd_scan_does_not_depend_on_the_chunk():
+    args = _scan_inputs(48, jnp.float32, seed=3)
+    a = ssm.ssd_scan(*args, chunk_size=8)
+    b = ssm.ssd_scan(*args, chunk_size=16)
+    np.testing.assert_allclose(a, b, atol=3e-5 * float(jnp.max(jnp.abs(a))))
+
+
+def test_ssd_scan_through_the_registry_and_the_tape():
+    x, dt, A, B, C, D = (mx.nd.array(np.asarray(t, np.float32))
+                         for t in _scan_inputs(16, jnp.float32, seed=4))
+    x.attach_grad()
+    with mx.autograd.record():
+        y = mx.nd.ssd_scan(x, dt, A, B, C, D, chunk_size=8)
+        loss = (y * y).sum()
+    loss.backward()
+    want = jax.grad(lambda x_: jnp.sum(jnp.square(ssm.ssd_scan_sequential(
+        x_, dt._data, A._data, B._data, C._data, D._data))))(x._data)
+    np.testing.assert_allclose(x.grad.asnumpy(), want, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("kernel,activation", [(4, "silu"), (4, None),
+                                               (3, "silu")])
+def test_causal_conv1d_equals_shifted_multiplies(kernel, activation):
+    rng = np.random.default_rng(kernel)
+    x = rng.standard_normal((2, 11, 6)).astype(np.float32)
+    w = rng.standard_normal((6, kernel)).astype(np.float32)
+    b = rng.standard_normal((6,)).astype(np.float32)
+    got = np.asarray(ssm.causal_conv1d(jnp.asarray(x), jnp.asarray(w),
+                                       jnp.asarray(b), activation=activation))
+    padded = np.pad(x, ((0, 0), (kernel - 1, 0), (0, 0)))
+    want = b + sum(padded[:, k:k + 11] * w[:, k] for k in range(kernel))
+    if activation == "silu":
+        want = want / (1.0 + np.exp(-want))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # and against XLA's grouped convolution, one group a channel
+    conv = jax.lax.conv_general_dilated(
+        jnp.asarray(x), jnp.asarray(w).T[:, None, :], (1,),
+        [(kernel - 1, 0)], dimension_numbers=("NWC", "WIO", "NWC"),
+        feature_group_count=6, precision="highest") + b
+    if activation == "silu":
+        conv = conv * jax.nn.sigmoid(conv)
+    np.testing.assert_allclose(got, conv, rtol=1e-5, atol=1e-5)
+    # causal: the output at t ignores everything after t
+    x2 = x.copy()
+    x2[:, 7:] += 1.0
+    got2 = np.asarray(ssm.causal_conv1d(jnp.asarray(x2), jnp.asarray(w),
+                                        jnp.asarray(b),
+                                        activation=activation))
+    np.testing.assert_array_equal(got[:, :7], got2[:, :7])
+
+
+def test_causal_conv1d_gradients_and_bf16():
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((1, 9, 4)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((4, 4)), jnp.float32)
+    b = jnp.asarray(rng.standard_normal((4,)), jnp.float32)
+
+    def shifted(x, w, b):
+        padded = jnp.pad(x, ((0, 0), (3, 0), (0, 0)))
+        out = b + sum(padded[:, k:k + 9] * w[:, k] for k in range(4))
+        return out * jax.nn.sigmoid(out)
+
+    for got, want in zip(
+            jax.grad(lambda *a: jnp.sum(jnp.sin(ssm.causal_conv1d(
+                *a, activation="silu"))), argnums=(0, 1, 2))(x, w, b),
+            jax.grad(lambda *a: jnp.sum(jnp.sin(shifted(*a))),
+                     argnums=(0, 1, 2))(x, w, b)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    low = ssm.causal_conv1d(x.astype(jnp.bfloat16), w, b, activation="silu")
+    assert low.dtype == jnp.bfloat16
+    np.testing.assert_allclose(low.astype(jnp.float32), shifted(x, w, b),
+                               atol=0.05)
+
+
+def test_rms_norms_follow_their_input_type():
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((2, 5, 16)), jnp.float32)
+    gate = jnp.asarray(rng.standard_normal((2, 5, 16)), jnp.float32)
+    gamma = jnp.asarray(1.0 + 0.1 * rng.standard_normal(16), jnp.float32)
+    want = x / np.sqrt(np.mean(np.square(x), -1, keepdims=True) + 1e-5) \
+        * gamma
+    np.testing.assert_allclose(ssm.rms_norm(x, gamma), want, rtol=1e-5)
+    gated = x * gate / (1.0 + np.exp(-gate))
+    grouped = np.asarray(gated).reshape(2, 5, 4, 4)
+    want = (grouped / np.sqrt(np.mean(np.square(grouped), -1, keepdims=True)
+                              + 1e-5)).reshape(2, 5, 16) * gamma
+    np.testing.assert_allclose(
+        ssm.gated_rms_norm(x, gate, gamma, num_groups=4), want, rtol=1e-5,
+        atol=1e-6)
+    for fn in (lambda t: ssm.rms_norm(t, gamma),
+               lambda t: ssm.gated_rms_norm(t, t, gamma, num_groups=4)):
+        assert fn(x.astype(jnp.bfloat16)).dtype == jnp.bfloat16
+
+
+def test_rms_norm_block_under_amp_returns_bf16():
+    norm = mx.gluon.nn.RMSNorm(in_channels=8)
+    norm.initialize()
+    x = mx.nd.array(np.random.default_rng(2).standard_normal((3, 8)))
+    mx.amp.init("bfloat16")
+    try:
+        assert norm(x.astype("bfloat16")).dtype == jnp.bfloat16
+        assert norm(x).dtype == np.float32
+    finally:
+        mx.amp.uninit()
+
+
+# -- causal attention with grouped key-value heads ---------------------------
+def _explicit_mask_attention(q, k, v, heads, kv_heads):
+    b, s, width = q.shape
+    d, group = width // heads, heads // kv_heads
+    q5 = q.reshape(b, s, kv_heads, group, d)
+    k4, v4 = k.reshape(b, s, kv_heads, d), v.reshape(b, s, kv_heads, d)
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", q5, k4,
+                        precision="highest") / math.sqrt(d)
+    mask = jnp.tril(jnp.ones((s, s), bool))
+    att = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", att, v4,
+                      precision="highest").reshape(b, s, width)
+
+
+def _qkv(b, s, heads, kv_heads, d, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (b, s, heads * d)),
+            jax.random.normal(ks[1], (b, s, kv_heads * d)),
+            jax.random.normal(ks[2], (b, s, kv_heads * d)),
+            jax.random.normal(ks[3], (b, s, heads * d)))
+
+
+@pytest.mark.parametrize("block", [16, 32, 64])
+def test_grouped_causal_kernels_equal_an_explicit_mask(block, monkeypatch):
+    """4 query heads a key-value head; several blocks a sequence, so the
+    skipped blocks above the diagonal and the clamped index maps are run."""
+    monkeypatch.setattr(pk, "_BLOCK", block)
+    heads, kv_heads = 8, 2
+    q, k, v, ct = _qkv(2, 64, heads, kv_heads, 16)
+    got = pk.flash_attention_gqa(q, k, v, heads, kv_heads)
+    want = _explicit_mask_attention(q, k, v, heads, kv_heads)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    grads = jax.grad(lambda *a: jnp.sum(pk.flash_attention_gqa(
+        *a, heads, kv_heads) * ct), argnums=(0, 1, 2))(q, k, v)
+    wants = jax.grad(lambda *a: jnp.sum(_explicit_mask_attention(
+        *a, heads, kv_heads) * ct), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(grads, wants):
+        np.testing.assert_allclose(g, w, atol=5e-5)
+
+
+def test_grouped_causal_kernels_write_no_copy_of_a_key():
+    """The kernel call itself takes k and v with their own two heads:
+    nothing of the query's width is made of them on the way in."""
+    q, k, v, _ = _qkv(1, 32, 8, 2, 16)
+
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from pallas_calls(sub)
+
+    calls = list(pallas_calls(jax.make_jaxpr(
+        lambda *a: pk.flash_attention_gqa(*a, 8, 2))(q, k, v).jaxpr))
+    assert len(calls) == 1
+    assert [tuple(var.aval.shape) for var in calls[0].invars] == \
+        [q.shape, k.shape, v.shape]
+
+
+def test_causal_gqa_selfatt_operator_both_paths(monkeypatch):
+    heads, kv_heads = 4, 2
+    q, k, v, _ = _qkv(2, 32, heads, kv_heads, 16, seed=5)
+    want = _explicit_mask_attention(q, k, v, heads, kv_heads)
+    base = mx.telemetry.snapshot()
+    args = [mx.nd.array(np.asarray(t)) for t in (q, k, v)]
+    out = mx.nd.causal_gqa_selfatt(*args, heads=heads, kv_heads=kv_heads)
+    np.testing.assert_allclose(out.asnumpy(), want, atol=2e-5)
+    assert mx.telemetry.delta(base)["attention.unfused"] >= 1
+    # the kernel path, forced as the BERT tests force it
+    monkeypatch.setattr(contrib, "_attention_platform", lambda: "tpu")
+    seq_before = max((e["seq"] for e in mx.telemetry.events("fallback")),
+                     default=0)
+    # head_dim 16 is no whole 128-lane column: a TPU trace refuses it loudly
+    out = mx.nd.causal_gqa_selfatt(*args, heads=heads, kv_heads=kv_heads)
+    np.testing.assert_allclose(out.asnumpy(), want, atol=2e-5)
+    new = [e for e in mx.telemetry.events("fallback")
+           if e["seq"] > seq_before]
+    assert new and new[-1]["name"] == "attention.fused"
+    # at 128 the kernels take it
+    q, k, v, _ = _qkv(1, 32, 2, 1, 128, seed=6)
+    base = mx.telemetry.snapshot()
+    out = mx.nd.causal_gqa_selfatt(
+        *[mx.nd.array(np.asarray(t)) for t in (q, k, v)], heads=2,
+        kv_heads=1)
+    np.testing.assert_allclose(
+        out.asnumpy(), _explicit_mask_attention(q, k, v, 2, 1), atol=2e-5)
+    assert mx.telemetry.delta(base)["attention.fused"] >= 1

@@ -14,7 +14,10 @@ Reference analog: the fused transformer attention matmuls
 ``interleaved_matmul_selfatt_qk/valatt``) — which still materialized the
 full score matrix; this is the TPU-first replacement, not a translation.
 Callers: ``ops/contrib.py interleaved_selfatt`` (the Gluon BERT attention
-core) and ``models/transformer_lm.py``.
+core) and ``models/transformer_lm.py``.  Beside it, for the state-space /
+sparse-expert decoder: the grouped causal kernels, the grouped matrix
+product of a rank's held experts, and the Mamba-2 chunked scan (each under
+its own heading below).
 
 On the CPU the kernels run under the Pallas interpreter (slow but exact) so
 the CPU test suite validates the same code path that runs on hardware; any
@@ -40,7 +43,7 @@ from .random import _keep, _seed_words, keep_mask
 
 __all__ = ["flash_attention", "flash_attention_qkv", "flash_attention_gqa",
            "qkv_heads_per_step", "gqa_block", "dropout_keep_mask",
-           "grouped_matmul", "GROUP_TILE"]
+           "grouped_matmul", "GROUP_TILE", "ssd_chunk_scan"]
 
 _NEG_INF = -1e30
 
@@ -939,3 +942,282 @@ def grouped_matmul(x, w, tile_group, tiles_used):
         raise ValueError(f"grouped_matmul cannot take x {x.shape}, "
                          f"w {w.shape}")
     return _grouped(x, w, tile_group, tiles_used)
+
+
+# -- the Mamba-2 scan: one chunk of one group a grid step --------------------
+#
+# ``ops/ssm.py`` states the recurrence and its chunked form; this is that
+# form with nothing of a chunk but its inputs and its output in HBM.  The
+# kernels take the sequence ALONG THE LANES: ``x`` as (batch, heads *
+# head_dim, length), ``B`` and ``C`` as (batch, groups * state, length).
+# That is how XLA lays a Mamba-2 mixer out on the v5e when left alone (the
+# projection's output, the depthwise convolution over the positions and the
+# grouped norm behind all run position-minor), so the transposes around the
+# call cost nothing, and everything a position owns (its decay, its time
+# step) is a ROW that broadcasts down the sublanes of a head's rows: no
+# lane broadcast, no lane reduction and no lane concatenation a head.
+#
+# The grid is (batch, group, chunk), the chunks in order.  A step reads the
+# group's rows of ``x`` and of ``B`` and ``C`` where they lie (the index
+# maps pick them), builds each head's (chunk, chunk) decay mask in VMEM,
+# multiplies and drops it, and carries the group's float32 state (heads *
+# head_dim, state) in scratch from one chunk to the next.  The per-chunk
+# cumulative sums of ``dt A`` come in from XLA, float32, as rows
+# (``rows``) and, for the mask's other index, as columns (``cols``): no
+# transpose of them runs in the kernel, and their ``exp`` does.
+#
+# The backward walks the chunks from the last to the first with the state's
+# gradient in scratch.  It rebuilds every mask, reads the state before each
+# chunk that the differentiated forward wrote (float32, alive inside one
+# layer's backward under ``remat_call``), and accumulates a group's ``dB``
+# and ``dC`` over its heads in VMEM.  The decay sums' gradient needs no sum
+# over a mask: scaling the decay into position ``i`` scales its output's
+# part without ``D x``, scaling the decay out of position ``j`` scales what
+# ``x_j`` feeds, and scaling a whole chunk's decay scales the state behind
+# it, so they are ``<dy_i, y_i - D x_i>``, ``<x_j, dx_j - D dy_j>`` a head
+# and ``<S, dS>``.  The reverse cumulative sum that turns them into ``d dt``
+# and ``dA`` is jax's own, of the cumulative sum in front.
+#
+# Precision as ``ssm._ssd_group``: decay sums, ``exp`` and the state
+# float32; a mask times ``C B^T``, the decayed inputs and the state handed to
+# ``C . S`` cast to the operands' type before their products; float32
+# accumulation.  A cotangent is cast the same way before a product.
+
+
+def _per_head_rows(rows, p):
+    """(heads, chunk) -> (heads * p, chunk): row ``k`` down head ``k``'s
+    ``p`` rows."""
+    e, q = rows.shape
+    return jnp.concatenate([jnp.broadcast_to(rows[k:k + 1], (p, q))
+                            for k in range(e)], axis=0)
+
+
+def _per_head_sums(t, e):
+    """(heads * p, chunk) -> (heads, chunk): the sum over each head's ``p``
+    rows."""
+    p = t.shape[0] // e
+    return jnp.concatenate(
+        [jnp.sum(t[k * p:(k + 1) * p], axis=0, keepdims=True)
+         for k in range(e)], axis=0)
+
+
+def _ssd_masks(cb, rows_ref, cols_ref, e):
+    """Per head, ``(C B^T . decay, decay, dt_j)``: ``cb`` (chunk, chunk)
+    holds ``B_j . C_i`` at [j, i], the decay ``exp(a_i - a_j)`` where
+    ``i >= j`` and 0 elsewhere, float32; ``dt_j`` a (chunk, 1) column."""
+    q = cb.shape[0]
+    visible = jax.lax.broadcasted_iota(jnp.int32, (1, q), 1) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0)
+    for k in range(e):
+        seg = rows_ref[k:k + 1, :] - cols_ref[:, k:k + 1]
+        decay = jnp.exp(jnp.where(visible, seg, _NEG_INF))
+        yield cb * decay, decay, cols_ref[:, e + k:e + k + 1]
+
+
+def _ssd_decays(rows_ref, e, n):
+    """From a chunk's decay sums ``a`` and ``dt`` (heads, chunk) each, the
+    float32 rows a kernel scales by: ``exp(a_i)`` (the decay from the
+    chunk's start into position ``i``), ``exp(a_end - a_j)`` (out of ``j``
+    to its end), ``dt_j``, and the whole chunk's decay along the ``n`` lanes
+    of a state."""
+    a, dt = rows_ref[:e, :], rows_ref[e:, :]
+    end = a[:, a.shape[1] - 1:]
+    # exp behind the broadcast: Mosaic folds a row's slice into a broadcast
+    # it stands on, and cannot broadcast one element down and across at once
+    return (jnp.exp(a), jnp.exp(end - a), dt,
+            jnp.exp(jnp.broadcast_to(end, (e, n))))
+
+
+def _ssd_fwd_kernel(x_ref, b_ref, c_ref, rows_ref, cols_ref, d_ref, y_ref,
+                    *rest, heads):
+    state = rest[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros(state.shape, state.dtype)
+
+    x, B, C = x_ref[...], b_ref[...], c_ref[...]
+    dtype, e, p = x.dtype, heads, x.shape[0] // heads
+    before = state[...]
+    if len(rest) == 2:                      # the differentiated forward
+        rest[0][...] = before
+    into, out_of, dt, whole = _ssd_decays(rows_ref, e, B.shape[0])
+    inside = [
+        _dot_nn(x[k * p:(k + 1) * p], (weight * dt_col).astype(dtype))
+        for k, (weight, _, dt_col) in enumerate(
+            _ssd_masks(_dot_tn(B, C), rows_ref, cols_ref, e))]
+    y = jnp.concatenate(inside, axis=0) \
+        + _dot_nn(before.astype(dtype), C) * _per_head_rows(into, p) \
+        + _per_head_rows(d_ref[...], p) * x.astype(jnp.float32)
+    y_ref[...] = y.astype(dtype)
+    to_end = _per_head_rows((out_of * dt).astype(dtype), p)
+    state[...] = before * _per_head_rows(whole, p) + _dot_nt(x * to_end, B)
+
+
+def _ssd_bwd_kernel(x_ref, b_ref, c_ref, rows_ref, cols_ref, d_ref, s_ref,
+                    dy_ref, dx_ref, db_ref, dc_ref, drow_ref, dd_ref, dstate,
+                    after, *, heads):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros(dstate.shape, dstate.dtype)
+        after[...] = jnp.zeros(after.shape, after.dtype)
+        dd_ref[...] = jnp.zeros(dd_ref.shape, dd_ref.dtype)
+
+    f32 = jnp.float32
+    x, B, C, dy = x_ref[...], b_ref[...], c_ref[...], dy_ref[...]
+    dtype, e, p, q = x.dtype, heads, x.shape[0] // heads, x.shape[1]
+    before, d_after = s_ref[...], dstate[...]
+    x32, dy32 = x.astype(f32), dy.astype(f32)
+    into, out_of, dt, whole = _ssd_decays(rows_ref, e, B.shape[0])
+    # per head: the forward's product again and its transpose on dy (the
+    # SAME rounded mask, so what the two send to a decay sum cancels as it
+    # does in exact arithmetic), the mask without dt_j on dy for d dt_j, and
+    # the mask's share of the group's d(C B^T)
+    inside, back, by_dt, dcb = [], [], [], jnp.zeros((q, q), f32)
+    for k, (weight, decay, dt_col) in enumerate(
+            _ssd_masks(_dot_tn(B, C), rows_ref, cols_ref, e)):
+        xk, dyk = (t[k * p:(k + 1) * p] for t in (x, dy))
+        w = (weight * dt_col).astype(dtype)
+        inside.append(_dot_nn(xk, w))
+        back.append(_dot_nt(dyk, w))
+        by_dt.append(_dot_nt(dyk, weight.astype(dtype)))
+        dcb = dcb + _dot_tn(xk, dyk) * (decay * dt_col)
+    into_rows = _per_head_rows(into, p)
+    y = jnp.concatenate(inside, axis=0) \
+        + _dot_nn(before.astype(dtype), C) * into_rows
+    to_end = _per_head_rows((out_of * dt).astype(dtype), p)
+    dxdec = _dot_nn(d_after.astype(dtype), B)
+    dx = jnp.concatenate(back, axis=0) + dxdec * to_end.astype(f32)
+    dx_ref[...] = (dx + _per_head_rows(d_ref[...], p) * dy32).astype(dtype)
+    dd_ref[...] += _per_head_sums(dy32 * x32, e)
+
+    # the decay sums, position by position (the comment above the kernels)
+    at_end = jnp.sum(_per_head_sums(d_after * after[...], e), axis=1,
+                     keepdims=True)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, q), 1) == q - 1
+    drow_ref[...] = jnp.concatenate(
+        [_per_head_sums(dy32 * y - x32 * dx, e)
+         + jnp.where(last, at_end, 0.0),
+         _per_head_sums(x32 * (jnp.concatenate(by_dt, axis=0)
+                               + dxdec * _per_head_rows(out_of, p)), e)],
+        axis=0)
+
+    dcs, dcb = (dy32 * into_rows).astype(dtype), dcb.astype(dtype)
+    dc_ref[...] = (_dot_tn(before.astype(dtype), dcs)
+                   + _dot_nn(B, dcb)).astype(dtype)
+    db_ref[...] = (_dot_tn(d_after.astype(dtype), x * to_end)
+                   + _dot_nt(C, dcb)).astype(dtype)
+    dstate[...] = d_after * _per_head_rows(whole, p) + _dot_nt(dcs, C)
+    after[...] = before
+
+
+def _ssd_call(kernel, x, B, C, a, dt, D, more, outs, reverse):
+    """``pallas_call`` over (batch, group, chunk); ``reverse`` walks the
+    chunks from the last and carries two states in scratch, not one.
+    ``more`` (array, kind) pairs and the outputs ``outs`` name their blocks
+    by kind: ``"x"`` the group's rows of an array like ``x``, ``"bc"`` of one
+    like ``B``, ``"sum"`` a float32 (heads, chunk) block a (batch, group)
+    that stays put over the chunks, a tuple the trailing block of a float32
+    (batch, group, chunk, ...) array.  Beside ``x``, ``B`` and ``C`` every
+    kernel reads, float32, the decay sums ``a`` and ``dt`` (batch, group,
+    chunk, head, position) as ``rows`` (.., 2 heads, position) and, the same
+    numbers, as ``cols`` (.., position, 2 heads), and ``D`` along a chunk's
+    lanes, (group, heads, position)."""
+    bsz, g, nc, e, q = a.shape
+    n, ep = B.shape[1] // g, x.shape[1] // g
+    f32 = jnp.float32
+
+    def chunk(ci):
+        return nc - 1 - ci if reverse else ci
+
+    def spec(kind):
+        if kind in ("x", "bc"):
+            return pl.BlockSpec((None, ep if kind == "x" else n, q),
+                                lambda bi, gi, ci: (bi, gi, chunk(ci)))
+        if kind == "sum":
+            return pl.BlockSpec((None, None, e, q),
+                                lambda bi, gi, ci: (bi, gi, 0, 0))
+        return pl.BlockSpec((None, None, None) + kind,
+                            lambda bi, gi, ci: (bi, gi, chunk(ci), 0, 0))
+
+    def shape(kind):
+        if kind in ("x", "bc"):
+            like = x if kind == "x" else B
+            return jax.ShapeDtypeStruct(like.shape, like.dtype)
+        if kind == "sum":
+            return jax.ShapeDtypeStruct((bsz, g, e, q), f32)
+        return jax.ShapeDtypeStruct((bsz, g, nc) + kind, f32)
+
+    rows = jnp.concatenate([a, dt], axis=-2)
+    d_rows = jnp.broadcast_to(D.astype(f32).reshape(g, e, 1), (g, e, q))
+    return pl.pallas_call(
+        functools.partial(kernel, heads=e), grid=(bsz, g, nc),
+        in_specs=[spec("x"), spec("bc"), spec("bc"), spec((2 * e, q)),
+                  spec((q, 2 * e)),
+                  pl.BlockSpec((None, e, q), lambda bi, gi, ci: (gi, 0, 0)),
+                  *(spec(kind) for _, kind in more)],
+        out_specs=[spec(kind) for kind in outs],
+        out_shape=[shape(kind) for kind in outs],
+        scratch_shapes=[pltpu.VMEM((ep, n), f32)] * (2 if reverse else 1),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")),
+        interpret=_interpret())(x, B, C, rows, rows.swapaxes(-1, -2),
+                                d_rows, *(t for t, _ in more))
+
+
+@functools.partial(jax.jit, static_argnums=(6,))
+def _ssd_forward(x, B, C, a, dt, D, keep_states):
+    """``[y]``, and the state before every chunk, (batch, group, chunk,
+    heads * head_dim, state), behind it if ``keep_states``."""
+    g = a.shape[1]
+    states = [(x.shape[1] // g, B.shape[1] // g)] if keep_states else []
+    return _ssd_call(_ssd_fwd_kernel, x, B, C, a, dt, D, [],
+                     ["x"] + states, False)
+
+
+@jax.jit
+def _ssd_backward(x, B, C, a, dt, D, states, dy):
+    e, q = a.shape[3:]
+    dx, dB, dC, drow, dd = _ssd_call(
+        _ssd_bwd_kernel, x, B, C, a, dt, D,
+        [(states, states.shape[3:]), (dy, "x")],
+        ["x", "bc", "bc", (2 * e, q), "sum"], True)
+    return (dx, dB, dC, drow[..., :e, :], drow[..., e:, :],
+            jnp.sum(dd, axis=(0, 3)).reshape(D.shape).astype(D.dtype))
+
+
+@jax.custom_vjp
+def _ssd(x, B, C, a, dt, D):
+    return _ssd_forward(x, B, C, a, dt, D, False)[0]
+
+
+def _ssd_fwd(x, B, C, a, dt, D):
+    y, states = _ssd_forward(x, B, C, a, dt, D, True)
+    return y, (x, B, C, a, dt, D, states)
+
+
+def _ssd_bwd(res, dy):
+    return _ssd_backward(*res, dy)
+
+
+_ssd.defvjp(_ssd_fwd, _ssd_bwd)
+
+
+def ssd_chunk_scan(x, B, C, a, dt, D):
+    """The chunked Mamba-2 scan with the ``D x`` term, the sequence along
+    the last axis: ``x`` (batch, heads * head_dim, length), ``B`` and ``C``
+    (batch, groups * state, length) of ``x``'s type, ``D`` (heads,), and
+    float32 ``a`` and ``dt`` (batch, groups, chunks, heads of a group,
+    chunk): ``dt`` and the cumulative sum of ``dt A`` inside each chunk;
+    chunks * chunk is the length.  Returns ``y`` shaped and typed as ``x``;
+    differentiable in all six.  On a TPU chunk and state are multiples of
+    128 and head_dim one of 16."""
+    bsz, g, nc, e, q = a.shape
+    if x.shape[0] != bsz or x.shape[2] != nc * q or x.shape[1] % (g * e) \
+            or B.shape != C.shape or B.shape[1] % g \
+            or B.shape[2] != x.shape[2] or dt.shape != a.shape \
+            or not x.dtype == B.dtype == C.dtype:
+        raise ValueError(f"ssd_chunk_scan cannot take x {x.shape} "
+                         f"{x.dtype}, B {B.shape} {B.dtype}, C {C.shape} "
+                         f"{C.dtype}, a {a.shape}, dt {dt.shape}")
+    return _ssd(x, B, C, a.astype(jnp.float32), dt.astype(jnp.float32), D)

@@ -6,10 +6,18 @@ B_t^T`` and ``y_t = S_t C_t + D x_t`` in the SSD form (Dao and Gu 2024,
 arXiv:2405.21060): inside a chunk the recurrence is three matrix products
 under a decay mask, between chunks one float32 state a head is carried.
 Decay sums and the carried state are float32 whatever the operands' type;
-the products take the operands' type and accumulate in float32.  No kernel
-of this repo's runs here: the products are XLA's and the backward pass is
-jax's own through a forward rematerialised group by group, so nothing of a
-chunk's (chunk, chunk, heads) decay mask is kept for it.
+the products take the operands' type and accumulate in float32.
+
+On a TPU with no mesh of more than one device the scan is a pair of Pallas
+kernels (``pallas_kernels.ssd_chunk_scan``): a chunk's decay mask is built,
+used and dropped in VMEM, the state is carried in scratch, and the backward
+kernel rebuilds the masks.  Anywhere else it is ``_ssd_chunked``, XLA's
+products group by group with jax's own backward through a rematerialised
+forward; that expression is also what the kernels are tested against.
+Which one is decided from what the trace can observe, as the attention core
+does (``ops/contrib.py``): ``ssm.scan_fused`` / ``ssm.scan_unfused`` count
+the sites, and a TPU trace that leaves the kernels says so with a
+``fallback`` event.
 
 The norms take and return the type that arrives (bf16 under AMP), float32
 inside, as ``BatchNorm`` does since PR 29 (``amp/lists.py``).
@@ -21,6 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry as _telemetry
 from .registry import register
 
 __all__ = ["ssd_scan", "ssd_scan_sequential", "causal_conv1d", "rms_norm",
@@ -98,8 +107,36 @@ def _ssd_chunked(x, dt, A, B, C, chunk):
     return jnp.moveaxis(y, 0, 2).reshape(b, l, h, p)  # (b, l, g, e, p)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk_size",))
-def _ssd_scan(x, dt, A, B, C, D, chunk_size):
+def _ssd_fused(x, dt, A, B, C, D, chunk):
+    """``y`` WITH the ``D x`` term from the Pallas kernels, shapes as
+    ``_ssd_chunked``.  The kernels take the sequence along the last axis;
+    XLA runs a mixer's projections and convolution that way round on the
+    chip, so the transposes here are dimension orders, not copies.  The
+    decay sums are 8 bytes a position and head, and jax differentiates
+    their cumulative sum."""
+    from . import pallas_kernels as _pk
+
+    b, l, h, p = x.shape
+    g, n = B.shape[2:]
+    # (batch, group, chunk, head, position)
+    dt = jnp.moveaxis(dt.astype(_F32).swapaxes(1, 2)
+                      .reshape(b, g, h // g, l // chunk, chunk), 2, 3)
+    # the sum over a chunk's steps up to each: a product with a triangle of
+    # ones at the highest precision (float32 to rounding; XLA's own
+    # cumulative sum along the lanes ran a millisecond a layer on the v5e)
+    a = jnp.einsum("bgceq,qr->bgcer",
+                   dt * A.astype(_F32).reshape(g, 1, h // g, 1),
+                   jnp.triu(jnp.ones((chunk, chunk), _F32)),
+                   precision=jax.lax.Precision.HIGHEST)
+    y = _pk.ssd_chunk_scan(
+        x.reshape(b, l, h * p).swapaxes(1, 2),
+        B.reshape(b, l, g * n).swapaxes(1, 2),
+        C.reshape(b, l, g * n).swapaxes(1, 2), a, dt, D)
+    return y.swapaxes(1, 2).reshape(b, l, h, p)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk_size", "fused"))
+def _ssd_scan(x, dt, A, B, C, D, chunk_size, fused):
     with jax.named_scope(SCAN_SCOPE):
         l = x.shape[1]
         pad = -l % chunk_size
@@ -109,9 +146,42 @@ def _ssd_scan(x, dt, A, B, C, D, chunk_size):
             args = tuple(jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] *
                                  (t.ndim - 2)) for t in args)
         xp, dtp, Bp, Cp = args
+        if fused:
+            return _ssd_fused(xp, dtp, A, Bp, Cp, D, chunk_size)[:, :l]
         y = _ssd_chunked(xp, dtp, A, Bp, Cp, chunk_size)
         y = y[:, :l].astype(_F32) + D.astype(_F32)[:, None] * x.astype(_F32)
         return y.astype(x.dtype)
+
+
+_SCAN_FUSED = _telemetry.counter(
+    "ssm.scan_fused", "state-space scan sites traced onto the Pallas kernels")
+_SCAN_UNFUSED = _telemetry.counter(
+    "ssm.scan_unfused",
+    "state-space scan sites traced as the chunked expression of XLA's "
+    "products")
+
+
+def _scan_platform() -> str:
+    return jax.default_backend()
+
+
+def _fused_scan_refusal(x, B, C, chunk):
+    """Why a TPU trace cannot take the Pallas kernels at this site, or None:
+    ``pallas_call`` has no partitioning rule, and Mosaic wants a chunk's
+    positions and a state in whole 128-lane columns and a head's rows in
+    whole bf16 tiles."""
+    from ..parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"mesh of {mesh.size} devices"
+    (h, p), (g, n) = x.shape[2:], B.shape[2:]
+    if chunk % 128 or n % 128 or h % g or p % 16:
+        return ("chunk_size and the state must be multiples of 128, "
+                "head_dim one of 16")
+    if not x.dtype == B.dtype == C.dtype:
+        return "x, B and C of different types"
+    return None
 
 
 @register("ssd_scan", num_inputs=6)
@@ -120,8 +190,20 @@ def ssd_scan(x, dt, A, B, C, D, chunk_size=128):
     ``dt`` (batch, length, heads), already positive; ``A`` (heads,),
     negative; ``B``, ``C`` (batch, length, groups, state); ``D`` (heads,).
     Returns ``y`` of ``x``'s shape and type.  Any length: one that is no
-    multiple of ``chunk_size`` is padded with steps that do nothing."""
-    return _ssd_scan(x, dt, A, B, C, D, int(chunk_size))
+    multiple of ``chunk_size`` is padded with steps that do nothing.  On a
+    TPU with no mesh the Pallas kernels, anywhere else the chunked
+    expression; counted and refused as ``interleaved_selfatt`` is."""
+    chunk_size = int(chunk_size)
+    fused = _scan_platform() == "tpu"
+    if fused:
+        refusal = _fused_scan_refusal(x, B, C, chunk_size)
+        if refusal is not None:
+            fused = False
+            _telemetry.event("fallback", "ssm.scan_fused",
+                             length=x.shape[1], chunk=chunk_size,
+                             why=refusal)
+    (_SCAN_FUSED if fused else _SCAN_UNFUSED).inc()
+    return _ssd_scan(x, dt, A, B, C, D, chunk_size, fused)
 
 
 def ssd_scan_sequential(x, dt, A, B, C, D):

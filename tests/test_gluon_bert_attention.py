@@ -490,3 +490,39 @@ def test_mosaic_compiles_the_grouped_products(one_chip, monkeypatch):
     both = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), x, w,
                           group, used)
     assert both.count("tpu_custom_call") == 3
+
+
+def test_mosaic_compiles_the_scan_kernels(one_chip, monkeypatch):
+    """``ssd_scan``'s kernel path at the state-space cell's widths (64 heads
+    of 64 in 8 groups, state 128, chunk 128, bf16) at a quarter of its 8,192
+    positions, forward and backward.  Forward: one Mosaic call.
+    Differentiated: the forward that also writes the states, and the
+    backward; and every instruction that carries a name stands under
+    ``SsdScan``, the scope the benchmark's scan metrics read."""
+    from mxnet_tpu.ops import ssm
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (spec((1, 2048, 64, 64)), spec((1, 2048, 64), jnp.float32),
+            spec((64,), jnp.float32), spec((1, 2048, 8, 128)),
+            spec((1, 2048, 8, 128)), spec((64,), jnp.float32))
+
+    def scan(*a):
+        return ssm._ssd_scan(*a, chunk_size=128, fused=True)
+
+    def loss(*a):
+        return scan(*a).astype(jnp.float32).sum()
+
+    assert _compiled_text(scan, *args).count("tpu_custom_call") == 1
+    text = _compiled_text(jax.grad(loss, argnums=tuple(range(6))), *args)
+    assert text.count("tpu_custom_call") == 2
+    names = re.findall(r'op_name="([^"]*)"', text[text.index("ENTRY"):])
+    outside = [n for n in names if ssm.SCAN_SCOPE not in n
+               and not re.fullmatch(r"a\[\d\]|jit\(\w+\)/"
+                                    r"(transpose\(jvp\(\)\)/)?"
+                                    r"(convert_element_type|reduce_sum|mul|"
+                                    r"broadcast_in_dim)", n)]
+    assert not outside, outside

@@ -86,6 +86,184 @@ def test_ssd_scan_through_the_registry_and_the_tape():
     np.testing.assert_allclose(x.grad.asnumpy(), want, rtol=2e-4, atol=2e-4)
 
 
+# -- the scan's Pallas kernels, under the interpreter -------------------------
+def _kernel_scan(*args, chunk_size=8):
+    return ssm._ssd_scan(*args, chunk_size=chunk_size, fused=True)
+
+
+def _chunked_scan(*args, chunk_size=8):
+    return ssm._ssd_scan(*args, chunk_size=chunk_size, fused=False)
+
+
+def _scan_grads(fn, args):
+    """Gradients of all six inputs under a fixed cotangent that bf16 holds
+    exactly: with bf16 operands the error is then the backward's own, not
+    a nonlinear loss read at a rounded output."""
+    ct = jax.random.normal(jax.random.PRNGKey(99), args[0].shape) \
+        .astype(jnp.bfloat16).astype(jnp.float32)
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * ct),
+                    argnums=tuple(range(6)))(*args)
+
+
+def _worst(got, want):
+    """The largest error as a share of the largest wanted value."""
+    got, want = (jnp.asarray(t, jnp.float32) for t in (got, want))
+    return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("length", [32, 37, 5, 8])
+@pytest.mark.parametrize("dtype,limit", [(jnp.float32, 2e-5),
+                                         (jnp.bfloat16, 2 ** -6)],
+                         ids=["float32", "bfloat16"])
+def test_scan_kernel_equals_the_sequential_recurrence(length, dtype, limit):
+    """Two heads a group, whole and broken chunks, one shorter than a
+    chunk; bf16 operands against the float32 recurrence on the ROUNDED
+    operands, which a bf16 state or bf16 decay sums would miss."""
+    args = _scan_inputs(length, dtype, seed=5)
+    got = _kernel_scan(*args)
+    want = ssm.ssd_scan_sequential(*args)
+    assert got.shape == want.shape and got.dtype == dtype
+    assert _worst(got, want) < limit
+
+
+@pytest.mark.parametrize("length", [32, 21])
+@pytest.mark.parametrize("dtype,limit", [(jnp.float32, 3e-5),
+                                         (jnp.bfloat16, 2 ** -6)],
+                         ids=["float32", "bfloat16"])
+def test_scan_kernel_gradients_equal_the_recurrences(length, dtype, limit):
+    args = _scan_inputs(length, dtype, seed=6)
+    got = _scan_grads(_kernel_scan, args)
+    want = _scan_grads(ssm.ssd_scan_sequential, args)
+    for g, w, a, name in zip(got, want, args, "x dt A B C D".split()):
+        assert g.shape == a.shape and g.dtype == a.dtype, name
+        assert _worst(g, w) < limit, name
+
+
+@pytest.mark.parametrize("length,groups", [(32, 2), (19, 1), (24, 4)])
+def test_scan_kernel_equals_the_chunked_expression(length, groups):
+    """float32, forward and all six gradients, to rounding: the kernels and
+    ``_ssd_chunked`` are the same arithmetic.  One group for all heads, and a
+    group a head."""
+    args = _scan_inputs(length, jnp.float32, seed=7, g=groups)
+    assert _worst(_kernel_scan(*args), _chunked_scan(*args)) < 2e-6
+    for g, w, name in zip(_scan_grads(_kernel_scan, args),
+                          _scan_grads(_chunked_scan, args),
+                          "x dt A B C D".split()):
+        assert _worst(g, w) < 5e-6, name
+
+
+def test_scan_kernel_does_not_depend_on_the_chunk():
+    args = _scan_inputs(48, jnp.float32, seed=3)
+    a = _kernel_scan(*args, chunk_size=8)
+    b = _kernel_scan(*args, chunk_size=16)
+    np.testing.assert_allclose(a, b, atol=3e-5 * float(jnp.max(jnp.abs(a))))
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def test_scan_kernels_read_the_operands_where_they_lie(monkeypatch):
+    """One Mosaic call forward; differentiated, two: the forward that also
+    writes the state before every chunk, and the backward.  ``x``, ``B``,
+    ``C`` and the cotangent go in whole, as (batch, channels, length) with
+    no group or head moved to the front, and the backward never evaluates
+    the chunked expression."""
+    args = _scan_inputs(32, jnp.float32, seed=8)
+
+    def no_chunked(*a, **kw):
+        raise AssertionError("the kernel path evaluated _ssd_chunked")
+
+    monkeypatch.setattr(ssm, "_ssd_chunked", no_chunked)
+    # not the jitted entry: its cache may hold a trace from another test
+    def scan(*a):
+        return ssm._ssd_scan.__wrapped__(*a, chunk_size=8, fused=True)
+
+    forward = list(_pallas_calls(jax.make_jaxpr(scan)(*args).jaxpr))
+    assert len(forward) == 1
+    shapes = [tuple(v.aval.shape) for v in forward[0].invars[:3]]
+    assert shapes == [(2, 32, 32), (2, 32, 32), (2, 32, 32)]
+    both = list(_pallas_calls(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(scan(*a)), argnums=tuple(range(6))))(*args)
+        .jaxpr))
+    assert len(both) == 2
+    states = [tuple(v.aval.shape) for v in both[0].outvars]
+    assert states == [(2, 32, 32), (2, 2, 4, 16, 16)]    # y, (b, g, c, e p, n)
+    big = [tuple(v.aval.shape) for v in both[1].outvars[:3]]
+    assert big == [(2, 32, 32)] * 3                       # dx, dB, dC
+
+
+def test_ssd_scan_operator_both_paths(monkeypatch):
+    """The registered operator on the CPU, then as a TPU trace would take it:
+    a shape the kernels refuse says so and still computes, one they take
+    counts a fused site."""
+    from mxnet_tpu.parallel import mesh as mesh_mod
+
+    def nd_args(args):
+        return [mx.nd.array(np.asarray(t, np.float32)) for t in args]
+
+    small = _scan_inputs(16, jnp.float32, seed=9)
+    want = ssm.ssd_scan_sequential(*small)
+    base = mx.telemetry.snapshot()
+    out = mx.nd.ssd_scan(*nd_args(small), chunk_size=8)
+    np.testing.assert_allclose(out.asnumpy(), want, atol=2e-5)
+    delta = mx.telemetry.delta(base)
+    assert delta["ssm.scan_unfused"] >= 1
+    assert not delta.get("ssm.scan_fused")
+
+    monkeypatch.setattr(ssm, "_scan_platform", lambda: "tpu")
+    monkeypatch.setattr(mesh_mod, "current_mesh", lambda: None)
+
+    def fallbacks():
+        return [e for e in mx.telemetry.events("fallback")
+                if e["name"] == "ssm.scan_fused"]
+
+    # a chunk of 8 and a state of 16 are no whole 128-lane columns
+    before, base = len(fallbacks()), mx.telemetry.snapshot()
+    out = mx.nd.ssd_scan(*nd_args(small), chunk_size=8)
+    np.testing.assert_allclose(out.asnumpy(), want, atol=2e-5)
+    assert len(fallbacks()) == before + 1
+    assert "128" in fallbacks()[-1]["why"]
+    assert mx.telemetry.delta(base)["ssm.scan_unfused"] >= 1
+    # two heads of 64 a group, state 128, chunk 128, a length that breaks
+    # the second chunk: the kernels take it, and no event
+    wide = _scan_inputs(150, jnp.float32, seed=10, b=1, h=4, p=64, g=2,
+                        n=128)
+    base = mx.telemetry.snapshot()
+    out = mx.nd.ssd_scan(*nd_args(wide), chunk_size=128)
+    want = ssm.ssd_scan_sequential(*wide)
+    np.testing.assert_allclose(out.asnumpy(), want, atol=3e-5 * float(
+        jnp.max(jnp.abs(want))))
+    delta = mx.telemetry.delta(base)
+    assert delta["ssm.scan_fused"] >= 1
+    assert not delta.get("ssm.scan_unfused")
+    assert len(fallbacks()) == before + 1
+
+
+@pytest.mark.parametrize("why,kw", [
+    (None, {}), ("mesh", {"devices": 4}), ("128", {"chunk": 64}),
+    ("128", {"n": 64}), ("16", {"p": 8}),
+    ("types", {"b_dtype": jnp.float32})],
+    ids=["taken", "mesh", "chunk", "state", "head_dim", "types"])
+def test_scan_kernel_refusals(why, kw, monkeypatch):
+    """What a TPU trace decides from: the mesh, the shapes, the types."""
+    from mxnet_tpu.parallel import mesh as mesh_mod
+
+    class Mesh:
+        size = kw.get("devices", 1)
+
+    monkeypatch.setattr(mesh_mod, "current_mesh", Mesh)
+    x = jax.ShapeDtypeStruct((1, 256, 4, kw.get("p", 64)), jnp.bfloat16)
+    c = jax.ShapeDtypeStruct((1, 256, 2, kw.get("n", 128)), jnp.bfloat16)
+    b = jax.ShapeDtypeStruct(c.shape, kw.get("b_dtype", jnp.bfloat16))
+    refusal = ssm._fused_scan_refusal(x, b, c, kw.get("chunk", 128))
+    assert refusal is None if why is None else why in refusal
+
+
 @pytest.mark.parametrize("kernel,activation", [(4, "silu"), (4, None),
                                                (3, "silu")])
 def test_causal_conv1d_equals_shifted_multiplies(kernel, activation):
@@ -216,15 +394,7 @@ def test_grouped_causal_kernels_write_no_copy_of_a_key():
     """The kernel call itself takes k and v with their own two heads:
     nothing of the query's width is made of them on the way in."""
     q, k, v, _ = _qkv(1, 32, 8, 2, 16)
-
-    def pallas_calls(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                yield eqn
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from pallas_calls(sub)
-
-    calls = list(pallas_calls(jax.make_jaxpr(
+    calls = list(_pallas_calls(jax.make_jaxpr(
         lambda *a: pk.flash_attention_gqa(*a, 8, 2))(q, k, v).jaxpr))
     assert len(calls) == 1
     assert [tuple(var.aval.shape) for var in calls[0].invars] == \

@@ -123,6 +123,9 @@ FP16_FP32_FUNCS = [
     # in the attention core; their products take the activations' type
     # (weights are cast to it inside), so the policy leaves the inputs alone
     "ssd_scan", "causal_conv1d", "causal_gqa_selfatt", "held_experts",
+    # rotary angles are float32 inside the operator, as are the latent
+    # attention core's rotary key and softmax statistics
+    "rope", "causal_latent_selfatt",
     # activations / simple elementwise
     "Activation", "LeakyReLU", "relu", "sigmoid", "tanh", "softsign",
     "hard_sigmoid", "abs", "sign", "negative", "ceil", "floor", "rint",
@@ -156,7 +159,7 @@ FP16_FP32_FUNCS = [
     # the sparse-label cross-entropy takes its logits as they arrive, like
     # `pick`: its statistics are float32 inside, and in FP32_FUNCS the
     # policy would hand it a float32 copy of the (tokens x vocabulary) array
-    "sparse_softmax_cross_entropy",
+    "sparse_softmax_cross_entropy", "multi_token_cross_entropy",
     # ordering / extrema (value-preserving)
     "argmax", "argmin", "argmax_channel", "argsort", "sort", "topk",
     "max", "min", "unique",

@@ -16,7 +16,7 @@ from .block import HybridBlock
 __all__ = [
     "Loss", "L2Loss", "L1Loss",
     "SigmoidBinaryCrossEntropyLoss", "SigmoidBCELoss",
-    "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
+    "SoftmaxCrossEntropyLoss", "SoftmaxCELoss", "MultiTokenCrossEntropyLoss",
     "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss", "SquaredHingeLoss",
     "LogisticLoss", "TripletLoss", "PoissonNLLLoss", "CosineEmbeddingLoss",
     "SDMLLoss",
@@ -144,6 +144,24 @@ class SoftmaxCrossEntropyLoss(Loss):
 
 
 SoftmaxCELoss = SoftmaxCrossEntropyLoss
+
+
+class MultiTokenCrossEntropyLoss(Loss):
+    """Next-token cross-entropy plus the weighted losses of a model's
+    multi-token-prediction modules (op ``multi_token_cross_entropy``).
+    ``pred`` (batch, depths, seq, classes) as such a model returns it,
+    ``label`` (batch, seq) the next token at every position;
+    ``depth_weights[d]`` weighs depth ``d`` (1 for the next token, the
+    paper's lambda for the rest).  Returns (batch,)."""
+
+    def __init__(self, depth_weights=(1.0, 0.3), weight=1.0, batch_axis=0):
+        super().__init__(weight, batch_axis)
+        self._depth_weights = tuple(float(w) for w in depth_weights)
+
+    def forward(self, pred, label, sample_weight=None):
+        loss = invoke("multi_token_cross_entropy", [pred, label],
+                      {"depth_weights": self._depth_weights})
+        return _apply_weighting(loss, self._weight, sample_weight)
 
 
 class KLDivLoss(Loss):

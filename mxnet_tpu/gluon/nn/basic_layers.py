@@ -4,6 +4,7 @@ Reference ``python/mxnet/gluon/nn/basic_layers.py``.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as onp
 
@@ -25,6 +26,7 @@ __all__ = [
     "InstanceNorm",
     "LayerNorm",
     "RMSNorm",
+    "LatentAttention",
     "GroupNorm",
     "Embedding",
     "Flatten",
@@ -407,6 +409,64 @@ class RMSNorm(HybridBlock):
     def forward(self, x):
         return invoke("RMSNorm", [x, self.gamma.data(x.ctx)],
                       {"eps": self._epsilon})
+
+
+class LatentAttention(HybridBlock):
+    """Causal multi-head latent attention as a model trains it (DeepSeek-V2,
+    arXiv:2405.04434 section 2.1; names follow the public code):
+
+        c_q = RMSNorm(q_a_proj x)               the query latent, q_lora_rank
+        q = q_b_proj c_q                        a head: [q_nope; q_rope]
+        [c_kv; k_rope] = kv_a_proj_with_mqa x   the key-value latent and ONE
+                                                rotary key a token
+        kv_b_proj RMSNorm(c_kv)                 a head: [k_nope; v]
+
+    ``q_rope`` and ``k_rope`` take rotary positions, every head's key is
+    ``[k_nope; k_rope]``, scores are scaled by ``1 / sqrt(nope + rope)``,
+    and ``o_proj`` maps the heads' values back.  No bias.  The core is one
+    operator, ``causal_latent_selfatt``; each stage runs under a scope the
+    device trace reads (``LatentDown``, ``LatentUp``, the operator's
+    ``LatentQK`` and ``LatentCore``, ``LatentOut``)."""
+
+    def __init__(self, hidden_size, num_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 rope_theta=10000.0, epsilon=1e-5, weight_initializer=None,
+                 out_initializer=None):
+        super().__init__()
+        self._heads, self._rope_dim = num_heads, qk_rope_head_dim
+        self._kv_rank, self._theta = kv_lora_rank, float(rope_theta)
+
+        def proj(units, in_units, init=weight_initializer):
+            return Dense(units, use_bias=False, flatten=False,
+                         in_units=in_units, weight_initializer=init)
+
+        self.q_a_proj = proj(q_lora_rank, hidden_size)
+        self.q_a_layernorm = RMSNorm(epsilon=epsilon, in_channels=q_lora_rank)
+        self.q_b_proj = proj(
+            num_heads * (qk_nope_head_dim + qk_rope_head_dim), q_lora_rank)
+        self.kv_a_proj_with_mqa = proj(kv_lora_rank + qk_rope_head_dim,
+                                       hidden_size)
+        self.kv_a_layernorm = RMSNorm(epsilon=epsilon,
+                                      in_channels=kv_lora_rank)
+        self.kv_b_proj = proj(num_heads * (qk_nope_head_dim + v_head_dim),
+                              kv_lora_rank)
+        self.o_proj = proj(hidden_size, num_heads * v_head_dim,
+                           out_initializer or weight_initializer)
+
+    def forward(self, x):
+        with jax.named_scope("LatentDown"):
+            c_q = self.q_a_layernorm(self.q_a_proj(x))
+            down = self.kv_a_proj_with_mqa(x)
+            c_kv = self.kv_a_layernorm(
+                down.slice_axis(axis=-1, begin=0, end=self._kv_rank))
+            k_rope = down.slice_axis(axis=-1, begin=self._kv_rank, end=None)
+        with jax.named_scope("LatentUp"):
+            q, kv = self.q_b_proj(c_q), self.kv_b_proj(c_kv)
+        out = invoke("causal_latent_selfatt", [q, kv, k_rope],
+                     {"heads": self._heads, "rope_dim": self._rope_dim,
+                      "theta": self._theta})
+        with jax.named_scope("LatentOut"):
+            return self.o_proj(out)
 
 
 class GroupNorm(HybridBlock):

@@ -147,18 +147,43 @@ def interleaved_selfatt(queries_keys_values, key=None, heads=1, p=0.0,
 
 def _unfused_causal_gqa(q, k, v, heads, kv_heads):
     """Grouped causal attention as two products around a float32 softmax
-    under an explicit lower-triangular mask."""
+    under an explicit lower-triangular mask.  The values' head may be wider
+    or narrower than the scores'."""
     bsz, seq, width = q.shape
     d, group = width // heads, heads // kv_heads
     q5 = q.reshape(bsz, seq, kv_heads, group, d)
-    k4, v4 = (t.reshape(bsz, seq, kv_heads, d) for t in (k, v))
+    k4, v4 = (t.reshape(bsz, seq, kv_heads, -1) for t in (k, v))
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", q5, k4,
                         preferred_element_type=jnp.float32) / (d ** 0.5)
     visible = jnp.arange(seq)[:, None] >= jnp.arange(seq)[None, :]
     att = jax.nn.softmax(jnp.where(visible, scores, -jnp.inf), axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", att.astype(v.dtype), v4,
                      preferred_element_type=jnp.float32)
-    return out.reshape(bsz, seq, width).astype(q.dtype)
+    return out.reshape(bsz, seq, -1).astype(q.dtype)
+
+
+def _causal_core(q, k, v, heads, kv_heads, fused, unfused, event, misfit):
+    """The causal grouped core of one site: the Pallas kernels on a TPU
+    with no mesh of more than one device when the shape fits (``misfit`` is
+    None, else why it does not), the unfused expression anywhere else.
+    Counted on ``fused`` / ``unfused``; a TPU trace that leaves the kernels
+    emits the ``fallback`` event ``event``."""
+    from ..parallel.mesh import current_mesh
+    from . import pallas_kernels as _pk
+
+    if _attention_platform() != "tpu":
+        unfused.inc()
+        return _unfused_causal_gqa(q, k, v, heads, kv_heads)
+    mesh = current_mesh()
+    refusal = f"mesh of {mesh.size} devices" \
+        if mesh is not None and mesh.size > 1 else misfit
+    if refusal is not None:
+        unfused.inc()
+        _telemetry.event("fallback", event, seq=q.shape[1],
+                         head_dim=q.shape[2] // heads, why=refusal)
+        return _unfused_causal_gqa(q, k, v, heads, kv_heads)
+    fused.inc()
+    return _pk.flash_attention_gqa(q, k, v, heads, kv_heads)
 
 
 @register("causal_gqa_selfatt", num_inputs=3)
@@ -172,43 +197,105 @@ def causal_gqa_selfatt(queries, keys, values, heads=1, kv_heads=1):
     projections in place and write no copy of a key or a value
     (``pallas_kernels.flash_attention_gqa``); anywhere else the unfused
     expression.  Counted and refused as ``interleaved_selfatt`` is."""
-    from ..parallel.mesh import current_mesh
     from . import pallas_kernels as _pk
 
     seq, head_dim = queries.shape[1], queries.shape[2] // heads
-    if _attention_platform() != "tpu":
-        _ATTN_UNFUSED.inc()
-        return _unfused_causal_gqa(queries, keys, values, heads, kv_heads)
-    mesh = current_mesh()
-    refusal = None
-    if mesh is not None and mesh.size > 1:
-        refusal = f"mesh of {mesh.size} devices"
-    elif head_dim % 128 or _pk.gqa_block(seq) is None:
-        refusal = ("head_dim must be whole 128-lane columns and seq a "
-                   "multiple of 16")
-    if refusal is not None:
-        _ATTN_UNFUSED.inc()
-        _telemetry.event("fallback", "attention.fused", seq=seq,
-                         head_dim=head_dim, why=refusal)
-        return _unfused_causal_gqa(queries, keys, values, heads, kv_heads)
-    _ATTN_FUSED.inc()
-    return _pk.flash_attention_gqa(queries, keys, values, heads, kv_heads)
+    misfit = None
+    if head_dim % 128 or _pk.gqa_block(seq) is None:
+        misfit = ("head_dim must be whole 128-lane columns and seq a "
+                  "multiple of 16")
+    return _causal_core(queries, keys, values, heads, kv_heads, _ATTN_FUSED,
+                        _ATTN_UNFUSED, "attention.fused", misfit)
+
+
+# --- latent attention: low-rank keys and values, one rotary key a token ----
+#
+# DeepSeek-V2's multi-head latent attention (arXiv:2405.04434, section 2.1)
+# as a model trains it: a head's key is ``[k_nope; k_rope]``, ``k_nope`` its
+# own rows of the key-value up-projection and ``k_rope`` ONE rotary vector a
+# token that every head uses (its gradient is the sum over the heads); a
+# head's query is ``[q_nope; q_rope]`` with rotary positions on ``q_rope``;
+# scores are scaled by the whole query width.  The products of the absorbed
+# form are a serving matter (the latent as the cache) and are not built.
+
+_LATENT_FUSED = _telemetry.counter(
+    "attention.latent_fused",
+    "latent-attention sites whose core was traced onto the Pallas flash "
+    "kernels")
+_LATENT_UNFUSED = _telemetry.counter(
+    "attention.latent_unfused",
+    "latent-attention sites whose core was traced as the unfused "
+    "scores/softmax/value expression")
+# the scopes the device trace reads (perfbench/scope_view)
+LATENT_QK_SCOPE = "LatentQK"
+LATENT_CORE_SCOPE = "LatentCore"
+
+
+@register("causal_latent_selfatt", num_inputs=3)
+def causal_latent_selfatt(queries, keys_values, rope_key, heads=1,
+                          rope_dim=0, theta=10000.0):
+    """Causal latent-attention core.  ``queries`` (batch, seq, heads *
+    (nope + rope_dim)), a head's ``[q_nope; q_rope]``; ``keys_values``
+    (batch, seq, heads * (nope + v_dim)), a head's ``[k_nope; v]``;
+    ``rope_key`` (batch, seq, rope_dim), the token's one ``k_rope``; both
+    as the up-projections give them, before rotary positions.
+    ``q_rope`` and ``k_rope`` take rotary positions (``rope``, base
+    ``theta``), every head's key is ``[k_nope; k_rope]``, and the result is
+    ``softmax(q k^T / sqrt(nope + rope_dim) + causal mask) v``, (batch, seq,
+    heads * v_dim).  On a TPU with no mesh, where scores and values have one
+    head width of whole 128-lane columns, the core is
+    ``pallas_kernels.flash_attention_gqa`` (as many key-value heads as query
+    heads); anywhere else the unfused expression.  Counted
+    (``attention.latent_fused`` / ``attention.latent_unfused``) and refused
+    as ``interleaved_selfatt`` is."""
+    from . import pallas_kernels as _pk
+    from .rotary import rope
+
+    bsz, seq, width = queries.shape
+    qk_dim = width // heads
+    nope = qk_dim - rope_dim
+    with jax.named_scope(LATENT_QK_SCOPE):
+        q4 = queries.reshape(bsz, seq, heads, qk_dim)
+        q = jnp.concatenate(
+            [q4[..., :nope], rope(q4[..., nope:], theta=theta)],
+            axis=-1).reshape(bsz, seq, width)
+        kv4 = keys_values.reshape(bsz, seq, heads, -1)
+        k_rope = jnp.broadcast_to(
+            rope(rope_key, theta=theta)[:, :, None, :],
+            (bsz, seq, heads, rope_dim))               # one key, every head
+        k = jnp.concatenate([kv4[..., :nope], k_rope.astype(kv4.dtype)],
+                            axis=-1).reshape(bsz, seq, width)
+        v = kv4[..., nope:].reshape(bsz, seq, -1)
+    misfit = None
+    if v.shape[-1] != width or qk_dim % 128 or _pk.gqa_block(seq) is None:
+        misfit = ("scores and values must share a head width of whole "
+                  "128-lane columns and seq be a multiple of 16")
+    with jax.named_scope(LATENT_CORE_SCOPE):
+        return _causal_core(q, k, v, heads, heads, _LATENT_FUSED,
+                            _LATENT_UNFUSED, "attention.latent_fused",
+                            misfit)
 
 
 @register("held_experts", num_inputs=5, num_outputs=2)
 def held_experts(data, router_weight, select_bias, up_weight, down_weight,
-                 held=(), k=1, scaling=1.0):
+                 held=(), k=1, scaling=1.0, hidden_act="relu2",
+                 capacity_factor=2.0):
     """One expert-parallel rank's part of a sparse-expert layer
     (``parallel.moe.held_experts_layer``): sigmoid scores over ALL the
     experts, the ``k`` largest of score + bias, the rows of the ``held``
-    experts sorted into a static buffer, two grouped products, a weighted
-    scatter back.  Outputs: the routed result, shaped and typed as
-    ``data``, and the call's float32 counts ``moe.HELD_STATS``."""
+    experts sorted into a static buffer, two grouped products with the
+    model's ``hidden_act`` between them (``relu2``: ``relu(.)^2``;
+    ``silu``: the gated form, ``up_weight`` holding gate and up side by
+    side), a weighted scatter back.  The buffer holds ``capacity_factor``
+    times the held experts' mean share of the rows.  Outputs: the routed
+    result, shaped and typed as ``data``, and the call's float32 counts
+    ``moe.HELD_STATS``."""
     from ..parallel.moe import held_experts_layer
 
     return held_experts_layer(
         data, router_weight, select_bias, up_weight, down_weight,
-        held=tuple(held), k=int(k), scaling=float(scaling))
+        held=tuple(held), k=int(k), scaling=float(scaling),
+        hidden_act=str(hidden_act), capacity_factor=float(capacity_factor))
 
 
 @register("interleaved_matmul_encdec_qk", num_inputs=2)

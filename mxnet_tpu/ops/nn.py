@@ -617,6 +617,54 @@ def sparse_softmax_cross_entropy(data, label, axis=-1, mode="clip"):
     return _sparse_ce(data, label, axis)
 
 
+def _unstack_bwd(axis, _, cotangents):
+    return (jnp.stack(cotangents, axis=axis),)
+
+
+# ``x[:, d]`` for every ``d``, with the backward written as ONE stack of the
+# cotangents: jax's own transposes each slice into a pad and adds the pads,
+# which XLA materialises (three arrays of the logits' size for two depths);
+# slices of a stack, in either direction, it folds away.
+_unstack = jax.custom_vjp(
+    lambda data, axis: tuple(
+        jax.lax.index_in_dim(data, d, axis, keepdims=False)
+        for d in range(data.shape[axis])),
+    nondiff_argnums=(1,))
+_unstack.defvjp(lambda data, axis: (_unstack(data, axis), None), _unstack_bwd)
+
+# the scope of a depth's loss in the device trace (perfbench/scope_view):
+# depth 0 is the next token's, the others a multi-token-prediction module's
+MULTI_TOKEN_LOSS_SCOPES = ("NextTokenLoss", "MultiTokenLoss")
+
+
+@register("multi_token_cross_entropy", num_inputs=2)
+def multi_token_cross_entropy(data, label, depth_weights=(1.0,)):
+    """The training loss of a model with multi-token-prediction modules
+    (DeepSeek-V3, arXiv:2412.19437 section 2.2).  ``data`` (batch, depths,
+    seq, classes): depth ``d``'s logits at position ``i`` predict token
+    ``i + 1 + d``; ``label`` (batch, seq) holds the NEXT token at every
+    position, so depth ``d``'s target at ``i`` is ``label[i + d]`` and its
+    last ``d`` positions have none and are left out.  Returns (batch,):
+    ``sum_d depth_weights[d] * mean over the positions with a target`` of
+    the cross-entropy, each depth one ``sparse_softmax_cross_entropy``."""
+    depths, seq = data.shape[1], data.shape[2]
+    if len(depth_weights) != depths:
+        raise ValueError(f"{depths} depths of logits, weights "
+                         f"{tuple(depth_weights)}")
+    label = pick_index(label, data.shape[-1], "clip")
+    total = 0.0
+    for d, (logits, weight) in enumerate(zip(_unstack(data, 1),
+                                             depth_weights)):
+        with jax.named_scope(MULTI_TOKEN_LOSS_SCOPES[min(d, 1)]):
+            _SPARSE_CE_FUSED.inc()
+            target = jnp.roll(label, -d, axis=1)       # the tail is masked
+            loss = _sparse_ce(logits, target[..., None], logits.ndim - 1)
+            has_target = jnp.arange(seq) < seq - d
+            total = total + float(weight) * jnp.sum(
+                jnp.where(has_target, loss, 0.0), axis=1) / (seq - d)
+    return total
+
+
 @register("SoftmaxOutput", num_inputs=2, aliases=["Softmax"])
 def softmax_output(data, label, grad_scale=1.0, ignore_label=-1.0,
                    multi_output=False, use_ignore=False, preserve_shape=False,

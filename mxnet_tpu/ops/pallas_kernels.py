@@ -489,7 +489,12 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, dropout_p=0.0,
 # k and v (batch, seq, kv_heads*head_dim); query head h reads key-value head
 # h // (heads // kv_heads) through the index map, so no copy of a key or a
 # value is written.  A block is (block, head_dim) columns of one batch row:
-# head_dim must be whole 128-lane columns.  The key blocks are a grid
+# head_dim must be whole 128-lane columns, one (32 query heads of 128 over 2
+# key-value heads: Nemotron-H) or several (20 over 20 at 256, a group of
+# one: latent attention, whose scores and values share the width).  A step
+# holds three (block, head_dim) operands and a float32 accumulator of that
+# shape, the dk/dv kernel four and two; Mosaic takes both widths at a block
+# of 512 (tests/test_gluon_bert_attention.py compiles them for a v5e).  The key blocks are a grid
 # dimension (accumulators in VMEM scratch): nothing of a whole sequence is
 # resident, so the length is bounded by HBM alone.  Blocks above the diagonal
 # are skipped, and their index maps repeat the block before, so nothing is
@@ -830,7 +835,10 @@ def flash_attention_qkv(qkv, num_heads, dropout_p=0.0, dropout_key=None):
 # times slower on the v5e and carries no scope (PERF.md, PR 31).
 
 GROUP_TILE = 256
-_GROUP_K = 384          # the contracted extent a grid step (21 x 128 = 2688)
+# the contracted extent a grid step: the largest divisor of K in whole
+# 128-lane columns up to this (384 of Nemotron-H's 2688 = 21 x 128 and of
+# 1536; 256 of GLM's 2048 = 16 x 128, which 384 does not divide)
+_GROUP_K = 384
 
 
 def _group_k(k):

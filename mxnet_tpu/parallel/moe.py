@@ -152,7 +152,10 @@ def moe_layer(x, gate_w, w_in, w_out, *, k: int = 2,
 # buffer of static size, two grouped products run over it (on a TPU the
 # Pallas kernel ``pallas_kernels.grouped_matmul``, elsewhere XLA's
 # ``lax.ragged_dot``; group sizes stay on the device), and the result is
-# scattered back weighted.  What the absent experts would add is
+# scattered back weighted.  An expert is the model's own: ``w_down
+# relu(w_up x)^2`` (Nemotron-H's ``relu2``) or the gated ``w_down
+# (silu(w_gate x) * (w_up x))`` (``silu``: DeepSeek-V3's and GLM's), whose
+# first product gives gate and up side by side.  What the absent experts would add is
 # left out: the exchange that brings it belongs to a mesh with an ``ep``
 # axis, and this layer adds nothing that stands in for it.  No row routed to
 # a held expert is dropped while the buffer holds; rows beyond it are
@@ -188,23 +191,48 @@ def _kernel_products(width: int) -> bool:
             and (mesh is None or mesh.size <= 1))
 
 
+HIDDEN_ACTS = ("relu2", "silu")
+
+
+def _expert_hidden(h, hidden_act):
+    """What an expert's first product becomes before its second.
+    ``relu2``: ``relu(h)^2`` in the type that arrives; ``silu``: ``h``
+    holds gate and up side by side, ``silu(gate) * up`` in float32."""
+    if hidden_act == "relu2":
+        return jnp.square(jax.nn.relu(h))
+    gate, up = jnp.split(h.astype(jnp.float32), 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
 def held_experts_layer(x, router_w, select_bias, w_up, w_down, *, held,
                        k: int, scaling: float = 1.0,
-                       capacity_factor: float = 2.0):
+                       capacity_factor: float = 2.0,
+                       hidden_act: str = "relu2"):
     """The held experts' part of the routed result.
 
     ``x`` (..., M); ``router_w`` (E, M) float32; ``select_bias`` (E,);
-    ``w_up`` (H, M, F) and ``w_down`` (H, F, M), the weights of the H
-    experts ``held`` (global ids, ascending) of the E the router scores.
+    ``w_up`` and ``w_down`` (H, F, M), the weights of the H experts
+    ``held`` (global ids, ascending) of the E the router scores.
+    ``hidden_act`` is the model's own and decides an expert's form:
+    ``relu2``, ``w_down relu(w_up x)^2`` with ``w_up`` (H, M, F);
+    ``silu``, the gated ``w_down (silu(w_gate x) * (w_up x))`` with
+    ``w_up`` (H, M, 2F) holding ``[w_gate | w_up]`` along its last axis.
     The router's product is float32 at the highest precision whatever ``x``
     is (a rounding there flips a choice); the experts' products take ``x``'s
-    type; an expert is ``w_down relu(w_up x)^2``.  The buffer holds
-    ``capacity_factor`` times the mean share (``held_buffer_rows``).
+    type.  The buffer holds ``capacity_factor`` times the mean share
+    (``held_buffer_rows``).
     Returns ``(out, stats)``: ``out`` of ``x``'s shape and type, ``stats``
     float32 ``HELD_STATS`` of this call (``load_max`` the busiest held
     expert's rows, ``steps`` 1)."""
     from ..ops import pallas_kernels as _pk
 
+    if hidden_act not in HIDDEN_ACTS:
+        raise ValueError(f"hidden_act={hidden_act!r}: one of {HIDDEN_ACTS}")
+    parts = 2 if hidden_act == "silu" else 1      # products side by side
+    if w_up.shape[2] != parts * w_down.shape[1]:
+        raise ValueError(
+            f"hidden_act={hidden_act!r}: w_up {w_up.shape} must be "
+            f"{parts} x w_down's hidden width {w_down.shape[1]}")
     shape, dtype = x.shape, x.dtype
     x = x.reshape(-1, shape[-1])
     tokens, num_experts = x.shape[0], router_w.shape[0]
@@ -269,16 +297,25 @@ def held_experts_layer(x, router_w, select_bias, w_up, w_down, *, held,
                 side="right") - 1, 0, num_held - 1).astype(jnp.int32)
             used = jnp.minimum((start[-1] + room[-1]) // tile,
                                tiles).reshape(1).astype(jnp.int32)
-            pad = -w_up.shape[2] % 128        # the hidden width in lanes
-            up = jnp.pad(w_up.astype(dtype), ((0, 0), (0, 0), (0, pad)))
+            hidden = w_down.shape[1]
+            pad = -hidden % 128               # the hidden width in lanes
+            up = w_up.astype(dtype)
+            if parts == 1:
+                up = jnp.pad(up, ((0, 0), (0, 0), (0, pad)))
+            elif pad:     # gate and up lie side by side: each padded alone
+                up = jnp.pad(
+                    up.reshape(up.shape[:2] + (parts, hidden)),
+                    ((0, 0), (0, 0), (0, 0), (0, pad))).reshape(
+                        up.shape[:2] + (parts * (hidden + pad),))
             down = jnp.pad(w_down.astype(dtype), ((0, 0), (0, pad), (0, 0)))
-            h = jnp.square(jax.nn.relu(
-                _pk.grouped_matmul(gathered, up, tile_group, used)))
+            h = _expert_hidden(
+                _pk.grouped_matmul(gathered, up, tile_group, used),
+                hidden_act)
             y = _pk.grouped_matmul(h.astype(dtype), down, tile_group, used)
         else:
             sizes = jnp.clip(rows - start, 0, count)       # what fits
             h = jax.lax.ragged_dot(gathered, w_up.astype(dtype), sizes)
-            h = jnp.square(jax.nn.relu(jnp.where(filled, h, 0)))
+            h = _expert_hidden(jnp.where(filled, h, 0), hidden_act)
             y = jax.lax.ragged_dot(h.astype(dtype), w_down.astype(dtype),
                                    sizes)
 

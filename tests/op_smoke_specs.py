@@ -86,6 +86,11 @@ SPECS = {
                             dict(heads=2, p=0.5, training=True)),
     "causal_gqa_selfatt": ([_f(2, 8, 32), _f(2, 8, 16), _f(2, 8, 16)],
                            dict(heads=4, kv_heads=2)),
+    # latent attention: 2 heads of [6 nope; 2 rope] queries and [6; 5]
+    # keys and values, one rotary key of 2 a token
+    "causal_latent_selfatt": ([_f(2, 8, 16), _f(2, 8, 22), _f(2, 8, 2)],
+                              dict(heads=2, rope_dim=2, theta=100.0)),
+    "rope": ([_f(2, 8, 3, 4)], dict(theta=100.0)),
     # --- state-space and sparse-expert layers (ops/ssm.py, parallel/moe.py)
     "ssd_scan": ([_f(2, 12, 4, 8), _f(2, 12, 4), -_f(4), _f(2, 12, 2, 16),
                   _f(2, 12, 2, 16), _f(4)], dict(chunk_size=8)),
@@ -215,6 +220,9 @@ SPECS = {
                               {}),
     "sparse_softmax_cross_entropy": (
         [_f(4, 6), _i(6, 4).astype(onp.float32)], {}),
+    "multi_token_cross_entropy": (
+        [_f(3, 2, 5, 6), _i(6, 3, 5).astype(onp.float32)],
+        dict(depth_weights=(1.0, 0.3))),
     "embedding": ([_i(10, 4), _f(10, 8)], {}),
     "take": ([_f(10, 8), _i(10, 4).astype(onp.float32)], {}),
     "Cast": ([_f(4, 6)], dict(dtype="float16")),

@@ -26,8 +26,11 @@ HEADS, HEAD_DIM = 2, 64           # two heads fill a 128-lane row
 @pytest.fixture
 def as_tpu(monkeypatch):
     """The operator's platform test says TPU; the kernels still see the CPU
-    and run under the Pallas interpreter."""
+    and run under the Pallas interpreter.  The event buffer is the
+    process's: a fallback another file's test provoked on purpose (the
+    worker ran it first) is not this test's."""
     monkeypatch.setattr(contrib, "_attention_platform", lambda: "tpu")
+    telemetry.clear_events()
 
 
 def _counts():
@@ -490,6 +493,35 @@ def test_mosaic_compiles_the_grouped_products(one_chip, monkeypatch):
     both = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), x, w,
                           group, used)
     assert both.count("tpu_custom_call") == 3
+
+
+def test_mosaic_compiles_the_latent_cells_kernels(one_chip, monkeypatch):
+    """The latent-attention cell's two kernels at its widths, bf16, forward
+    and backward: ``flash_attention_gqa`` at 20 = 20 heads of 256 (blocks,
+    scratch and both backward kernels at twice the width it had run at) at a
+    quarter of the 8,192 keys, and ``grouped_matmul`` at the gated experts'
+    widths (8 experts, 2048 to gate and up side by side, 3072; 1536 back)."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    qkv = spec((1, 2048, 20 * 256))
+    text = _compiled_text(jax.grad(
+        lambda q, k, v: pk.flash_attention_gqa(q, k, v, 20, 20)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)), qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") == 3
+    rows = 24 * pk.GROUP_TILE
+    group, used = spec((24,), jnp.int32), spec((1,), jnp.int32)
+
+    def loss(x, w, group, used):
+        return pk.grouped_matmul(x, w, group, used).astype(jnp.float32).sum()
+
+    for n_in, n_out in ((2048, 3072), (1536, 2048)):
+        both = _compiled_text(
+            jax.value_and_grad(loss, argnums=(0, 1)), spec((rows, n_in)),
+            spec((8, n_in, n_out)), group, used)
+        assert both.count("tpu_custom_call") == 3
 
 
 def test_mosaic_compiles_the_scan_kernels(one_chip, monkeypatch):

@@ -274,3 +274,123 @@ def test_routed_part_and_shared_expert_are_separate():
     np.testing.assert_allclose(
         block(x).asnumpy(),
         (block.routed(x) + block.shared_expert(x)).asnumpy(), atol=1e-6)
+
+
+# -- the gated expert: w_down (silu(w_gate x) * (w_up x)) ---------------------
+def _gated_weights(**kw):
+    """``up`` holds gate and up side by side: (experts, width, 2 x hidden)."""
+    w = _weights(**kw)
+    gate = 0.2 * jax.random.normal(jax.random.PRNGKey(99), w["up"].shape)
+    return {**w, "up": jnp.concatenate([gate, w["up"]], axis=-1)}
+
+
+def _gated_loop(w, held, k=K):
+    s = jax.nn.sigmoid(jnp.einsum("tm,em->te", w["x"], w["router"],
+                                  precision="highest"))
+    _, chosen = jax.lax.top_k(s + w["bias"], k)
+    picked = jnp.take_along_axis(s, chosen, -1)
+    weight = picked / picked.sum(-1, keepdims=True) * SCALING
+    hidden = w["down"].shape[1]
+    out = 0.0
+    for e in held:
+        mine = jnp.sum(jnp.where(chosen == e, weight, 0.0), -1)
+        gate, up = w["up"][e][:, :hidden], w["up"][e][:, hidden:]
+        h = jax.nn.silu(w["x"] @ gate) * (w["x"] @ up)
+        out = out + mine[:, None] * (h @ w["down"][e])
+    return out
+
+
+def _gated(w, held, k=K):
+    ids = jnp.asarray(held)
+    return moe.held_experts_layer(
+        w["x"], w["router"], w["bias"], w["up"][ids], w["down"][ids],
+        held=held, k=k, scaling=SCALING, capacity_factor=4.0,
+        hidden_act="silu")
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_the_gated_expert_equals_the_loop_over_experts(monkeypatch, kernel):
+    """Values and gradients, through XLA's ragged product and through the
+    layout a TPU trace takes (Pallas interpreter; a hidden width of 40 is
+    padded to whole lanes, gate and up each alone)."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    if kernel:
+        monkeypatch.setattr(pk, "GROUP_TILE", 8)
+        monkeypatch.setattr(moe, "_grouped_platform", lambda: "tpu")
+    w = _gated_weights(tokens=96, width=128, hidden=40, experts=16, seed=11)
+    w["router"] = 0.4 * w["router"]
+    held = (0, 3, 5, 6)
+    out, stats = _gated(w, held)
+    np.testing.assert_allclose(out, _gated_loop(w, held), atol=1e-4)
+    assert float(stats[moe.HELD_STATS.index("rows_overflow")]) == 0
+
+    def ours(x, up, down):
+        return jnp.sum(jnp.sin(_gated({**w, "x": x, "up": up, "down": down},
+                                      held)[0]))
+
+    def loop(x, up, down):
+        return jnp.sum(jnp.sin(_gated_loop(
+            {**w, "x": x, "up": up, "down": down}, held)))
+
+    args = (w["x"], w["up"], w["down"])
+    for got, want in zip(jax.grad(ours, argnums=(0, 1, 2))(*args),
+                         jax.grad(loop, argnums=(0, 1, 2))(*args)):
+        np.testing.assert_allclose(got, want, atol=2e-3)
+
+
+def test_the_gated_shares_add_up_to_the_uncut_layer():
+    """Eight ranks, each told its 8 of 64 experts, four a token."""
+    w = _gated_weights(tokens=48, experts=64, seed=3)
+    whole = _gated_loop(w, range(64), k=4)
+    total = sum(_gated(w, tuple(range(8 * r, 8 * r + 8)), k=4)[0]
+                for r in range(8))
+    np.testing.assert_allclose(total, whole, atol=2e-5)
+
+
+def test_the_relu2_expert_is_unchanged_to_the_bit():
+    """``hidden_act`` left out is ``relu2``, and the ``relu2`` layer is the
+    expression it was before the layer knew a second form: XLA's two ragged
+    products over the sorted rows with ``relu(.)^2`` between them, bit for
+    bit (the rows' order is the counting sort's, so the old expression is
+    rebuilt from the layer's own statistics-free inputs)."""
+    w = _weights(tokens=64, seed=13)
+    held = (1, 2, 5, 8)
+    ids = jnp.asarray(held)
+    args = (w["x"], w["router"], w["bias"], w["up"][ids], w["down"][ids])
+    kw = dict(held=held, k=K, scaling=SCALING, capacity_factor=4.0)
+    default, _ = moe.held_experts_layer(*args, **kw)
+    named, _ = moe.held_experts_layer(*args, hidden_act="relu2", **kw)
+    np.testing.assert_array_equal(default, named)
+
+    # the expression as PR 31 wrote it, on the same sorted rows
+    s = jax.nn.sigmoid(jnp.einsum("tm,em->te", w["x"], w["router"],
+                                  precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(s + w["bias"], K)
+    picked = jnp.take_along_axis(s, chosen, -1)
+    weight = (picked / picked.sum(-1, keepdims=True) * SCALING).reshape(-1)
+    flat, token = chosen.reshape(-1), jnp.arange(64 * K) // K
+    order = jnp.concatenate([jnp.nonzero(flat == e)[0] for e in held])
+    sizes = jnp.asarray([int(jnp.sum(flat == e)) for e in held], jnp.int32)
+    rows = moe.held_buffer_rows(64, K, 16, len(held), 4.0)
+    pad = rows - order.shape[0]
+    gathered = jnp.pad(w["x"][token[order]], ((0, pad), (0, 0)))
+    h = jax.lax.ragged_dot(gathered, w["up"][ids], sizes)
+    h = jnp.square(jax.nn.relu(h))
+    y = jax.lax.ragged_dot(h, w["down"][ids], sizes)
+    y = y * jnp.pad(weight[order], (0, pad))[:, None]
+    want = jnp.zeros_like(w["x"]).at[
+        jnp.pad(token[order], (0, pad))].add(y)
+    np.testing.assert_array_equal(default, want)
+
+
+def test_an_unknown_hidden_act_and_a_wrong_width_are_refused():
+    w = _weights()
+    ids = jnp.arange(4)
+    args = (w["x"], w["router"], w["bias"], w["up"][ids], w["down"][ids])
+    with pytest.raises(ValueError, match="hidden_act="):
+        moe.held_experts_layer(*args, held=(0, 1, 2, 3), k=K,
+                               hidden_act="gelu")
+    with pytest.raises(ValueError, match="w_up"):       # no gate beside up
+        moe.held_experts_layer(*args, held=(0, 1, 2, 3), k=K,
+                               hidden_act="silu")

@@ -36,7 +36,8 @@ __all__ = ["SparseExperts"]
 # become a ``fallback`` event, which fails a benchmark cell's ``correct``.
 
 _LAYERS = []      # every expert layer this process built, as _Counted
-_ROUTED, _HELD, _OVERFLOW, _LOAD_MAX, _STEPS = range(len(_moe.HELD_STATS))
+(_ROUTED, _HELD, _OVERFLOW, _LOAD_MAX, _STEPS, _TILES_USED,
+ _TILES) = range(len(_moe.HELD_STATS))
 
 
 class _Counted:
@@ -53,7 +54,7 @@ class _Counted:
 
 
 def _counts():
-    """``[(record, its five counts)]`` of every expert layer whose counts
+    """``[(record, its counts)]`` of every expert layer whose counts
     are concrete (one host read each)."""
     out = []
     for rec in list(_LAYERS):
@@ -86,6 +87,16 @@ def _load_max_over_mean():
     return max(ratios) if ratios else None
 
 
+def _tiles_used_share():
+    """Tiles of the buffers that are a held expert's own (its rows' tiles,
+    at least one each) over the buffers' tiles: what the grouped kernels'
+    time follows over what the dispatch's does (no tiles: the products were
+    XLA's ``ragged_dot``)."""
+    counts = _counts()
+    tiles = sum(c[_TILES] for _, c in counts)
+    return sum(c[_TILES_USED] for _, c in counts) / tiles if tiles else None
+
+
 def _poll_overflow():
     for rec, c in _counts():
         new = c[_OVERFLOW] - rec.overflow_reported
@@ -113,6 +124,9 @@ telemetry.gauge_fn("moe.rows_held_share", _rows_held_share,
                    "rows the held experts got over their mean share")
 telemetry.gauge_fn("moe.load_max_over_mean", _load_max_over_mean,
                    "the busiest held expert's rows over an expert's mean")
+telemetry.gauge_fn("moe.tiles_used_share", _tiles_used_share,
+                   "tiles of the buffers that are a held expert's own (the "
+                   "grouped kernels pay for these) over the buffers' tiles")
 telemetry.event_source(_poll_overflow)
 
 

@@ -823,42 +823,117 @@ def flash_attention_qkv(qkv, num_heads, dropout_p=0.0, dropout_key=None):
 # ``x`` (rows, K) holds whole TILES of ``GROUP_TILE`` rows, each tile's rows
 # of ONE group (``tile_group[t]``, ascending; rows a group does not fill are
 # zeros), and ``w`` (groups, K, N) one matrix a group: ``out[tile t] =
-# x[tile t] @ w[tile_group[t]]``.  The tile's group reaches the index maps as
-# a scalar prefetch, so a tile fetches its group's matrix and nothing is
-# gathered or copied.  Tiles at or behind ``tiles_used`` are skipped (zeros
-# out).  The backward is the same kernel on the transposed matrices for the
-# rows' gradient and one kernel for the matrices': a group's tiles are
-# consecutive, so its (K, N) gradient is accumulated in VMEM over them and
-# written once.  Every group owns at least one tile (the caller's layout), so
-# every gradient block is written.  For a sparse-expert layer's held experts
+# x[tile t] @ w[tile_group[t]]``.  The tile's group and ``tiles_used`` reach
+# the index maps as scalar prefetches, so a tile fetches its group's matrix
+# and nothing is gathered or copied.
+#
+# What is resident: the tiles are the INNER grid dimension and a step
+# contracts the whole K, so a group's matrix block has one index over all the
+# group's consecutive tiles and crosses HBM once a group, not once a tile,
+# and no accumulator is read and written between steps.  The block is the
+# whole (K, N) matrix where two copies of it (the pipeline's) fit
+# ``_GROUP_VMEM``: 10.3 MB, 12.6 MB and 6.3 MB at the widths the two
+# sparse-expert cells run, over Mosaic's default limit, which is why the
+# calls state ``vmem_limit_bytes``.  A wider matrix is cut into column blocks
+# on the OUTER dimension (``_group_cols``; the rows are then read once a
+# column block).
+#
+# What a skipped tile costs: tiles at or behind ``tiles_used`` take the
+# blocks of the last used tile (``_held_tile``), which are already in VMEM,
+# so nothing is fetched for them; their output block is still written, as
+# zeros (the caller's ``filled`` mask relies on finite values there): 0.4 to
+# 0.8 MB, a microsecond.
+#
+# Why a tile has 128 rows: with the matrix resident the product's intensity
+# does not depend on the tile's rows any more, so the tile is as small as
+# one pass of the MXU's 128 rows.  An expert loses half a tile to rounding on
+# average and owns one when it gets no row, and the dispatch around the
+# products pays for those rows too.  On the v5e the three kernels take the
+# same time at 128 and at 256 rows a tile for the same held rows (0.98 and
+# 1.00 ms at 2688 x 1920, 1.78 and 1.71 at 2048 x 3072), and both cells'
+# steps are 0.4% faster at 128 (PERF.md, PR 34).
+#
+# The backward is the same kernel with the matrices read transposed (the
+# MXU takes a transposed operand; no transposed copy of the weights is
+# written: it cost as much as the product) for the rows' gradient, and one
+# kernel for the matrices': a group's tiles are consecutive, so its gradient
+# is accumulated in float32 in VMEM over them and written once, in blocks of
+# as many of the K rows as ``_GROUP_VMEM`` holds (``_group_rows``: all of
+# them at the cells' widths; fewer, and ``dy`` is read once a block).
+# Every group owns at least one tile (the caller's layout), so every gradient
+# block is written.  For a sparse-expert layer's held experts
 # (``parallel/moe.py``): XLA's own ragged product ran the same work 3 to 5
 # times slower on the v5e and carries no scope (PERF.md, PR 31).
 
-GROUP_TILE = 256
-# the contracted extent a grid step: the largest divisor of K in whole
-# 128-lane columns up to this (384 of Nemotron-H's 2688 = 21 x 128 and of
-# 1536; 256 of GLM's 2048 = 16 x 128, which 384 does not divide)
-_GROUP_K = 384
+GROUP_TILE = 128
+# what a call's resident blocks may take of VMEM, pipeline copies and
+# accumulator included (48 MiB is exactly the 2048 x 3072 gradient's float32
+# accumulator and two bf16 copies; half of it cost up to 9% of the
+# matrices' gradient, more bought nothing), and what Mosaic is told it may
+# use in all (the v5e's, v5p's and v6e's cores have 128 MiB)
+_GROUP_VMEM = 48 << 20
+_GROUP_VMEM_LIMIT = 100 << 20
 
 
-def _group_k(k):
-    return _divisor(k, _GROUP_K, multiple=128)
+def _group_cols(k, n, itemsize):
+    """Columns of a group's (K, N) matrix a forward step holds: all of them,
+    or the most whole 128-lane columns dividing N that fit twice."""
+    return _divisor(n, max(128, _GROUP_VMEM // (2 * k * itemsize)),
+                    multiple=128)
 
 
-def _gmm_kernel(group_ref, used_ref, x_ref, w_ref, o_ref, acc):
-    i, kk = pl.program_id(0), pl.program_id(1)
+def _group_rows(k, n, itemsize):
+    """Rows of a group's (K, N) gradient a step accumulates: a float32
+    accumulator and the output's two copies."""
+    return _divisor(k, max(128, _GROUP_VMEM // (n * (4 + 2 * itemsize))),
+                    multiple=128)
 
-    @pl.when(kk == 0)
+
+def _held_tile(i, used):
+    """The tile whose blocks tile ``i``'s step reads: itself, or the last
+    used one when ``i`` holds nothing (then no block changes: no fetch)."""
+    return jnp.minimum(i, jnp.maximum(used[0] - 1, 0))
+
+
+# index maps over (outer block, tile, tile_group, tiles_used)
+def _gmm_x_map(j, i, group, used):
+    return _held_tile(i, used), 0
+
+
+def _gmm_w_map(j, i, group, used):
+    return group[_held_tile(i, used)], 0, j
+
+
+def _gmm_wt_map(j, i, group, used):         # the matrix read transposed
+    return group[_held_tile(i, used)], j, 0
+
+
+def _gmm_out_map(j, i, group, used):
+    return i, j
+
+
+def _tgmm_x_map(kk, i, group, used):
+    return _held_tile(i, used), kk
+
+
+def _tgmm_dy_map(kk, i, group, used):
+    return _held_tile(i, used), 0
+
+
+def _tgmm_dw_map(kk, i, group, used):
+    return group[i], kk, 0
+
+
+def _gmm_kernel(dot, group_ref, used_ref, x_ref, w_ref, o_ref):
+    held = pl.program_id(1) < used_ref[0]
+
+    @pl.when(held)
     def _():
-        acc[...] = jnp.zeros(acc.shape, acc.dtype)
+        o_ref[...] = dot(x_ref[...], w_ref[...]).astype(o_ref.dtype)
 
-    @pl.when(i < used_ref[0])
+    @pl.when(jnp.logical_not(held))
     def _():
-        acc[...] += _dot_nn(x_ref[...], w_ref[...])
-
-    @pl.when(kk == pl.num_programs(1) - 1)
-    def _():
-        o_ref[...] = acc[...].astype(o_ref.dtype)
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
 
 def _tgmm_kernel(group_ref, used_ref, x_ref, dy_ref, dw_ref, acc):
@@ -880,58 +955,77 @@ def _tgmm_kernel(group_ref, used_ref, x_ref, dy_ref, dw_ref, acc):
 
 
 def _grouped_call(kernel, grid, in_specs, out_spec, out_shape, scratch,
-                  semantics, *args):
+                  *args):
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
             out_specs=out_spec, scratch_shapes=scratch),
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_GROUP_VMEM_LIMIT),
         interpret=_interpret())(*args)
 
 
-@jax.jit
-def _gmm(x, w, tile_group, tiles_used):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _gmm(x, w, tile_group, tiles_used, tile, tn, transposed):
+    """``x[tile] @ w[group]``, or ``x[tile] @ w[group].T`` (``transposed``:
+    ``w`` is (groups, N, K)), ``tn`` of the N columns a step."""
     rows, k = x.shape
-    n, tk = w.shape[2], _group_k(k)
+    n = w.shape[1] if transposed else w.shape[2]
+    if transposed:
+        w_spec = pl.BlockSpec((None, tn, k), _gmm_wt_map)
+    else:
+        w_spec = pl.BlockSpec((None, k, tn), _gmm_w_map)
     return _grouped_call(
-        _gmm_kernel, (rows // GROUP_TILE, k // tk),
-        [pl.BlockSpec((GROUP_TILE, tk), lambda i, kk, g, u: (i, kk)),
-         pl.BlockSpec((None, tk, n), lambda i, kk, g, u: (g[i], kk, 0))],
-        pl.BlockSpec((GROUP_TILE, n), lambda i, kk, g, u: (i, 0)),
-        jax.ShapeDtypeStruct((rows, n), x.dtype),
-        [pltpu.VMEM((GROUP_TILE, n), jnp.float32)],
-        ("parallel", "arbitrary"), tile_group, tiles_used, x, w)
+        functools.partial(_gmm_kernel, _dot_nt if transposed else _dot_nn),
+        (n // tn, rows // tile),
+        [pl.BlockSpec((tile, k), _gmm_x_map), w_spec],
+        pl.BlockSpec((tile, tn), _gmm_out_map),
+        jax.ShapeDtypeStruct((rows, n), x.dtype), [],
+        tile_group, tiles_used, x, w)
 
 
-@functools.partial(jax.jit, static_argnums=(4,))
-def _tgmm(x, dy, tile_group, tiles_used, groups):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _tgmm(x, dy, tile_group, tiles_used, groups, tile, tk):
+    """``dw[group] = sum over the group's tiles of x[tile].T @ dy[tile]``,
+    ``tk`` of the K rows a pass over the tiles."""
     rows, k = x.shape
-    n, tk = dy.shape[1], _group_k(k)
+    n = dy.shape[1]
     return _grouped_call(
-        _tgmm_kernel, (k // tk, rows // GROUP_TILE),
-        [pl.BlockSpec((GROUP_TILE, tk), lambda kk, i, g, u: (i, kk)),
-         pl.BlockSpec((GROUP_TILE, n), lambda kk, i, g, u: (i, 0))],
-        pl.BlockSpec((None, tk, n), lambda kk, i, g, u: (g[i], kk, 0)),
+        _tgmm_kernel, (k // tk, rows // tile),
+        [pl.BlockSpec((tile, tk), _tgmm_x_map),
+         pl.BlockSpec((tile, n), _tgmm_dy_map)],
+        pl.BlockSpec((None, tk, n), _tgmm_dw_map),
         jax.ShapeDtypeStruct((groups, k, n), x.dtype),
         [pltpu.VMEM((tk, n), jnp.float32)],
-        ("parallel", "arbitrary"), tile_group, tiles_used, x, dy)
+        tile_group, tiles_used, x, dy)
+
+
+def _product(x, w, tile_group, tiles_used, transposed=False):
+    # the tile and the budget as the trace finds them: static to the jitted
+    # calls, so a changed one is never served a cached trace
+    n = w.shape[1] if transposed else w.shape[2]
+    return _gmm(x, w, tile_group, tiles_used, GROUP_TILE,
+                _group_cols(x.shape[1], n, x.dtype.itemsize), transposed)
 
 
 @jax.custom_vjp
 def _grouped(x, w, tile_group, tiles_used):
-    return _gmm(x, w, tile_group, tiles_used)
+    return _product(x, w, tile_group, tiles_used)
 
 
 def _grouped_fwd(x, w, tile_group, tiles_used):
-    return _gmm(x, w, tile_group, tiles_used), (x, w, tile_group, tiles_used)
+    return (_product(x, w, tile_group, tiles_used),
+            (x, w, tile_group, tiles_used))
 
 
 def _grouped_bwd(res, dy):
     x, w, tile_group, tiles_used = res
-    dx = _gmm(dy, w.transpose(0, 2, 1), tile_group, tiles_used)
-    dw = _tgmm(x, dy, tile_group, tiles_used, w.shape[0])
+    dx = _product(dy, w, tile_group, tiles_used, transposed=True)
+    dw = _tgmm(x, dy, tile_group, tiles_used, w.shape[0], GROUP_TILE,
+               _group_rows(*w.shape[1:], x.dtype.itemsize))
     zero = onp.zeros(tile_group.shape, jax.dtypes.float0)
     return dx, dw, zero, onp.zeros(tiles_used.shape, jax.dtypes.float0)
 

@@ -162,7 +162,7 @@ def moe_layer(x, gate_w, w_in, w_out, *, k: int = 2,
 # COUNTED, never silently lost (``HELD_STATS``).
 
 HELD_STATS = ("rows_routed", "rows_held", "rows_overflow", "load_max",
-              "steps")
+              "steps", "tiles_used", "tiles")
 
 
 def held_buffer_rows(tokens: int, k: int, num_experts: int, num_held: int,
@@ -223,7 +223,10 @@ def held_experts_layer(x, router_w, select_bias, w_up, w_down, *, held,
     (``held_buffer_rows``).
     Returns ``(out, stats)``: ``out`` of ``x``'s shape and type, ``stats``
     float32 ``HELD_STATS`` of this call (``load_max`` the busiest held
-    expert's rows, ``steps`` 1)."""
+    expert's rows, ``steps`` 1; ``tiles_used`` of the buffer's ``tiles`` are
+    the held experts' own, at least one each, and the kernel's products pay
+    for those alone; both 0 on the ``ragged_dot`` path, which has no
+    tiles)."""
     from ..ops import pallas_kernels as _pk
 
     if hidden_act not in HIDDEN_ACTS:
@@ -283,20 +286,21 @@ def held_experts_layer(x, router_w, select_bias, w_up, w_down, *, held,
         filled = jnp.zeros((rows,), bool).at[at].set(True, mode="drop")[
             :, None]
         n_held = jnp.sum(count)
+        tiles = rows // tile if kernel else 0
+        used = jnp.minimum((start[-1] + room[-1]) // tile, tiles)
         stats = jnp.stack([
             jnp.asarray(tokens * k, f32), n_held.astype(f32),
             jnp.sum(is_held & (at >= rows)).astype(f32),
-            jnp.max(count).astype(f32), jnp.asarray(1.0, f32)])
+            jnp.max(count).astype(f32), jnp.asarray(1.0, f32),
+            used.astype(f32), jnp.asarray(tiles, f32)])
         gathered = jnp.where(filled, x[source], 0)         # (rows, M)
 
     with jax.named_scope("MoEExperts"):
         if kernel:
-            tiles = rows // tile
             tile_group = jnp.clip(jnp.searchsorted(
                 start, jnp.arange(tiles, dtype=jnp.int32) * tile,
                 side="right") - 1, 0, num_held - 1).astype(jnp.int32)
-            used = jnp.minimum((start[-1] + room[-1]) // tile,
-                               tiles).reshape(1).astype(jnp.int32)
+            used = used.reshape(1).astype(jnp.int32)
             hidden = w_down.shape[1]
             pad = -hidden % 128               # the hidden width in lanes
             up = w_up.astype(dtype)

@@ -472,26 +472,55 @@ def test_mosaic_compiles_the_grouped_causal_kernels(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") == 3
 
 
-def test_mosaic_compiles_the_grouped_products(one_chip, monkeypatch):
-    """``grouped_matmul`` at the held experts' widths (8 experts, 2688 to
-    the hidden width 1856 padded to 1920 lanes, a buffer of 32 tiles),
-    forward and backward: the product, its transpose for the rows and the
-    accumulating kernel for the matrices."""
-    monkeypatch.setattr(pk, "_interpret", lambda: False)
-    rows = 32 * pk.GROUP_TILE
-    x = jax.ShapeDtypeStruct((rows, 2688), jnp.bfloat16, sharding=one_chip)
-    w = jax.ShapeDtypeStruct((8, 2688, 1920), jnp.bfloat16,
-                             sharding=one_chip)
-    group = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
-    used = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+def _grouped_products_text(one_chip, tiles, k, n, fn=jax.value_and_grad):
+    """The compiled text of ``grouped_matmul``'s forward and backward over a
+    buffer of ``tiles`` tiles and 8 groups of (k, n) bf16 matrices."""
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(x, w, group, used):
         return pk.grouped_matmul(x, w, group, used).astype(jnp.float32).sum()
 
-    text = _compiled_text(jax.grad(loss, argnums=(0, 1)), x, w, group, used)
-    assert text.count("tpu_custom_call") == 2          # dx and dw
-    both = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), x, w,
-                          group, used)
+    return _compiled_text(
+        fn(loss, argnums=(0, 1)), spec((tiles * pk.GROUP_TILE, k)),
+        spec((8, k, n)), spec((tiles,), jnp.int32), spec((1,), jnp.int32))
+
+
+def _group_blocks(k, n):
+    """(columns a forward step holds, columns the rows' gradient's step
+    holds, rows of K the matrices' gradient accumulates) at bf16."""
+    return (pk._group_cols(k, n, 2), pk._group_cols(n, k, 2),
+            pk._group_rows(k, n, 2))
+
+
+def test_mosaic_compiles_the_grouped_products(one_chip, monkeypatch):
+    """``grouped_matmul`` at the held experts' widths of the state-space
+    cell (8 experts, 2688 to the hidden width 1856 padded to 1920 lanes and
+    back, the cell's buffer of 7,168 rows), forward and backward: the product with a
+    group's whole 10.3 MB matrix resident (over Mosaic's default VMEM limit:
+    the calls state theirs), the same kernel reading the matrix transposed
+    for the rows (no transposed copy of the weights in the program) and the
+    accumulating kernel for the matrices."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    for k, n in ((2688, 1920), (1920, 2688)):
+        assert _group_blocks(k, n) == (n, k, k)        # everything resident
+        text = _grouped_products_text(one_chip, 56, k, n, jax.grad)
+        assert text.count("tpu_custom_call") == 2          # dx and dw
+        both = _grouped_products_text(one_chip, 56, k, n)
+        assert both.count("tpu_custom_call") == 3
+        assert " transpose(" not in both
+
+
+def test_mosaic_compiles_the_grouped_products_in_blocks(one_chip,
+                                                        monkeypatch):
+    """The same kernels where a group's matrix does NOT fit the budget (a
+    sixth of it here, at the state-space cell's widths): column blocks on
+    the outer grid dimension forward, row blocks of K for the matrices'
+    gradient."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    monkeypatch.setattr(pk, "_GROUP_VMEM", pk._GROUP_VMEM // 6)
+    assert _group_blocks(2688, 1920) == (640, 896, 384)
+    both = _grouped_products_text(one_chip, 28, 2688, 1920)
     assert both.count("tpu_custom_call") == 3
 
 
@@ -500,7 +529,8 @@ def test_mosaic_compiles_the_latent_cells_kernels(one_chip, monkeypatch):
     and backward: ``flash_attention_gqa`` at 20 = 20 heads of 256 (blocks,
     scratch and both backward kernels at twice the width it had run at) at a
     quarter of the 8,192 keys, and ``grouped_matmul`` at the gated experts'
-    widths (8 experts, 2048 to gate and up side by side, 3072; 1536 back)."""
+    widths (8 experts, 2048 to gate and up side by side, 3072; 1536 back),
+    every group's whole matrix resident."""
     monkeypatch.setattr(pk, "_interpret", lambda: False)
 
     def spec(shape, dtype=jnp.bfloat16):
@@ -511,17 +541,13 @@ def test_mosaic_compiles_the_latent_cells_kernels(one_chip, monkeypatch):
         lambda q, k, v: pk.flash_attention_gqa(q, k, v, 20, 20)
         .astype(jnp.float32).sum(), argnums=(0, 1, 2)), qkv, qkv, qkv)
     assert text.count("tpu_custom_call") == 3
-    rows = 24 * pk.GROUP_TILE
-    group, used = spec((24,), jnp.int32), spec((1,), jnp.int32)
-
-    def loss(x, w, group, used):
-        return pk.grouped_matmul(x, w, group, used).astype(jnp.float32).sum()
-
-    for n_in, n_out in ((2048, 3072), (1536, 2048)):
-        both = _compiled_text(
-            jax.value_and_grad(loss, argnums=(0, 1)), spec((rows, n_in)),
-            spec((8, n_in, n_out)), group, used)
+    # the gated experts' buffer of 17,408 rows; the matrices' gradient of the
+    # 2048 x 3072 product is exactly what the budget holds
+    for k, n in ((2048, 3072), (1536, 2048)):
+        assert _group_blocks(k, n) == (n, k, k)
+        both = _grouped_products_text(one_chip, 136, k, n)
         assert both.count("tpu_custom_call") == 3
+        assert " transpose(" not in both
 
 
 def test_mosaic_compiles_the_scan_kernels(one_chip, monkeypatch):

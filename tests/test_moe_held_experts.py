@@ -221,6 +221,138 @@ def test_the_kernel_path_equals_the_loop_over_experts(monkeypatch, bias0):
         np.testing.assert_allclose(got, want, atol=2e-3)
 
 
+# -- the kernels' blocking: what a tile behind ``tiles_used`` costs --------------
+# tiles' groups and tiles_used: every group ONE used tile, and groups of several
+_LAYOUTS = {"one": ([0, 1, 2, 2, 2], 3), "several": ([0, 0, 0, 1, 2, 2, 2, 2], 6)}
+# (K, N) in the proportions of the two forms' first products: relu2 narrows
+# (2688 -> 1920), the gated one widens (2048 -> gate and up, 3072)
+_WIDTHS = {"relu2": (384, 256), "silu": (256, 384)}
+
+
+@pytest.mark.parametrize("resident", [True, False],
+                         ids=["whole_matrix", "blocks"])
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+@pytest.mark.parametrize("act", sorted(_WIDTHS))
+def test_rows_behind_tiles_used_are_never_read(monkeypatch, act, layout,
+                                               resident):
+    """NaN in every row behind ``tiles_used``, operands and cotangent: zeros
+    come out there, and what lies in front is finite and right, values and
+    both gradients; with a group's whole matrix resident and (a VMEM budget
+    of a fraction of it) cut into column blocks and row blocks."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    tile, (k, n) = 8, _WIDTHS[act]
+    monkeypatch.setattr(pk, "GROUP_TILE", tile)
+    if not resident:
+        monkeypatch.setattr(pk, "_GROUP_VMEM", k * n)
+        assert pk._group_cols(k, n, 4) == 128 < n
+        assert pk._group_rows(k, n, 4) == 128 < k
+    else:
+        assert pk._group_cols(k, n, 4) == n and pk._group_rows(k, n, 4) == k
+    groups, used = _LAYOUTS[layout]
+    tiles, front = len(groups), used * tile
+    groups = jnp.asarray(groups, jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(ks[0], (tiles * tile, k)).at[front:].set(jnp.nan)
+    w = jax.random.normal(ks[1], (3, k, n))
+    ct = jax.random.normal(ks[2], (tiles * tile, n)).at[front:].set(jnp.nan)
+
+    def ours(x, w):
+        return pk.grouped_matmul(x, w, groups, jnp.asarray([used], jnp.int32))
+
+    def want(x, w):
+        return jnp.einsum("tmk,tkn->tmn", x[:front].reshape(used, tile, k),
+                          w[groups[:used]],
+                          precision="highest").reshape(front, n)
+
+    out, vjp = jax.vjp(ours, x, w)
+    dx, dw = vjp(ct)
+    ref, ref_vjp = jax.vjp(want, x, w)
+    ref_dx, ref_dw = ref_vjp(ct[:front])
+    for got, ref_front in ((out, ref), (dx, ref_dx[:front])):
+        assert bool(jnp.all(got[front:] == 0))
+        np.testing.assert_allclose(got[:front], ref_front, atol=1e-3)
+    np.testing.assert_allclose(dw, ref_dw, atol=1e-3)
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_a_tile_behind_tiles_used_takes_the_last_used_tiles_blocks(layout):
+    """The index maps themselves: a tile at or behind ``tiles_used`` names
+    the operand blocks of the last used tile, whatever the outer index, so
+    the pipeline has nothing to fetch for it; its output block is its own
+    (zeros are written there), and a group's matrix block does not change
+    over the group's tiles (fetched once a group)."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    groups, used = _LAYOUTS[layout]
+    g, u = np.asarray(groups, np.int32), np.asarray([used], np.int32)
+    operands = (pk._gmm_x_map, pk._gmm_w_map, pk._gmm_wt_map,
+                pk._tgmm_x_map, pk._tgmm_dy_map)
+
+    def blocks(fn, outer, i):
+        return tuple(int(v) for v in fn(outer, i, g, u))
+
+    for outer in (0, 2):
+        for i in range(used, len(groups)):
+            for fn in operands:
+                assert blocks(fn, outer, i) == blocks(fn, outer, used - 1)
+            assert blocks(pk._gmm_out_map, outer, i) == (i, outer)
+            assert blocks(pk._tgmm_dw_map, outer, i) == (groups[i], outer, 0)
+        for i in range(1, used):
+            same = groups[i] == groups[i - 1]
+            for fn in (pk._gmm_w_map, pk._gmm_wt_map, pk._tgmm_dw_map):
+                assert (blocks(fn, outer, i) == blocks(fn, outer, i - 1)) \
+                    == same
+            assert blocks(pk._gmm_x_map, outer, i) == (i, 0)
+            assert blocks(pk._tgmm_x_map, outer, i) == (i, outer)
+    # no tile used at all (not the layer's layout: every expert owns one)
+    assert tuple(int(v) for v in pk._gmm_x_map(
+        0, 3, g, np.asarray([0], np.int32))) == (0, 0)
+
+
+@pytest.mark.parametrize("act", moe.HIDDEN_ACTS)
+def test_the_kernel_path_equals_ragged_dot_at_four_times_the_mean_share(
+        monkeypatch, act):
+    """A buffer 4 times the mean share, so most of its tiles hold nothing:
+    the kernels' layer against XLA's ``ragged_dot`` layer, values, the three
+    gradients and the counts both paths share; the kernel path also counts
+    its tiles."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    make = _gated_weights if act == "silu" else _weights
+    w = make(tokens=96, width=128, hidden=40, experts=16, seed=17)
+    w["router"] = 0.4 * w["router"]
+    held = (2, 3, 9, 12)
+    ids = jnp.asarray(held)
+
+    def layer(x, up, down):
+        return moe.held_experts_layer(
+            x, w["router"], w["bias"], up[ids], down[ids], held=held, k=K,
+            scaling=SCALING, capacity_factor=4.0, hidden_act=act)
+
+    def loss(x, up, down):
+        return jnp.sum(jnp.sin(layer(x, up, down)[0]))
+
+    args = (w["x"], w["up"], w["down"])
+    ragged, ragged_stats = layer(*args)
+    ragged_grads = jax.grad(loss, argnums=(0, 1, 2))(*args)
+    monkeypatch.setattr(pk, "GROUP_TILE", 8)
+    monkeypatch.setattr(moe, "_grouped_platform", lambda: "tpu")
+    out, stats = layer(*args)
+    np.testing.assert_allclose(out, ragged, atol=1e-4)
+    for got, want in zip(jax.grad(loss, argnums=(0, 1, 2))(*args),
+                         ragged_grads):
+        np.testing.assert_allclose(got, want, atol=2e-3)
+    shared = len(moe.HELD_STATS) - 2
+    np.testing.assert_array_equal(stats[:shared], ragged_stats[:shared])
+    assert list(ragged_stats[shared:]) == [0, 0]          # no tiles there
+    stats = dict(zip(moe.HELD_STATS, np.asarray(stats)))
+    # 4 x 96 x 3 x 4/16 = 288 rows up to 512, and a tile a held expert
+    assert stats["tiles"] == 512 // 8 + 4
+    assert stats["rows_held"] / 8 <= stats["tiles_used"] \
+        <= stats["rows_held"] / 8 + 4 < stats["tiles"] / 2
+
+
 # -- the block: counts on the device, read when somebody asks -----------------
 def _block(held=(0, 1), experts=8):
     block = nh.NemotronHMoE(16, experts, 2, 24, 32,
@@ -266,6 +398,32 @@ def test_block_overflow_becomes_a_fallback_event_when_events_are_read():
     assert not [e for e in mx.telemetry.events("fallback")
                 if e["seq"] > new[0]["seq"]]
     assert mx.telemetry.snapshot()["moe.rows_overflow"] >= new[0]["rows"]
+
+
+def test_tiles_used_share_is_what_the_tile_arithmetic_gives(monkeypatch):
+    """Eight held experts of which two receive every row: 20 tokens, two
+    experts a token, tiles of 8 rows: 3 tiles each for the two, one each
+    for the six that own a tile and fill none, of a buffer of 256 rows and
+    a tile an expert: 12 of 40."""
+    from mxnet_tpu.gluon.model_zoo import sparse_experts as se
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "GROUP_TILE", 8)
+    monkeypatch.setattr(moe, "_grouped_platform", lambda: "tpu")
+    monkeypatch.setattr(se, "_LAYERS", [])          # this layer's alone
+    assert mx.telemetry.snapshot()["moe.tiles_used_share"] is None
+    block = nh.NemotronHMoE(128, 8, 2, 24, 32, routed_scaling_factor=SCALING)
+    block.initialize()
+    block.e_score_correction_bias.set_data(
+        mx.nd.array(np.array([10.0, 10.0] + [0.0] * 6, np.float32)))
+    x = mx.nd.array(np.random.default_rng(4).standard_normal((1, 20, 128)))
+    with mx.autograd.train_mode():
+        block(x)
+        block(x)
+    counts = dict(zip(moe.HELD_STATS, block.counts.data().asnumpy()))
+    assert counts["rows_held"] == 2 * 40 and counts["load_max"] == 20
+    assert counts["tiles_used"] == 2 * 12 and counts["tiles"] == 2 * 40
+    assert mx.telemetry.snapshot()["moe.tiles_used_share"] == 12 / 40
 
 
 def test_routed_part_and_shared_expert_are_separate():

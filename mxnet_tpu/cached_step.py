@@ -359,13 +359,16 @@ class TrainStep:
         _DEFERRED_READ.inc()
         scaler = getattr(self._trainer, "_amp_loss_scaler", None)
         if scaler is not None:
-            # graftlint: disable=host-sync -- the deliberate deferred AMP
-            # gate read at drain time, counted via count_host_sync above
-            overflow = not bool(prev)
-            if overflow:
-                _telemetry.event("amp_overflow", "cached_step",
-                                 where="drain")
-            scaler.update_scale(overflow)
+            with _telemetry.span("train_step.gate", cat="train_step",
+                                 args={"where": "drain"}):
+                # graftlint: disable=host-sync -- the deliberate deferred
+                # AMP gate read at drain time, counted via
+                # count_host_sync above
+                overflow = not bool(prev)
+                if overflow:
+                    _telemetry.event("amp_overflow", "cached_step",
+                                     where="drain")
+                scaler.update_scale(overflow)
 
     def __call__(self, *args, batch_size: Optional[int] = None):
         # train-step injection site (fail-fast like trainer.step: a step
@@ -373,9 +376,18 @@ class TrainStep:
         _faults.inject("cached_step.step")
         step_idx = _telemetry.next_step()
         with _telemetry.span("train_step.step", cat="train_step") as sp:
-            return self._call_inner(args, batch_size, step_idx, sp)
+            # the step's host phases (docs/OBSERVABILITY.md, "Host
+            # phases"): children of this span, one after the other
+            ph = _telemetry.phases("train_step")
+            ph.to("train_step.prep")
+            out = self._call_inner(args, batch_size, step_idx, sp, ph)
+            # closed here, not where the step's work ends: the last phase
+            # also holds the frames' teardown (the references to the
+            # donated buffers are dropped as _compiled_step returns)
+            ph.end()
+            return out
 
-    def _call_inner(self, args, batch_size, step_idx, sp):
+    def _call_inner(self, args, batch_size, step_idx, sp, ph):
         tr = self._trainer
         if batch_size is None:
             batch_size = int(args[0].shape[0]) \
@@ -390,6 +402,7 @@ class TrainStep:
                 _telemetry.event("fallback", "cached_step", reason=reason)
             self.last_fallback_reason = reason
             sp.annotate(path="eager", step=step_idx)
+            ph.drop()             # the eager tape has no phases
             return self._eager_step(args, batch_size)
         opt = tr._optimizer
         # host-side update-count bump BEFORE reading lrs (the eager order:
@@ -407,8 +420,9 @@ class TrainStep:
         if window_final:
             opt._update_count(list(indices))
         try:
-            out = self._compiled_step(pargs, batch_size)
+            out = self._compiled_step(pargs, batch_size, ph)
         except Exception as e:  # staging/trace failure -> sticky fallback
+            ph.drop()
             opt._index_update_count.clear()
             opt._index_update_count.update(count_snap[0])
             opt.num_update = count_snap[1]
@@ -836,7 +850,8 @@ class TrainStep:
                 rec = _pstore.build(
                     "train_step", jitted, lower_args,
                     meta=(out_struct, mutated_names),
-                    label=type(self._net).__name__)
+                    label=type(self._net).__name__,
+                    module="jit_" + jitted.__name__)    # _named
             self._programs.insert(sig, rec)
         return rec
 
@@ -967,13 +982,13 @@ class TrainStep:
                 jax.random.PRNGKey(0), list(g32), list(g32), list(g32),
                 f32, f32, f32, f32, prev_ok, want_dig)
 
-    def _compiled_step(self, args, batch_size):
+    def _compiled_step(self, args, batch_size, ph):
         from .gluon import block as _gb
         from .ndarray import ndarray as _ndmod
         from .optimizer import fused as _fused
 
         if self._accum_steps > 1:
-            return self._accum_compiled_step(args, batch_size)
+            return self._accum_compiled_step(args, batch_size, ph)
         tr = self._trainer
         in_leaves, in_struct = _gb._flatten_args(args)
         ctx = in_leaves[0].ctx if in_leaves else current_context()
@@ -1016,6 +1031,9 @@ class TrainStep:
         base = getattr(tr, "_amp_original_scale", tr._scale)
         rescale = base / (scale_val * batch_size)
         rescale_alt = base / (s_over * batch_size)
+        # from here every line makes or reads a device value: the small
+        # programs beside the step are launched in this phase
+        ph.to("train_step.operands")
         if self._pending_ok is not None:
             prev_ok = self._pending_ok
         elif mesh is not None:
@@ -1065,11 +1083,13 @@ class TrainStep:
             jnp.asarray(s_over, jnp.float32),
             jnp.asarray(rescale_alt, jnp.float32),
             prev_ok, want_arg)
+        ph.to("train_step.launch", program="step")
         rec = self._ensure_program(sig, prep, in_struct, ctx, flavor,
                                    call_args)
         out_struct, mutated_names = rec.meta
         with self._mesh_ctx(mesh):
             out_raw, mut_vals, new_w, new_s, ok, dig = rec(*call_args)
+        ph.to("train_step.writeback")
         if want_digest:
             # hand the UNREAD device fingerprint to the sentinel; it
             # consumes the previous pending one (deferred a full
@@ -1093,6 +1113,7 @@ class TrainStep:
         out_nd = [_ndmod._wrap(o, ctx, flavor) for o in out_raw]
         loss = _gb._rebuild_output(out_struct[0], out_nd)
         if scaler is not None:
+            ph.to("train_step.gate", where="deferred" if lag else "sync")
             if lag:
                 # deferred gate: hold THIS step's flag, read the
                 # PREVIOUS one (already materialized — its program
@@ -1165,7 +1186,7 @@ class TrainStep:
         self._accum_key = key
         self._accum_i = 0
 
-    def _accum_compiled_step(self, args, batch_size):
+    def _accum_compiled_step(self, args, batch_size, ph):
         """One microbatch of an accumulation window: dispatch the grad
         program (adds this microbatch's scaled grads into the donated
         accumulators); the window-FINAL microbatch also dispatches the
@@ -1211,6 +1232,7 @@ class TrainStep:
             s_clean = s_over = scaler.loss_scale
         else:
             s_clean = s_over = 1.0
+        ph.to("train_step.operands")
         if self._pending_ok is not None:
             prev_ok = self._pending_ok
         elif mesh is not None:
@@ -1232,11 +1254,13 @@ class TrainStep:
                   _random.next_key(),
                   jnp.asarray(s_clean, jnp.float32),
                   jnp.asarray(s_over, jnp.float32), prev_ok)
+        ph.to("train_step.launch", program="grad")
         grec = self._ensure_program(gsig, prep, in_struct, ctx, flavor,
                                     g_call, kind="grad")
         out_struct, mutated_names = grec.meta
         with self._mesh_ctx(mesh):
             out_raw, mut_vals, new_acc = grec(*g_call)
+        ph.to("train_step.writeback")
         self._accum_bufs = list(new_acc)
         for n, v in zip(mutated_names, mut_vals):
             prep.params[n]._data[0]._set_data(v)
@@ -1253,6 +1277,8 @@ class TrainStep:
         self._accum_i = 0
 
         # ---- window close: the ONE fused update dispatch ---------------
+        # (the same phases a second time: this call has two launches)
+        ph.to("train_step.prep")
         indices, group_layout = prep.indices, prep.group_layout
         counts = [opt._index_update_count[i] for i in indices]
         lrs = opt._get_lrs(list(indices))
@@ -1267,6 +1293,7 @@ class TrainStep:
         # equal one (accum × batch_size)-batch step's mean
         rescale = base / (scale_val * batch_size * accum)
         rescale_alt = base / (s_over * batch_size * accum)
+        ph.to("train_step.operands")
         lrs_g = [jnp.asarray([lrs[i] for i in m], jnp.float32)
                  for _mp, m in group_layout]
         wds_g = [jnp.asarray([wds[i] for i in m], jnp.float32)
@@ -1285,10 +1312,12 @@ class TrainStep:
                   jnp.asarray(rescale, jnp.float32),
                   jnp.asarray(rescale_alt, jnp.float32),
                   prev_ok, want_arg)
+        ph.to("train_step.launch", program="update")
         urec = self._ensure_program(usig, prep, None, ctx, flavor,
                                     u_call, kind="update")
         with self._mesh_ctx(mesh):
             new_w, new_s, new_acc, ok, dig = urec(*u_call)
+        ph.to("train_step.writeback")
         self._accum_bufs = list(new_acc)
         if want_digest:
             snt.offer(*dig)
@@ -1297,6 +1326,7 @@ class TrainStep:
         for s, ns in zip(prep.states, new_s):
             _fused._write(s, ns)
         if scaler is not None:
+            ph.to("train_step.gate", where="deferred" if lag else "sync")
             if lag:
                 prev = self._pending_ok
                 self._pending_ok = ok

@@ -35,8 +35,12 @@ from __future__ import annotations
 import contextlib
 import queue as _queue
 import threading
+import time
 import weakref
-from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional
+from collections import deque
+from typing import Any, Callable, Dict, Iterable, Iterator, Optional
+
+from . import telemetry as _telemetry
 
 __all__ = ["bulk", "set_bulk_size", "waitall", "engine_type", "is_naive",
            "prefetch", "DevicePrefetcher", "prefetch_depth",
@@ -213,6 +217,23 @@ def naive_sync(arrays) -> None:
 # device prefetch stage
 # ---------------------------------------------------------------------------
 
+# takes remembered for stats()' median: the gauge of a steady state, not
+# a history
+_AHEAD_WINDOW = 1024
+
+
+def _host_bytes(item) -> int:
+    """Bytes of a host batch's array leaves."""
+    if isinstance(item, (tuple, list)):
+        return sum(_host_bytes(x) for x in item)
+    if isinstance(item, dict):
+        return _host_bytes(list(item.values()))
+    nbytes = getattr(item, "nbytes", None)
+    if nbytes is None:
+        nbytes = getattr(getattr(item, "_data", None), "nbytes", 0)  # NDArray
+    return int(nbytes)
+
+
 def _default_transfer(item):
     """Host batch -> device NDArrays (the DataLoader._wrap staging
     contract: one device_put per array leaf)."""
@@ -325,7 +346,11 @@ class DevicePrefetcher:
         self._idle = threading.Event()  # no transfer in flight
         self._idle.set()
         self._staged = 0
-        self._ahead_samples: List[int] = []
+        # dispatch-ahead depth at each take: the count and the maximum
+        # for ever, the samples of the newest takes for the median
+        self._consumed = 0
+        self._max_ahead = 0
+        self._ahead_samples: "deque" = deque(maxlen=_AHEAD_WINDOW)
         self._done = False
         self._thread = threading.Thread(
             target=self._run, daemon=True, name=f"mxnet-{name}")
@@ -360,6 +385,7 @@ class DevicePrefetcher:
                     self._put(("end", None))
                     return
                 self._idle.clear()
+                t0 = time.time_ns()
                 try:
                     # transfer is pure (same host batch -> same device
                     # payload), so a transient device_put hiccup retries
@@ -367,8 +393,17 @@ class DevicePrefetcher:
                                              site="engine.prefetch")
                 finally:
                     self._idle.set()
+                # the thread's own time as spans (docs/OBSERVABILITY.md,
+                # "Host phases"): the transfer, then the wait for a free
+                # slot of the FIFO
+                t1 = time.time_ns()
+                _telemetry.record_span("input.transfer", "input", t0, t1,
+                                       args={"bytes": _host_bytes(item)})
                 self._staged += 1
+                t2 = time.time_ns()
                 self._put(("ok", out))
+                _telemetry.record_span("input.slot_wait", "input", t2,
+                                       time.time_ns())
         except BaseException as e:   # delivered in order, then stop
             self._put(("error", e))
         finally:
@@ -392,6 +427,8 @@ class DevicePrefetcher:
         # only takes that yielded a batch count toward the gauge (the
         # terminal end/error take is not a consume)
         self._ahead_samples.append(ahead)
+        self._consumed += 1
+        self._max_ahead = max(self._max_ahead, ahead)
         return val
 
     # -- lifecycle / introspection --------------------------------------
@@ -412,14 +449,16 @@ class DevicePrefetcher:
         self._done = True
 
     def stats(self) -> Dict[str, Any]:
-        samples = self._ahead_samples
+        samples = list(self._ahead_samples)
         # the first take races thread start-up; steady state is the rest
-        steady = sorted(samples[1:]) if len(samples) > 1 else sorted(samples)
+        if 1 < self._consumed <= _AHEAD_WINDOW:
+            samples = samples[1:]
+        steady = sorted(samples)
         return {
             "depth": self._depth,
             "staged": self._staged,
-            "consumed": len(samples),
-            "max_ahead": max(samples, default=0),
+            "consumed": self._consumed,
+            "max_ahead": self._max_ahead,
             "steady_ahead": steady[len(steady) // 2] if steady else 0,
         }
 

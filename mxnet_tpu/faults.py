@@ -253,7 +253,7 @@ def record_event(site: str, action: str, error: Optional[BaseException] = None,
     outside :func:`retry_call` — e.g. checkpoint-restore degradation —
     log through this too).  Every entry also mirrors onto the telemetry
     event bus (kind ``fault``) where it picks up the current train-step
-    index and monotonic timestamp — and, inside a request's
+    index and its timestamp on the spans' clock — and, inside a request's
     ``telemetry.trace_scope`` (``retry_call`` runs on the request's own
     thread, so a routed request's retries/deadlines inherit its scope
     ambiently), both copies stamp the request's ``trace_id``."""

@@ -240,7 +240,8 @@ class Trainer:
         index, and whether the step ran compiled or fell back eager."""
         from .. import telemetry as _telemetry
 
-        return _telemetry.spans(cat="train_step", limit=limit)
+        return _telemetry.spans(cat="train_step", limit=limit,
+                                name="train_step.step")
 
     # -- the step --------------------------------------------------------
     def step(self, batch_size, ignore_stale_grad=False):

@@ -91,6 +91,13 @@ def resume(profile_process="worker"):
     _PAUSED = False
 
 
+def collecting() -> bool:
+    """True while emitted events are kept (``set_state('run')``, not
+    paused): what a caller with a cost of its own asks before it builds
+    an event."""
+    return _RUNNING and not _PAUSED
+
+
 def ops_active() -> bool:
     """True when imperative op bracketing should record (the reference
     engine brackets every Push under kImperative mode,
@@ -107,11 +114,14 @@ def record_op(name: str, t0_ns: int, t1_ns: int) -> None:
 
 
 def _emit(name, cat, ph, ts=None, dur=None, args=None, flow_id=None):
+    """One chrome-trace event.  ``ts`` is microseconds of
+    ``time.time_ns``, the clock of ``telemetry``'s spans and of a
+    ``jax.profiler`` device trace, so the three line up."""
     if not _RUNNING or _PAUSED:
         return
     ev = {"name": name, "cat": cat, "ph": ph, "pid": os.getpid(),
           "tid": threading.get_ident(),
-          "ts": (time.perf_counter_ns() // 1000) if ts is None else ts}
+          "ts": (time.time_ns() // 1000) if ts is None else ts}
     if dur is not None:
         ev["dur"] = dur
     if args is not None:
@@ -172,13 +182,13 @@ class _DurationScope:
         self._t0 = None
 
     def start(self):
-        self._t0 = time.perf_counter_ns()
+        self._t0 = time.time_ns()
         return self
 
     def stop(self):
         if self._t0 is None:
             return
-        dur = (time.perf_counter_ns() - self._t0) // 1000
+        dur = (time.time_ns() - self._t0) // 1000
         _emit(self.name, self._cat, "X", ts=self._t0 // 1000, dur=dur)
         self._t0 = None
 
@@ -323,12 +333,12 @@ class StepTimeline:
 
         def __enter__(self):
             if self._tl._step_t0 is None:
-                self._tl._step_t0 = time.perf_counter_ns()
-            self._t0 = time.perf_counter_ns()
+                self._tl._step_t0 = time.time_ns()
+            self._t0 = time.time_ns()
             return self
 
         def __exit__(self, *exc):
-            t1 = time.perf_counter_ns()
+            t1 = time.time_ns()
             dur = t1 - self._t0
             self._tl.phase_ns[self._name] += dur
             self._tl._accounted_ns += dur
@@ -346,7 +356,7 @@ class StepTimeline:
     def step(self) -> None:
         """Close one step: everything not inside a phase() since the
         step began is the host-gap."""
-        now = time.perf_counter_ns()
+        now = time.time_ns()
         if self._step_t0 is not None:
             wall = now - self._step_t0
             gap = max(0, wall - self._accounted_ns)

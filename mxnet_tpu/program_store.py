@@ -222,7 +222,14 @@ class Namespace:
         self._c["compile_seconds"] = _telemetry.counter(
             f"program_store.{name}.compile_seconds",
             f"ProgramStore namespace {name!r}: wall-clock building "
-            "programs", kind="time", family="program_store.namespace")
+            "programs (tracing, and compiling or loading)", kind="time",
+            family="program_store.namespace")
+        self._c["trace_seconds"] = _telemetry.counter(
+            f"program_store.{name}.trace_seconds",
+            f"ProgramStore namespace {name!r}: the part of "
+            "compile_seconds spent tracing and lowering (jitted.lower); "
+            "the rest is XLA's compile or the disk cache's load",
+            kind="time", family="program_store.namespace")
         # weakrefs, not strong refs: a dropped owner (a dead TrainStep,
         # a closed engine) must release its programs' HBM
         self._scopes: list = []
@@ -290,6 +297,7 @@ class Namespace:
             "load_degrades": self.load_degrades,
             "compile_count": self.compile_count,
             "compile_seconds": round(self.compile_seconds, 3),
+            "trace_seconds": round(self.trace_seconds, 3),
         }
 
 
@@ -303,7 +311,7 @@ def _ns_prop(field):
     return property(_get, _set)
 
 
-for _f in _NS_FIELDS + ("compile_seconds",):
+for _f in _NS_FIELDS + ("compile_seconds", "trace_seconds"):
     setattr(Namespace, _f, _ns_prop(_f))
 del _f
 
@@ -628,8 +636,61 @@ def _persistent_entry_involved(e: BaseException) -> bool:
         for fr, _ in traceback.walk_tb(e.__traceback__))
 
 
+def _lower_and_compile(jitted, lower_args: Tuple):
+    """``(jitted.lower(*lower_args).compile(), seconds of the lowering)``
+    as the two spans it is: ``program.trace`` (jax traces the Python body
+    and lowers it to StableHLO: host work no cache saves) and
+    ``program.compile`` (XLA compiles, or the persistent cache hands the
+    executable back: ``cache`` says which, from the disk counters'
+    difference around it)."""
+    with _telemetry.span("program.trace", cat="program"):
+        t0 = time.perf_counter()
+        lowered = jitted.lower(*lower_args)
+        trace_s = time.perf_counter() - t0
+    with _telemetry.span("program.compile", cat="program") as sp:
+        disk = dict(_DISK)
+        try:
+            return lowered.compile(), trace_s
+        finally:
+            hits = _DISK["hits"] - disk["hits"]
+            misses = _DISK["misses"] - disk["misses"]
+            sp.annotate(
+                cache="hit" if hits and not misses
+                else "miss" if misses else "off",
+                retrieval_s=_DISK["retrieval_s"] - disk["retrieval_s"])
+
+
+def _executable(ns: Namespace, name: str, jitted, lower_args: Tuple,
+                label: str):
+    """:func:`_lower_and_compile` through the ``program_store.load``
+    site."""
+    try:
+        _faults.inject("program_store.load")
+        with _loud_cache_errors():
+            return _lower_and_compile(jitted, lower_args)
+    except Exception as e:
+        live_dir = persistent_cache_dir()
+        if live_dir is None or not _persistent_entry_involved(e):
+            # no persistent entry was in play: a real trace/compile
+            # failure (a forward that cannot stage, a Mosaic
+            # refusal, an HBM OOM) — it propagates, never retried
+            # into a second identical failure
+            raise
+        ns.bump("load_degrades")
+        _faults.record_event(
+            "program_store.load", "degrade_to_recompile", e,
+            namespace=name, label=label, cache_dir=live_dir)
+        # bypass the (possibly corrupt) disk entry and compile
+        # fresh; the cache comes back for every later program
+        try:
+            jax.config.update("jax_compilation_cache_dir", None)
+            return _lower_and_compile(jitted, lower_args)
+        finally:
+            jax.config.update("jax_compilation_cache_dir", live_dir)
+
+
 def build(name: str, jitted, lower_args: Tuple, meta: Any = None,
-          label: str = "") -> Program:
+          label: str = "", module: str = "") -> Program:
     """Trace + compile ``jitted`` for ``lower_args`` (concrete arrays
     and/or ``jax.ShapeDtypeStruct`` specs — the latter is what makes
     warm-up from abstract shapes possible) into a :class:`Program`.
@@ -639,36 +700,26 @@ def build(name: str, jitted, lower_args: Tuple, meta: Any = None,
     unreadable entry (or an injected fault) degrades LOUDLY to a fresh
     compile with the disk cache bypassed for this program — recorded in
     ``load_degrades`` + the faults event log, never a crash.  Any other
-    trace/compile failure propagates to the caller untouched."""
+    trace/compile failure propagates to the caller untouched.
+
+    Recorded as the span ``program.build`` (cat ``program``; ``module``
+    is the HLO module's name where the caller named the function) with
+    ``program.trace`` and ``program.compile`` as its children; a build
+    inside a train step is a child of that step's ``train_step.launch``
+    (docs/OBSERVABILITY.md, "Host phases").  A build that raises counts
+    no seconds; a degraded one counts both attempts in
+    ``compile_seconds`` and the fresh attempt's lowering in
+    ``trace_seconds``."""
     ns = namespace(name)
-    t0 = time.perf_counter()
-    executable = None
-    if _aot_enabled():
-        try:
-            _faults.inject("program_store.load")
-            with _loud_cache_errors():
-                executable = jitted.lower(*lower_args).compile()
-        except Exception as e:
-            live_dir = persistent_cache_dir()
-            if live_dir is None or not _persistent_entry_involved(e):
-                # no persistent entry was in play: a real trace/compile
-                # failure (a forward that cannot stage, a Mosaic
-                # refusal, an HBM OOM) — it propagates, never retried
-                # into a second identical failure
-                raise
-            ns.bump("load_degrades")
-            _faults.record_event(
-                "program_store.load", "degrade_to_recompile", e,
-                namespace=name, label=label, cache_dir=live_dir)
-            # bypass the (possibly corrupt) disk entry and compile
-            # fresh; the cache comes back for every later program
-            try:
-                jax.config.update("jax_compilation_cache_dir", None)
-                executable = jitted.lower(*lower_args).compile()
-            finally:
-                jax.config.update("jax_compilation_cache_dir", live_dir)
-    ns.bump("compile_count")
-    ns.bump("compile_seconds", time.perf_counter() - t0)
+    with _telemetry.span("program.build", cat="program", args={
+            "namespace": name, "label": label, "module": module}):
+        t0 = time.perf_counter()
+        executable, trace_s = \
+            _executable(ns, name, jitted, lower_args, label) \
+            if _aot_enabled() else (None, 0.0)
+        ns.bump("compile_count")
+        ns.bump("compile_seconds", time.perf_counter() - t0)
+        ns.bump("trace_seconds", trace_s)
     return Program(executable, jitted, meta, ns)
 
 

@@ -409,9 +409,10 @@ class ServingEngine:
             _telemetry.event("retire", self._stats.prefix, rows=req.rows)
         # request lifecycle span (admit -> dispatch -> deliver): the
         # serving leg of the unified chrome-trace timeline
+        off = _telemetry.monotonic_offset_ns()     # to the spans' clock
         _telemetry.record_span(
             "serving.request", "serving",
-            int(req.t_enqueue * 1e9), int(req.t_done * 1e9),
+            int(req.t_enqueue * 1e9) + off, int(req.t_done * 1e9) + off,
             args={"rows": req.rows, "engine": self._stats.prefix})
         return req.result
 

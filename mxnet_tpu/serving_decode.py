@@ -1322,9 +1322,10 @@ class GenerativeEngine:
                              tokens_out=len(req.out),
                              preempts=req.preempts)
         # request lifecycle span (admit -> prefill -> decode* -> retire)
+        off = _telemetry.monotonic_offset_ns()     # to the spans' clock
         _telemetry.record_span(
             "decode.request", "serving",
-            int(req.t_enqueue * 1e9), int(req.t_done * 1e9),
+            int(req.t_enqueue * 1e9) + off, int(req.t_done * 1e9) + off,
             args={"model": self.name, "tokens_out": len(req.out),
                   "preempts": req.preempts})
         return list(req.out)

@@ -742,8 +742,10 @@ class ReplicaRouter:
         if trace_id is not None:
             _telemetry.event("retire", self.name, label=label,
                              attempts=req.attempt, hedged=req.hedged)
+        off = _telemetry.monotonic_offset_ns()     # to the spans' clock
         _telemetry.record_span(
-            "router.request", "serving", int(t0 * 1e9), int(t1 * 1e9),
+            "router.request", "serving", int(t0 * 1e9) + off,
+            int(t1 * 1e9) + off,
             args={"router": self.name, "label": label,
                   "attempts": req.attempt, "hedged": req.hedged})
         return result

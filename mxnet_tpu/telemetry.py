@@ -26,14 +26,22 @@ those measurements ONE queryable, exportable system:
   :func:`events`) of runtime *happenings*: retrace, fallback, shed,
   preempt, cache evict, AMP overflow, and every fault-site action
   (``faults.record_event`` mirrors here), each stamped with the current
-  train-step index and a monotonic timestamp.  Capacity:
-  ``MXNET_TELEMETRY_EVENTS``.
+  train-step index and a ``time.time_ns`` timestamp (the one clock of
+  this module, and the clock a ``jax.profiler`` device trace counts
+  from).  Capacity: ``MXNET_TELEMETRY_EVENTS``.
 
 - **Spans** — duration records (:func:`span` context manager /
-  :func:`record_span` post-hoc) unifying ``profiler.StepTimeline``
-  phases, the compiled train step, serving request admit→dispatch→retire
-  lifecycles, and decode iterations into one chrome-trace timeline:
-  completed spans land in the profiler's trace buffer (the existing
+  :func:`phases` for the consecutive parts of one / :func:`record_span`
+  post-hoc) unifying ``profiler.StepTimeline`` phases, the compiled
+  train step and its host phases, program builds, the prefetcher's
+  transfers, serving request admit→dispatch→retire lifecycles, and
+  decode iterations into one chrome-trace timeline.  A record's
+  ``t0_ns`` / ``t1_ns`` are ``time.time_ns`` readings taken at the
+  boundary itself, so a span lies over a device trace by subtraction
+  of the trace's ``profile_start_time``; every record has an ``id`` and
+  the ``parent`` that caused it.  One bounded ring a category, so a
+  serving burst cannot push a train step's phases out.  Completed
+  spans land in the profiler's trace buffer (the existing
   ``profiler.dump`` pipe) and, under ``MXNET_TELEMETRY_XLA=1``, inside
   ``jax.profiler`` device traces via trace annotations.
 
@@ -69,6 +77,7 @@ hierarchy, trace-field schema, and how to add a counter.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -84,7 +93,8 @@ __all__ = [
     "Counter", "CounterGroup", "counter", "gauge", "gauge_fn", "get",
     "registered", "snapshot", "delta", "reset", "instance_name",
     "event", "events", "set_step", "current_step", "next_step",
-    "span", "record_span", "spans", "report", "flush",
+    "span", "phases", "record_span", "spans", "monotonic_offset_ns",
+    "report", "flush",
     "flight_recorder_path", "KINDS",
     "tracing_enabled", "new_trace_id", "trace_scope", "current_trace",
     "current_span_id", "trace", "merge", "merge_chrome_trace", "main",
@@ -369,7 +379,15 @@ def current_step() -> Optional[int]:
 # bare ServingEngine.infer / GenerativeEngine.generate); worker threads
 # re-enter with the explicit id stamped on the request object, so every
 # event and span a request touches — on any thread — carries one id.
-_TRACE = threading.local()
+class _Ambient(threading.local):
+    # class-level defaults: a thread that never entered a scope reads
+    # them at attribute speed (a missing thread-local attribute costs an
+    # exception inside getattr)
+    stack = None      # the request's frames (trace_scope)
+    cur = None        # id of the innermost span open on this thread
+
+
+_TRACE = _Ambient()
 
 _TRACES_MINTED = counter(
     "telemetry.traces_minted",
@@ -392,7 +410,7 @@ def new_trace_id() -> str:
 
 
 def _trace_stack() -> List:
-    st = getattr(_TRACE, "stack", None)
+    st = _TRACE.stack
     if st is None:
         st = _TRACE.stack = []
     return st
@@ -401,20 +419,29 @@ def _trace_stack() -> List:
 def current_trace() -> Optional[str]:
     """The ambient trace id on this thread, or None (one thread-local
     read — hot-path safe)."""
-    st = getattr(_TRACE, "stack", None)
+    st = _TRACE.stack
     return st[-1][0] if st else None
 
 
 def current_span_id() -> Optional[str]:
     """The ambient parent-span id on this thread, or None."""
-    st = getattr(_TRACE, "stack", None)
+    st = _TRACE.stack
     return st[-1][1] if st else None
 
 
 def _next_span_id() -> str:
-    with _LOCK:
-        _SPANS_SEQ[0] += 1
-        return f"s{_SPANS_SEQ[0]:x}"
+    # itertools.count: one atomic step, no lock on the step path
+    return f"s{next(_SPAN_IDS):x}"
+
+
+def _ambient_parent() -> Optional[str]:
+    """The span a record made now on this thread was caused by: the
+    request's frame inside a :class:`trace_scope`, else the innermost
+    :class:`span` / :class:`phases` part open on this thread."""
+    st = _TRACE.stack
+    if st and st[-1][1] is not None:
+        return st[-1][1]
+    return _TRACE.cur
 
 
 class trace_scope:
@@ -472,9 +499,10 @@ def trace(trace_id: str) -> Dict[str, Any]:
     stamped with ``trace_id`` plus every span that carries it (directly,
     or in its ``args.trace_ids`` list — decode iterations batch many
     requests into one dispatch), merged into one time-ordered
-    ``records`` list.  Events and spans share the monotonic clock, so
-    admission → dispatch attempts → prefill/decode iterations →
-    retire/shed come back in lifecycle order."""
+    ``records`` list.  Events and spans share one clock
+    (``time.time_ns``), so admission → dispatch attempts →
+    prefill/decode iterations → retire/shed come back in lifecycle
+    order."""
     evs = [e for e in events() if e.get("trace_id") == trace_id]
     sps = []
     for s in spans():
@@ -512,14 +540,15 @@ _RESERVED_EVENT_KEYS = ("kind", "name", "step", "t_us", "seq",
 def event(kind: str, name: str, /, step: Any = "auto", **fields) -> None:
     """Append one structured event: ``kind`` from the taxonomy, ``name``
     the subsystem/site, ``step`` the train-step index (default: the
-    current one), plus a monotonic microsecond timestamp.  Inside a
+    current one), plus a microsecond timestamp on the spans' clock
+    (``time.time_ns``).  Inside a
     request's :class:`trace_scope` the event additionally stamps
     ``trace_id`` (+ ``parent`` span id) — nothing otherwise.  Extra
     fields whose names collide with the bus keys are prefixed ``x_``."""
     ev: Dict[str, Any] = {
         "kind": kind, "name": name,
         "step": current_step() if step == "auto" else step,
-        "t_us": time.monotonic_ns() // 1000,
+        "t_us": time.time_ns() // 1000,
     }
     tid = current_trace()
     if tid is not None:
@@ -573,57 +602,61 @@ def clear_events() -> None:
 # ---------------------------------------------------------------------------
 # spans
 # ---------------------------------------------------------------------------
-_SPANS: "deque" = deque(maxlen=2048)
-_SPANS_SEQ = [0]          # span ids + flight-recorder flush cursor
+# One ring a category.  A record is a plain tuple (what the step path
+# pays for is one append); :func:`spans` builds the dicts when somebody
+# reads.  8,192 records a ring: a ``--trace 1`` run of the benchmark
+# keeps its traced steps and its timed window (about 110 steps of 6
+# ``train_step`` records, two ``input`` records a batch) several times
+# over, and a CPU rehearsal's faster steps too; a reader that needs an
+# older record than the ring holds must say so, never average what is
+# left (``perfbench/host_view.py``).
+_RING = 8192
+_SPANS: Dict[str, "deque"] = {}
+_SPAN_IDS = itertools.count(1)        # span ids
+_SPAN_SEQ = itertools.count(1)        # record order + flush cursor
 _SPANS_RECORDED = counter(
-    "telemetry.spans", "completed spans recorded (train_step / "
-    "step_phase / serving / decode / user categories)")
+    "telemetry.spans", "completed spans recorded (train_step / program "
+    "/ input / step_phase / serving / decode / user categories)")
 # trace ids whose chrome flow already emitted its "s" (start) arrow —
 # later spans of the same trace emit "t" (step) so the whole request
 # renders as ONE connected flow in chrome://tracing / Perfetto
 _FLOW_STARTED: set = set()
+_PROFILER = None                      # mxnet_tpu.profiler, on first use
+
+
+def monotonic_offset_ns() -> int:
+    """What to add to a ``time.monotonic`` reading (the clock deadlines
+    are kept on) to put it on the spans' clock.  Read where it is used,
+    never once at import: the wall clock is slewed."""
+    return time.time_ns() - time.monotonic_ns()
 
 
 def _flow_id(trace_id: str) -> int:
     return zlib.crc32(trace_id.encode()) & 0x7FFFFFFF
 
 
-def record_span(name: str, cat: str, t0_ns: int, t1_ns: int,
-                step: Any = "auto",
-                args: Optional[Dict[str, Any]] = None,
-                span_id: Optional[str] = None) -> Dict[str, Any]:
-    """Record one completed span post-hoc (the lifecycle spans whose
-    endpoints were timed elsewhere — serving admit→retire).  Also emits
-    into the profiler's chrome-trace buffer when collection is running,
-    so every span category lands in the one ``profiler.dump``
-    timeline.  Inside a request's :class:`trace_scope` the record
-    stamps ``trace_id`` / ``parent`` / its own ``id``, and the chrome
-    export additionally links it into the request's flow."""
-    rec = {
-        "name": name, "cat": cat,
-        "step": current_step() if step == "auto" else step,
-        "t0_us": t0_ns // 1000,
-        "dur_us": max((t1_ns - t0_ns) // 1000, 1),
-        "thread": threading.get_ident(),
-    }
-    tid = current_trace()
-    if tid is not None:
-        rec["trace_id"] = tid
-        rec["id"] = span_id if span_id is not None else _next_span_id()
-        parent = current_span_id()
-        if parent is not None and parent != rec["id"]:
-            rec["parent"] = parent
-    with _LOCK:
-        _SPANS_SEQ[0] += 1
-        rec["seq"] = _SPANS_SEQ[0]
-    if args:
-        rec["args"] = dict(args)
-    _SPANS_RECORDED.inc()
-    _SPANS.append(rec)
-    from . import profiler as _profiler
+def _ring(cat: str) -> "deque":
+    ring = _SPANS.get(cat)
+    if ring is None:
+        with _LOCK:
+            ring = _SPANS.setdefault(cat, deque(maxlen=_RING))
+    return ring
 
-    _profiler._emit(name, cat, "X", ts=rec["t0_us"], dur=rec["dur_us"],
-                    args=rec.get("args"))
+
+def _record(name, cat, t0_ns, t1_ns, step, args, sid, parent) -> None:
+    """One completed span into its category's ring and, when collection
+    runs, into the profiler's chrome-trace buffer."""
+    global _PROFILER
+    tid = current_trace()
+    _SPANS_RECORDED.inc()
+    _ring(cat).append((next(_SPAN_SEQ), name, cat, step, t0_ns, t1_ns,
+                       threading.get_ident(), sid, parent, tid, args))
+    if _PROFILER is None:
+        from . import profiler as _PROFILER
+    if not _PROFILER.collecting():
+        return
+    _PROFILER._emit(name, cat, "X", ts=t0_ns // 1000,
+                    dur=max((t1_ns - t0_ns) // 1000, 1), args=args)
     if tid is not None:
         # one request = one chrome flow: an "s" arrow from the trace's
         # first span, "t" steps through every later one
@@ -633,9 +666,38 @@ def record_span(name: str, cat: str, t0_ns: int, t1_ns: int,
             if len(_FLOW_STARTED) > 8192:
                 _FLOW_STARTED.clear()
                 _FLOW_STARTED.add(tid)
-        _profiler._emit(f"trace:{tid}", "flow", "s" if first else "t",
-                        ts=rec["t0_us"], flow_id=_flow_id(tid))
-    return rec
+        _PROFILER._emit(f"trace:{tid}", "flow", "s" if first else "t",
+                        ts=t0_ns // 1000, flow_id=_flow_id(tid))
+
+
+def _as_dict(rec) -> Dict[str, Any]:
+    seq, name, cat, step, t0, t1, thread, sid, parent, tid, args = rec
+    out = {"name": name, "cat": cat, "step": step,
+           "t0_ns": t0, "t1_ns": t1,
+           "t0_us": t0 // 1000, "dur_us": max((t1 - t0) // 1000, 1),
+           "thread": thread, "id": sid, "parent": parent, "seq": seq}
+    if tid is not None:
+        out["trace_id"] = tid
+    if args:
+        out["args"] = dict(args)
+    return out
+
+
+def record_span(name: str, cat: str, t0_ns: int, t1_ns: int,
+                step: Any = "auto",
+                args: Optional[Dict[str, Any]] = None) -> None:
+    """Record one completed span post-hoc (the lifecycle spans whose
+    endpoints were timed elsewhere — serving admit→retire), ``t0_ns`` /
+    ``t1_ns`` on ``time.time_ns``.  Also emits into the profiler's
+    chrome-trace buffer when collection is running, so every span
+    category lands in the one ``profiler.dump`` timeline.  The record's
+    ``parent`` is the span that caused it (:func:`_ambient_parent`);
+    inside a request's :class:`trace_scope` it stamps ``trace_id`` too
+    and the chrome export links it into the request's flow."""
+    _record(name, cat, t0_ns, t1_ns,
+            current_step() if step == "auto" else step,
+            dict(args) if args else None, _next_span_id(),
+            _ambient_parent())
 
 
 def _xla_annotations_on() -> bool:
@@ -646,11 +708,13 @@ class span:
     """Context-manager span: times the enclosed work, records it (see
     :func:`record_span`), and — with ``MXNET_TELEMETRY_XLA=1`` — wraps
     it in a ``jax.profiler`` trace annotation so the host-side bracket
-    shows up inside XLA device profiles.  Inside a request's
-    :class:`trace_scope` the span takes an id at entry and becomes the
-    ambient PARENT for everything recorded underneath it."""
+    shows up inside XLA device profiles.  It takes an id at entry and is
+    the ambient PARENT of everything recorded underneath it on this
+    thread (and, inside a request's :class:`trace_scope`, on the threads
+    the request's frame is carried to)."""
 
-    __slots__ = ("name", "cat", "args", "_t0", "_ann", "_sid", "_pushed")
+    __slots__ = ("name", "cat", "args", "_t0", "_ann", "_sid", "_pushed",
+                 "_parent", "_outer")
 
     def __init__(self, name: str, cat: str = "user",
                  args: Optional[Dict[str, Any]] = None):
@@ -670,10 +734,13 @@ class span:
         return self
 
     def __enter__(self) -> "span":
-        self._t0 = time.perf_counter_ns()
-        st = getattr(_TRACE, "stack", None)
+        self._t0 = time.time_ns()
+        self._sid = _next_span_id()
+        self._parent = _ambient_parent()
+        self._outer = _TRACE.cur
+        _TRACE.cur = self._sid
+        st = _TRACE.stack
         if st:
-            self._sid = _next_span_id()
             st.append((st[-1][0], self._sid))
             self._pushed = True
         if _xla_annotations_on():
@@ -697,25 +764,74 @@ class span:
             _trace_stack().pop()
             self._pushed = False
         if self._t0 is not None:
-            record_span(self.name, self.cat, self._t0,
-                        time.perf_counter_ns(), args=self.args,
-                        span_id=self._sid)
+            _TRACE.cur = self._outer
+            _record(self.name, self.cat, self._t0, time.time_ns(),
+                    current_step(), self.args, self._sid, self._parent)
             self._t0 = None
 
 
-def spans(cat: Optional[str] = None,
-          limit: Optional[int] = None) -> List[Dict[str, Any]]:
-    """Recent completed span records, oldest first (bounded buffer)."""
-    out = list(_SPANS)
+class phases:
+    """The consecutive parts of the span open on this thread, as its
+    children: ``to(name)`` ends the part that is open and starts the next
+    at the SAME reading of the clock (one reading a boundary; no gap and
+    no overlap between parts), ``end()`` closes the last.  While a part
+    is open it is the ambient parent, so what it causes (a
+    ``program.build`` under ``train_step.launch``) records it.  No
+    ``TraceAnnotation``: a part costs two tuple slots and an append.
+    ``drop()`` forgets the open part unrecorded (the step took another
+    path)."""
+
+    __slots__ = ("cat", "_parent", "_name", "_t0", "_sid", "_args")
+
+    def __init__(self, cat: str):
+        self.cat = cat
+        self._parent = _TRACE.cur
+        self._name = None
+
+    def _close(self, now: int) -> None:
+        if self._name is not None:
+            _record(self._name, self.cat, self._t0, now, current_step(),
+                    self._args, self._sid, self._parent)
+
+    def to(self, name: str, **args) -> None:
+        now = time.time_ns()
+        self._close(now)
+        self._name, self._t0, self._args = name, now, args or None
+        self._sid = _TRACE.cur = _next_span_id()
+
+    def end(self) -> None:
+        self._close(time.time_ns())
+        self.drop()
+
+    def drop(self) -> None:
+        self._name = None
+        _TRACE.cur = self._parent
+
+
+def _records(cat: Optional[str] = None) -> List[tuple]:
+    """One ring's tuples, or all rings' in the order they were recorded
+    (a tuple starts with its sequence number)."""
     if cat is not None:
-        out = [s for s in out if s["cat"] == cat]
+        return list(_SPANS.get(cat, ()))
+    return sorted(r for ring in list(_SPANS.values()) for r in list(ring))
+
+
+def spans(cat: Optional[str] = None, limit: Optional[int] = None,
+          name: Optional[str] = None) -> List[Dict[str, Any]]:
+    """Recent completed span records, oldest first: of one category's
+    ring, or of all rings merged in the order they were recorded;
+    ``name`` keeps one span name, ``limit`` the newest so many."""
+    recs = _records(cat)
+    if name is not None:
+        recs = [r for r in recs if r[1] == name]
     if limit is not None:
-        out = out[-int(limit):]
-    return out
+        recs = recs[-int(limit):]
+    return [_as_dict(r) for r in recs]
 
 
 def clear_spans() -> None:
-    _SPANS.clear()
+    for ring in list(_SPANS.values()):
+        ring.clear()
     _FLOW_STARTED.clear()
 
 
@@ -821,8 +937,8 @@ def flush(snapshot_too: bool = True,
             pending = [e for e in _EVENTS if e["seq"] > _FLUSH_SEQ[0]]
             if pending:
                 _FLUSH_SEQ[0] = pending[-1]["seq"]
-        pend_spans = [s for s in list(_SPANS)
-                      if s.get("seq", 0) > _SPAN_FLUSH_SEQ[0]]
+        pend_spans = [_as_dict(r) for r in _records()
+                      if r[0] > _SPAN_FLUSH_SEQ[0]]
         if pend_spans:
             _SPAN_FLUSH_SEQ[0] = pend_spans[-1]["seq"]
         # prior data lines survive the rewrite (meta + snapshot are
@@ -851,7 +967,7 @@ def flush(snapshot_too: bool = True,
             lines = lines[-cap:]
         meta = {"kind": "meta", "pid": os.getpid(),
                 "rank": _process_rank(),
-                "t_us": time.monotonic_ns() // 1000,
+                "t_us": time.time_ns() // 1000,
                 "counter_kinds": {n: m["kind"]
                                   for n, m in registered().items()}}
         directory = os.path.dirname(path)
@@ -864,7 +980,7 @@ def flush(snapshot_too: bool = True,
             if snapshot_too:
                 f.write(json.dumps({
                     "kind": "snapshot", "step": current_step(),
-                    "t_us": time.monotonic_ns() // 1000,
+                    "t_us": time.time_ns() // 1000,
                     "counters": snapshot()}) + "\n")
         os.replace(tmp, path)
         _rotate_shards(directory, keep=path)

@@ -754,10 +754,11 @@ def _measure_router() -> dict:
         h0 = _ndmod.host_sync_count()
         evs = _tel.events()
         e0 = evs[-1]["seq"] if evs else 0
-        sp0 = {id(s) for s in _tel.spans()}
+        sps = _tel.spans()          # built when read: told apart by seq
+        sp0 = sps[-1]["seq"] if sps else 0
         outs = [front.generate(p, max_new_tokens=5) for p in prompts]
         new_evs = [e for e in _tel.events() if e["seq"] > e0]
-        new_sps = [s for s in _tel.spans() if id(s) not in sp0]
+        new_sps = [s for s in _tel.spans() if s["seq"] > sp0]
         row = {"outs": outs,
                "dispatches": sd.dispatch_count() - d0,
                "retraces": sd.trace_count() - t0,

@@ -39,6 +39,7 @@ import numpy as onp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import telemetry as _telemetry
 from .random import _keep, _seed_words, keep_mask
 
 __all__ = ["flash_attention", "flash_attention_qkv", "flash_attention_gqa",
@@ -491,16 +492,64 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, dropout_p=0.0,
 # value is written.  A block is (block, head_dim) columns of one batch row:
 # head_dim must be whole 128-lane columns, one (32 query heads of 128 over 2
 # key-value heads: Nemotron-H) or several (20 over 20 at 256, a group of
-# one: latent attention, whose scores and values share the width).  A step
-# holds three (block, head_dim) operands and a float32 accumulator of that
-# shape, the dk/dv kernel four and two; Mosaic takes both widths at a block
-# of 512 (tests/test_gluon_bert_attention.py compiles them for a v5e).  The key blocks are a grid
-# dimension (accumulators in VMEM scratch): nothing of a whole sequence is
-# resident, so the length is bounded by HBM alone.  Blocks above the diagonal
-# are skipped, and their index maps repeat the block before, so nothing is
-# fetched for them.  The backward is two kernels: dq over (query head, query
-# block) with the key blocks inside, dk and dv over (key-value head, key
-# block) with the group's query heads and their query blocks inside.
+# one: latent attention, whose scores and values share the width).  Blocks
+# above the diagonal are never fetched.
+#
+# The forward holds three (block, head_dim) operands and a float32
+# accumulator of that shape a step, the key blocks a grid dimension: nothing
+# of a whole sequence is resident, so its length is bounded by HBM alone.
+#
+# The backward is ONE kernel where its accumulators fit ``_GQA_BWD_VMEM``
+# (``_gqa_bwd_resident``; both cells' shapes do: 10 MiB at 8,192 keys of 256
+# in groups of one, 12 MiB at 8,192 of 128 in groups of 16).  Its grid is
+# (batch, key-value head, head of the group, visible (key block, query
+# block) pair), the pairs key block by key block from two prefetched tables,
+# so a tile above the diagonal is no grid step at all.  A pair's ``(P, dS)``
+# is built once (``_bwd_tile``) and feeds all three gradients.  What is
+# resident, in float32 VMEM scratch: ``dq`` of the current head over the
+# WHOLE sequence, because a query block's gradient is revisited once a key
+# block; ``dk`` and ``dv`` of the current key block, or of the whole
+# sequence where a group's heads share them (they are revisited once a
+# head).  An output block is written exactly once, in the one run of steps
+# that maps to it: Mosaic writes an output block back when its index
+# changes and does NOT read it back on a later visit (the interpreter does,
+# so only the accumulators may be revisited).  ``dq`` of query block i is
+# complete at the diagonal pair (i, i), the first step of key block i, and
+# goes out under key block i's index; ``dk`` and ``dv`` of key block j are
+# complete at the group's last head's pair (j, last) and until that head
+# their output index rests on block 0, which nothing writes before.  The
+# scratch is over Mosaic's default scoped limit, so the call states
+# ``vmem_limit_bytes``.
+#
+# Past the budget (32,768 keys of 256: 34 MiB) the backward is the two
+# kernels it was before: dq over (query head, query block) with the key
+# blocks inside, dk and dv over (key-value head, key block) with the group's
+# query heads and their query blocks inside; every tile is then built twice.
+# Nothing of a whole sequence is resident there.  Which form a trace took is
+# counted (``attention.gqa_backward_fused`` / ``..._split``).
+
+# what the fused backward's float32 accumulators may take of VMEM, and what
+# Mosaic is told the call may use in all: the accumulators, the pipeline's
+# copies of nine (block, head_dim) blocks and a tile's float32 intermediates
+_GQA_BWD_VMEM = 24 << 20
+_GQA_BWD_VMEM_LIMIT = 64 << 20
+# the fused backward's block.  A tile's fixed costs (the grid step, three
+# accumulators read and written, nine blocks handed over) are paid once for
+# four times the work of the forward's 512.  On the v5e at 8,192 keys, the
+# delta reduction included (tools/gqa_backward_check.py; PERF.md, PR 36):
+# 20 heads of 256 in groups of one 12.3 ms a call against 14.1 at 512 and
+# 20.9 at 256 (the two kernels: 17.6); 32 heads of 128 over 2 11.0 against
+# 14.3 and 29.3 (17.2).  The forward keeps _BLOCK.
+_GQA_BWD_BLOCK = 1024
+
+_GQA_BWD_FUSED = _telemetry.counter(
+    "attention.gqa_backward_fused",
+    "backward passes of the grouped causal core traced as the one kernel "
+    "that builds a score tile once for dq, dk and dv")
+_GQA_BWD_SPLIT = _telemetry.counter(
+    "attention.gqa_backward_split",
+    "backward passes of the grouped causal core traced as the dq kernel and "
+    "the dk/dv kernel: the fused kernel's accumulators did not fit VMEM")
 
 
 def _gqa_visible(qi, ki):
@@ -587,6 +636,42 @@ def _gqa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _gqa_bwd_fused_kernel(kb_ref, qb_ref, q_ref, k_ref, v_ref, do_ref,
+                          lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+                          dk_acc, dv_acc, *, sm_scale, block, group):
+    g, t = pl.program_id(2), pl.program_id(3)
+    ki, qi = kb_ref[t], qb_ref[t]
+    # a group of one visits a key block's dk and dv in one run of steps
+    slot = ki if group > 1 else 0
+    pd, ds = _bwd_tile(None, 0, qi * block, ki * block, q_ref[...],
+                       k_ref[...], v_ref[...], do_ref[...], lse_ref[...],
+                       delta_ref[...], sm_scale=sm_scale, causal=True,
+                       dropout_p=0.0)
+
+    def add(acc, at, first, term):
+        @pl.when(first)
+        def _():
+            acc[at] = term
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            acc[at] += term
+
+    add(dq_acc, qi, ki == 0, _dot_nn(ds, k_ref[...]))
+    opens = (qi == ki) & (g == 0)
+    add(dk_acc, slot, opens, _dot_tn(ds, q_ref[...]))
+    add(dv_acc, slot, opens, _dot_tn(pd, do_ref[...]))
+
+    @pl.when(qi == ki)                      # no later key block is visible
+    def _():
+        dq_ref[...] = dq_acc[qi].astype(dq_ref.dtype)
+
+    @pl.when((qi == dq_acc.shape[0] - 1) & (g == group - 1))
+    def _():
+        dk_ref[...] = dk_acc[slot].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[slot].astype(dv_ref.dtype)
+
+
 def _gqa_call(kernel, grid, in_specs, out_specs, out_shape, scratch, *args):
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
@@ -633,13 +718,66 @@ def _gqa_forward(q, k, v, heads, kv_heads, sm_scale, block):
          pltpu.VMEM((block, 1), jnp.float32)], q, k, v)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
-def _gqa_backward(q, k, v, o, lse, do, heads, kv_heads, sm_scale, block):
+def _gqa_bwd_block(seq, block):
+    """Rows a block of the fused backward where the forward's is ``block``:
+    up to ``_GQA_BWD_BLOCK`` where the sequence is four of them or more
+    (with fewer, the masked half of the diagonal tiles costs what the
+    larger tile saves), else the forward's."""
+    wide = _divisor(seq, _GQA_BWD_BLOCK, multiple=16)
+    return wide if wide % 16 == 0 and 4 * wide <= seq else block
+
+
+def _gqa_bwd_resident(seq, d, group, block):
+    """Bytes the fused backward's float32 accumulators take of VMEM: ``dq``
+    of one head over the whole sequence, and ``dk`` and ``dv`` of one key
+    block, or of the whole sequence where a group's heads share them."""
+    return (seq + 2 * (seq if group > 1 else block)) * d * 4
+
+
+def _gqa_bwd_fused(q, k, v, do, lse, delta, heads, kv_heads, sm_scale, block):
     bsz, s, width = q.shape
     d, group, nq = width // heads, heads // kv_heads, s // block
-    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
-                    .reshape(bsz, s, heads, d), axis=-1) \
-        .transpose(0, 2, 1)[..., None]                   # (bsz, heads, s, 1)
+    # the visible (key block, query block) pairs, key block by key block
+    kb, qb = (t.astype(onp.int32) for t in onp.triu_indices(nq))
+
+    def head(h, g):
+        return h * group + g
+
+    q_spec = pl.BlockSpec(
+        (None, block, d), lambda b, h, g, t, kb, qb: (b, qb[t], head(h, g)))
+    k_spec = pl.BlockSpec(
+        (None, block, d), lambda b, h, g, t, kb, qb: (b, kb[t], h))
+    stat_spec = pl.BlockSpec(
+        (None, None, block, 1),
+        lambda b, h, g, t, kb, qb: (b, head(h, g), qb[t], 0))
+    dq_spec = pl.BlockSpec(
+        (None, block, d), lambda b, h, g, t, kb, qb: (b, kb[t], head(h, g)))
+    dk_spec = pl.BlockSpec(
+        (None, block, d),
+        lambda b, h, g, t, kb, qb: (b, jnp.where(g == group - 1, kb[t], 0), h))
+    kv_acc = pltpu.VMEM((nq if group > 1 else 1, block, d), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_gqa_bwd_fused_kernel, sm_scale=sm_scale,
+                          block=block, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(bsz, kv_heads, group, len(kb)),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, stat_spec, stat_spec],
+            out_specs=[dq_spec, dk_spec, dk_spec],
+            scratch_shapes=[pltpu.VMEM((nq, block, d), jnp.float32), kv_acc,
+                            kv_acc]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_GQA_BWD_VMEM_LIMIT),
+        interpret=_interpret())(kb, qb, q, k, v, do, lse, delta)
+
+
+def _gqa_bwd_split(q, k, v, do, lse, delta, heads, kv_heads, sm_scale, block):
+    bsz, s, width = q.shape
+    d, group, nq = width // heads, heads // kv_heads, s // block
     q_spec, k_spec, stat_spec = _gqa_specs(heads, kv_heads, d, block)
     kw = dict(sm_scale=sm_scale, block=block)
     dq = _gqa_call(
@@ -674,6 +812,17 @@ def _gqa_backward(q, k, v, o, lse, do, heads, kv_heads, sm_scale, block):
     return dq, dk, dv
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+def _gqa_backward(q, k, v, o, lse, do, heads, kv_heads, sm_scale, block,
+                  fused):
+    bsz, s, width = q.shape
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(bsz, s, heads, width // heads), axis=-1) \
+        .transpose(0, 2, 1)[..., None]                   # (bsz, heads, s, 1)
+    return (_gqa_bwd_fused if fused else _gqa_bwd_split)(
+        q, k, v, do, lse, delta, heads, kv_heads, sm_scale, block)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_gqa(q, k, v, heads, kv_heads, sm_scale, block):
     return _gqa_forward(q, k, v, heads, kv_heads, sm_scale, block)[0]
@@ -685,7 +834,21 @@ def _flash_gqa_fwd(q, k, v, heads, kv_heads, sm_scale, block):
 
 
 def _flash_gqa_bwd(heads, kv_heads, sm_scale, block, res, do):
-    return _gqa_backward(*res, do, heads, kv_heads, sm_scale, block)
+    _, s, width = res[0].shape
+    d = width // heads
+    fused_block = _gqa_bwd_block(s, block)
+    resident = _gqa_bwd_resident(s, d, heads // kv_heads, fused_block)
+    fused = resident <= _GQA_BWD_VMEM
+    if fused:
+        _GQA_BWD_FUSED.inc()
+        block = fused_block
+    else:
+        _GQA_BWD_SPLIT.inc()
+        _telemetry.event(
+            "fallback", "attention.gqa_backward_fused", seq=s, head_dim=d,
+            why=f"{resident} bytes of float32 accumulators do not fit the "
+                f"{_GQA_BWD_VMEM} the fused backward may keep in VMEM")
+    return _gqa_backward(*res, do, heads, kv_heads, sm_scale, block, fused)
 
 
 _flash_gqa.defvjp(_flash_gqa_fwd, _flash_gqa_bwd)

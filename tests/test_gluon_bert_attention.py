@@ -453,23 +453,58 @@ def test_v5e_resnet_stem_is_recomputed_and_no_activation_is_float32(one_chip):
     assert not readers, readers
 
 
-def test_mosaic_compiles_the_grouped_causal_kernels(one_chip, monkeypatch):
-    """``flash_attention_gqa`` at the state-space cell's widths (32 query
-    heads over 2 key-value heads of 128, bf16) at a quarter of its 8,192
-    keys, forward and backward: three Mosaic calls, and the two key-value
-    heads go in as they are."""
-    monkeypatch.setattr(pk, "_interpret", lambda: False)
-    q = jax.ShapeDtypeStruct((1, 2048, 4096), jnp.bfloat16,
+def _grouped_causal_text(one_chip, seq, heads, kv_heads, d):
+    """The compiled text of ``flash_attention_gqa``'s forward and backward
+    at bf16 over ``seq`` keys."""
+    q = jax.ShapeDtypeStruct((1, seq, heads * d), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, 2048, 256), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((1, seq, kv_heads * d), jnp.bfloat16,
                               sharding=one_chip)
 
     def loss(q, k, v):
-        return pk.flash_attention_gqa(q, k, v, 32, 2) \
+        return pk.flash_attention_gqa(q, k, v, heads, kv_heads) \
             .astype(jnp.float32).sum()
 
-    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    return _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+
+# a quarter of the cells' 8,192 keys, and all of them: the fused backward
+# keeps a head's dq over the WHOLE sequence in VMEM, so only the full length
+# shows that Mosaic takes it
+@pytest.mark.parametrize("seq", [2048, 8192])
+def test_mosaic_compiles_the_grouped_causal_kernels(one_chip, monkeypatch,
+                                                    seq):
+    """``flash_attention_gqa`` at the state-space cell's widths (32 query
+    heads over 2 key-value heads of 128, bf16), forward and backward: two
+    Mosaic calls, the forward and the ONE backward kernel (in blocks of
+    1,024 at 8,192 keys, 12 MiB of float32 accumulators: dq of a head, dk
+    and dv of a key-value head that 16 heads share), and the two key-value
+    heads go in as they are."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    assert pk._gqa_bwd_block(8192, 512) == 1024
+    assert pk._gqa_bwd_resident(8192, 128, 16, 1024) == 12 << 20
+    text = _grouped_causal_text(one_chip, seq, 32, 2, 128)
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("heads,kv_heads,d", [(4, 4, 256), (32, 2, 128)],
+                         ids=["group_of_one", "grouped"])
+def test_mosaic_compiles_the_split_backward_past_the_budget(
+        one_chip, monkeypatch, heads, kv_heads, d):
+    """32,768 keys: the fused backward's accumulators (34 MiB at heads of
+    256 in groups of one, 48 MiB at 128 in groups of 16) do not fit
+    ``_GQA_BWD_VMEM``, so the backward is the dq kernel and the dk/dv
+    kernel, three Mosaic calls with the forward, and says so."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    seq = 32768
+    assert pk._gqa_bwd_resident(seq, d, heads // kv_heads, 1024) \
+        > pk._GQA_BWD_VMEM
+    base = telemetry.snapshot()
+    text = _grouped_causal_text(one_chip, seq, heads, kv_heads, d)
     assert text.count("tpu_custom_call") == 3
+    moved = telemetry.delta(base)
+    assert (moved["attention.gqa_backward_fused"],
+            moved["attention.gqa_backward_split"]) == (0, 1)
 
 
 def _grouped_products_text(one_chip, tiles, k, n, fn=jax.value_and_grad):
@@ -527,8 +562,9 @@ def test_mosaic_compiles_the_grouped_products_in_blocks(one_chip,
 def test_mosaic_compiles_the_latent_cells_kernels(one_chip, monkeypatch):
     """The latent-attention cell's two kernels at its widths, bf16, forward
     and backward: ``flash_attention_gqa`` at 20 = 20 heads of 256 (blocks,
-    scratch and both backward kernels at twice the width it had run at) at a
-    quarter of the 8,192 keys, and ``grouped_matmul`` at the gated experts'
+    scratch and the one backward kernel at twice the width it had run at,
+    10 MiB of float32 accumulators) at a quarter of the 8,192 keys and at all
+    of them, two Mosaic calls, and ``grouped_matmul`` at the gated experts'
     widths (8 experts, 2048 to gate and up side by side, 3072; 1536 back),
     every group's whole matrix resident."""
     monkeypatch.setattr(pk, "_interpret", lambda: False)
@@ -536,11 +572,14 @@ def test_mosaic_compiles_the_latent_cells_kernels(one_chip, monkeypatch):
     def spec(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    qkv = spec((1, 2048, 20 * 256))
-    text = _compiled_text(jax.grad(
-        lambda q, k, v: pk.flash_attention_gqa(q, k, v, 20, 20)
-        .astype(jnp.float32).sum(), argnums=(0, 1, 2)), qkv, qkv, qkv)
-    assert text.count("tpu_custom_call") == 3
+    assert pk._gqa_bwd_resident(8192, 256, 1, 1024) == 10 << 20
+    base = telemetry.snapshot()
+    for seq in (2048, 8192):
+        text = _grouped_causal_text(one_chip, seq, 20, 20, 256)
+        assert text.count("tpu_custom_call") == 2
+    moved = telemetry.delta(base)
+    assert (moved["attention.gqa_backward_fused"],
+            moved["attention.gqa_backward_split"]) == (2, 0)
     # the gated experts' buffer of 17,408 rows; the matrices' gradient of the
     # 2048 x 3072 product is exactly what the budget holds
     for k, n in ((2048, 3072), (1536, 2048)):

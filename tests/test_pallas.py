@@ -346,24 +346,34 @@ def test_every_public_kernel_has_a_caller(name):
     assert users, f"pallas_kernels.{name} has no caller in mxnet_tpu/"
 
 
-def test_grouped_causal_kernels_at_twenty_equal_heads_of_256(monkeypatch):
+@pytest.mark.parametrize("kv_heads", [20, 5], ids=["group_of_one", "grouped"])
+@pytest.mark.parametrize("seq", [48, 16, 80])
+def test_grouped_causal_kernels_at_twenty_equal_heads_of_256(
+        seq, kv_heads, monkeypatch):
     """``flash_attention_gqa`` as latent attention runs it: as many
     key-value heads as query heads (a group of one), heads of 256 (two
-    128-lane columns a block), several blocks a sequence; values and the
-    three gradients against the unfused expression, Pallas interpreter."""
+    128-lane columns a block), and the same width in groups of four (a
+    group's heads share dk and dv); one, three and five blocks a sequence,
+    so the one backward kernel revisits a query block's dq after other
+    blocks ran; values and the three gradients against the unfused
+    expression, Pallas interpreter."""
     from mxnet_tpu.ops import contrib
 
     monkeypatch.setattr(pk, "_BLOCK", 16)
-    heads, d, seq = 20, 256, 48
+    heads, d = 20, 256
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
-    q, k, v, ct = (jax.random.normal(key, (1, seq, heads * d)) * 0.3
-                   for key in ks)
-    got = pk.flash_attention_gqa(q, k, v, heads, heads)
-    want = contrib._unfused_causal_gqa(q, k, v, heads, heads)
+    q, ct = (jax.random.normal(key, (1, seq, heads * d)) * 0.3
+             for key in ks[:2])
+    k, v = (jax.random.normal(key, (1, seq, kv_heads * d)) * 0.3
+            for key in ks[2:])
+    got = pk.flash_attention_gqa(q, k, v, heads, kv_heads)
+    want = contrib._unfused_causal_gqa(q, k, v, heads, kv_heads)
     onp.testing.assert_allclose(got, want, atol=2e-5)
+    base = mx.telemetry.snapshot()
     grads = jax.grad(lambda *a: jnp.sum(pk.flash_attention_gqa(
-        *a, heads, heads) * ct), argnums=(0, 1, 2))(q, k, v)
+        *a, heads, kv_heads) * ct), argnums=(0, 1, 2))(q, k, v)
+    assert mx.telemetry.delta(base)["attention.gqa_backward_fused"] == 1
     wants = jax.grad(lambda *a: jnp.sum(contrib._unfused_causal_gqa(
-        *a, heads, heads) * ct), argnums=(0, 1, 2))(q, k, v)
+        *a, heads, kv_heads) * ct), argnums=(0, 1, 2))(q, k, v)
     for g, w in zip(grads, wants):
         onp.testing.assert_allclose(g, w, atol=5e-5)

@@ -372,22 +372,83 @@ def _qkv(b, s, heads, kv_heads, d, seed=0):
             jax.random.normal(ks[3], (b, s, heads * d)))
 
 
-@pytest.mark.parametrize("block", [16, 32, 64])
-def test_grouped_causal_kernels_equal_an_explicit_mask(block, monkeypatch):
-    """4 query heads a key-value head; several blocks a sequence, so the
-    skipped blocks above the diagonal and the clamped index maps are run."""
+@pytest.fixture(params=["fused", "split"])
+def backward_form(request, monkeypatch):
+    """Both forms of the grouped causal backward: the one kernel, and the dq
+    and dk/dv kernels a shape past the VMEM budget takes (forced here by a
+    budget of nothing)."""
+    if request.param == "split":
+        monkeypatch.setattr(pk, "_GQA_BWD_VMEM", 0)
+    return request.param
+
+
+# seq 64 at three blocks, then one, three and five blocks of 16: with three
+# and more a query block's dq is revisited after other blocks ran in between
+@pytest.mark.parametrize("heads,kv_heads", [(8, 2), (4, 4)],
+                         ids=["grouped", "group_of_one"])
+@pytest.mark.parametrize("block,seq", [(16, 64), (32, 64), (64, 64),
+                                       (16, 16), (16, 48), (16, 80),
+                                       (16, 128)])
+def test_grouped_causal_kernels_equal_an_explicit_mask(
+        block, seq, heads, kv_heads, backward_form, monkeypatch):
+    """4 query heads a key-value head (they share dk and dv) and a group of
+    one; several blocks a sequence, so the pairs above the diagonal, the
+    index maps and the accumulators a later key block revisits are run.
+    At 128 keys the fused backward takes four blocks of 32 behind a forward
+    of eight blocks of 16."""
     monkeypatch.setattr(pk, "_BLOCK", block)
-    heads, kv_heads = 8, 2
-    q, k, v, ct = _qkv(2, 64, heads, kv_heads, 16)
+    monkeypatch.setattr(pk, "_GQA_BWD_BLOCK", 2 * block)
+    assert pk._gqa_bwd_block(seq, block) == (32 if seq == 128 else block)
+    q, k, v, ct = _qkv(2, seq, heads, kv_heads, 16)
     got = pk.flash_attention_gqa(q, k, v, heads, kv_heads)
     want = _explicit_mask_attention(q, k, v, heads, kv_heads)
     np.testing.assert_allclose(got, want, atol=2e-5)
+    base = mx.telemetry.snapshot()
     grads = jax.grad(lambda *a: jnp.sum(pk.flash_attention_gqa(
         *a, heads, kv_heads) * ct), argnums=(0, 1, 2))(q, k, v)
+    moved = mx.telemetry.delta(base)
+    assert moved["attention.gqa_backward_" + backward_form] == 1
     wants = jax.grad(lambda *a: jnp.sum(_explicit_mask_attention(
         *a, heads, kv_heads) * ct), argnums=(0, 1, 2))(q, k, v)
     for g, w in zip(grads, wants):
         np.testing.assert_allclose(g, w, atol=5e-5)
+
+
+def test_grouped_causal_backward_counts_the_form_it_took(monkeypatch):
+    """A traced backward whose accumulators fit counts the fused kernel once
+    and the split form never, and the program holds ONE kernel call for dq,
+    dk and dv; past the budget the reverse, two calls, and a ``fallback``
+    event whose ``why`` holds the bytes that did not fit."""
+    monkeypatch.setattr(pk, "_BLOCK", 16)
+    heads, kv_heads, d, seq = 4, 2, 16, 48
+    q, k, v, ct = _qkv(1, seq, heads, kv_heads, d)
+
+    def trace():
+        base = mx.telemetry.snapshot()
+        seen = max((e["seq"] for e in mx.telemetry.events("fallback")),
+                   default=0)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+            pk.flash_attention_gqa(*a, heads, kv_heads) * ct),
+            argnums=(0, 1, 2)))(q, k, v).jaxpr
+        moved = mx.telemetry.delta(base)
+        return (len(list(_pallas_calls(jaxpr))) - 1,   # less the forward
+                moved["attention.gqa_backward_fused"],
+                moved["attention.gqa_backward_split"],
+                [e for e in mx.telemetry.events("fallback")
+                 if e["seq"] > seen])
+
+    # the fused backward's block at the cells' 8,192 keys and around them
+    assert [pk._gqa_bwd_block(n, 512) for n in (2048, 4096, 8192, 32768)] \
+        == [512, 1024, 1024, 1024]
+    resident = pk._gqa_bwd_resident(seq, d, heads // kv_heads, 16)
+    assert resident == 3 * seq * d * 4                 # dq, dk, dv: whole
+    assert pk._gqa_bwd_resident(seq, d, 1, 16) == (seq + 2 * 16) * d * 4
+    assert trace() == (1, 1, 0, [])
+    monkeypatch.setattr(pk, "_GQA_BWD_VMEM", resident - 1)
+    calls, fused, split, events = trace()
+    assert (calls, fused, split) == (2, 0, 1)
+    assert [e["name"] for e in events] == ["attention.gqa_backward_fused"]
+    assert str(resident) in events[0]["why"] and events[0]["head_dim"] == d
 
 
 def test_grouped_causal_kernels_write_no_copy_of_a_key():

@@ -126,6 +126,10 @@ FP16_FP32_FUNCS = [
     # rotary angles are float32 inside the operator, as are the latent
     # attention core's rotary key and softmax statistics
     "rope", "causal_latent_selfatt",
+    # the gated delta rule likewise: float32 decay sums, triangular inverse
+    # and carried state inside, the products in the activations' type; the
+    # L2 norm in front of it is float32 inside as the RMS norms are
+    "gated_delta_rule", "L2Norm",
     # activations / simple elementwise
     "Activation", "LeakyReLU", "relu", "sigmoid", "tanh", "softsign",
     "hard_sigmoid", "abs", "sign", "negative", "ceil", "floor", "rint",

@@ -19,11 +19,9 @@ from __future__ import annotations
 import math
 from typing import Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 
 from ... import amp, initializer
-from ... import random as _random
 from ...ndarray.ndarray import invoke
 from ..block import HybridBlock, remat_call
 from ..nn import Dense, Embedding, HybridSequential, RMSNorm
@@ -41,23 +39,6 @@ def _residual_out_init(rescale_layers):
     """``rescale_prenorm_residual``: the projections that write into the
     residual stream start smaller by ``1 / sqrt(2 x layers)``."""
     return initializer.Normal(_INIT_STD / math.sqrt(2 * rescale_layers))
-
-
-class _TimeStepBias(initializer.Initializer):
-    """``dt_bias`` as the public Mamba-2 code draws it: ``dt`` log-uniform
-    in [dt_min, dt_max], floored, and the bias its inverse softplus."""
-
-    def __init__(self, dt_min, dt_max, floor):
-        super().__init__(dt_min=dt_min, dt_max=dt_max, floor=floor)
-        self._range = (dt_min, dt_max, floor)
-
-    def _init_weight(self, _, arr):
-        dt_min, dt_max, floor = self._range
-        u = jax.random.uniform(_random.next_key(), arr.shape, jnp.float32)
-        dt = jnp.exp(u * (math.log(dt_max) - math.log(dt_min))
-                     + math.log(dt_min))
-        dt = jnp.maximum(dt, floor)
-        self._fill(arr, dt + jnp.log(-jnp.expm1(-dt)))
 
 
 class _ALog(initializer.Initializer):
@@ -103,7 +84,8 @@ class NemotronHMamba2Mixer(HybridBlock):
         self.conv_bias = Parameter("conv_bias", shape=(self._conv_dim,),
                                    init=initializer.Uniform(bound))
         self.dt_bias = Parameter("dt_bias", shape=(num_heads,),
-                                 init=_TimeStepBias(*time_step), wd_mult=0.0)
+                                 init=initializer.TimeStepBias(*time_step),
+                                 wd_mult=0.0)
         self.A_log = Parameter("A_log", shape=(num_heads,), init=_ALog(),
                                wd_mult=0.0)
         self.D = Parameter("D", shape=(num_heads,), init=initializer.One(),

@@ -27,6 +27,7 @@ __all__ = [
     "LayerNorm",
     "RMSNorm",
     "LatentAttention",
+    "GatedDeltaNet",
     "GroupNorm",
     "Embedding",
     "Flatten",
@@ -467,6 +468,109 @@ class LatentAttention(HybridBlock):
                       "theta": self._theta})
         with jax.named_scope("LatentOut"):
             return self.o_proj(out)
+
+
+class GatedDeltaNet(HybridBlock):
+    """Linear attention under the gated delta rule (Yang, Kautz and
+    Hatamizadeh 2024, arXiv:2412.06464; names follow the public
+    flash-linear-attention layer), for the ``held_heads`` of ``num_heads``:
+
+        q, k, v = silu(conv(q_proj x)), silu(conv(k_proj x)), silu(conv(v_proj x))
+        q = q / |q| / sqrt(key_dim),  k = k / |k|          a head
+        beta = sigmoid(b_proj x)       x 2 with ``allow_neg_eigval``
+        alpha = exp(-exp(A_log) * softplus(a_proj x + dt_bias))
+        o = gated_delta_rule(q, k, v, log alpha, beta)
+        y = o_proj(RMSNorm_head(o) * silu(g_proj x))
+
+    The convolutions are depthwise, causal and without bias; the norm is
+    over each head's values with ONE learned scale of ``value_dim``.  Every
+    projection is built for the held heads alone, and the result is their
+    part of ``o_proj``'s sum over heads: with every head held (the default)
+    it is the whole layer, and the parts of disjoint shares add up to it.
+    No bias anywhere."""
+
+    def __init__(self, hidden_size, num_heads, key_dim, value_dim,
+                 conv_kernel=4, chunk_size=64, allow_neg_eigval=False,
+                 epsilon=1e-5, held_heads=None, weight_initializer=None,
+                 out_initializer=None):
+        super().__init__()
+        from ... import initializer as init
+
+        self._held = held_head_ids(num_heads, held_heads)
+        held = len(self._held)
+        self._dims = (held, key_dim, value_dim)
+        self._chunk, self._eps = chunk_size, epsilon
+        self._beta_scale = 2.0 if allow_neg_eigval else 1.0
+
+        def proj(units, in_units=hidden_size, w=weight_initializer):
+            return Dense(units, use_bias=False, flatten=False,
+                         in_units=in_units, weight_initializer=w)
+
+        def conv(name, channels):
+            return Parameter(name, shape=(channels, conv_kernel),
+                             init=init.Uniform(conv_kernel ** -0.5))
+
+        self.q_proj = proj(held * key_dim)
+        self.k_proj = proj(held * key_dim)
+        self.v_proj = proj(held * value_dim)
+        self.g_proj = proj(held * value_dim)
+        self.a_proj, self.b_proj = proj(held), proj(held)
+        self.q_conv = conv("q_conv", held * key_dim)
+        self.k_conv = conv("k_conv", held * key_dim)
+        self.v_conv = conv("v_conv", held * value_dim)
+        self.A_log = Parameter("A_log", shape=(held,),
+                               init=init.LogUniform(0.0, 16.0), wd_mult=0.0)
+        self.dt_bias = Parameter("dt_bias", shape=(held,),
+                                 init=init.TimeStepBias(), wd_mult=0.0)
+        self.o_norm = Parameter("o_norm", shape=(value_dim,), init=init.One())
+        self.o_proj = proj(hidden_size, held * value_dim,
+                           out_initializer or weight_initializer)
+
+    @property
+    def held_heads(self):
+        return self._held
+
+    def forward(self, x):
+        ctx = x.ctx
+        held, key_dim, value_dim = self._dims
+        bsz, length = x.shape[0], x.shape[1]
+
+        def mixed(projected, weight, width, unit=None):
+            """A projection through its short convolution and SiLU, cut
+            into heads; with ``unit`` each head's vector is scaled to that
+            length (queries and keys)."""
+            y = invoke("causal_conv1d", [projected, weight.data(ctx)],
+                       {"activation": "silu"})
+            y = y.reshape((bsz, length, held, width))
+            return y if unit is None else invoke("L2Norm", [y],
+                                                 {"scale": unit})
+
+        q = mixed(self.q_proj(x), self.q_conv, key_dim, key_dim ** -0.5)
+        k = mixed(self.k_proj(x), self.k_conv, key_dim, 1.0)
+        v = mixed(self.v_proj(x), self.v_conv, value_dim)
+        rate = invoke("exp", [self.A_log.data(ctx)], {})
+        log_alpha = invoke("negative", [rate * invoke(
+            "softrelu", [self.a_proj(x) + self.dt_bias.data(ctx)], {})], {})
+        beta = invoke("sigmoid", [self.b_proj(x).astype("float32")], {}) \
+            * self._beta_scale
+        o = invoke("gated_delta_rule", [q, k, v, log_alpha, beta],
+                   {"chunk_size": self._chunk})
+        gate = self.g_proj(x).reshape((bsz, length, held, value_dim))
+        y = invoke("GatedRMSNorm", [o, gate, self.o_norm.data(ctx)],
+                   {"eps": self._eps, "norm_before_gate": True})
+        return self.o_proj(y.reshape((bsz, length, held * value_dim)))
+
+
+def held_head_ids(num_heads, held_heads):
+    """The ids of the heads a token mixer holds, checked: all of them when
+    ``held_heads`` is None."""
+    held = tuple(range(num_heads)) if held_heads is None \
+        else tuple(int(h) for h in held_heads)
+    if not held or len(set(held)) != len(held) \
+            or not all(0 <= h < num_heads for h in held):
+        raise ValueError(f"held_heads {held}: distinct ids of the "
+                         f"{num_heads} heads, at least one")
+    return held
 
 
 class GroupNorm(HybridBlock):

@@ -38,6 +38,8 @@ __all__ = [
     "MSRAPrelu",
     "Bilinear",
     "LSTMBias",
+    "TimeStepBias",
+    "LogUniform",
     "Mixed",
     "Load",
 ]
@@ -341,6 +343,41 @@ class LSTMBias(Initializer):
         num_hidden = int(arr.shape[0] / 4)
         bias[num_hidden : 2 * num_hidden] = self.forget_bias
         self._fill(arr, bias)
+
+
+@register
+class TimeStepBias(Initializer):
+    """A state-space or linear-attention mixer's ``dt_bias`` as the public
+    Mamba-2 code draws it: ``dt`` log-uniform in [dt_min, dt_max], floored,
+    and the bias its inverse softplus."""
+
+    def __init__(self, dt_min=0.001, dt_max=0.1, floor=1e-4):
+        super().__init__(dt_min=dt_min, dt_max=dt_max, floor=floor)
+        self._range = (dt_min, dt_max, floor)
+
+    def _init_weight(self, _, arr):
+        dt_min, dt_max, floor = self._range
+        u = jax.random.uniform(_random.next_key(), arr.shape, jnp.float32)
+        dt = jnp.exp(u * (math.log(dt_max) - math.log(dt_min))
+                     + math.log(dt_min))
+        dt = jnp.maximum(dt, floor)
+        self._fill(arr, dt + jnp.log(-jnp.expm1(-dt)))
+
+
+@register
+class LogUniform(Initializer):
+    """``log(u)``, ``u`` uniform in (low, high): a decay rate kept as its
+    logarithm (the gated delta rule's ``A_log``)."""
+
+    def __init__(self, low=0.0, high=16.0):
+        super().__init__(low=low, high=high)
+        self._range = (low, high)
+
+    def _init_weight(self, _, arr):
+        low, high = self._range
+        u = jax.random.uniform(_random.next_key(), arr.shape, jnp.float32,
+                               minval=low, maxval=high)
+        self._fill(arr, jnp.log(jnp.maximum(u, jnp.finfo(jnp.float32).tiny)))
 
 
 class Mixed:

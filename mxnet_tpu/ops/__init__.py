@@ -28,6 +28,7 @@ from . import np_extra as _np_extra  # noqa: F401
 from . import graph_sampling as _graph_sampling  # noqa: F401
 from . import ssm as _ssm  # noqa: F401
 from . import rotary as _rotary  # noqa: F401
+from . import delta_rule as _delta_rule  # noqa: F401
 from . import ref_aliases as _ref_aliases  # noqa: F401  (must be last;
 # contrib.quantization registers late — mxnet_tpu/__init__ re-applies)
 

@@ -227,9 +227,10 @@ def ssd_scan_sequential(x, dt, A, B, C, D):
 
 
 @register("causal_conv1d", num_inputs=3)
-def causal_conv1d(data, weight, bias, activation=None):
+def causal_conv1d(data, weight, bias=None, activation=None):
     """Depthwise causal convolution over a sequence: ``data`` (batch,
-    length, channels), ``weight`` (channels, kernel), ``bias`` (channels,):
+    length, channels), ``weight`` (channels, kernel), ``bias`` (channels,)
+    or none:
     ``out[t] = bias + sum_k weight[:, k] * data[t - (kernel - 1) + k]``,
     positions before the start read as zero.  ``activation='silu'`` applies
     ``x * sigmoid(x)`` to the result.  Computed as ``kernel`` shifted
@@ -243,8 +244,9 @@ def causal_conv1d(data, weight, bias, activation=None):
         padded = jnp.pad(data, ((0, 0), (kernel - 1, 0), (0, 0))) \
             .astype(_F32)
         w = weight.astype(_F32)
-        out = bias.astype(_F32) + sum(
-            padded[:, k:k + length] * w[:, k] for k in range(kernel))
+        out = sum(padded[:, k:k + length] * w[:, k] for k in range(kernel))
+        if bias is not None:
+            out = bias.astype(_F32) + out
         if activation == "silu":
             out = out * jax.nn.sigmoid(out)
         elif activation is not None:
@@ -267,12 +269,20 @@ def rms_norm(data, gamma, eps=1e-5):
 
 
 @register("GatedRMSNorm", num_inputs=3)
-def gated_rms_norm(data, gate, gamma, num_groups=1, eps=1e-5):
+def gated_rms_norm(data, gate, gamma, num_groups=1, eps=1e-5,
+                   norm_before_gate=False):
     """The Mamba-2 mixer's norm: ``data * silu(gate)``, then an RMS norm
     over each of ``num_groups`` equal groups of the last axis, times
-    ``gamma``.  float32 inside, ``data``'s type out."""
+    ``gamma``.  With ``norm_before_gate`` the order is the gated delta
+    rule's: the norm and ``gamma`` first, ``silu(gate)`` on the result.
+    float32 inside, ``data``'s type out."""
     g32 = gate.astype(_F32)
-    x = data.astype(_F32) * (g32 * jax.nn.sigmoid(g32))
+    silu = g32 * jax.nn.sigmoid(g32)
+    x = data.astype(_F32)
+    if not norm_before_gate:
+        x = x * silu
     grouped = x.reshape(x.shape[:-1] + (num_groups, -1))
-    return (_rms(grouped, eps).reshape(x.shape) * gamma.astype(_F32)) \
-        .astype(data.dtype)
+    out = _rms(grouped, eps).reshape(x.shape) * gamma.astype(_F32)
+    if norm_before_gate:
+        out = out * silu
+    return out.astype(data.dtype)

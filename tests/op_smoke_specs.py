@@ -98,6 +98,11 @@ SPECS = {
                       dict(activation="silu")),
     "RMSNorm": ([_f(4, 6), _f(6)], {}),
     "GatedRMSNorm": ([_f(4, 8), _f(4, 8), _f(8)], dict(num_groups=2)),
+    # the gated delta rule: 3 heads of keys of 8 and values of 12, two
+    # chunks and a part; log alpha at most 0, beta under 2
+    "gated_delta_rule": ([_f(2, 20, 3, 8) * 0.3, _f(2, 20, 3, 8) * 0.3,
+                          _f(2, 20, 3, 12), -_f(2, 20, 3), _f(2, 20, 3)],
+                         dict(chunk_size=8)),
     "held_experts": ([_f(10, 16), _f(8, 16), _f(8) * 0.05, _f(4, 16, 12),
                       _f(4, 12, 16)],
                      dict(held=(0, 1, 2, 3), k=2, scaling=2.5)),

@@ -589,6 +589,23 @@ def test_mosaic_compiles_the_latent_cells_kernels(one_chip, monkeypatch):
         assert " transpose(" not in both
 
 
+def test_mosaic_compiles_the_delta_rule_cells_core(one_chip, monkeypatch):
+    """``flash_attention_gqa`` as the delta-rule cell's full-attention layer
+    runs it on a tensor-parallel rank of two: 15 = 15 heads of 128 (a width
+    of 1,920), bf16, 8,192 keys, forward and the ONE backward kernel (5 MiB
+    of float32 accumulators: dq of a head over the sequence, dk and dv of
+    one key block of 1,024)."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    assert pk.gqa_block(8192) == 512 and pk._gqa_bwd_block(8192, 512) == 1024
+    assert pk._gqa_bwd_resident(8192, 128, 1, 1024) == 5 << 20
+    base = telemetry.snapshot()
+    text = _grouped_causal_text(one_chip, 8192, 15, 15, 128)
+    assert text.count("tpu_custom_call") == 2
+    moved = telemetry.delta(base)
+    assert (moved["attention.gqa_backward_fused"],
+            moved["attention.gqa_backward_split"]) == (1, 0)
+
+
 def test_mosaic_compiles_the_scan_kernels(one_chip, monkeypatch):
     """``ssd_scan``'s kernel path at the state-space cell's widths (64 heads
     of 64 in 8 groups, state 128, chunk 128, bf16) at a quarter of its 8,192

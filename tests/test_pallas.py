@@ -346,21 +346,24 @@ def test_every_public_kernel_has_a_caller(name):
     assert users, f"pallas_kernels.{name} has no caller in mxnet_tpu/"
 
 
-@pytest.mark.parametrize("kv_heads", [20, 5], ids=["group_of_one", "grouped"])
+@pytest.mark.parametrize("heads,d,kv_heads", [(20, 256, 20), (20, 256, 5),
+                                              (15, 128, 15)],
+                         ids=["group_of_one", "grouped", "fifteen_of_128"])
 @pytest.mark.parametrize("seq", [48, 16, 80])
-def test_grouped_causal_kernels_at_twenty_equal_heads_of_256(
-        seq, kv_heads, monkeypatch):
+def test_grouped_causal_kernels_at_the_decoder_cells_head_shapes(
+        seq, heads, d, kv_heads, monkeypatch):
     """``flash_attention_gqa`` as latent attention runs it: as many
     key-value heads as query heads (a group of one), heads of 256 (two
     128-lane columns a block), and the same width in groups of four (a
-    group's heads share dk and dv); one, three and five blocks a sequence,
+    group's heads share dk and dv); and as Olmo-Hybrid's full layer runs
+    it on a tensor-parallel rank of two: 15 = 15 heads of 128, a width of
+    1,920; one, three and five blocks a sequence,
     so the one backward kernel revisits a query block's dq after other
     blocks ran; values and the three gradients against the unfused
     expression, Pallas interpreter."""
     from mxnet_tpu.ops import contrib
 
     monkeypatch.setattr(pk, "_BLOCK", 16)
-    heads, d = 20, 256
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     q, ct = (jax.random.normal(key, (1, seq, heads * d)) * 0.3
              for key in ks[:2])

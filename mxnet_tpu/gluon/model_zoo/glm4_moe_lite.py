@@ -31,10 +31,11 @@ import math
 
 import jax
 
-from ... import amp, initializer
+from ... import initializer
 from ...ndarray.ndarray import invoke
-from ..block import HybridBlock, remat_call
+from ..block import HybridBlock
 from ..nn import Dense, Embedding, HybridSequential, LatentAttention, RMSNorm
+from .decoder_parts import GatedMLP, low_stream, run_layers
 from .sparse_experts import SparseExperts
 
 __all__ = ["Glm4MoeLiteMLP", "Glm4MoeLiteMoE", "Glm4MoeLiteDecoderLayer",
@@ -42,25 +43,9 @@ __all__ = ["Glm4MoeLiteMLP", "Glm4MoeLiteMoE", "Glm4MoeLiteDecoderLayer",
            "glm4_moe_lite"]
 
 
-class Glm4MoeLiteMLP(HybridBlock):
+class Glm4MoeLiteMLP(GatedMLP):
     """``down_proj(silu(gate x) * (up x))``; ``gate_up_proj`` gives gate and
     up side by side in one product."""
-
-    def __init__(self, hidden_size, intermediate_size, init, out_init):
-        super().__init__()
-        self._width = intermediate_size
-        self.gate_up_proj = Dense(2 * intermediate_size, use_bias=False,
-                                  flatten=False, in_units=hidden_size,
-                                  weight_initializer=init)
-        self.down_proj = Dense(hidden_size, use_bias=False, flatten=False,
-                               in_units=intermediate_size,
-                               weight_initializer=out_init)
-
-    def forward(self, x):
-        both = self.gate_up_proj(x)
-        gate = both.slice_axis(axis=-1, begin=0, end=self._width)
-        up = both.slice_axis(axis=-1, begin=self._width, end=None)
-        return self.down_proj(gate * invoke("sigmoid", [gate], {}) * up)
 
 
 class Glm4MoeLiteMoE(SparseExperts):
@@ -119,20 +104,6 @@ def _layer(c, dense, *, held, capacity, init, out_init):
     return Glm4MoeLiteDecoderLayer(h, attn, mlp, eps)
 
 
-def _low(x):
-    """The residual stream follows the activations' type under AMP."""
-    low = amp.target_dtype()
-    return x if low is None else x.astype(low)
-
-
-def _run(layers, x, recompute):
-    if not recompute:
-        return layers(x)
-    for layer in layers:
-        x = remat_call([layer], x)
-    return x
-
-
 class Glm4MoeLiteModel(HybridBlock):
     """Embedding, ``first_k_dense_replace`` dense layers, the sparse layers
     behind them, the final norm.  ``forward(tokens[B, S]) -> hidden``."""
@@ -150,8 +121,8 @@ class Glm4MoeLiteModel(HybridBlock):
                             in_channels=c["hidden_size"])
 
     def forward(self, tokens):
-        x = _low(self.embed_tokens(tokens))
-        return self.norm(_run(self.layers, x, self._recompute))
+        x = low_stream(self.embed_tokens(tokens))
+        return self.norm(run_layers(self.layers, x, self._recompute))
 
 
 class Glm4MoeLiteMTP(HybridBlock):
@@ -177,7 +148,7 @@ class Glm4MoeLiteMTP(HybridBlock):
             x = self.eh_proj(invoke(
                 "concat", [self.enorm(next_embedding), self.hnorm(hidden)],
                 {"dim": -1}))
-        return self.norm(_run(self.layers, x, self._recompute))
+        return self.norm(run_layers(self.layers, x, self._recompute))
 
 
 class Glm4MoeLiteForCausalLM(HybridBlock):
@@ -222,7 +193,7 @@ class Glm4MoeLiteForCausalLM(HybridBlock):
         # earlier position sees it under the causal mask)
         nxt = invoke("roll", [tokens], {"shift": -1, "axis": 1})
         with jax.named_scope("MTPModule"):
-            ahead = self.mtp(hidden, _low(self.model.embed_tokens(nxt)))
+            ahead = self.mtp(hidden, low_stream(self.model.embed_tokens(nxt)))
             ahead = self.lm_head(ahead)
         return invoke("stack", [logits, ahead], {"axis": 1})
 

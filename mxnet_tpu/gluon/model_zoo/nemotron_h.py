@@ -25,6 +25,7 @@ from ... import amp, initializer
 from ...ndarray.ndarray import invoke
 from ..block import HybridBlock, remat_call
 from ..nn import Dense, Embedding, HybridSequential, RMSNorm
+from ..nn.basic_layers import held_head_ids
 from ..parameter import Parameter
 from .sparse_experts import SparseExperts
 
@@ -42,11 +43,21 @@ def _residual_out_init(rescale_layers):
 
 
 class _ALog(initializer.Initializer):
-    """``A_log = log(1..heads)``, so ``A = -exp(A_log)`` is -1..-heads."""
+    """``A_log = log(1..heads)``, so ``A = -exp(A_log)`` is -1..-heads; a
+    mixer that holds some of the heads (``held``, ids) takes their own
+    entries, ``log(id + 1)``."""
+
+    def __init__(self, held=None):
+        super().__init__()
+        self._held = held
 
     def _init_weight(self, _, arr):
-        self._fill(arr, jnp.log(jnp.arange(1, arr.shape[0] + 1,
-                                           dtype=jnp.float32)))
+        if self._held is None:
+            self._fill(arr, jnp.log(jnp.arange(1, arr.shape[0] + 1,
+                                               dtype=jnp.float32)))
+        else:
+            self._fill(arr, jnp.log(jnp.asarray(self._held, jnp.float32)
+                                    + 1.0))
 
 
 def _slices(x, widths):
@@ -62,12 +73,28 @@ class NemotronHMamba2Mixer(HybridBlock):
     """The Mamba-2 mixer: one input projection into gate, convolved
     ``x B C`` and time steps; a causal depthwise convolution with SiLU; the
     selective scan (``ssd_scan``, chunked); a gated grouped RMS norm; the
-    output projection."""
+    output projection.
+
+    ``held_heads`` (ids of the ``num_heads``; all by default) builds the
+    mixer for those heads alone, as a tensor-parallel rank holds it: their
+    rows of ``z``, ``x`` and ``dt`` in ``in_proj``, their convolution
+    channels, ``A_log`` (their own entries), ``dt_bias``, ``D``, norm scale
+    and columns of ``out_proj``.  With ONE group ``B`` and ``C`` and their
+    channels are whole on every rank, and the gated norm's group is the held
+    heads' width; the result is the held heads' part of ``out_proj``'s
+    sum."""
 
     def __init__(self, hidden_size, num_heads, head_dim, n_groups, state_size,
                  conv_kernel=4, chunk_size=128, eps=1e-5, rescale_layers=1,
-                 time_step=(0.001, 0.1, 1e-4)):
+                 time_step=(0.001, 0.1, 1e-4), held_heads=None):
         super().__init__()
+        held = None if held_heads is None \
+            else held_head_ids(num_heads, held_heads)
+        if held is not None and len(held) < num_heads and n_groups != 1:
+            raise ValueError(f"held_heads: a share of the heads needs ONE "
+                             f"group of B and C, not {n_groups}")
+        self._held = held or tuple(range(num_heads))
+        num_heads = len(self._held)
         self._heads, self._head_dim = num_heads, head_dim
         self._groups, self._state = n_groups, state_size
         self._chunk, self._eps = chunk_size, eps
@@ -86,7 +113,7 @@ class NemotronHMamba2Mixer(HybridBlock):
         self.dt_bias = Parameter("dt_bias", shape=(num_heads,),
                                  init=initializer.TimeStepBias(*time_step),
                                  wd_mult=0.0)
-        self.A_log = Parameter("A_log", shape=(num_heads,), init=_ALog(),
+        self.A_log = Parameter("A_log", shape=(num_heads,), init=_ALog(held),
                                wd_mult=0.0)
         self.D = Parameter("D", shape=(num_heads,), init=initializer.One(),
                            wd_mult=0.0)
@@ -96,6 +123,10 @@ class NemotronHMamba2Mixer(HybridBlock):
                               in_units=self._inner,
                               weight_initializer=_residual_out_init(
                                   rescale_layers))
+
+    @property
+    def held_heads(self):
+        return self._held
 
     def forward(self, x):
         ctx = x.ctx
@@ -125,30 +156,51 @@ class NemotronHMamba2Mixer(HybridBlock):
 class NemotronHAttention(HybridBlock):
     """Causal self-attention, ``num_heads`` query heads over
     ``num_kv_heads`` key-value heads, no positional encoding.  The core is
-    one operator, ``causal_gqa_selfatt``."""
+    one operator, ``causal_gqa_selfatt``, its scores scaled by ``scale``
+    (``1 / sqrt(head_dim)`` where None).
+
+    ``held_heads`` (ids of the query heads, whole groups of a key-value
+    head; all by default) builds the layer for those heads and the
+    key-value heads they read, as a tensor-parallel rank holds it; the
+    result is the held heads' part of ``o_proj``'s sum."""
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
-                 rescale_layers=1):
+                 rescale_layers=1, held_heads=None, scale=None):
         super().__init__()
-        self._heads, self._kv_heads = num_heads, num_kv_heads
+        held = held_head_ids(num_heads, held_heads)
+        group = num_heads // num_kv_heads
+        kv_held = sorted({h // group for h in held})
+        if sorted(held) != [k * group + g for k in kv_held
+                            for g in range(group)]:
+            raise ValueError(f"held_heads {held}: whole groups of {group} "
+                             f"query heads a key-value head, in order")
+        self._held = held
+        self._heads, self._kv_heads = len(held), len(kv_held)
+        self._scale = scale
         normal = initializer.Normal(_INIT_STD)
 
         def proj(units):
             return Dense(units, use_bias=False, flatten=False,
                          in_units=hidden_size, weight_initializer=normal)
 
-        self.q_proj = proj(num_heads * head_dim)
-        self.k_proj = proj(num_kv_heads * head_dim)
-        self.v_proj = proj(num_kv_heads * head_dim)
+        self.q_proj = proj(self._heads * head_dim)
+        self.k_proj = proj(self._kv_heads * head_dim)
+        self.v_proj = proj(self._kv_heads * head_dim)
         self.o_proj = Dense(hidden_size, use_bias=False, flatten=False,
-                            in_units=num_heads * head_dim,
+                            in_units=self._heads * head_dim,
                             weight_initializer=_residual_out_init(
                                 rescale_layers))
 
+    @property
+    def held_heads(self):
+        return self._held
+
     def forward(self, x):
+        attrs = {"heads": self._heads, "kv_heads": self._kv_heads}
+        if self._scale is not None:
+            attrs["scale"] = self._scale
         out = invoke("causal_gqa_selfatt",
-                     [self.q_proj(x), self.k_proj(x), self.v_proj(x)],
-                     {"heads": self._heads, "kv_heads": self._kv_heads})
+                     [self.q_proj(x), self.k_proj(x), self.v_proj(x)], attrs)
         return self.o_proj(out)
 
 

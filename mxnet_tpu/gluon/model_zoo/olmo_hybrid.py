@@ -30,7 +30,7 @@ from ...ndarray.ndarray import invoke
 from ..block import HybridBlock
 from ..nn import Dense, Embedding, GatedDeltaNet, HybridSequential, RMSNorm
 from ..nn.basic_layers import held_head_ids
-from .glm4_moe_lite import Glm4MoeLiteMLP, _low, _run
+from .decoder_parts import GatedMLP, low_stream, run_layers
 
 __all__ = ["OlmoHybridAttention", "OlmoHybridMLP", "OlmoHybridDecoderLayer",
            "OlmoHybridModel", "OlmoHybridForCausalLM", "olmo_hybrid"]
@@ -77,10 +77,9 @@ class OlmoHybridAttention(HybridBlock):
         return self.o_proj(out)
 
 
-class OlmoHybridMLP(Glm4MoeLiteMLP):
+class OlmoHybridMLP(GatedMLP):
     """``down_proj(silu(gate x) * (up x))``, gate and up side by side in
-    one product (the block GLM's dense layer runs, under this model's
-    scope)."""
+    one product (``decoder_parts.GatedMLP`` under this model's scope)."""
 
 
 class OlmoHybridDecoderLayer(HybridBlock):
@@ -142,8 +141,8 @@ class OlmoHybridModel(HybridBlock):
         self.norm = RMSNorm(epsilon=eps, in_channels=h)
 
     def forward(self, tokens):
-        x = _low(self.embed_tokens(tokens))
-        return self.norm(_run(self.layers, x, self._recompute))
+        x = low_stream(self.embed_tokens(tokens))
+        return self.norm(run_layers(self.layers, x, self._recompute))
 
 
 class OlmoHybridForCausalLM(HybridBlock):

@@ -145,16 +145,18 @@ def interleaved_selfatt(queries_keys_values, key=None, heads=1, p=0.0,
         dropout_key=key), bsz)
 
 
-def _unfused_causal_gqa(q, k, v, heads, kv_heads):
+def _unfused_causal_gqa(q, k, v, heads, kv_heads, scale=None):
     """Grouped causal attention as two products around a float32 softmax
-    under an explicit lower-triangular mask.  The values' head may be wider
-    or narrower than the scores'."""
+    under an explicit lower-triangular mask, the scores times ``scale``
+    (``1 / sqrt(head_dim)`` where None).  The values' head may be wider or
+    narrower than the scores'."""
     bsz, seq, width = q.shape
     d, group = width // heads, heads // kv_heads
     q5 = q.reshape(bsz, seq, kv_heads, group, d)
     k4, v4 = (t.reshape(bsz, seq, kv_heads, -1) for t in (k, v))
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", q5, k4,
-                        preferred_element_type=jnp.float32) / (d ** 0.5)
+                        preferred_element_type=jnp.float32)
+    scores = scores / (d ** 0.5) if scale is None else scores * scale
     visible = jnp.arange(seq)[:, None] >= jnp.arange(seq)[None, :]
     att = jax.nn.softmax(jnp.where(visible, scores, -jnp.inf), axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", att.astype(v.dtype), v4,
@@ -162,18 +164,20 @@ def _unfused_causal_gqa(q, k, v, heads, kv_heads):
     return out.reshape(bsz, seq, -1).astype(q.dtype)
 
 
-def _causal_core(q, k, v, heads, kv_heads, fused, unfused, event, misfit):
+def _causal_core(q, k, v, heads, kv_heads, fused, unfused, event, misfit,
+                 scale=None):
     """The causal grouped core of one site: the Pallas kernels on a TPU
     with no mesh of more than one device when the shape fits (``misfit`` is
-    None, else why it does not), the unfused expression anywhere else.
-    Counted on ``fused`` / ``unfused``; a TPU trace that leaves the kernels
-    emits the ``fallback`` event ``event``."""
+    None, else why it does not), the unfused expression anywhere else; the
+    scores times ``scale`` (``1 / sqrt(head_dim)`` where None).  Counted on
+    ``fused`` / ``unfused``; a TPU trace that leaves the kernels emits the
+    ``fallback`` event ``event``."""
     from ..parallel.mesh import current_mesh
     from . import pallas_kernels as _pk
 
     if _attention_platform() != "tpu":
         unfused.inc()
-        return _unfused_causal_gqa(q, k, v, heads, kv_heads)
+        return _unfused_causal_gqa(q, k, v, heads, kv_heads, scale)
     mesh = current_mesh()
     refusal = f"mesh of {mesh.size} devices" \
         if mesh is not None and mesh.size > 1 else misfit
@@ -181,31 +185,35 @@ def _causal_core(q, k, v, heads, kv_heads, fused, unfused, event, misfit):
         unfused.inc()
         _telemetry.event("fallback", event, seq=q.shape[1],
                          head_dim=q.shape[2] // heads, why=refusal)
-        return _unfused_causal_gqa(q, k, v, heads, kv_heads)
+        return _unfused_causal_gqa(q, k, v, heads, kv_heads, scale)
     fused.inc()
-    return _pk.flash_attention_gqa(q, k, v, heads, kv_heads)
+    return _pk.flash_attention_gqa(q, k, v, heads, kv_heads, scale)
 
 
 @register("causal_gqa_selfatt", num_inputs=3)
-def causal_gqa_selfatt(queries, keys, values, heads=1, kv_heads=1):
+def causal_gqa_selfatt(queries, keys, values, heads=1, kv_heads=1,
+                       scale=None):
     """Causal self-attention core with grouped key-value heads:
     ``queries`` (batch, seq, heads * head_dim), ``keys`` and ``values``
     (batch, seq, kv_heads * head_dim), as the three projections give them;
     query head ``h`` reads key-value head ``h // (heads // kv_heads)``.
-    ``softmax(q k^T / sqrt(head_dim) + causal mask) v``, shaped as
-    ``queries``.  On a TPU with no mesh the Pallas kernels read the
-    projections in place and write no copy of a key or a value
-    (``pallas_kernels.flash_attention_gqa``); anywhere else the unfused
+    ``softmax(q k^T * scale + causal mask) v``, shaped as ``queries``;
+    ``scale`` is ``1 / sqrt(head_dim)`` where None (a model may state its
+    own: Granite's ``attention_multiplier``).  On a TPU with no mesh the
+    Pallas kernels (``pallas_kernels.flash_attention_gqa``): heads of whole
+    128-lane columns read in place, with no copy of a key or a value; heads
+    of 64 moved head-major around the call.  Anywhere else the unfused
     expression.  Counted and refused as ``interleaved_selfatt`` is."""
     from . import pallas_kernels as _pk
 
     seq, head_dim = queries.shape[1], queries.shape[2] // heads
     misfit = None
-    if head_dim % 128 or _pk.gqa_block(seq) is None:
-        misfit = ("head_dim must be whole 128-lane columns and seq a "
-                  "multiple of 16")
+    if head_dim % 64 or _pk.gqa_block(seq) is None:
+        misfit = ("head_dim must be a multiple of 64 and seq a multiple "
+                  "of 16")
     return _causal_core(queries, keys, values, heads, kv_heads, _ATTN_FUSED,
-                        _ATTN_UNFUSED, "attention.fused", misfit)
+                        _ATTN_UNFUSED, "attention.fused", misfit,
+                        None if scale is None else float(scale))
 
 
 # --- latent attention: low-rank keys and values, one rotary key a token ----

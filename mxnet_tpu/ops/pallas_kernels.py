@@ -45,7 +45,7 @@ from .random import _keep, _seed_words, keep_mask
 __all__ = ["flash_attention", "flash_attention_qkv", "flash_attention_gqa",
            "qkv_heads_per_step", "gqa_block", "dropout_keep_mask",
            "grouped_matmul", "GROUP_TILE", "ssd_chunk_scan",
-           "delta_rule_chunks"]
+           "ssd_head_block", "delta_rule_chunks"]
 
 _NEG_INF = -1e30
 
@@ -493,8 +493,11 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, dropout_p=0.0,
 # value is written.  A block is (block, head_dim) columns of one batch row:
 # head_dim must be whole 128-lane columns, one (32 query heads of 128 over 2
 # key-value heads: Nemotron-H) or several (20 over 20 at 256, a group of
-# one: latent attention, whose scores and values share the width).  Blocks
-# above the diagonal are never fetched.
+# one: latent attention, whose scores and values share the width).  A head
+# narrower than a column (16 query heads of 64 over 4) is handed over
+# head-major instead, (batch, heads, seq, head_dim), where a block is a
+# head's whole width; the kernels are the same.  Blocks above the diagonal
+# are never fetched.
 #
 # The forward holds three (block, head_dim) operands and a float32
 # accumulator of that shape a step, the key blocks a grid dimension: nothing
@@ -689,14 +692,38 @@ def gqa_block(seq):
     return block if block % 16 == 0 else None
 
 
-def _gqa_specs(heads, kv_heads, d, block):
+def _gqa_dims(q, heads):
+    """``(batch, seq, head_dim, head_major)`` of a grouped kernel's ``q``:
+    (batch, seq, heads * head_dim) as the projections give it, or (batch,
+    heads, seq, head_dim) where a head is narrower than a 128-lane column
+    (:func:`flash_attention_gqa`)."""
+    if q.ndim == 4:
+        return q.shape[0], q.shape[2], q.shape[3], True
+    bsz, s, width = q.shape
+    return bsz, s, width // heads, False
+
+
+def _head_spec(d, block, head_major, index):
+    """The (block, d) block of one head: ``index(*grid ids)`` gives (batch,
+    row block, head)."""
+    if head_major:
+        return pl.BlockSpec((None, None, block, d),
+                            lambda *ids: _head_major(*index(*ids)))
+    return pl.BlockSpec((None, block, d), index)
+
+
+def _head_major(b, rows, head):
+    return b, head, rows, 0
+
+
+def _gqa_specs(heads, kv_heads, d, block, head_major):
     """Block specs over the grid (batch, head, query block, key block):
     ``(q-like, k-like, lse-like)``; a key block above the diagonal maps to
     the diagonal's."""
     group = heads // kv_heads
-    q_spec = pl.BlockSpec((None, block, d), lambda b, h, i, j: (b, i, h))
-    k_spec = pl.BlockSpec(
-        (None, block, d),
+    q_spec = _head_spec(d, block, head_major, lambda b, h, i, j: (b, i, h))
+    k_spec = _head_spec(
+        d, block, head_major,
         lambda b, h, i, j: (b, jnp.minimum(j, i), h // group))
     stat_spec = pl.BlockSpec((None, None, block, 1),
                              lambda b, h, i, j: (b, h, i, 0))
@@ -705,9 +732,9 @@ def _gqa_specs(heads, kv_heads, d, block):
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _gqa_forward(q, k, v, heads, kv_heads, sm_scale, block):
-    bsz, s, width = q.shape
-    d = width // heads
-    q_spec, k_spec, stat_spec = _gqa_specs(heads, kv_heads, d, block)
+    bsz, s, d, head_major = _gqa_dims(q, heads)
+    q_spec, k_spec, stat_spec = _gqa_specs(heads, kv_heads, d, block,
+                                           head_major)
     return _gqa_call(
         functools.partial(_gqa_fwd_kernel, sm_scale=sm_scale, block=block),
         (bsz, heads, s // block, s // block), [q_spec, k_spec, k_spec],
@@ -736,25 +763,24 @@ def _gqa_bwd_resident(seq, d, group, block):
 
 
 def _gqa_bwd_fused(q, k, v, do, lse, delta, heads, kv_heads, sm_scale, block):
-    bsz, s, width = q.shape
-    d, group, nq = width // heads, heads // kv_heads, s // block
+    bsz, s, d, head_major = _gqa_dims(q, heads)
+    group, nq = heads // kv_heads, s // block
     # the visible (key block, query block) pairs, key block by key block
     kb, qb = (t.astype(onp.int32) for t in onp.triu_indices(nq))
 
     def head(h, g):
         return h * group + g
 
-    q_spec = pl.BlockSpec(
-        (None, block, d), lambda b, h, g, t, kb, qb: (b, qb[t], head(h, g)))
-    k_spec = pl.BlockSpec(
-        (None, block, d), lambda b, h, g, t, kb, qb: (b, kb[t], h))
+    def spec(index):
+        return _head_spec(d, block, head_major, index)
+
+    q_spec = spec(lambda b, h, g, t, kb, qb: (b, qb[t], head(h, g)))
+    k_spec = spec(lambda b, h, g, t, kb, qb: (b, kb[t], h))
     stat_spec = pl.BlockSpec(
         (None, None, block, 1),
         lambda b, h, g, t, kb, qb: (b, head(h, g), qb[t], 0))
-    dq_spec = pl.BlockSpec(
-        (None, block, d), lambda b, h, g, t, kb, qb: (b, kb[t], head(h, g)))
-    dk_spec = pl.BlockSpec(
-        (None, block, d),
+    dq_spec = spec(lambda b, h, g, t, kb, qb: (b, kb[t], head(h, g)))
+    dk_spec = spec(
         lambda b, h, g, t, kb, qb: (b, jnp.where(g == group - 1, kb[t], 0), h))
     kv_acc = pltpu.VMEM((nq if group > 1 else 1, block, d), jnp.float32)
     return pl.pallas_call(
@@ -777,9 +803,10 @@ def _gqa_bwd_fused(q, k, v, do, lse, delta, heads, kv_heads, sm_scale, block):
 
 
 def _gqa_bwd_split(q, k, v, do, lse, delta, heads, kv_heads, sm_scale, block):
-    bsz, s, width = q.shape
-    d, group, nq = width // heads, heads // kv_heads, s // block
-    q_spec, k_spec, stat_spec = _gqa_specs(heads, kv_heads, d, block)
+    bsz, s, d, head_major = _gqa_dims(q, heads)
+    group, nq = heads // kv_heads, s // block
+    q_spec, k_spec, stat_spec = _gqa_specs(heads, kv_heads, d, block,
+                                           head_major)
     kw = dict(sm_scale=sm_scale, block=block)
     dq = _gqa_call(
         functools.partial(_gqa_bwd_dq_kernel, **kw),
@@ -796,10 +823,10 @@ def _gqa_bwd_split(q, k, v, do, lse, delta, heads, kv_heads, sm_scale, block):
     def first_query(j, t):
         return jnp.maximum(t % nq, j)
 
-    qd_spec = pl.BlockSpec(
-        (None, block, d),
+    qd_spec = _head_spec(
+        d, block, head_major,
         lambda b, h, j, t: (b, first_query(j, t), head(h, t)))
-    kd_spec = pl.BlockSpec((None, block, d), lambda b, h, j, t: (b, j, h))
+    kd_spec = _head_spec(d, block, head_major, lambda b, h, j, t: (b, j, h))
     sd_spec = pl.BlockSpec(
         (None, None, block, 1),
         lambda b, h, j, t: (b, head(h, t), first_query(j, t), 0))
@@ -816,10 +843,13 @@ def _gqa_bwd_split(q, k, v, do, lse, delta, heads, kv_heads, sm_scale, block):
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
 def _gqa_backward(q, k, v, o, lse, do, heads, kv_heads, sm_scale, block,
                   fused):
-    bsz, s, width = q.shape
-    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
-                    .reshape(bsz, s, heads, width // heads), axis=-1) \
-        .transpose(0, 2, 1)[..., None]                   # (bsz, heads, s, 1)
+    bsz, s, d, head_major = _gqa_dims(q, heads)
+    delta = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if head_major:
+        delta = jnp.sum(delta, axis=-1)[..., None]
+    else:
+        delta = jnp.sum(delta.reshape(bsz, s, heads, d), axis=-1) \
+            .transpose(0, 2, 1)[..., None]               # (bsz, heads, s, 1)
     return (_gqa_bwd_fused if fused else _gqa_bwd_split)(
         q, k, v, do, lse, delta, heads, kv_heads, sm_scale, block)
 
@@ -835,8 +865,7 @@ def _flash_gqa_fwd(q, k, v, heads, kv_heads, sm_scale, block):
 
 
 def _flash_gqa_bwd(heads, kv_heads, sm_scale, block, res, do):
-    _, s, width = res[0].shape
-    d = width // heads
+    _, s, d, _ = _gqa_dims(res[0], heads)
     fused_block = _gqa_bwd_block(s, block)
     resident = _gqa_bwd_resident(s, d, heads // kv_heads, fused_block)
     fused = resident <= _GQA_BWD_VMEM
@@ -859,10 +888,14 @@ def flash_attention_gqa(q, k, v, num_heads, num_kv_heads, sm_scale=None):
     """Causal self-attention straight from grouped projections: ``q``
     (batch, seq, num_heads * head_dim), ``k`` and ``v`` (batch, seq,
     num_kv_heads * head_dim); query head ``h`` reads key-value head
-    ``h // (num_heads // num_kv_heads)``.  The result has ``q``'s shape.
-    Nothing is copied around the kernels, forward or backward, and the
-    backward keeps ``q``, ``k``, ``v``, the output and one float32
-    statistic a query.  Only for lengths :func:`gqa_block` accepts."""
+    ``h // (num_heads // num_kv_heads)``.  The scores are ``q k^T *
+    sm_scale`` (``1 / sqrt(head_dim)`` where None).  The result has
+    ``q``'s shape.  Where a head is whole 128-lane columns nothing is copied
+    around the kernels, forward or backward; a head that ends half a column
+    over (64) is moved head-major, (batch, heads, seq, head_dim), and back,
+    so that a block is a head's whole width.  The backward keeps ``q``,
+    ``k``, ``v``, the output and one float32 statistic a query.  Only for
+    lengths :func:`gqa_block` accepts."""
     bsz, s, width = q.shape
     if num_heads % num_kv_heads or width % num_heads:
         raise ValueError(f"flash_attention_gqa: {num_heads} query heads "
@@ -874,8 +907,16 @@ def flash_attention_gqa(q, k, v, num_heads, num_kv_heads, sm_scale=None):
     d = width // num_heads
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    return _flash_gqa(q, k, v, num_heads, num_kv_heads, float(sm_scale),
-                      block)
+    if d % 128 != 64:
+        return _flash_gqa(q, k, v, num_heads, num_kv_heads, float(sm_scale),
+                          block)
+
+    def head_major(t):
+        return t.reshape(bsz, s, -1, d).transpose(0, 2, 1, 3)
+
+    out = _flash_gqa(head_major(q), head_major(k), head_major(v), num_heads,
+                     num_kv_heads, float(sm_scale), block)
+    return out.transpose(0, 2, 1, 3).reshape(bsz, s, width)
 
 
 # -- self-attention over the interleaved projection, in place ---------------
@@ -1210,7 +1251,7 @@ def grouped_matmul(x, w, tile_group, tiles_used):
     return _grouped(x, w, tile_group, tiles_used)
 
 
-# -- the Mamba-2 scan: one chunk of one group a grid step --------------------
+# -- the Mamba-2 scan: one chunk of a block of a group's heads a grid step ---
 #
 # ``ops/ssm.py`` states the recurrence and its chunked form; this is that
 # form with nothing of a chunk but its inputs and its output in HBM.  The
@@ -1248,6 +1289,51 @@ def grouped_matmul(x, w, tile_group, tiles_used):
 # float32; a mask times ``C B^T``, the decayed inputs and the state handed to
 # ``C . S`` cast to the operands' type before their products; float32
 # accumulation.  A cotangent is cast the same way before a product.
+#
+# A group of many heads is taken a BLOCK of heads at a time
+# (``ssd_head_block``): the grid is then (batch, group, chunk, head block),
+# the blocks innermost, and the kernels above see a block as they would see
+# a group of that many heads.  Each block reads the chunk's ``B`` and ``C``
+# and rebuilds ``C B^T``; the states of every block of the group stay in
+# scratch from one chunk to the next; the backward adds the blocks' parts of
+# the group's ``dB`` and ``dC`` in float32 scratch and writes them once, at
+# the last block (Mosaic does not read an output block back), and each block
+# writes its own rows of ``D``'s gradient.  A group that fits one block
+# (8 heads of 64 at chunks of 128) keeps the grid (batch, group, chunk).
+# ``a`` and ``dt`` are handed over block by block, a block's ``a`` rows and
+# then its ``dt`` rows.
+
+# the most a grid step's block of heads may hold: heads x head_dim x chunk
+# elements.  One block of 8 heads of 64 at chunks of 128 is 65,536.  At 8,192
+# positions, one group of 32 heads of 64 in chunks of 256 (v5e, bf16,
+# tools/granite_kernels_check.py): the whole group (2,097,152) runs the
+# backward out of Mosaic's scoped VMEM (20.7 MiB of 16); blocks of 16 heads
+# take 0.295 ms forward and 0.868 differentiated, blocks of 8 0.310 and
+# 0.919
+_SSD_BLOCK = 16 * 64 * 256
+
+
+def ssd_head_block(heads, head_dim, chunk):
+    """Heads a grid step of the scan kernels takes of a group of ``heads``:
+    the most that divide the group within ``_SSD_BLOCK``, a multiple of 8
+    (a block's float32 rows of ``D`` fill whole sublane tiles) or the whole
+    group; the smallest such where none fits."""
+    blocks = [d for d in range(1, heads + 1)
+              if heads % d == 0 and (d == heads or d % 8 == 0)]
+    fits = [d for d in blocks if d * head_dim * chunk <= _SSD_BLOCK]
+    return max(fits) if fits else min(blocks)
+
+
+def _block_view(ref, blocked):
+    """The current head block's part of a scratch that holds every block's
+    (leading axis), or the whole scratch where there is one block."""
+    return ref.at[pl.program_id(3)] if blocked else ref
+
+
+def _block_rows(ref, rows, blocked):
+    """The current head block's ``rows`` rows of a block that holds the
+    whole group's."""
+    return ref.at[pl.ds(pl.program_id(3) * rows, rows)] if blocked else ref
 
 
 def _per_head_rows(rows, p):
@@ -1295,8 +1381,8 @@ def _ssd_decays(rows_ref, e, n):
 
 
 def _ssd_fwd_kernel(x_ref, b_ref, c_ref, rows_ref, cols_ref, d_ref, y_ref,
-                    *rest, heads):
-    state = rest[-1]
+                    *rest, heads, blocked):
+    state = _block_view(rest[-1], blocked)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -1322,7 +1408,10 @@ def _ssd_fwd_kernel(x_ref, b_ref, c_ref, rows_ref, cols_ref, d_ref, y_ref,
 
 def _ssd_bwd_kernel(x_ref, b_ref, c_ref, rows_ref, cols_ref, d_ref, s_ref,
                     dy_ref, dx_ref, db_ref, dc_ref, drow_ref, dd_ref, dstate,
-                    after, *, heads):
+                    after, *acc, heads, blocked):
+    dstate, after = (_block_view(t, blocked) for t in (dstate, after))
+    dd_ref = _block_rows(dd_ref, heads, blocked)
+
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate[...] = jnp.zeros(dstate.shape, dstate.dtype)
@@ -1369,42 +1458,86 @@ def _ssd_bwd_kernel(x_ref, b_ref, c_ref, rows_ref, cols_ref, d_ref, s_ref,
         axis=0)
 
     dcs, dcb = (dy32 * into_rows).astype(dtype), dcb.astype(dtype)
-    dc_ref[...] = (_dot_tn(before.astype(dtype), dcs)
-                   + _dot_nn(B, dcb)).astype(dtype)
-    db_ref[...] = (_dot_tn(d_after.astype(dtype), x * to_end)
-                   + _dot_nt(C, dcb)).astype(dtype)
+    dc = _dot_tn(before.astype(dtype), dcs) + _dot_nn(B, dcb)
+    if not blocked:
+        dc_ref[...] = dc.astype(dtype)
+    db = _dot_tn(d_after.astype(dtype), x * to_end) + _dot_nt(C, dcb)
+    if not blocked:
+        db_ref[...] = db.astype(dtype)
     dstate[...] = d_after * _per_head_rows(whole, p) + _dot_nt(dcs, C)
     after[...] = before
+    if not blocked:
+        return
+    # the group's dB and dC: every block's part, written at the last block
+    block, db_acc, dc_acc = pl.program_id(3), *acc
+
+    @pl.when(block == 0)
+    def _():
+        db_acc[...] = db
+        dc_acc[...] = dc
+
+    @pl.when(block > 0)
+    def _():
+        db_acc[...] += db
+        dc_acc[...] += dc
+
+    @pl.when(block == pl.num_programs(3) - 1)
+    def _():
+        db_ref[...] = db_acc[...].astype(dtype)
+        dc_ref[...] = dc_acc[...].astype(dtype)
+
+
+def _ssd_blocks(x, a):
+    """``(heads a block, blocks)`` of the scan's call on these operands."""
+    e, q = a.shape[3:]
+    block = ssd_head_block(e, x.shape[1] // (a.shape[1] * e), q)
+    return block, e // block
 
 
 def _ssd_call(kernel, x, B, C, a, dt, D, more, outs, reverse):
-    """``pallas_call`` over (batch, group, chunk); ``reverse`` walks the
-    chunks from the last and carries two states in scratch, not one.
-    ``more`` (array, kind) pairs and the outputs ``outs`` name their blocks
-    by kind: ``"x"`` the group's rows of an array like ``x``, ``"bc"`` of one
-    like ``B``, ``"sum"`` a float32 (heads, chunk) block a (batch, group)
-    that stays put over the chunks, a tuple the trailing block of a float32
-    (batch, group, chunk, ...) array.  Beside ``x``, ``B`` and ``C`` every
-    kernel reads, float32, the decay sums ``a`` and ``dt`` (batch, group,
-    chunk, head, position) as ``rows`` (.., 2 heads, position) and, the same
-    numbers, as ``cols`` (.., position, 2 heads), and ``D`` along a chunk's
-    lanes, (group, heads, position)."""
+    """``pallas_call`` over (batch, group, chunk), and a head block a step
+    where a group takes more than one (``ssd_head_block``): (batch, group,
+    chunk, head block).  ``reverse`` walks the chunks from the last and
+    carries two states a block in scratch, not one.  ``more`` (array, kind)
+    pairs and the outputs ``outs`` name their blocks by kind: ``"x"`` the
+    head block's rows of an array like ``x``, ``"bc"`` the group's rows of
+    one like ``B``, ``"sum"`` a float32 (heads, chunk) block a (batch,
+    group) that stays put over the chunks, ``"state"`` the head block's
+    state of a float32 (batch, group, chunk, heads * head_dim, state) array,
+    ``"rows"`` the head block's rows of a float32 (batch, group, chunk,
+    2 heads, chunk) array laid out as ``rows`` below.  Beside ``x``, ``B``
+    and ``C`` every kernel reads, float32, the decay sums ``a`` and ``dt``
+    (batch, group, chunk, head, position) as ``rows`` (.., a block's ``a``
+    then its ``dt``, position) and, the same numbers, as ``cols`` (..,
+    position, 2 heads of a block), and ``D`` along a chunk's lanes, (group,
+    heads, position)."""
     bsz, g, nc, e, q = a.shape
     n, ep = B.shape[1] // g, x.shape[1] // g
+    eb, nb = _ssd_blocks(x, a)
+    blocked, rows_b = nb > 1, ep // nb
     f32 = jnp.float32
 
-    def chunk(ci):
-        return nc - 1 - ci if reverse else ci
+    def at(index):
+        """An index map over the grid from ``index(b, g, chunk, block)``."""
+        def chunk(ci):
+            return nc - 1 - ci if reverse else ci
+
+        if blocked:
+            return lambda bi, gi, ci, hi: index(bi, gi, chunk(ci), hi)
+        return lambda bi, gi, ci: index(bi, gi, chunk(ci), 0)
 
     def spec(kind):
-        if kind in ("x", "bc"):
-            return pl.BlockSpec((None, ep if kind == "x" else n, q),
-                                lambda bi, gi, ci: (bi, gi, chunk(ci)))
+        if kind == "x":
+            return pl.BlockSpec((None, rows_b, q), at(
+                lambda b, gi, c, h: (b, gi * nb + h if blocked else gi, c)))
+        if kind == "bc":
+            return pl.BlockSpec((None, n, q), at(lambda b, gi, c, h: (b, gi, c)))
         if kind == "sum":
             return pl.BlockSpec((None, None, e, q),
-                                lambda bi, gi, ci: (bi, gi, 0, 0))
-        return pl.BlockSpec((None, None, None) + kind,
-                            lambda bi, gi, ci: (bi, gi, chunk(ci), 0, 0))
+                                at(lambda b, gi, c, h: (b, gi, 0, 0)))
+        block = (rows_b, n) if kind == "state" else (2 * eb, q)
+        return pl.BlockSpec((None, None, None) + block,
+                            at(lambda b, gi, c, h: (b, gi, c, h, 0)))
 
     def shape(kind):
         if kind in ("x", "bc"):
@@ -1412,43 +1545,63 @@ def _ssd_call(kernel, x, B, C, a, dt, D, more, outs, reverse):
             return jax.ShapeDtypeStruct(like.shape, like.dtype)
         if kind == "sum":
             return jax.ShapeDtypeStruct((bsz, g, e, q), f32)
-        return jax.ShapeDtypeStruct((bsz, g, nc) + kind, f32)
+        return jax.ShapeDtypeStruct(
+            (bsz, g, nc) + ((ep, n) if kind == "state" else (2 * e, q)), f32)
 
-    rows = jnp.concatenate([a, dt], axis=-2)
+    if blocked:                 # (.., block, 2 eb, position)
+        rows = jnp.concatenate([t.reshape(bsz, g, nc, nb, eb, q)
+                                for t in (a, dt)], axis=-2)
+        cols_spec = pl.BlockSpec((None,) * 4 + (q, 2 * eb),
+                                 at(lambda b, gi, c, h: (b, gi, c, h, 0, 0)))
+        state = pltpu.VMEM((nb, rows_b, n), f32)
+        scratch = [state] * (2 if reverse else 1) \
+            + [pltpu.VMEM((n, q), f32)] * (2 if reverse else 0)
+    else:
+        rows = jnp.concatenate([a, dt], axis=-2)
+        cols_spec = pl.BlockSpec((None, None, None, q, 2 * e),
+                                 at(lambda b, gi, c, h: (b, gi, c, 0, 0)))
+        scratch = [pltpu.VMEM((ep, n), f32)] * (2 if reverse else 1)
     d_rows = jnp.broadcast_to(D.astype(f32).reshape(g, e, 1), (g, e, q))
+    cols = rows.swapaxes(-1, -2)
+    rows = rows.reshape(bsz, g, nc, 2 * e, q)
     return pl.pallas_call(
-        functools.partial(kernel, heads=e), grid=(bsz, g, nc),
-        in_specs=[spec("x"), spec("bc"), spec("bc"), spec((2 * e, q)),
-                  spec((q, 2 * e)),
-                  pl.BlockSpec((None, e, q), lambda bi, gi, ci: (gi, 0, 0)),
+        functools.partial(kernel, heads=eb, blocked=blocked),
+        grid=(bsz, g, nc) + ((nb,) if blocked else ()),
+        in_specs=[spec("x"), spec("bc"), spec("bc"), spec("rows"), cols_spec,
+                  pl.BlockSpec((None, eb, q),
+                               at(lambda b, gi, c, h: (gi, h, 0))),
                   *(spec(kind) for _, kind in more)],
         out_specs=[spec(kind) for kind in outs],
         out_shape=[shape(kind) for kind in outs],
-        scratch_shapes=[pltpu.VMEM((ep, n), f32)] * (2 if reverse else 1),
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "arbitrary")),
-        interpret=_interpret())(x, B, C, rows, rows.swapaxes(-1, -2),
-                                d_rows, *(t for t, _ in more))
+            "parallel", "parallel", "arbitrary")
+            + (("arbitrary",) if blocked else ())),
+        interpret=_interpret())(x, B, C, rows, cols, d_rows,
+                                *(t for t, _ in more))
 
 
 @functools.partial(jax.jit, static_argnums=(6,))
 def _ssd_forward(x, B, C, a, dt, D, keep_states):
     """``[y]``, and the state before every chunk, (batch, group, chunk,
     heads * head_dim, state), behind it if ``keep_states``."""
-    g = a.shape[1]
-    states = [(x.shape[1] // g, B.shape[1] // g)] if keep_states else []
     return _ssd_call(_ssd_fwd_kernel, x, B, C, a, dt, D, [],
-                     ["x"] + states, False)
+                     ["x"] + (["state"] if keep_states else []), False)
 
 
 @jax.jit
 def _ssd_backward(x, B, C, a, dt, D, states, dy):
-    e, q = a.shape[3:]
+    bsz, g, nc, e, q = a.shape
     dx, dB, dC, drow, dd = _ssd_call(
-        _ssd_bwd_kernel, x, B, C, a, dt, D,
-        [(states, states.shape[3:]), (dy, "x")],
-        ["x", "bc", "bc", (2 * e, q), "sum"], True)
-    return (dx, dB, dC, drow[..., :e, :], drow[..., e:, :],
+        _ssd_bwd_kernel, x, B, C, a, dt, D, [(states, "state"), (dy, "x")],
+        ["x", "bc", "bc", "rows", "sum"], True)
+    eb, nb = _ssd_blocks(x, a)
+    if nb > 1:                  # a block's a rows, then its dt rows
+        drow = drow.reshape(bsz, g, nc, nb, 2, eb, q)
+        da, ddt = (drow[..., i, :, :].reshape(a.shape) for i in (0, 1))
+    else:
+        da, ddt = drow[..., :e, :], drow[..., e:, :]
+    return (dx, dB, dC, da, ddt,
             jnp.sum(dd, axis=(0, 3)).reshape(D.shape).astype(D.dtype))
 
 

@@ -11,7 +11,9 @@ the products take the operands' type and accumulate in float32.
 On a TPU with no mesh of more than one device the scan is a pair of Pallas
 kernels (``pallas_kernels.ssd_chunk_scan``): a chunk's decay mask is built,
 used and dropped in VMEM, the state is carried in scratch, and the backward
-kernel rebuilds the masks.  Anywhere else it is ``_ssd_chunked``, XLA's
+kernel rebuilds the masks; a group of many heads is taken in blocks of
+heads a grid step (``pallas_kernels.ssd_head_block``, counted by
+``ssm.scan_head_blocks``).  Anywhere else it is ``_ssd_chunked``, XLA's
 products group by group with jax's own backward through a rematerialised
 forward; that expression is also what the kernels are tested against.
 Which one is decided from what the trace can observe, as the attention core
@@ -159,6 +161,11 @@ _SCAN_UNFUSED = _telemetry.counter(
     "ssm.scan_unfused",
     "state-space scan sites traced as the chunked expression of XLA's "
     "products")
+_SCAN_HEAD_BLOCKS = _telemetry.counter(
+    "ssm.scan_head_blocks",
+    "blocks of a group's heads the Pallas scan kernels take a grid step at "
+    "a time, summed over the sites traced onto them (1 a site where a group "
+    "is one block: pallas_kernels.ssd_head_block)")
 
 
 def _scan_platform() -> str:
@@ -203,6 +210,12 @@ def ssd_scan(x, dt, A, B, C, D, chunk_size=128):
                              length=x.shape[1], chunk=chunk_size,
                              why=refusal)
     (_SCAN_FUSED if fused else _SCAN_UNFUSED).inc()
+    if fused:
+        from . import pallas_kernels as _pk
+
+        (h, p), g = x.shape[2:], B.shape[2]
+        _SCAN_HEAD_BLOCKS.inc(
+            h // g // _pk.ssd_head_block(h // g, p, chunk_size))
     return _ssd_scan(x, dt, A, B, C, D, chunk_size, fused)
 
 

@@ -675,3 +675,45 @@ def test_mosaic_compiles_the_delta_rule_kernels(one_chip, monkeypatch):
     assert len(calls) == 3
     assert all(dr.RULE_SCOPE in name for name in calls), calls
     assert " while(" not in text
+
+
+def test_mosaic_compiles_the_granite_cells_kernels(one_chip, monkeypatch):
+    """The Granite-4.0-H cell's two kernel paths at its widths, bf16, 8,192
+    positions, forward and backward: the scan over ONE group of 32 heads of
+    64 in chunks of 256 (the whole group's backward does not fit Mosaic's
+    scoped VMEM: blocks of 16 heads a grid step), two Mosaic calls; and
+    ``flash_attention_gqa`` at 16 query heads of 64 over 4, head-major, two
+    Mosaic calls, the backward the one fused kernel."""
+    from mxnet_tpu.ops import ssm
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert pk.ssd_head_block(32, 64, 256) == 16
+    args = (spec((1, 8192, 32, 64)), spec((1, 8192, 32), jnp.float32),
+            spec((32,), jnp.float32), spec((1, 8192, 1, 128)),
+            spec((1, 8192, 1, 128)), spec((32,), jnp.float32))
+
+    def loss(*a):
+        return ssm._ssd_scan(*a, chunk_size=256, fused=True) \
+            .astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=tuple(range(6))), *args)
+    assert text.count("tpu_custom_call") == 2
+    base = telemetry.snapshot()
+    q = jax.ShapeDtypeStruct((1, 8192, 16 * 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4 * 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def core(q, k, v):
+        return pk.flash_attention_gqa(q, k, v, 16, 4, 1 / 64) \
+            .astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(core, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") == 2
+    moved = telemetry.delta(base)
+    assert (moved["attention.gqa_backward_fused"],
+            moved["attention.gqa_backward_split"]) == (1, 0)

@@ -380,3 +380,115 @@ def test_grouped_causal_kernels_at_the_decoder_cells_head_shapes(
         *a, heads, kv_heads) * ct), argnums=(0, 1, 2))(q, k, v)
     for g, w in zip(grads, wants):
         onp.testing.assert_allclose(g, w, atol=5e-5)
+
+
+def _scan_grid(x, B, chunk):
+    """The grid of the forward scan kernel's ``pallas_call`` at these
+    shapes."""
+    from mxnet_tpu.ops import ssm
+
+    b, l, h, _ = x.shape
+    args = (x, jax.ShapeDtypeStruct((b, l, h), jnp.float32),
+            jax.ShapeDtypeStruct((h,), jnp.float32), B, B,
+            jax.ShapeDtypeStruct((h,), jnp.float32))
+    jaxpr = jax.make_jaxpr(lambda *a: ssm._ssd_scan.__wrapped__(
+        *a, chunk_size=chunk, fused=True))(*args).jaxpr
+
+    def calls(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    call, = calls(jaxpr)
+    return tuple(call.params["grid_mapping"].grid)
+
+
+def test_scan_head_blocks_by_shape():
+    """A group's heads a grid step: Nemotron-H's 8 heads of 64 at chunks of
+    128 stay ONE block on the grid (batch, group, chunk) it always had;
+    Granite-4.0-H's one group of 32 heads of 64 at chunks of 256 takes two
+    blocks of 16 on (batch, group, chunk, block); a block is a multiple of 8
+    heads or the whole group."""
+    assert pk.ssd_head_block(8, 64, 128) == 8
+    assert pk.ssd_head_block(32, 64, 256) == 16
+    assert pk.ssd_head_block(4, 64, 256) == 4
+    assert pk.ssd_head_block(12, 128, 1024) == 12      # none fits: the group
+    bf = jnp.bfloat16
+    x = jax.ShapeDtypeStruct((1, 1024, 64, 64), bf)
+    assert _scan_grid(x, jax.ShapeDtypeStruct((1, 1024, 8, 128), bf),
+                      128) == (1, 8, 8)
+    x = jax.ShapeDtypeStruct((1, 1024, 32, 64), bf)
+    assert _scan_grid(x, jax.ShapeDtypeStruct((1, 1024, 1, 128), bf),
+                      256) == (1, 1, 4, 2)
+
+
+@pytest.mark.parametrize("h,g,chunk", [(32, 1, 256), (16, 2, 128)],
+                         ids=["granite_one_group_of_32", "groups_of_8"])
+def test_scan_kernels_in_head_blocks_equal_the_chunked_form(h, g, chunk):
+    """The scan's kernel pair (Pallas interpreter) at Granite-4.0-H's one
+    group of 32 heads of 64 in chunks of 256 (two blocks of 16: the group's
+    dB and dC added over the blocks, each block's rows of dD) and at groups
+    of 8 (one block), two chunks a sequence: y and all six gradients
+    against XLA's chunked form."""
+    from mxnet_tpu.ops import ssm
+
+    ks = jax.random.split(jax.random.PRNGKey(7), 7)
+    seq, p, n = 2 * chunk, 64, 128
+    args = (jax.random.normal(ks[0], (1, seq, h, p)),
+            jax.nn.softplus(jax.random.normal(ks[1], (1, seq, h)) - 3.0),
+            -jnp.exp(0.5 * jax.random.normal(ks[2], (h,))),
+            0.3 * jax.random.normal(ks[3], (1, seq, g, n)),
+            0.3 * jax.random.normal(ks[4], (1, seq, g, n)),
+            jax.random.normal(ks[5], (h,)))
+    ct = jax.random.normal(ks[6], (1, seq, h, p))
+
+    def run(fused):
+        def loss(*a):
+            return jnp.sum(ssm._ssd_scan(*a, chunk_size=chunk, fused=fused)
+                           * ct)
+        with jax.default_matmul_precision("highest"):
+            return (ssm._ssd_scan(*args, chunk_size=chunk, fused=fused),) \
+                + jax.grad(loss, argnums=tuple(range(6)))(*args)
+
+    for name, got, want in zip(("y", "x", "dt", "A", "B", "C", "D"),
+                               run(True), run(False)):
+        onp.testing.assert_allclose(got, want,
+                                    atol=2e-5 * float(jnp.max(jnp.abs(want))),
+                                    err_msg=name)
+
+
+@pytest.mark.parametrize("scale", [None, 1 / 64])
+def test_grouped_causal_kernels_at_heads_of_64(scale, monkeypatch):
+    """``flash_attention_gqa`` as Granite-4.0-H's attention layer runs it on
+    a tensor-parallel rank of two, at toy counts: query heads of 64 over
+    key-value heads of 64 in groups of four, handed over head-major (no
+    whole 128-lane column a head), at its own scale of the scores
+    (``attention_multiplier``, a power of two) and at the default; three
+    blocks a sequence; values and the three gradients against the unfused
+    expression, Pallas interpreter."""
+    from mxnet_tpu.ops import contrib
+
+    monkeypatch.setattr(pk, "_BLOCK", 16)
+    heads, kv_heads, d, seq = 8, 2, 64, 48
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, ct = (jax.random.normal(key, (2, seq, heads * d)) * 0.5
+             for key in ks[:2])
+    k, v = (jax.random.normal(key, (2, seq, kv_heads * d)) * 0.5
+            for key in ks[2:])
+    got = pk.flash_attention_gqa(q, k, v, heads, kv_heads, scale)
+    want = contrib._unfused_causal_gqa(q, k, v, heads, kv_heads, scale)
+    onp.testing.assert_allclose(got, want, atol=2e-5)
+    if scale is not None:            # the scale is the one asked for
+        assert onp.abs(onp.asarray(got) - onp.asarray(
+            contrib._unfused_causal_gqa(q, k, v, heads, kv_heads))).max() \
+            > 1e-2
+    base = mx.telemetry.snapshot()
+    grads = jax.grad(lambda *a: jnp.sum(pk.flash_attention_gqa(
+        *a, heads, kv_heads, scale) * ct), argnums=(0, 1, 2))(q, k, v)
+    assert mx.telemetry.delta(base)["attention.gqa_backward_fused"] == 1
+    wants = jax.grad(lambda *a: jnp.sum(contrib._unfused_causal_gqa(
+        *a, heads, kv_heads, scale) * ct), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(grads, wants):
+        onp.testing.assert_allclose(g, w, atol=5e-5)

@@ -60,15 +60,6 @@ class _ALog(initializer.Initializer):
                                     + 1.0))
 
 
-def _slices(x, widths):
-    """``x`` cut along its last axis into pieces of ``widths``."""
-    out, at = [], 0
-    for w in widths:
-        out.append(x.slice_axis(axis=-1, begin=at, end=at + w))
-        at += w
-    return out
-
-
 class NemotronHMamba2Mixer(HybridBlock):
     """The Mamba-2 mixer: one input projection into gate, convolved
     ``x B C`` and time steps; a causal depthwise convolution with SiLU; the
@@ -132,12 +123,18 @@ class NemotronHMamba2Mixer(HybridBlock):
         ctx = x.ctx
         bsz, length = x.shape[0], x.shape[1]
         bc = self._groups * self._state
-        z, xbc, dt = _slices(self.in_proj(x),
-                             (self._inner, self._conv_dim, self._heads))
-        xbc = invoke("causal_conv1d",
-                     [xbc, self.conv_weight.data(ctx),
-                      self.conv_bias.data(ctx)], {"activation": "silu"})
-        xs, b, c = _slices(xbc, (self._inner, bc, bc))
+        proj = self.in_proj(x)
+        z = proj.slice_axis(axis=-1, begin=0, end=self._inner)
+        dt = proj.slice_axis(axis=-1, begin=self._inner + self._conv_dim,
+                             end=None)
+        # the convolution reads its channels out of the whole projection and
+        # hands x, B and C over as three arrays: no slice of either side is
+        # written out for it
+        xs, b, c = invoke("causal_conv1d",
+                          [proj, self.conv_weight.data(ctx),
+                           self.conv_bias.data(ctx)],
+                          {"activation": "silu", "begin": self._inner,
+                           "splits": (self._inner, bc, bc)})
         dt = invoke("softrelu", [dt + self.dt_bias.data(ctx)], {})
         a = invoke("negative", [invoke("exp", [self.A_log.data(ctx)], {})],
                    {})

@@ -16,8 +16,9 @@ full score matrix; this is the TPU-first replacement, not a translation.
 Callers: ``ops/contrib.py interleaved_selfatt`` (the Gluon BERT attention
 core) and ``models/transformer_lm.py``.  Beside it, for the state-space /
 sparse-expert decoder: the grouped causal kernels, the grouped matrix
-product of a rank's held experts, the Mamba-2 chunked scan, and the gated
-delta rule in chunks (each under its own heading below).
+product of a rank's held experts, the Mamba-2 chunked scan, the gated
+delta rule in chunks, and the short causal depthwise convolution in front of
+both (each under its own heading below).
 
 On the CPU the kernels run under the Pallas interpreter (slow but exact) so
 the CPU test suite validates the same code path that runs on hardware; any
@@ -45,7 +46,8 @@ from .random import _keep, _seed_words, keep_mask
 __all__ = ["flash_attention", "flash_attention_qkv", "flash_attention_gqa",
            "qkv_heads_per_step", "gqa_block", "dropout_keep_mask",
            "grouped_matmul", "GROUP_TILE", "ssd_chunk_scan",
-           "ssd_head_block", "delta_rule_chunks"]
+           "ssd_head_block", "delta_rule_chunks", "causal_conv_lanes",
+           "conv_channel_block"]
 
 _NEG_INF = -1e30
 
@@ -1990,3 +1992,243 @@ def delta_rule_chunks(q, k, v, g, beta):
                          f"{q.dtype}, k {k.shape} {k.dtype}, v {v.shape} "
                          f"{v.dtype}, g {g.shape}, beta {beta.shape}")
     return _delta(q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32))
+
+
+# -- the short causal depthwise convolution: a block of channels a step -------
+#
+# ``ops/ssm.py:causal_conv1d`` in front of the Mamba-2 scan and the gated
+# delta rule: ``out[c, t] = b[c] + sum_k w[c, k] x[c, t - (K - 1) + k]``, a
+# SiLU on it, positions before the start read as zero.  XLA lays every such
+# convolution out position-minor on the v5e (the mixers' projections write
+# (batch, channels, length) in memory), so the kernels take it that way round:
+# a grid step is a batch row and a block of channels over the WHOLE sequence,
+# channels down the sublanes and positions along the lanes.  A shift by ``j``
+# positions is a lane rotation with the first ``j`` lanes zeroed; the
+# multiply-adds, the bias and the SiLU are float32 in VMEM and only the
+# operand's type reaches HBM.  The backward kernel rebuilds the
+# pre-activation from ``x``, forms its gradient and in the same pass writes
+# the data's gradient (the taps shifted the other way, zeros past the end)
+# and, a batch row at a time, every channel's float32 sums for the taps and
+# the bias, which are added over the batch outside.  The differentiated
+# forward keeps nothing but its inputs.
+#
+# The input may be a wider projection whose channels from ``begin`` on are
+# convolved (the Mamba-2 mixer's ``x B C`` between ``z`` and ``dt``), and the
+# result may leave as several arrays cut along channels (``x``, ``B`` and
+# ``C``): a slice handed to a custom call, or out of one, is a copy.  Every
+# cut is a whole number of channel blocks; an output's block index stands
+# still outside its own range, so it is written back once, after the step
+# that filled it (and the channel axis runs in order).
+
+# the most elements (channels x positions) a grid step's block may hold: each
+# float32 intermediate of a block of 64 channels at 8,192 positions is 2 MiB.
+# At the hybrid cells' shapes (v5e, bf16, tools/conv_check.py) blocks of 16
+# to 64 channels run within 3% of one another, 128 take 1.7 times as long
+# differentiated, and 256 do not fit VMEM
+_CONV_BLOCK = 64 * 8192
+# past this the smallest block's float32 intermediates outgrow VMEM
+_CONV_MAX_BLOCK = 16 * 65536
+_CONV_VMEM_LIMIT = 64 << 20
+
+
+def conv_channel_block(bounds, length, itemsize):
+    """Channels a grid step of the convolution kernels takes: the most that
+    divide every one of ``bounds`` (the channel count, where the convolved
+    channels begin and every cut of the result) in whole tiles of rows
+    (8 float32, 16 bf16) within ``_CONV_BLOCK`` elements at ``length``
+    positions, the fewest such where none fits; None where no whole tile
+    divides them all or the fewest do not fit ``_CONV_MAX_BLOCK``."""
+    g = functools.reduce(math.gcd, bounds)
+    tile = 32 // itemsize
+    blocks = [d for d in range(tile, g + 1, tile) if g % d == 0]
+    if not blocks or blocks[0] * length > _CONV_MAX_BLOCK:
+        return None
+    fits = [d for d in blocks if d * length <= _CONV_BLOCK]
+    return max(fits) if fits else blocks[0]
+
+
+def _conv_shift(t, j, later=True):
+    """``t`` (channels, positions) moved ``j`` positions later, or earlier
+    where not ``later``; zeros where nothing arrives."""
+    if j == 0:
+        return t
+    n = t.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
+    if later:
+        return jnp.where(lane >= j, pltpu.roll(t, j, 1), 0.0)
+    return jnp.where(lane < n - j, pltpu.roll(t, n - j, 1), 0.0)
+
+
+def _conv_pre(x, wb, taps, bias):
+    """The float32 pre-activation of a block, the taps added in the order
+    XLA's expression adds them, then the bias (``wb``'s last column)."""
+    pre = None
+    for k in range(taps):
+        term = wb[:, k:k + 1] * _conv_shift(x, taps - 1 - k)
+        pre = term if pre is None else pre + term
+    return wb[:, taps:taps + 1] + pre if bias else pre
+
+
+def _conv_in_range(refs, starts, fill):
+    """``fill(ref)`` for the one of ``refs`` whose blocks ``starts[i]`` to
+    ``starts[i + 1]`` hold this step's channel block."""
+    if len(refs) == 1:
+        fill(refs[0])
+        return
+    block = pl.program_id(1)
+    for ref, lo, hi in zip(refs, starts, starts[1:]):
+        pl.when((block >= lo) & (block < hi))(functools.partial(fill, ref))
+
+
+def _conv_fwd_kernel(x_ref, wb_ref, *out_refs, taps, bias, silu, starts):
+    pre = _conv_pre(x_ref[...].astype(jnp.float32), wb_ref[...], taps, bias)
+    y = (pre * jax.nn.sigmoid(pre) if silu else pre).astype(out_refs[0].dtype)
+
+    def fill(ref):
+        ref[...] = y
+
+    _conv_in_range(out_refs, starts, fill)
+
+
+def _conv_bwd_kernel(x_ref, wb_ref, *refs, taps, bias, silu, starts):
+    dy_refs, (dx_ref, dwb_ref) = refs[:-2], refs[-2:]
+    f32 = jnp.float32
+    x, wb = x_ref[...].astype(f32), wb_ref[...]
+    col = jax.lax.broadcasted_iota(jnp.int32, dwb_ref.shape, 1)
+
+    def fill(dy_ref):
+        dpre = dy_ref[...].astype(f32)
+        if silu:
+            pre = _conv_pre(x, wb, taps, bias)
+            s = jax.nn.sigmoid(pre)
+            dpre = dpre * s * (1.0 + pre * (1.0 - s))
+        dx, dwb = None, jnp.zeros(dwb_ref.shape, f32)
+        for k in range(taps):
+            j = taps - 1 - k
+            term = wb[:, k:k + 1] * _conv_shift(dpre, j, later=False)
+            dx = term if dx is None else dx + term
+            dwb = jnp.where(col == k, jnp.sum(dpre * _conv_shift(x, j), axis=1,
+                                              keepdims=True), dwb)
+        if bias:
+            dwb = jnp.where(col == taps, jnp.sum(dpre, axis=1, keepdims=True),
+                            dwb)
+        dx_ref[...] = dx.astype(dx_ref.dtype)
+        dwb_ref[...] = dwb
+
+    _conv_in_range(dy_refs, starts, fill)
+
+
+def _conv_call(kernel, x, wb, dys, plan):
+    """``pallas_call`` over (batch row, channel block).  ``plan`` is
+    ``(taps, bias, silu, begin, splits, block)``; the forward (no ``dys``)
+    writes one array a cut, the backward reads one cotangent a cut and
+    writes the convolved channels' gradient and the (batch, channels,
+    columns of ``wb``) float32 sums."""
+    taps, bias, silu, begin, splits, block = plan
+    bsz, _, length = x.shape
+    starts = [0]
+    for width in splits:
+        starts.append(starts[-1] + width // block)
+    first = begin // block
+
+    def rows(index):
+        return pl.BlockSpec((None, block, length), index)
+
+    def cut(s):
+        lo, n = starts[s], starts[s + 1] - starts[s]
+        if n == starts[-1]:
+            return rows(lambda b, i: (b, i, 0))
+        return rows(lambda b, i: (
+            b, jax.lax.min(jax.lax.max(i - lo, 0), n - 1), 0))
+
+    in_specs = [rows(lambda b, i: (b, first + i, 0)),
+                pl.BlockSpec((block, wb.shape[1]), lambda b, i: (i, 0))]
+    if dys:
+        in_specs += [cut(s) for s in range(len(splits))]
+        out_specs = [rows(lambda b, i: (b, i, 0)),
+                     pl.BlockSpec((None, block, wb.shape[1]),
+                                  lambda b, i: (b, i, 0))]
+        out_shape = [jax.ShapeDtypeStruct((bsz, sum(splits), length),
+                                          x.dtype),
+                     jax.ShapeDtypeStruct((bsz,) + wb.shape, jnp.float32)]
+    else:
+        out_specs = [cut(s) for s in range(len(splits))]
+        out_shape = [jax.ShapeDtypeStruct((bsz, width, length), x.dtype)
+                     for width in splits]
+    return pl.pallas_call(
+        functools.partial(kernel, taps=taps, bias=bias, silu=silu,
+                          starts=tuple(starts)),
+        grid=(bsz, starts[-1]), in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_CONV_VMEM_LIMIT),
+        interpret=_interpret())(x, wb, *dys)
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _conv_forward(x, wb, plan):
+    return tuple(_conv_call(_conv_fwd_kernel, x, wb, (), plan))
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _conv_backward(x, wb, plan, dys):
+    begin, channels = plan[3], sum(plan[4])
+    dx, dwb = _conv_call(_conv_bwd_kernel, x, wb, tuple(dys), plan)
+    if begin or channels != x.shape[1]:
+        dx = jnp.pad(dx, ((0, 0), (begin, x.shape[1] - begin - channels),
+                          (0, 0)))
+    return dx, jnp.sum(dwb, axis=0).astype(wb.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _conv(x, wb, plan):
+    return _conv_forward(x, wb, plan)
+
+
+def _conv_fwd(x, wb, plan):
+    return _conv_forward(x, wb, plan), (x, wb)
+
+
+def _conv_bwd(plan, res, dys):
+    return _conv_backward(*res, plan, dys)
+
+
+_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def causal_conv_lanes(x, weight, bias=None, activation=None, begin=0,
+                      splits=None):
+    """The causal depthwise convolution of ``ops/ssm.py:causal_conv1d``, the
+    sequence along the last axis: ``x`` (batch, channels, length); the
+    ``weight`` (channels convolved, kernel) rows convolve ``x``'s channels
+    from ``begin`` on, ``bias`` (channels convolved,) or None, ``activation``
+    ``"silu"`` or None.  Returns a tuple of (batch, width, length) arrays of
+    ``x``'s type, the result cut along channels at ``splits`` (one piece by
+    default); differentiable in ``x``, ``weight`` and ``bias``.  Any length
+    (padded to whole 128-lane columns inside); on a TPU every cut a whole
+    number of ``conv_channel_block`` channels."""
+    channels, taps = weight.shape
+    splits = (channels,) if splits is None else tuple(int(s) for s in splits)
+    length = x.shape[2]
+    if sum(splits) != channels or begin + channels > x.shape[1] \
+            or (bias is not None and bias.shape != (channels,)) \
+            or activation not in (None, "silu"):
+        raise ValueError(f"causal_conv_lanes cannot take x {x.shape}, "
+                         f"weight {weight.shape}, begin {begin}, splits "
+                         f"{splits}, activation {activation!r}")
+    padded = length + (-length % 128)
+    block = conv_channel_block((channels, begin) + splits, padded,
+                               x.dtype.itemsize)
+    if block is None:
+        raise ValueError(f"causal_conv_lanes: no block of channels divides "
+                         f"{(channels, begin) + splits} at {padded} positions")
+    if padded != length:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, padded - length)))
+    wb = weight if bias is None else jnp.concatenate(
+        [weight, bias[:, None].astype(weight.dtype)], axis=1)
+    outs = _conv(x, wb.astype(jnp.float32),
+                 (taps, bias is not None, activation == "silu", begin,
+                  splits, block))
+    return tuple(o[:, :, :length] for o in outs) if padded != length \
+        else outs

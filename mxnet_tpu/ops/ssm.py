@@ -21,6 +21,12 @@ does (``ops/contrib.py``): ``ssm.scan_fused`` / ``ssm.scan_unfused`` count
 the sites, and a TPU trace that leaves the kernels says so with a
 ``fallback`` event.
 
+The short causal depthwise convolution in front of the scan (and of the
+gated delta rule) is decided the same way: a Pallas kernel pair
+(``pallas_kernels.causal_conv_lanes``) on a TPU with no mesh, counted by
+``ssm.conv_fused`` / ``ssm.conv_unfused``; XLA's shifted multiply-adds
+anywhere else.
+
 The norms take and return the type that arrives (bf16 under AMP), float32
 inside, as ``BatchNorm`` does since PR 29 (``amp/lists.py``).
 """
@@ -239,21 +245,46 @@ def ssd_scan_sequential(x, dt, A, B, C, D):
     return y.swapaxes(0, 1) + D.astype(_F32)[:, None] * x
 
 
-@register("causal_conv1d", num_inputs=3)
-def causal_conv1d(data, weight, bias=None, activation=None):
-    """Depthwise causal convolution over a sequence: ``data`` (batch,
-    length, channels), ``weight`` (channels, kernel), ``bias`` (channels,)
-    or none:
-    ``out[t] = bias + sum_k weight[:, k] * data[t - (kernel - 1) + k]``,
-    positions before the start read as zero.  ``activation='silu'`` applies
-    ``x * sigmoid(x)`` to the result.  Computed as ``kernel`` shifted
-    multiply-adds in float32, which XLA fuses with what stands around them.
-    (One grouped ``Convolution`` with as many groups as channels, the same
-    arithmetic, read 5 ms a step less under its own scope on the v5e and
-    cost the step 1.9% of its rate and 0.73 GiB: PERF.md, PR 31.)"""
+_CONV_FUSED = _telemetry.counter(
+    "ssm.conv_fused",
+    "causal depthwise convolution sites traced onto the Pallas kernels")
+_CONV_UNFUSED = _telemetry.counter(
+    "ssm.conv_unfused",
+    "causal depthwise convolution sites traced as XLA's shifted "
+    "multiply-adds")
+
+
+def _conv_platform() -> str:
+    return jax.default_backend()
+
+
+def _fused_conv_refusal(data, channels, begin, splits):
+    """Why a TPU trace cannot take the Pallas kernels at this site, or None:
+    ``pallas_call`` has no partitioning rule, and a grid step takes a whole
+    number of tiles of rows of every cut and of the convolved channels."""
+    from ..parallel.mesh import current_mesh
+    from . import pallas_kernels as _pk
+
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"mesh of {mesh.size} devices"
+    length = data.shape[1] + (-data.shape[1] % 128)
+    if _pk.conv_channel_block((channels, begin) + splits, length,
+                              data.dtype.itemsize) is None:
+        return (f"no block of whole {32 // data.dtype.itemsize}-row tiles "
+                f"divides {(channels, begin) + splits} at {length} positions "
+                f"within VMEM")
+    return None
+
+
+def _conv_shifted(data, weight, bias, activation, begin, splits):
+    """XLA's form: ``kernel`` shifted multiply-adds in float32 over the
+    convolved channels, the result cut at ``splits``."""
     channels, kernel = weight.shape
     length = data.shape[1]
     with jax.named_scope(CONV_SCOPE):
+        if begin or channels != data.shape[2]:
+            data = data[..., begin:begin + channels]
         padded = jnp.pad(data, ((0, 0), (kernel - 1, 0), (0, 0))) \
             .astype(_F32)
         w = weight.astype(_F32)
@@ -262,10 +293,70 @@ def causal_conv1d(data, weight, bias=None, activation=None):
             out = bias.astype(_F32) + out
         if activation == "silu":
             out = out * jax.nn.sigmoid(out)
-        elif activation is not None:
-            raise ValueError(f"causal_conv1d: unknown activation "
-                             f"{activation!r}")
-        return out.astype(data.dtype)
+        out = out.astype(data.dtype)
+        if len(splits) == 1:
+            return (out,)
+        at = [0]
+        for width in splits:
+            at.append(at[-1] + width)
+        return tuple(out[..., lo:hi] for lo, hi in zip(at, at[1:]))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("activation", "begin", "splits"))
+def _conv_fused(data, weight, bias, activation, begin, splits):
+    """The Pallas kernel pair (``pallas_kernels.causal_conv_lanes``) over
+    ``data`` turned position-minor, as XLA lays the mixers out: the
+    transposes are dimension orders, not copies."""
+    from . import pallas_kernels as _pk
+
+    with jax.named_scope(CONV_SCOPE):
+        return tuple(o.swapaxes(1, 2) for o in _pk.causal_conv_lanes(
+            data.swapaxes(1, 2), weight, bias, activation, begin, splits))
+
+
+@register("causal_conv1d", num_inputs=3)
+def causal_conv1d(data, weight, bias=None, activation=None, begin=0,
+                  splits=None):
+    """Depthwise causal convolution over a sequence: ``data`` (batch,
+    length, channels), ``weight`` (channels, kernel), ``bias`` (channels,)
+    or none:
+    ``out[t] = bias + sum_k weight[:, k] * data[t - (kernel - 1) + k]``,
+    positions before the start read as zero.  ``activation='silu'`` applies
+    ``x * sigmoid(x)`` to the result.  With ``begin`` the ``weight``'s rows
+    convolve ``data``'s channels from there on (a mixer's projection that
+    holds more than the convolved channels); with ``splits`` the result is a
+    tuple of arrays cut along channels at those widths.
+
+    On a TPU with no mesh of more than one device the Pallas kernel pair
+    (``pallas_kernels.causal_conv_lanes``: the shifts, the float32
+    multiply-adds, the bias and the SiLU in VMEM, and one backward pass for
+    the data's, the taps' and the bias's gradients); anywhere else
+    ``kernel`` shifted multiply-adds in float32, which XLA fuses with what
+    stands around them and which the kernels are tested against.  Counted
+    on ``ssm.conv_fused`` / ``ssm.conv_unfused``, a site once a trace; a TPU
+    trace that leaves the kernels emits a ``fallback`` event.  (On the v5e
+    at the Mamba-2 cells' widths XLA's form ran 5.7 to 7.7 ms a layer and
+    training step, the float32 round trips and the padded copy in HBM; the
+    kernels run 1.5 to 2.0: PERF.md, section 5.)"""
+    channels = weight.shape[0]
+    if activation not in (None, "silu"):
+        raise ValueError(f"causal_conv1d: unknown activation "
+                         f"{activation!r}")
+    begin = int(begin)
+    cuts = (channels,) if splits is None else tuple(int(s) for s in splits)
+    fused = _conv_platform() == "tpu"
+    if fused:
+        refusal = _fused_conv_refusal(data, channels, begin, cuts)
+        if refusal is not None:
+            fused = False
+            _telemetry.event("fallback", "ssm.conv_fused",
+                             length=data.shape[1], channels=channels,
+                             why=refusal)
+    (_CONV_FUSED if fused else _CONV_UNFUSED).inc()
+    out = (_conv_fused if fused else _conv_shifted)(
+        data, weight, bias, activation, begin, cuts)
+    return out if splits is not None else out[0]
 
 
 def _rms(x32, eps):

@@ -717,3 +717,125 @@ def test_mosaic_compiles_the_granite_cells_kernels(one_chip, monkeypatch):
     moved = telemetry.delta(base)
     assert (moved["attention.gqa_backward_fused"],
             moved["attention.gqa_backward_split"]) == (1, 0)
+
+
+# the three hybrid cells' convolutions: (batch, projection width, convolved
+# channels from, cuts of the result, bias)
+CONV_CELLS = {
+    "nemotron_xbc": (1, 10304, 4096, (4096, 1024, 1024), True),
+    "granite_xbc": (2, 4384, 2048, (2048, 128, 128), True),
+    "olmo_keys": (1, 1440, 0, (1440,), False),
+    "olmo_values": (1, 2880, 0, (2880,), False)}
+
+
+@pytest.mark.parametrize("cell", list(CONV_CELLS))
+def test_mosaic_compiles_the_conv_kernels(one_chip, monkeypatch, cell):
+    """``causal_conv1d``'s kernel pair at the hybrid cells' widths, bf16,
+    8,192 positions, forward and backward: the Mamba-2 mixers' convolution
+    read out of the whole ``in_proj`` output and cut into x, B and C, the
+    delta-rule mixer's three (1,440 and 2,880 channels, no bias).  Forward:
+    one Mosaic call; differentiated with the output kept: the forward and
+    the ONE backward call, both under ``CausalConv1d``, the scope the
+    benchmark's mixer metrics read."""
+    from mxnet_tpu.ops import ssm
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    bsz, width, begin, splits, bias = CONV_CELLS[cell]
+    channels = sum(splits)
+    assert pk.conv_channel_block((channels, begin) + splits, 8192, 2) \
+        == (48 if channels == 1440 else 64)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (spec((bsz, 8192, width)), spec((channels, 4), jnp.float32),
+            spec((channels,), jnp.float32) if bias else None)
+
+    def conv(x, w, b):
+        return ssm._conv_fused(x, w, b, "silu", begin, splits)
+
+    def loss(x, w, b):
+        return sum(jnp.sum(o.astype(jnp.float32)) for o in conv(x, w, b))
+
+    assert _compiled_text(conv, *args).count("tpu_custom_call") == 1
+    text = _compiled_text(jax.value_and_grad(
+        loss, argnums=(0, 1, 2) if bias else (0, 1)), *args)
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*'
+                       r'op_name="([^"]*)"', text)
+    assert len(calls) == 2
+    assert all(ssm.CONV_SCOPE in name for name in calls), calls
+
+
+@pytest.mark.parametrize("mixer,calls,copies", [
+    ("nemotron", 4, 0), ("granite", 4, 0), ("olmo", 8, 8)])
+def test_mosaic_mixer_layers_copy_no_more_for_the_conv_kernels(
+        one_chip, monkeypatch, mixer, calls, copies):
+    """One mixer layer at its cell's widths under ``amp.init``, recomputed
+    as ``remat_call`` stages every layer, differentiated, every kernel
+    forced: the Mamba-2 mixers hand their whole projection to the
+    convolution and its x, B and C to the scan with no activation-sized
+    copy in between (none either way, as under XLA's convolution); the
+    delta-rule mixer makes the 8 copies of its L2 norms and rule it made
+    under XLA's convolution, none larger."""
+    from mxnet_tpu.gluon import block as gblock
+    from mxnet_tpu.gluon.model_zoo.nemotron_h import NemotronHMamba2Mixer
+    from mxnet_tpu.ops import delta_rule, ssm
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    monkeypatch.setattr(pmesh, "current_mesh", lambda: None)
+    for module, name in ((ssm, "_conv_platform"), (ssm, "_scan_platform"),
+                         (delta_rule, "_rule_platform")):
+        monkeypatch.setattr(module, name, lambda: "tpu")
+    if mixer == "nemotron":
+        layer, bsz, hidden = NemotronHMamba2Mixer(2688, 64, 64, 8, 128), 1, \
+            2688
+    elif mixer == "granite":
+        layer, bsz, hidden = NemotronHMamba2Mixer(
+            2048, 32, 64, 1, 128, chunk_size=256), 2, 2048
+    else:
+        layer, bsz, hidden = gluon.nn.GatedDeltaNet(
+            3840, 15, 96, 192, allow_neg_eigval=True), 1, 3840
+
+    class Recomputed(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            self.layer = layer
+
+        def forward(self, x):
+            return gblock.remat_call([self.layer], x)
+
+    net = Recomputed()
+    net.initialize()
+    params = net.collect_params()
+    x = nd.zeros((1, 8, hidden), dtype="bfloat16")
+    raw_fn, _, _ = gblock._stage_fn(net, params, list(params),
+                                    gblock._flatten_args((x,))[1], True,
+                                    x.ctx)
+
+    def loss(weights, x, key):
+        (out,), _ = raw_fn(weights, [x], key)
+        return out.astype(jnp.float32).sum()
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    base = telemetry.snapshot()
+    amp.init("bfloat16")
+    try:
+        text = _compiled_text(
+            jax.grad(loss, argnums=(0, 1)),
+            [shape(p.shape) for p in params.values()],
+            shape((bsz, 8192, hidden), jnp.bfloat16),
+            shape((2,), jnp.uint32))
+    finally:
+        amp.uninit()
+    assert telemetry.delta(base)["ssm.conv_fused"] == \
+        (3 if mixer == "olmo" else 1)
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+    entry = text[text.index("\nENTRY "):]
+    copied = [dims for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(",
+                                          entry)
+              if math.prod(map(int, dims.split(","))) >= bsz * 8192 * 1024]
+    assert len(copied) == copies, copied
+    assert all(math.prod(map(int, d.split(","))) <= bsz * 8192 * 2880
+               for d in copied), copied

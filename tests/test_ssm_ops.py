@@ -318,6 +318,226 @@ def test_causal_conv1d_gradients_and_bf16():
                                atol=0.05)
 
 
+# -- the convolution's Pallas kernels, under the interpreter ------------------
+def _conv_args(b, l, c, kernel, dtype, bias, seed=0, total=None):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.standard_normal((b, l, total or c)), dtype)
+    w = jnp.asarray(rng.standard_normal((c, kernel)) / kernel, jnp.float32)
+    bias = jnp.asarray(rng.standard_normal((c,)), jnp.float32) if bias \
+        else None
+    return x, w, bias
+
+
+def _kernel_conv(x, w, b, activation, begin=0, splits=None):
+    out = ssm._conv_fused(x, w, b, activation, begin,
+                          splits or (w.shape[0],))
+    return out if splits else out[0]
+
+
+def _xla_conv(x, w, b, activation, begin=0, splits=None):
+    return ssm.causal_conv1d(x, w, b, activation=activation, begin=begin,
+                             splits=splits)
+
+
+def _conv_grads(fn, args, seed=99):
+    """The output(s) and the gradients of the data, the taps and the bias
+    (where there is one) under fixed cotangents that bf16 holds exactly."""
+    outs = fn(*args)
+    outs = outs if isinstance(outs, tuple) else (outs,)
+    cts = [jax.random.normal(jax.random.PRNGKey(seed + i), o.shape)
+           .astype(jnp.bfloat16).astype(jnp.float32)
+           for i, o in enumerate(outs)]
+
+    def loss(*a):
+        got = fn(*a)
+        got = got if isinstance(got, tuple) else (got,)
+        return sum(jnp.sum(o.astype(jnp.float32) * ct)
+                   for o, ct in zip(got, cts))
+
+    wrt = (0, 1) if args[2] is None else (0, 1, 2)
+    return outs, jax.grad(loss, argnums=wrt)(*args)
+
+
+@pytest.mark.parametrize("kernel,bias,activation", [
+    (4, True, "silu"), (4, False, "silu"), (4, True, None), (2, False, None),
+    (2, True, "silu")])
+@pytest.mark.parametrize("dtype,limit", [(jnp.float32, 2e-6),
+                                         (jnp.bfloat16, 2 ** -7)],
+                         ids=["float32", "bfloat16"])
+def test_conv_kernels_equal_xlas_expression(kernel, bias, activation, dtype,
+                                            limit, monkeypatch):
+    """Forward and the gradients of data, taps and bias, against XLA's
+    shifted multiply-adds: 37 positions, under one 128-lane column (padded
+    inside), two batch rows, three blocks of 16 channels.  float32 to
+    rounding; bf16 within a rounding of the output's type (both take the
+    same bf16 operands, float32 inside)."""
+    monkeypatch.setattr(pk, "_CONV_BLOCK", 16 * 128)
+    assert pk.conv_channel_block((48, 0, 48), 128,
+                                 jnp.dtype(dtype).itemsize) == 16
+    args = _conv_args(2, 37, 48, kernel, dtype, bias)
+    (got,), got_grads = _conv_grads(
+        lambda *a: _kernel_conv(*a, activation), args)
+    (want,), want_grads = _conv_grads(
+        lambda *a: _xla_conv(*a, activation), args)
+    assert got.shape == want.shape and got.dtype == dtype
+    assert _worst(got, want) < limit
+    for g, w, a, name in zip(got_grads, want_grads, args, ("x", "w", "b")):
+        assert g.shape == a.shape and g.dtype == a.dtype, name
+        assert _worst(g, w) < limit, name
+
+
+@pytest.mark.parametrize("channels,begin,splits,total", [
+    (1440, 0, None, 1440), (2880, 0, None, 2880),
+    (2304, 2048, (2048, 128, 128), 4384)],
+    ids=["olmo_keys", "olmo_values", "granite_xbc"])
+def test_conv_kernels_at_the_cells_channel_counts(channels, begin, splits,
+                                                  total, monkeypatch):
+    """The delta-rule cell's 1,440 and 2,880 channels (no multiple of 128)
+    and the Granite mixer's 2,304 read out of its 4,384-wide projection and
+    cut into x, B and C, at a toy length, bf16, in blocks of 16 channels
+    (forced small: a block's index maps, and the cuts' blocks that stand
+    still outside their own range, are run many times)."""
+    monkeypatch.setattr(pk, "_CONV_BLOCK", 16 * 128)
+    assert pk.conv_channel_block(
+        (channels, begin) + (splits or (channels,)), 128, 2) == 16
+    args = _conv_args(1, 20, channels, 4, jnp.bfloat16, begin > 0, seed=3,
+                      total=total)
+    got, got_grads = _conv_grads(
+        lambda *a: _kernel_conv(*a, "silu", begin, splits), args)
+    want, want_grads = _conv_grads(
+        lambda *a: _xla_conv(*a, "silu", begin, splits), args)
+    assert len(got) == len(splits or (channels,))
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and _worst(g, w) < 2 ** -7
+    for g, w, name in zip(got_grads, want_grads, ("x", "w", "b")):
+        assert _worst(g, w) < 2 ** -7, name
+    if begin:       # nothing flows into the projection's other channels
+        dx = np.asarray(got_grads[0], np.float32)
+        assert not dx[..., :begin].any() and not dx[..., begin + channels:] \
+            .any()
+
+
+def test_conv_kernels_read_the_projection_where_it_lies():
+    """One Mosaic call forward, which reads the WHOLE projection, (batch,
+    channels, length), and writes x, B and C as three arrays; differentiated,
+    the backward call reads the three cotangents as they come and the
+    forward keeps nothing for it but its inputs (no call writes more than
+    the three pieces)."""
+    args = _conv_args(2, 128, 64, 4, jnp.float32, True, total=88)
+
+    def conv(x, w, b):
+        return ssm._conv_fused.__wrapped__(x, w, b, "silu", 16, (32, 16, 16))
+
+    forward = list(_pallas_calls(jax.make_jaxpr(conv)(*args).jaxpr))
+    assert len(forward) == 1
+    assert tuple(forward[0].invars[0].aval.shape) == (2, 88, 128)
+    assert [tuple(v.aval.shape) for v in forward[0].outvars] == \
+        [(2, 32, 128), (2, 16, 128), (2, 16, 128)]
+    both = list(_pallas_calls(jax.make_jaxpr(jax.grad(
+        lambda *a: sum(jnp.sum(o) for o in conv(*a)), argnums=(0, 1, 2)))(
+        *args).jaxpr))
+    assert len(both) == 2
+    assert len(both[0].outvars) == 3
+    backward = both[1]
+    assert [tuple(v.aval.shape) for v in backward.invars[2:]] == \
+        [(2, 32, 128), (2, 16, 128), (2, 16, 128)]
+    assert [tuple(v.aval.shape) for v in backward.outvars] == \
+        [(2, 64, 128), (2, 64, 5)]             # dx, and taps + bias a row
+
+
+def test_causal_conv1d_operator_both_paths(monkeypatch):
+    """The registered operator on the CPU, then as a TPU trace would take it:
+    a channel count no tile divides says so and still computes, one the
+    kernels take counts a fused site, and a mesh of more than one device
+    takes XLA's form and says so."""
+    from mxnet_tpu.parallel import mesh as mesh_mod
+
+    def fallbacks():
+        return [e for e in mx.telemetry.events("fallback")
+                if e["name"] == "ssm.conv_fused"]
+
+    def run(args, **attrs):
+        base = mx.telemetry.snapshot()
+        out = mx.nd.causal_conv1d(*[mx.nd.array(np.asarray(t))
+                                    for t in args], **attrs)
+        moved = mx.telemetry.delta(base)
+        return out, moved.get("ssm.conv_fused", 0), \
+            moved.get("ssm.conv_unfused", 0)
+
+    narrow = _conv_args(2, 11, 6, 4, jnp.float32, True, seed=5)
+    want = _xla_conv(*narrow, "silu")
+    out, fused, unfused = run(narrow, activation="silu")
+    np.testing.assert_allclose(out.asnumpy(), want, atol=1e-6)
+    assert (fused, unfused) == (0, 1)
+
+    monkeypatch.setattr(ssm, "_conv_platform", lambda: "tpu")
+    monkeypatch.setattr(mesh_mod, "current_mesh", lambda: None)
+    before = len(fallbacks())
+    out, fused, unfused = run(narrow, activation="silu")
+    np.testing.assert_allclose(out.asnumpy(), want, atol=1e-6)
+    assert (fused, unfused) == (0, 1)
+    assert len(fallbacks()) == before + 1
+    assert "8-row tiles" in fallbacks()[-1]["why"]
+    wide = _conv_args(1, 40, 32, 4, jnp.float32, True, seed=6)
+    out, fused, unfused = run(wide, activation="silu")
+    np.testing.assert_allclose(out.asnumpy(), _xla_conv(*wide, "silu"),
+                               atol=2e-6)
+    assert (fused, unfused) == (1, 0) and len(fallbacks()) == before + 1
+
+    class Mesh:
+        size = 4
+
+    monkeypatch.setattr(mesh_mod, "current_mesh", Mesh)
+    out, fused, unfused = run(wide, activation="silu")
+    np.testing.assert_allclose(out.asnumpy(), _xla_conv(*wide, "silu"),
+                               atol=2e-6)
+    assert (fused, unfused) == (0, 1)
+    assert "mesh of 4" in fallbacks()[-1]["why"]
+
+
+@pytest.mark.parametrize("mixer", ["mamba2", "delta_net"])
+def test_the_mixers_take_the_conv_kernels(mixer, monkeypatch):
+    """Both callers, forced onto the kernels (the scan and the rule stay on
+    XLA's form): the Mamba-2 mixer hands its whole projection over and
+    takes x, B and C back, the delta-rule mixer three projections; the
+    layer's output and every parameter's gradient equal XLA's form's, and
+    every site counts a fused convolution."""
+    from mxnet_tpu.gluon.model_zoo import nemotron_h as nh
+
+    mx.random.seed(0)
+    if mixer == "mamba2":
+        net = nh.NemotronHMamba2Mixer(32, 4, 8, 1, 16, chunk_size=8)
+        sites = 1
+    else:
+        net = mx.gluon.nn.GatedDeltaNet(32, 2, 16, 24, chunk_size=8)
+        sites = 3
+    net.initialize()
+    x = mx.nd.array(np.random.default_rng(7).standard_normal((2, 19, 32))
+                    .astype(np.float32))
+    params = net.collect_params()
+
+    def run(platform):
+        monkeypatch.setattr(ssm, "_conv_platform", lambda: platform)
+        base = mx.telemetry.snapshot()
+        with mx.autograd.record():
+            y = net(x)
+            loss = (y * y).sum()
+        loss.backward()
+        moved = mx.telemetry.delta(base)
+        return (y.asnumpy(), {n: p.grad().asnumpy()
+                              for n, p in params.items()},
+                moved.get("ssm.conv_fused", 0))
+
+    y_k, g_k, fused = run("tpu")
+    y_x, g_x, unfused = run("cpu")
+    assert (fused, unfused) == (sites, 0)
+    np.testing.assert_allclose(y_k, y_x, atol=2e-5 * np.abs(y_x).max())
+    for name in params:
+        np.testing.assert_allclose(
+            g_k[name], g_x[name], atol=5e-5 * max(np.abs(g_x[name]).max(), 1),
+            err_msg=name)
+
+
 def test_rms_norms_follow_their_input_type():
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((2, 5, 16)), jnp.float32)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as onp
 
+from .. import telemetry as _telemetry
 from .random import keep_mask
 from .registry import register
 from .tensor import pick_index
@@ -143,10 +144,93 @@ def fully_connected(arrays, num_hidden=0, no_bias=False, flatten=True,
     int8 graph pass when a following relu folded into this node."""
     data, weight = arrays[0], arrays[1]
     x = data.reshape(data.shape[0], -1) if flatten else data
-    out = jnp.matmul(x, weight.T)
+    sides = _wgrad_sides(weight.shape)
+    for written in sides:
+        (_WGRAD_MATERIALISED if written else _WGRAD_FUSED).inc()
+    out = dense_product(x, weight, *sides)
     if not no_bias:
         out = out + arrays[2]
     return jnp.maximum(out, 0) if fused_relu else out
+
+
+# --- the dense product's weight gradient -----------------------------------
+#
+# In ``dW = dy^T x``, contracted over every token, XLA fuses an operand's
+# elementwise producer (a norm with its residual, SwiGLU's product, the
+# gradient of the norm behind the layer, the softmax gradient) into the
+# product, and each output tile then streams that operand over all tokens
+# again: the producer is re-run from its inputs, often twice the operand's
+# bytes, once for every tile along the OTHER operand's width.  Written once
+# behind an optimization barrier (XLA cannot fuse through one), an operand
+# costs a write and a read.  On the v5e (``tools/dense_wgrad_check.py``: one
+# layer's training step with its producers at every cell's shapes; PERF.md,
+# section 6) writing both operands made the step faster at all five
+# products that span 8 output tiles or more on each side, at three of the
+# five that span 7 and at three of the fourteen narrower ones; the rule
+# writes both where both widths reach 8 tiles.  The forward product, the
+# data gradient and the bias gradient stay as XLA fuses them.
+
+WGRAD_TILE = 384            # the output tile's columns in these fusions
+WGRAD_MIN_TILES = 8         # on each side of a product whose operands are
+                            # written
+
+
+def _wgrad_sides(weight_shape):
+    """(write the activation, write the output's gradient) for the weight
+    gradient of a product with a weight of this shape."""
+    wide = all(-(-int(w) // WGRAD_TILE) >= WGRAD_MIN_TILES
+               for w in weight_shape)
+    return wide, wide
+
+
+_WGRAD_MATERIALISED = _telemetry.counter(
+    "dense.wgrad_materialised",
+    "dense weight-gradient operands written once behind a barrier (a site's "
+    "activation and its output's gradient count apart, once a trace)")
+_WGRAD_FUSED = _telemetry.counter(
+    "dense.wgrad_fused",
+    "dense weight-gradient operands left for XLA to fuse with their "
+    "producers (a site's activation and its output's gradient apart, once a "
+    "trace)")
+
+
+def _product(x, weight):
+    return jnp.matmul(x, weight.T)
+
+
+def _transposed(fn, primal, cotangent):
+    return jax.linear_transpose(fn, primal)(cotangent)[0]
+
+
+def _dense_product_fwd(x, weight, barrier_x, barrier_dy):
+    return _product(x, weight), (x, weight)
+
+
+def _dense_product_bwd(barrier_x, barrier_dy, res, dy):
+    x, weight = res
+    dx = _transposed(lambda x: _product(x, weight), x, dy)
+    if barrier_x:
+        x = jax.lax.optimization_barrier(x)
+    if barrier_dy:
+        dy = jax.lax.optimization_barrier(dy)
+    return dx, _transposed(lambda w: _product(x, w), weight, dy)
+
+
+_barriered_product = jax.custom_vjp(
+    lambda x, weight, barrier_x, barrier_dy: _product(x, weight),
+    nondiff_argnums=(2, 3))
+_barriered_product.defvjp(_dense_product_fwd, _dense_product_bwd)
+
+
+def dense_product(x, weight, barrier_x=False, barrier_dy=False):
+    """``x @ weight.T``.  Its weight gradient reads ``x`` (``barrier_x``)
+    and the output's gradient (``barrier_dy``) as written arrays where
+    asked: the same products in the same precision (bit for bit on the CPU;
+    on the TPU another tiling may add the float32 partial sums in another
+    order)."""
+    if not (barrier_x or barrier_dy):
+        return _product(x, weight)
+    return _barriered_product(x, weight, barrier_x, barrier_dy)
 
 
 def _conv_dimension_numbers(layout: str):
@@ -176,8 +260,6 @@ def _conv_dimension_numbers(layout: str):
 # fp32/bf16, 32 for int8 (the int8 path applies it in
 # contrib/quantization.py quantized_conv).  Bit-exactness is asserted by
 # tests/test_pad_channels.py.
-
-from .. import telemetry as _telemetry  # noqa: E402
 
 _PAD_CHANNELS = _telemetry.counter(
     "nn.pad_channels", "convolutions the MXU-alignment pass padded "

@@ -139,28 +139,7 @@ def main():
                     help="sweep every registered op with generic inputs")
     ap.add_argument("--runs", type=int, default=20)
     ap.add_argument("--output", default=None)
-    ap.add_argument("--eager-latency", action="store_true",
-                    help="run the eager-dispatch A/B lane (per-op jit "
-                         "cache vs plain dispatch, benchmark/"
-                         "eager_latency.py) instead of the op sweep")
     args = ap.parse_args()
-
-    if args.eager_latency:
-        import subprocess
-
-        script = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "eager_latency.py")
-        cmd = [sys.executable, script, "--ops", str(args.runs)]
-        if args.output:
-            out = subprocess.run(cmd + ["--json"], capture_output=True,
-                                 text=True)
-            if out.returncode == 0:
-                with open(args.output, "w") as f:
-                    f.write(out.stdout)
-            sys.stdout.write(out.stdout)
-            sys.stderr.write(out.stderr)
-            raise SystemExit(out.returncode)
-        raise SystemExit(subprocess.call(cmd))
 
     if args.ops:
         ops = args.ops.split(",")

@@ -5,10 +5,10 @@ The reference funnels every execution mode through ``CachedOp``
 AND backward run as one engine-scheduled graph each.  Our eager training
 path, by contrast, still ran as a per-op vjp tape — forward dispatching
 op-by-op, ``autograd.backward`` pushing one XLA program per ``TapeNode``,
-and only the optimizer update fused (PR 1).  On chip every eager dispatch
-pays a host round-trip (docs/PERF.md: BatchNorm 82 ms plain vs 0.3 ms
-compiled), and the remaining ResNet reduce/copy texture (~37% of device
-time) only fuses away when XLA sees forward and backward in ONE program.
+and only the optimizer update fused (PR 1).  Every eager dispatch pays a
+host round-trip, and BatchNorm's reduce/copy texture only fuses away
+when XLA sees forward and backward in ONE program (docs/PERF.md
+"Compiled whole-train-step").
 
 :class:`TrainStep` (``Trainer.compile_step(net, loss_fn)``) closes that
 gap: loss-fn forward (via the same staging machinery that backs
@@ -96,8 +96,7 @@ __all__ = ["TrainStep", "enabled", "trace_count", "dispatch_count",
 # (re)traced, dispatches per compiled launch, hits/misses/evictions
 # track the shape-keyed program cache.  The module-level functions below
 # are views over that one shared surface (tools/check_dispatch_budget.py
-# and benchmark/eager_latency.py read them; the bar: 1 dispatch/step,
-# 0 retraces after warm-up).
+# reads them; the bar: 1 dispatch/step, 0 retraces after warm-up).
 _NS = _pstore.namespace("train_step")
 _DEFERRED_READ = _telemetry.counter(
     "cached_step.deferred_read",

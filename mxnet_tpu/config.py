@@ -276,10 +276,11 @@ declare("MXNET_PROGRAM_AOT", int, 1,
 declare("MXNET_EAGER_JIT_EXCLUDE", str, "mean,sum,prod,max,min",
         "Comma-set of op names kept OUT of the per-op eager jit cache "
         "(MXNET_EAGER_JIT): single-primitive reductions measured SLOWER "
-        "jitted than plain dispatch (docs/PERF.md: mean(axis) 0.62x on "
-        "chip — one primitive is already one dispatch, so the cache only "
-        "adds lookup overhead).  Override with your own list; empty "
-        "string re-admits every op.", cached=False)
+        "jitted than plain dispatch (mean(axis) 0.62x, measured through "
+        "the old remote relay; ROADMAP D6 still owes a run on a directly "
+        "attached chip — one primitive is already one dispatch, so the "
+        "cache only adds lookup overhead).  Override with your own list; "
+        "empty string re-admits every op.", cached=False)
 declare("MXNET_PAD_CHANNELS", int, 1,
         "MXU-alignment padding pass for staged convolutions (ops/nn.py "
         "Convolution, trace-time only): channel axes that miss the TPU "
@@ -725,45 +726,5 @@ declare("MXNET_PROFILER_AUTOSTART", bool, False,
 declare("MXNET_EXEC_BULK_EXEC_TRAIN", bool, True,
         "Accepted for parity; XLA whole-graph compilation subsumes "
         "engine op bulking", subsystem="engine")
-# bench.py knobs.  BENCH_MODEL/BENCH_TIMEOUT/BENCH_PROBE_TIMEOUT are read
-# raw (os.environ) by bench.py's parent, which never imports mxnet_tpu/jax
-# (a chip belongs to one process: the lane's), so they are declared here
-# for the generated docs; the post-import knobs go through config.get.
-declare("BENCH_MODEL", str, "all",
-        "bench.py lane selection: 'all' (every lane into one JSON line) "
-        "or one of <zoo-name>[_bf16|_int8] | bert | train_step | infer "
-        "| decode | pipeline | multichip | elastic",
-        subsystem="bench")
-declare("BENCH_BATCH", int, None, "bench.py batch size override",
-        subsystem="bench")
-declare("BENCH_STEPS", int, None, "bench.py timed step count",
-        subsystem="bench")
-declare("BENCH_IMG", int, 224, "bench.py image edge length",
-        validator=lambda v: v >= 8, subsystem="bench")
-declare("BENCH_SEQ", int, 128, "bench.py BERT sequence length",
-        validator=lambda v: v >= 1, subsystem="bench")
-declare("BENCH_LAYOUT", str, "NHWC",
-        "bench.py ResNet compute layout: NHWC (TPU-native default) or "
-        "NCHW (the reference texture); non-resnet lanes ignore it",
-        validator=lambda v: v in ("NHWC", "NCHW"), subsystem="bench")
-declare("BENCH_S2D", bool, False,
-        "bench.py ResNet lanes: space-to-depth stem rewrite (exact, "
-        "MLPerf trick).  Default OFF since the 2026-08-01 chip A/B: "
-        "XLA now handles the 7x7 stem well and s2d costs ~2.2% "
-        "(2,554 vs 2,611 img/s NHWC bs128); 1 re-enables",
-        subsystem="bench")
-declare("BENCH_ACCUM", int, 1,
-        "bench.py BERT gradient-accumulation factor",
-        validator=lambda v: v >= 1, subsystem="bench")
-declare("BENCH_TIMEOUT", float, 2700.0,
-        "bench.py watchdog (a separate process sharing stdout): emit the "
-        "completed lanes after this many seconds and kill the bench",
-        subsystem="bench")
-declare("BENCH_PARTIAL_PATH", str, None,
-        "bench.py: override for the side file where completed lanes "
-        "persist for the watchdog process", subsystem="bench")
-declare("BENCH_PROBE_TIMEOUT", float, 240.0,
-        "bench.py device-backend subprocess probe timeout (seconds)",
-        subsystem="bench")
 declare("GRAFT_NDEV", int, 8,
         "__graft_entry__ dryrun virtual device count", subsystem="testing")

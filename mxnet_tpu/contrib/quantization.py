@@ -450,7 +450,7 @@ def quantize_symbol(sym, params: Dict[str, Any],
             return got
         new_inputs = [(rewrite(src), i) for (src, i) in n.inputs]
         out = None
-        # quantized_conv implements the 2D NCHW/NHWC paths (the bench's
+        # quantized_conv implements the 2D NCHW/NHWC paths (the
         # channel-minor fast path quantizes natively); other ranks /
         # layouts stay fp32 rather than silently mis-lowering
         conv_ok = (n.op != "Convolution"

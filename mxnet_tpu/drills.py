@@ -95,8 +95,7 @@ the memoized reference run) and returns a report with the measured
 ``recovery_wall_s`` (process start -> first resumed step),
 ``steps_replayed``, ``drain_s``, and the restart's disk
 ``fresh_compiles`` (0 when the cache is warm — the PR-7 promise).
-``tools/check_recovery_budget.py`` gates all of it in CI; bench.py's
-``elastic`` lane stamps the numbers into the artifact.
+``tools/check_recovery_budget.py`` gates all of it in CI.
 
 Child entry: ``python -m mxnet_tpu.drills train|decode ...`` (the
 orchestrator builds the exact argv; children force ``JAX_PLATFORMS=cpu``
@@ -1173,10 +1172,9 @@ def _cmd_router_fleet(a) -> int:
 
 def _drill_telemetry_dir(root: str) -> str:
     """Where this drill's child processes flush their flight-recorder
-    shards (ISSUE 15): an outer ``MXNET_TELEMETRY_DIR`` (bench.py's
-    fleet dir) wins so the bench lane's merge sees drill children too;
-    otherwise a per-root directory the parent merges for its
-    merged-vs-observed assertions."""
+    shards (ISSUE 15): an outer ``MXNET_TELEMETRY_DIR`` wins so a
+    caller's fleet merge sees drill children too; otherwise a per-root
+    directory the parent merges for its merged-vs-observed assertions."""
     from mxnet_tpu import config as _config
 
     return _config.get("MXNET_TELEMETRY_DIR") \

@@ -38,7 +38,7 @@ __all__ = [
 
 # device->host reads performed by metric HOST paths (metrics bypassing
 # the device-accumulator path, or the path disabled): the loud fallback
-# counter — benchmark/pipeline_latency.py and the budget gate read it
+# counter — tests assert the device path leaves it at 0
 from . import telemetry as _telemetry  # noqa: E402
 
 _HOST_SYNC = _telemetry.counter(

@@ -775,8 +775,8 @@ def _check_int_bounds(key, shape):
                 f"index {k} is out of bounds for axis with size {d}")
 
 
-# operator dispatches since import: with fused.dispatch_count() this gives
-# benchmark/eager_latency.py the dispatches-per-step lane a denominator
+# operator dispatches since import: with fused.dispatch_count() this
+# counts an eager step's dispatches
 from .. import telemetry as _telemetry  # noqa: E402
 
 _INVOKE = _telemetry.counter(
@@ -913,7 +913,8 @@ def _attrs_key(v):
 
 # per-op cache opt-out (MXNET_EAGER_JIT_EXCLUDE): single-primitive
 # reductions measured SLOWER through the cache than plain dispatch
-# (docs/PERF.md chip table: mean(axis) 0.62x — one primitive is already
+# (mean(axis) 0.62x, measured through the old remote relay; ROADMAP D6
+# still owes a run on a directly attached chip — one primitive is already
 # one dispatch; the cache only adds lookup + executable-launch overhead).
 # Memoized on the raw string so the per-dispatch cost is one dict read.
 _EAGER_JIT_EXCLUDE_MEMO: tuple = (None, frozenset())

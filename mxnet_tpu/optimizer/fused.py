@@ -44,8 +44,7 @@ _FINITE_JIT: Dict[Any, Any] = {}
 
 # observability: fused.trace bumps when a group/finite-check program body
 # is (re)traced; fused.dispatch bumps per compiled-program launch.  Tests
-# assert re-trace stays at 0 across repeated step() calls and
-# benchmark/eager_latency.py reports dispatches per step.
+# assert re-trace stays at 0 across repeated step() calls.
 from .. import telemetry as _telemetry  # noqa: E402
 
 _TRACE = _telemetry.counter(

@@ -122,7 +122,7 @@ _WARNED_SHAPES: set = set()
 # placed leaf's actual sharding (shard_shape) — so an fsdp-sharded
 # layout reads ~1/N of the replicated one.  Recorded by the TrainStep
 # warmup (record_layout), surfaced as computed gauges in
-# telemetry.report() and stamped into the MULTICHIP bench lanes.
+# telemetry.report().
 _LAYOUT_BYTES = {"param": 0, "opt": 0}
 
 

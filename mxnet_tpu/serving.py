@@ -291,7 +291,7 @@ class ServingEngine:
             doc="ServingEngine per-instance counters",
             family="serving.engine")
         # load() fields double as registered computed gauges (ISSUE
-        # 17): balancer, autoscaler, and perf gate read one surface
+        # 17): balancer and autoscaler read one surface
         _telemetry.register_load_gauges(self, self._stats.prefix)
 
     # -- public ------------------------------------------------------------

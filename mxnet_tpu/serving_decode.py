@@ -174,7 +174,7 @@ class _DispatchGate:
 # process-global counters (family 'prefix'): sharing is a cross-pool
 # property of the workload, so unlike the per-instance kv_pool group
 # these are NOT instance-numbered — telemetry.merge sums them across
-# the fleet and the perf gate diffs them by exact name
+# the fleet under exact names
 _PREFIX_STATS = _telemetry.CounterGroup(
     "prefix", ("hit_blocks", "miss_blocks", "cow_forks", "evictions"),
     doc="content-addressed KV prefix cache (MXNET_PREFIX_CACHE)",
@@ -183,7 +183,7 @@ _PREFIX_STATS = _telemetry.CounterGroup(
 # speculative-decoding counters (family 'spec'): like prefix sharing,
 # acceptance is a property of the model PAIR and the workload, so the
 # family is process-global (not instance-numbered) — telemetry.merge
-# sums it across the fleet and check_perf_delta diffs exact names.
+# sums it across the fleet under exact names.
 # rounds = spec rounds completed (1 draft + 1 verify dispatch each);
 # proposed/accepted = draft tokens offered / surviving rejection
 # sampling; fallback_rounds = rounds the arbiter declined (cost table
@@ -196,7 +196,7 @@ _SPEC_STATS = _telemetry.CounterGroup(
     doc="speculative decoding (MXNET_SPEC_DECODE)", family="spec")
 
 # measured acceptance and amortization ride as computed gauges over the
-# same counters the perf gate diffs: acceptance_rate = accepted /
+# same counters: acceptance_rate = accepted /
 # proposed; tokens_per_target_dispatch = (accepted + rounds) / rounds
 # (each round costs ONE target-equivalent verify dispatch and yields
 # n_acc + 1 tokens) — the k-for-1 number the tentpole is judged on
@@ -680,10 +680,10 @@ class DecodeModel:
 class TinyCausalLM(DecodeModel):
     """Reference :class:`DecodeModel`: a small pre-LN-free causal
     transformer (learned token + position embeddings, multi-head
-    attention, ReLU MLP, untied output head) used by the parity tests,
-    the dispatch-budget gate, and the decode bench lanes.  Everything
-    is plain ``jnp`` on explicit parameter pytrees, so both entry
-    points trace into single fused programs."""
+    attention, ReLU MLP, untied output head) used by the parity tests
+    and the dispatch-budget gate.  Everything is plain ``jnp`` on
+    explicit parameter pytrees, so both entry points trace into single
+    fused programs."""
 
     def __init__(self, vocab: int = 64, d_model: int = 32,
                  n_layers: int = 2, n_heads: int = 2,
@@ -1018,8 +1018,8 @@ def eager_generate(model: DecodeModel, params, prompt: Sequence[int],
     """The one-request-at-a-time reference loop: a FULL forward over
     the tokens so far for every generated token (no KV cache, no
     batching, exact shapes) — the parity oracle for the continuous
-    batcher and the bench A/B baseline.  ``sampling`` runs the SAME
-    :func:`sample_token` the compiled programs trace, keyed by
+    batcher.  ``sampling`` runs the SAME :func:`sample_token` the
+    compiled programs trace, keyed by
     ``fold_in(PRNGKey(seed), position)`` — the seed-for-seed oracle
     for stochastic decode (``None`` / temperature 0 = greedy, the
     plain argmax, exactly as before)."""
@@ -1217,8 +1217,8 @@ class GenerativeEngine:
             doc=f"GenerativeEngine counters (model {self.name!r})",
             family="decode.engine")
         # the load() fields double as registered computed gauges
-        # (ISSUE 17): the autoscaler, dashboards, and check_perf_delta
-        # all read the SAME numbers the router balances on
+        # (ISSUE 17): the autoscaler and dashboards read the SAME
+        # numbers the router balances on
         _telemetry.register_load_gauges(self, self._stats.prefix)
         from . import engine as _engine
 

@@ -360,7 +360,7 @@ class ReplicaRouter:
             doc=f"ReplicaRouter counters (router {self.name!r})",
             family="serving.router")
         # fleet-lifecycle counters (ISSUE 17): membership and scaling
-        # events, one family the perf gate holds tolerances on
+        # events, one family a fleet merge sums
         self._fleet = _telemetry.CounterGroup(
             _telemetry.instance_name("router.fleet"),
             ("joins", "drains", "gone", "warm_programs", "scale_ups",

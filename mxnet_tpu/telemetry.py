@@ -68,7 +68,7 @@ those measurements ONE queryable, exportable system:
   into one fleet snapshot (cumulative counters summed, gauges kept
   per-process) and :func:`merge_chrome_trace` into one chrome trace
   with per-process lanes.  :func:`report` renders the one-call counter
-  table, bench.py stamps :func:`delta` per lane, and
+  table, :func:`delta` the counters a span of work moved, and
   ``python -m mxnet_tpu.telemetry`` is the on-box CLI
   (``report`` / ``trace <id>`` / ``merge <dir>``).
 
@@ -197,8 +197,8 @@ def register_load_gauges(engine, prefix: str) -> None:
     """Expose an engine's live ``load()`` fields — queue depth,
     in-flight occupancy, KV page-pool pressure — as computed gauges
     under its counter-group prefix (``decode.engine0.queue_depth``
-    …), so the replica router's balancer, the fleet autoscaler,
-    dashboards, and ``check_perf_delta`` all read the SAME numbers
+    …), so the replica router's balancer, the fleet autoscaler and
+    dashboards all read the SAME numbers
     (ISSUE 17).  Weakly bound: a closed or collected engine reads 0.0
     at snapshot time instead of pinning the instance alive."""
     import weakref

@@ -605,7 +605,8 @@ def compare_optimizer(opt1, opt2, shapes, dtype, w_stype="default",
 
 def check_speed(sym_or_fn, *args, n=20, **kwargs):
     """Steady-state seconds/call with a host-read fence (reference
-    check_speed; the fence discipline is bench.py's)."""
+    check_speed): a warm-up call, then the timed loop ends in a read of
+    its last result, so asynchronous dispatch cannot hide device time."""
     import time as _time
 
     fn = sym_or_fn

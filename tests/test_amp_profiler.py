@@ -143,8 +143,8 @@ def test_convert_hybrid_block():
 def test_convert_hybrid_block_rehomed_ctx():
     # convert_hybrid_block(ctx=...) re-homes the params; a hybridized call
     # on the new device must trace against the CALLER's ctx, not the
-    # process default (caught live: the bench's bf16 inference reference
-    # failed replica lookup after reset_ctx to the accelerator)
+    # process default (caught live: a bf16 inference reference failed
+    # replica lookup after reset_ctx to the accelerator)
     import jax
     import jax.numpy as jnp
 

@@ -255,9 +255,9 @@ def test_keyless_rng_ops_never_jitted(eager_jit):
 
 def test_reduction_opt_out_default_and_override(eager_jit, monkeypatch):
     """Single-primitive reductions stay OUT of the per-op cache by
-    default (docs/PERF.md: mean(axis) measured 0.62x through the cache
-    on chip) and the list is overridable through MXNET_EAGER_JIT_EXCLUDE
-    (config.py)."""
+    default (one primitive is already one dispatch, so the cache only
+    adds lookup overhead) and the list is overridable through
+    MXNET_EAGER_JIT_EXCLUDE (config.py)."""
     x = nd.array(onp.random.RandomState(2).randn(4, 6).astype(onp.float32))
     x.mean(axis=1)
     x.sum(axis=0)

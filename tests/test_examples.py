@@ -76,7 +76,7 @@ def test_autoencoder_example():
 @pytest.mark.slow     # 38 s alone, 51 s beside three other workers (PR 28)
 def test_bi_lstm_sort_example():
     # 140 biLSTM steps need ~6 min on the 1-core CI host and can exceed the
-    # default budget when the host is also driving a bench lane; the wider
+    # default budget when the host is also running other workers; the wider
     # timeout keeps this a completion test, not a speed test
     out = _run("example/bi-lstm-sort/bi_lstm_sort.py", "--steps", "140",
                timeout=900)

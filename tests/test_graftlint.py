@@ -272,7 +272,7 @@ def test_full_gate_subprocess_and_artifact():
     """`python -m tools.lint --all`: static rules + the fresh-process
     lock-order scenario (compiled train step + decode batch + preemption
     drain) exit 0, the acquisition graph is acyclic, and the JSON report
-    lands in benchmark/artifacts/ for bench rounds to diff."""
+    lands in benchmark/artifacts/."""
     artifact = os.path.join(REPO, "benchmark", "artifacts",
                             "graftlint.json")
     env = dict(os.environ)

@@ -175,7 +175,7 @@ def test_conv_bn_relu_folds_and_requantize_fuses():
 
 
 def test_quantize_net_nhwc_s2d_fast_path():
-    """The bench's channel-minor fast path quantizes natively: NHWC convs
+    """The channel-minor fast path quantizes natively: NHWC convs
     (incl. the space-to-depth stem) become quantized_conv with layout NHWC
     and the axis=3 BatchNorms still fold (reference quantized_conv.cc is
     NCHW-only; this build is layout-general so no relayout is needed)."""

@@ -1,4 +1,4 @@
-"""Fleet telemetry aggregation + the perf-regression gate (ISSUE 15).
+"""Fleet telemetry aggregation (ISSUE 15).
 
 Covers: (1) the flight recorder's atomic per-process shards —
 pid/rank-stamped names, write-then-rename (no torn finals, no litter),
@@ -9,11 +9,7 @@ per-process, events/spans come back process-stamped, torn shards and
 one lane per process plus cross-process flow linking by trace_id; (4)
 the ``MXNET_TELEMETRY_MAX_MB`` oldest-shard rotation (counted in
 ``telemetry.shards_rotated``); (5) the ``python -m mxnet_tpu.telemetry``
-CLI (report/trace/merge) and ``tools/telemetry_merge.py``; (6)
-``tools/check_perf_delta.py``: passes on the committed
-``BENCH_r04``/``BENCH_r05`` pair, FAILS an injected +1-retrace
-candidate naming the counter and the lane, honors reasoned waivers,
-rejects unreasoned ones, and its ``--self-test``.
+CLI (report/trace/merge) and ``tools/telemetry_merge.py``.
 """
 import json
 import os
@@ -21,14 +17,11 @@ import subprocess
 import sys
 import time
 
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from mxnet_tpu import telemetry  # noqa: E402
 
-import tools.check_perf_delta as perf_delta  # noqa: E402
 import tools.telemetry_merge as merge_tool  # noqa: E402
 
 
@@ -225,109 +218,3 @@ def test_telemetry_merge_tool(tmp_path):
     empty.mkdir()
     assert merge_tool.main([str(empty)]) == 1
 
-
-# ---------------------------------------------------------------------------
-# check_perf_delta
-# ---------------------------------------------------------------------------
-
-def _lane(metric, telem=None, **extra):
-    lane = {"metric": metric, "value": 1.0, "unit": "u"}
-    if telem is not None:
-        lane["telemetry"] = telem
-    lane.update(extra)
-    return lane
-
-
-def _artifact(tmp_path, name, lanes):
-    p = tmp_path / name
-    with open(p, "w") as f:
-        json.dump({"parsed": {"metric": lanes[0]["metric"],
-                              **lanes[0], "lanes": lanes}}, f)
-    return str(p)
-
-
-BASE_TEL = {"program_store.serving_decode.traces": 5,
-            "program_store.serving_decode.dispatches": 60,
-            "program_store.serving_decode.misses": 6,
-            "ndarray.host_sync": 12,
-            "decode.engine0.shed": 2,
-            "serving.router0.sheds": 1}
-
-
-def test_perf_delta_passes_on_committed_bench_pair(capsys):
-    rc = perf_delta.main(
-        ["--baseline", os.path.join(REPO, "BENCH_r04.json"),
-         "--candidate", os.path.join(REPO, "BENCH_r05.json")])
-    assert rc == 0
-    assert "PASS" in capsys.readouterr().out
-
-
-def test_perf_delta_injected_retrace_fails_naming_counter_and_lane(
-        tmp_path, capsys):
-    base = _artifact(tmp_path, "base.json",
-                     [_lane("decode_continuous_tokens_per_s",
-                            dict(BASE_TEL))])
-    cand_tel = dict(BASE_TEL)
-    cand_tel["program_store.serving_decode.traces"] += 1   # +1 retrace
-    cand = _artifact(tmp_path, "cand.json",
-                     [_lane("decode_continuous_tokens_per_s", cand_tel)])
-    rc = perf_delta.main(["--baseline", base, "--candidate", cand])
-    err = capsys.readouterr().err
-    assert rc == 1
-    assert "program_store.serving_decode.traces" in err    # the counter
-    assert "decode_continuous_tokens_per_s" in err         # the lane
-    assert "retrace" in err                                # the rule
-
-
-def test_perf_delta_tolerances_and_instance_normalization(tmp_path):
-    base = _artifact(tmp_path, "base.json",
-                     [_lane("m", dict(BASE_TEL))])
-    # within tolerance: +1 dispatch (slack 2), renumbered engine
-    # instance, one MORE shed inside 10%+2 slack
-    cand_tel = {"program_store.serving_decode.traces": 5,
-                "program_store.serving_decode.dispatches": 61,
-                "program_store.serving_decode.misses": 6,
-                "ndarray.host_sync": 13,
-                "decode.engine7.shed": 3,        # engine0 -> engine7
-                "serving.router2.sheds": 1}
-    cand = _artifact(tmp_path, "cand.json", [_lane("m", cand_tel)])
-    assert perf_delta.main(["--baseline", base,
-                            "--candidate", cand]) == 0
-    # far past tolerance: shed storm fails under the shed-rate rule
-    cand_tel2 = dict(cand_tel)
-    cand_tel2["decode.engine7.shed"] = 50
-    cand2 = _artifact(tmp_path, "cand2.json", [_lane("m", cand_tel2)])
-    assert perf_delta.main(["--baseline", base,
-                            "--candidate", cand2]) == 1
-
-
-def test_perf_delta_waivers_reasoned_only(tmp_path, capsys):
-    base = _artifact(tmp_path, "base.json",
-                     [_lane("m", dict(BASE_TEL))])
-    cand_tel = dict(BASE_TEL)
-    cand_tel["program_store.serving_decode.traces"] += 1
-    cand = _artifact(tmp_path, "cand.json", [_lane("m", cand_tel)])
-    waivers = tmp_path / "waivers.json"
-    with open(waivers, "w") as f:
-        json.dump({"waivers": [
-            {"lane": "m",
-             "counter": "program_store.serving_decode.traces",
-             "reason": "bucket grid intentionally grew this round"}]}, f)
-    rc = perf_delta.main(["--baseline", base, "--candidate", cand,
-                          "--waivers", str(waivers)])
-    out = capsys.readouterr().out
-    assert rc == 0 and "WAIVED" in out
-    # an unreasoned waiver is itself a gate failure
-    with open(waivers, "w") as f:
-        json.dump({"waivers": [
-            {"lane": "m",
-             "counter": "program_store.serving_decode.traces"}]}, f)
-    with pytest.raises(SystemExit):
-        perf_delta.main(["--baseline", base, "--candidate", cand,
-                         "--waivers", str(waivers)])
-
-
-def test_perf_delta_self_test_and_shipped_waiver_file():
-    assert perf_delta.main(["--self-test"]) == 0
-    shipped = perf_delta.load_waivers(perf_delta.WAIVER_PATH)
-    assert shipped == []            # ships empty, stays empty

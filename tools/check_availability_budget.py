@@ -58,8 +58,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 # the budget docs/ROBUSTNESS.md promises.  Seconds bounds are
 # CI-generous (a loaded runner must not flake); the drill REPORTS the
-# real measured numbers and bench.py's decode lane tracks them per
-# round.
+# real measured numbers.
 BUDGET = {
     "dropped": 0,
     "leaked_kv_pages": 0,

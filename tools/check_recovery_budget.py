@@ -38,7 +38,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 # the budget docs/ROBUSTNESS.md promises.  The seconds bounds are CI-
 # generous (a loaded runner must not flake) — the drill REPORTS the
-# real measured numbers; bench.py's elastic lane tracks them per round.
+# real measured numbers.
 BUDGET = {
     "graceful_steps_replayed": 0,
     "warm_recovery_fresh_compiles": 0,

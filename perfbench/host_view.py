@@ -24,7 +24,11 @@ What a reader author must know (beside ``scope_view.py``'s docstring):
   before the trace ends.  Where the trace shows FEWER runs than launches
   (the profiler lost a device event) the newest are matched to the newest
   and ``matched`` says ``tail``; a run that would start before its launch
-  does, or more runs than launches, gives ``None``.
+  does, by more than ``CLOCK_SLACK_NS``, or more runs than launches, gives
+  ``None``.  The slack is the error of laying the device trace over
+  ``time.time_ns``: the first traced run, which the chip starts as soon as
+  its launch hands it over, began 0.17 ms before to 1.04 ms after its
+  ``launch`` span's start in five delta-rule and Granite runs on a v5e.
 - **The classes of idle time.**  For a gap ``(a, b)`` of the worst chip's
   idle time, ended by a step program's run whose ``launch`` span ended at
   ``L``: ``(max(a, L), b)`` is ``launched`` (the host had handed the program
@@ -96,6 +100,7 @@ STEP_PROGRAMS = (("jit_mx_train_step__", "step"),
 PHASES = ("prep", "operands", "launch", "writeback", "gate")
 CLASSES = ("launched",) + PHASES + ("outside_step",)
 _EVERYTHING = (float("-inf"), float("inf"))
+CLOCK_SLACK_NS = 2_000_000
 _CACHE: Dict[Tuple[str, float], Optional[Dict[str, Any]]] = {}
 
 
@@ -126,7 +131,8 @@ def _match(runs, launches, window_end):
         return None, None
     how = "one_to_one" if len(launches) == len(runs) else "tail"
     mine = launches[len(launches) - len(runs):]
-    if any(run.start_ns < l["t0"] for run, l in zip(runs, mine)):
+    if any(run.start_ns < l["t0"] - CLOCK_SLACK_NS
+           for run, l in zip(runs, mine)):
         return None, None
     return mine, how
 

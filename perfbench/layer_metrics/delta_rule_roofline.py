@@ -2,12 +2,14 @@
 chunk products of one step (forward and backward, from shapes: the
 configuration's ``delta_rule_macs``, recomputation and the triangular
 inverse not counted) at the bf16 peak, over the device time a step under the
-rule's scope (``DeltaRule``), whatever implements the rule.  A chunk's
-products are 64 rows tall between elementwise decay masks and a sequential
-carry, so this reads low: it is the distance to a matrix unit that XLA's
-form of the rule cannot fill, and says by how much a kernel that kept a
-chunk in VMEM could shorten it.  The sizes and the sequence length are the
-cell's own, from the driver's ``obs``."""
+rule's scope (``DeltaRule``), whatever implements the rule.  On a TPU that
+scope holds the Pallas kernel pair (``pallas_kernels.delta_rule_chunks``:
+the forward call, its recomputation and one backward call a layer) and the
+decay sums around it; elsewhere XLA's chunked products.  A chunk's products
+are ``chunk_size`` rows tall between elementwise decay masks, a triangular
+solve and a sequential carry, so this reads low: it is the distance to a
+matrix unit that such products cannot fill.  The sizes and the sequence
+length are the cell's own, from the driver's ``obs``."""
 from perfbench import manifest, opcount, scope_view
 
 RULE = "DeltaRule"

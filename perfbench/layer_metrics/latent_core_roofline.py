@@ -3,10 +3,12 @@ value products of every latent-attention core of one step (forward and
 backward, from shapes: the configuration's ``latent_core_macs``, 20 heads x
 (256 + 256) at half the square; recomputation not counted) at the bf16 peak,
 over the device time a step under the core's scope (``LatentCore``).  The
-core is ``pallas_kernels.flash_attention_gqa`` at this shape, whose backward
-computes the scores twice more and whose forward runs a second time under
-``recompute_layers``: 11 products are run for the 6 counted.  The sizes and
-the sequence length are the cell's own, from the driver's ``obs``."""
+core is ``pallas_kernels.flash_attention_gqa`` at this shape, whose forward
+runs a second time under ``recompute_layers`` and whose one backward call
+computes the scores again: 9 products are run for the 6 counted (2 forward,
+2 recomputed, 5 backward), so about 67 is the kernels at full speed.  The
+sizes and the sequence length are the cell's own, from the driver's
+``obs``."""
 from perfbench import manifest, opcount, scope_view
 
 CORE = "LatentCore"

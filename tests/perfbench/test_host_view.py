@@ -35,6 +35,7 @@ The gaps of chip 0, 730 ns in all:
 The first two begin before run 1 does: the refill, 100 ns with 40
 launched; of the other 630 ns, 300 are launched.
 """
+import copy
 import json
 import os
 import time
@@ -246,16 +247,42 @@ def test_a_lost_device_event_matches_the_newest_launches(events):
         == WANT
 
 
+def _launch_after_its_run(spans):
+    """Run 3 starts at 1500: its launch now starts 100 ns after it."""
+    launch = next(s for s in spans if s["id"] == "s3l")
+    launch.update(t0_ns=START + 1600, t1_ns=START + 1620)
+    return spans
+
+
 @pytest.mark.parametrize("what", ["more_runs_than_launches",
                                   "a_run_before_its_launch"])
-def test_runs_that_cannot_be_matched_read_as_nothing(events, what):
+def test_runs_that_cannot_be_matched_read_as_nothing(events, what,
+                                                     monkeypatch):
     if what == "more_runs_than_launches":
         spans = _spans(lambda s: s["id"] != "s2l")
     else:
-        spans = _spans()
-        launch = next(s for s in spans if s["id"] == "s3l")
-        launch.update(t0_ns=START + 1600, t1_ns=START + 1620)
+        # by more than the two clocks' error, at this fixture's scale
+        monkeypatch.setattr(host_view, "CLOCK_SLACK_NS", 50)
+        spans = _launch_after_its_run(_spans())
     assert host_view.view(events, START, spans, steps=3) is None
+
+
+def test_a_run_inside_the_clocks_slack_is_its_launchs(events, monkeypatch):
+    """The device trace lies on ``time.time_ns`` to about a millisecond: a
+    run that begins less than ``CLOCK_SLACK_NS`` before its launch span is
+    still that launch's, and the gap it ends is the host's whole."""
+    monkeypatch.setattr(host_view, "CLOCK_SLACK_NS", 200)
+    v = host_view.view(events, START, _launch_after_its_run(_spans()),
+                       steps=3)
+    assert v["matched"] == "one_to_one"
+    classes = {c: round(s / NS, 6)
+               for c, s in _gap(v, 1410)["class_s"].items()}
+    assert classes == {"operands": 10, "writeback": 10, "outside_step": 70}
+    # and at the chip's slack, the fixture's nanoseconds are well inside it
+    monkeypatch.undo()
+    assert host_view.CLOCK_SLACK_NS == 2_000_000
+    assert host_view.view(events, START, _launch_after_its_run(_spans()),
+                          steps=3)["matched"] == "one_to_one"
 
 
 @pytest.fixture
@@ -361,19 +388,47 @@ def test_the_readers_read_nothing(traced_process, tmp_path, monkeypatch, how):
 # ---------------------------------------------------------------------------
 # the entries, and the readers on rehearsed cells
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("name", list(ROWS))
-def test_a_new_entry_is_its_row_of_the_issue(name):
-    bench = manifest.load_benchmark()
-    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+def _holds_the_row(per_layer, name):
+    entry = next(m for m in per_layer if m["name"] == name)
     assert (entry["unit"], entry["better"], entry["source"], entry["layer"],
             entry["moves"]) == ROWS[name]
+    # every cell trains through a compiled step: with no list of cells the
+    # entry reaches each of them, those added later too
     assert set(entry) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-    # every cell trains through a compiled step: each reports them
-    assert entry["workloads"] == [w["name"] for w in bench["workloads"]][:7]
-    # the additions stand at the end of the list, in the table's order
-    assert [m["name"] for m in bench["per_layer"]][-8:] == list(ROWS)
+                          "moves"}
+    # the eight stand together, in the table's order, anywhere in the list:
+    # entries appended after them leave them where they are
+    names = [m["name"] for m in per_layer]
+    first = names.index(next(iter(ROWS)))
+    assert names[first:first + len(ROWS)] == list(ROWS)
     assert callable(manifest.load_module("layer_metrics", name).read)
+
+
+@pytest.mark.parametrize("name", list(ROWS))
+def test_a_new_entry_is_its_row_of_the_issue(name):
+    _holds_the_row(manifest.load_benchmark()["per_layer"], name)
+    # the two cells that no list of the eight ever named report them too
+    for cell in ("olmo_hybrid_train_s8k", "granite_h_micro_train_s8k"):
+        assert name in {m["name"] for m in manifest.resolve(cell).per_layer}
+
+
+@pytest.mark.parametrize("name", list(ROWS))
+def test_an_entry_appended_after_them_is_taken(name):
+    """The next change to the benchmark appends its entries at the end of
+    ``per_layer`` (an entry inserted in front of another is an edit of the
+    one it displaces): the rows still hold with one more behind them."""
+    per_layer = copy.deepcopy(manifest.load_benchmark()["per_layer"])
+    per_layer.append({"name": "kernel.window_core_share", "unit": "%",
+                      "better": "lower", "source": "device_trace",
+                      "layer": "kernels", "moves": "mfu",
+                      "workloads": ["olmo_hybrid_train_s8k"]})
+    _holds_the_row(per_layer, name)
+    # ... and an entry slipped in among them breaks the run
+    names = [m["name"] for m in per_layer]
+    per_layer.insert(names.index(name) + 1, per_layer.pop())
+    if name != list(ROWS)[-1]:
+        with pytest.raises(AssertionError):
+            _holds_the_row(per_layer, name)
 
 
 @pytest.mark.parametrize("cell", ["bert_base_train_s128",

@@ -277,6 +277,11 @@ def test_rehearsal_of_a_traced_run_reports_the_accepted_counters(capsys):
     # trace, have nothing to read and the line leaves them out
     assert not set(NEW_READERS) & set(got)
     assert "expert layers" in out.err and "'moe.rows_overflow': 0.0" in out.err
+    # the routing counters, which the expert layers share with the
+    # state-space cell's, are in the line
+    assert got["moe.rows_overflow"]["value"] == 0
+    assert 0 < got["moe.rows_held_share"]["value"] < 8
+    assert got["moe.load_max_over_mean"]["value"] >= 1
 
 
 def test_the_trace_readers_on_a_view_by_hand(monkeypatch):

@@ -326,10 +326,25 @@ def test_the_trace_readers_on_a_view_by_hand(monkeypatch):
 
 @pytest.mark.parametrize("name", NEW_READERS)
 def test_a_new_reader_is_in_place_without_its_entry(name):
-    """The readers are here, their ``per_layer`` entries are not:
-    ``test_host_view.py`` takes none after the eight host-phase entries, so
-    a later change to the benchmark appends them (``PERF.md`` section 7 has
-    them)."""
+    """Each reader is in place, and so is its ``per_layer`` entry, appended
+    at the end of the list: a device-trace share of the kernels layer that
+    moves ``mfu`` in this cell (the two rooflines in the state-space cell
+    too, whose scan and core they count from its own configuration)."""
     assert callable(manifest.load_module("layer_metrics", name).read)
-    assert name not in {m["name"] for m in
-                        manifest.load_benchmark()["per_layer"]}
+    entry = next(m for m in manifest.load_benchmark()["per_layer"]
+                 if m["name"] == name)
+    assert (entry["unit"], entry["source"], entry["layer"], entry["moves"]) \
+        == ("%", "device_trace", "kernels", "mfu")
+    roofline = name.endswith("_roofline")
+    assert entry["better"] == ("higher" if roofline else "lower")
+    assert entry["workloads"] == (["nemotron_h_train_s8k", CELL] if roofline
+                                  else [CELL])
+
+
+@pytest.mark.parametrize("name", SHARED_READERS)
+def test_a_shared_reader_names_this_cell(name):
+    """The accepted readers that read this cell through the shared classes
+    list it beside the state-space cell."""
+    assert name in {m["name"] for m in manifest.resolve(CELL).per_layer}
+    assert name in {m["name"] for m in
+                    manifest.resolve("nemotron_h_train_s8k").per_layer}

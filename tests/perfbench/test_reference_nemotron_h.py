@@ -362,6 +362,13 @@ def test_the_trace_readers_on_a_view_by_hand(monkeypatch):
     expert_ops = 6 * 4 * 3000 * 9_977_856
     assert read("expert_matmul_roofline") == pytest.approx(
         100 * expert_ops / 197e12 / 4e-3)
+    # the rooflines entered for the Granite cell count this cell's layers:
+    # four scan layers (``M``) over ``SsdScan``, one core (``*``) at half
+    # its square over the attention block itself
+    assert read("scan_roofline") == pytest.approx(read("ssd_scan_roofline"))
+    core_ops = 6 * c.config_module.attention_core_macs(c.sizes, 8192)
+    assert read("causal_core_roofline") == pytest.approx(
+        100 * core_ops / 197e12 / 10e-3)
     for name in NEW_READERS:
         value = read(name) if NEW_READERS[name]["source"] == "device_trace" \
             else 0.0

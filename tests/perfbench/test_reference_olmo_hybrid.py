@@ -244,11 +244,18 @@ NEW_READERS = ("kernel.delta_mixer_share", "kernel.delta_rule_share",
 
 @pytest.mark.parametrize("name", NEW_READERS)
 def test_a_new_reader_is_in_place_for_its_entry(name):
-    """The readers are here, their ``per_layer`` entries are not: PR 38's
-    check refused five entries in front of PR 35's eight, and
-    ``test_host_view.py`` refuses any after them, so a ``benchmark`` issue
-    appends the five (``PERF.md`` section 7 has them) with that pin relaxed."""
+    """Each reader has its ``per_layer`` entry, appended at the end of the
+    list: a device-trace share of the kernels layer that moves ``mfu`` and
+    names this cell alone."""
     assert callable(manifest.load_module("layer_metrics", name).read)
+    entry = next(m for m in manifest.load_benchmark()["per_layer"]
+                 if m["name"] == name)
+    assert (entry["unit"], entry["source"], entry["layer"], entry["moves"],
+            entry["workloads"]) == ("%", "device_trace", "kernels", "mfu",
+                                    [CELL])
+    assert entry["better"] == ("higher" if name.endswith("_roofline")
+                               else "lower")
+    assert name in {m["name"] for m in manifest.resolve(CELL).per_layer}
 
 
 def test_rehearsal_of_a_traced_run_goes_through_the_new_driver(capsys):

@@ -212,6 +212,25 @@ def test_readers_on_the_toy_trace(traced_process, metric, want):
     assert read(traced_process) == pytest.approx(want)
 
 
+@pytest.mark.parametrize("norm", sorted(
+    n for n in dir(mx.gluon.nn)
+    if "Norm" in n and isinstance(getattr(mx.gluon.nn, n), type)))
+def test_every_norm_class_counts_where_it_is_innermost(monkeypatch, norm):
+    """A decoder's norms are ``RMSNorm`` blocks: each normalisation class of
+    ``gluon.nn`` (the scope is the class's own name) counts as the innermost
+    block of a row, and none counts where a block of its own stands below
+    it."""
+    layer = ["NemotronHForCausalLM", "NemotronHModel", "NemotronHBlock"]
+    rows = [(layer + [norm], 3e-3), (layer + ["NemotronHAttention"], 5e-3),
+            (layer + [norm, "Dense"], 1e-3), (layer + ["Dense"], 1e-3)]
+    view = {"steps": 1, "busy_s": 0.1,
+            "rows": [{"pass": "forward", "classes": c, "step_scope": None,
+                      "s": s} for c, s in rows]}
+    monkeypatch.setattr(scope_view, "traced", lambda obs: view)
+    read = manifest.load_module("layer_metrics", "kernel.norm_share").read
+    assert read({"trace": {"steps": 1}}) == pytest.approx(3.0)
+
+
 def test_the_view_is_cached_written_and_rendered(traced_process, trace_file):
     v = scope_view.traced(traced_process)
     assert scope_view.traced(traced_process) is v       # one reduction
